@@ -38,14 +38,13 @@ from collections import OrderedDict
 from functools import partial as _partial
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.conf import bool_conf, conf, int_conf
+from spark_rapids_tpu.conf import bool_conf, int_conf
 from spark_rapids_tpu.obs.registry import get_registry
 
 __all__ = ["fragment_key", "fingerprint", "get_or_build", "shared_jit",
            "instrument", "SharedJit", "cache_info", "reset_cache",
            "mesh_key_part",
-           "FUSION_ENABLED", "FUSION_MIN_OPS", "FUSION_DONATE",
-           "COMPILE_CACHE_DIR"]
+           "FUSION_ENABLED", "FUSION_MIN_OPS", "FUSION_DONATE"]
 
 FUSION_ENABLED = bool_conf(
     "spark.rapids.sql.fusion.enabled", True,
@@ -73,15 +72,6 @@ FUSION_DONATE = bool_conf(
     "stage cannot replay/split that batch and surfaces an actionable "
     "error instead; set false to trade buffer reuse for full "
     "split-and-retry coverage (docs/tuning-guide.md).")
-
-COMPILE_CACHE_DIR = conf(
-    "spark.rapids.sql.compile.cacheDir", "",
-    "When set, force the persistent XLA compilation cache ON rooted at "
-    "this directory (overriding spark.rapids.tpu.compilationCache.* "
-    "including its XLA:CPU auto-off), so cold sessions start warm: a "
-    "fragment compiled by ANY past process on this machine loads from "
-    "disk instead of recompiling. Empty (default) defers to the "
-    "spark.rapids.tpu.compilationCache.enabled mode.")
 
 COMPILE_CACHE_MAX_ENTRIES = int_conf(
     "spark.rapids.sql.compile.cacheMaxEntries", 1024,

@@ -190,13 +190,23 @@ def all_gather_batch(b: ColumnBatch, p: int, axis: str) -> ColumnBatch:
     return dk.compact(gb, real)
 
 
+def _mesh_devices(size: int):
+    """The first ``size`` devices; a configured mesh wider than the
+    devices present raises instead of quietly running single-device."""
+    devs = jax.devices()
+    if len(devs) < size:
+        raise RuntimeError(
+            f"spark.rapids.tpu.mesh.deviceCount={size} but only "
+            f"{len(devs)} device(s) are present; refusing to run a "
+            "configured mesh on fewer devices")
+    return devs[:size]
+
+
 def mesh_for(ctx: ExecCtx, size: int, axis_name: str = "data"):
-    """The ctx-cached 1-D device mesh, or None if < size devices exist."""
+    """The ctx-cached 1-D device mesh over the first ``size`` devices."""
     key = ("mesh", size, axis_name)
     if key not in ctx.cache:
-        devs = jax.devices()
-        ctx.cache[key] = (make_mesh(size, axis_name, devs[:size])
-                          if len(devs) >= size else None)
+        ctx.cache[key] = make_mesh(size, axis_name, _mesh_devices(size))
     return ctx.cache[key]
 
 
@@ -354,8 +364,7 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
     Device plan per shard: pre-project -> partial sorted group-by ->
     all-to-all exchange of buffer rows by key hash -> merge group-by ->
     final projection.  Falls back to a complete-mode
-    :class:`HashAggregateExec` on the host backend, when fewer devices
-    than ``mesh_size`` exist, or on empty input.
+    :class:`HashAggregateExec` on the host backend or on empty input.
     """
 
     def __init__(self, group_exprs: Sequence[Expression],
@@ -483,7 +492,7 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
         batches = list(drain_partitions(ctx, self.children[0]))
         mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
         t0 = None
-        if mesh is not None and batches:
+        if batches:
             try:
                 _check_slice_fault(ctx, "meshagg", mesh)
                 shards = place_shards(batches, self.mesh_size)
@@ -633,7 +642,7 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
     def _fallback_outputs(self, ctx: ExecCtx):
         """Single-device recompute from lineage: the in-process exchange
         over the same child and keys — also the degenerate path when
-        the mesh never existed or the child produced nothing."""
+        the child produced nothing."""
         he = self._host_exchange()
         return ("host", [list(he.partition_iter(ctx, pid))
                          for pid in range(self._num_parts)])
@@ -663,7 +672,7 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
         batches = drain_cached(ctx, self.children[0])
         mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
         t0 = None
-        if mesh is not None and batches:
+        if batches:
             try:
                 _check_slice_fault(ctx, "meshex", mesh)
                 shards = place_shards(batches, self.mesh_size)
@@ -773,13 +782,7 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
 
     # -- hooks ---------------------------------------------------------
     def _shard_devices(self, ctx: ExecCtx):
-        devs = jax.devices()
-        if len(devs) < self.mesh_size:
-            # degrade like mesh_for/MeshAggregateExec: with fewer real
-            # devices than the configured mesh, run single-device so a
-            # downstream fallback consumer never sees mixed placements
-            return devs[:1]
-        return devs[:self.mesh_size]
+        return _mesh_devices(self.mesh_size)
 
     def _mesh_shards(self, ctx: ExecCtx):
         def make():

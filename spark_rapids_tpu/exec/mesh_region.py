@@ -203,7 +203,7 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
     def _fallback_outputs(self, ctx: ExecCtx):
         """Single-device recompute from lineage: the in-process global
         sort over the same child — also the degenerate path when the
-        mesh never existed or the child produced nothing."""
+        child produced nothing."""
         out = [list(self._single_exec().partition_iter(ctx, 0))]
         out += [[] for _ in range(self.mesh_size - 1)]
         return out
@@ -213,7 +213,7 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
         batches = list(drain_partitions(ctx, self.children[0]))
         mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
         t0 = None
-        if mesh is not None and batches:
+        if batches:
             try:
                 _check_slice_fault(ctx, "meshsort", mesh)
                 shards = place_shards(batches, self.mesh_size)
@@ -366,8 +366,8 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
 
     def _fallback_outputs(self, ctx: ExecCtx):
         """Single-device recompute from lineage: the in-process window
-        over the same child — also the degenerate path when the mesh
-        never existed or the child produced nothing."""
+        over the same child — also the degenerate path when the
+        child produced nothing."""
         out = [list(WindowExec.partition_iter(self, ctx, 0))]
         out += [[] for _ in range(self.mesh_size - 1)]
         return out
@@ -377,7 +377,7 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
         batches = list(drain_partitions(ctx, self.children[0]))
         mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
         t0 = None
-        if mesh is not None and batches:
+        if batches:
             try:
                 _check_slice_fault(ctx, "meshwindow", mesh)
                 shards = place_shards(batches, self.mesh_size)
@@ -690,12 +690,12 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
         from spark_rapids_tpu.exec.core import drain_partitions
         mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
         chained = None
-        if mesh is not None and ctx.conf.get(MESH_REGION_CHAINING):
+        if ctx.conf.get(MESH_REGION_CHAINING):
             chained = self._chained_shards(ctx)
         batches = chained if chained is not None \
             else list(drain_partitions(ctx, self.children[0]))
         t0 = None
-        if mesh is not None and batches:
+        if batches:
             try:
                 _check_slice_fault(ctx, "meshregion", mesh)
                 shards = chained if chained is not None \
@@ -721,7 +721,7 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
             except Exception as err:
                 _reraise_unless_slice_lost(err)
                 t0 = time.perf_counter()
-        # lost slice / no mesh / empty input: the terminal's own
+        # lost slice / empty input: the terminal's own
         # fallback recomputes through the intact member chain — a join
         # member's island path re-materializes BOTH its sides, so the
         # whole region lineage (build subtrees included) replays

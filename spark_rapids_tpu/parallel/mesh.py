@@ -24,17 +24,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
 
-# jax moved shard_map out of jax.experimental in 0.6; support both homes.
-# The experimental version's replication checker chokes on some
-# multi-result primitives (its rule table returns None), so turn it off
-# there — it is a static sanity check, not part of program semantics.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version dependent
-    from functools import partial as _partial
-
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-    shard_map = _partial(_exp_shard_map, check_rep=False)
+shard_map = jax.shard_map
 
 __all__ = ["make_mesh", "shard_batches", "unshard_batch", "split_shards",
            "local_view", "stacked_spec", "shard_map"]

@@ -1,0 +1,166 @@
+"""Compile the main path's programs for a DESCRIBED v5e (no chip attached).
+
+The TPU compiler is installed alongside JAX and compiles for a topology
+that is described, not present — so what the chip's compiler would
+refuse (64-bit bitcasts, unsupported sorts, programs that do not fit)
+is refused here, at no chip time.  Nothing runs: a passing compile says
+nothing about results or times.
+
+This is the only file that describes the chip.  The topology is touched
+only inside the module-scoped fixture below (never at import, in a
+``skipif``/``parametrize`` argument or in conftest): only one process
+may load the TPU library, and every xdist worker imports every file.
+Capacity 2^14 keeps each compile to seconds; the 2^20 programs of a real
+q6 are sized by hand before a chip call (PERF.md).
+"""
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from spark_rapids_tpu import types as T
+from spark_rapids_tpu.columnar import batch as B
+from spark_rapids_tpu.columnar.batch import ColumnBatch
+
+CAP = 1 << 14
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip compile can be written to the persistent cache
+    # but never read back without a chip: keep it out of the way
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, *args):
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    compiled = jitted.lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+def _sales_batch(n: int = 900, cap: int = CAP) -> ColumnBatch:
+    import __graft_entry__ as g
+    return g._make_batch(n, cap)
+
+
+def test_query_step_compiles_for_v5e(one_chip):
+    """filter -> compact -> sorted_group_by: the q6-shaped step."""
+    import __graft_entry__ as g
+    _compile(g.query_step, _shapes(_sales_batch(), one_chip))
+
+
+def test_unpack_decode_compiles_for_v5e(one_chip, monkeypatch):
+    """The packed H2D unpack + wire-codec decode program of a scanned
+    batch (nullable int32 keys, an s64, an f64, a dictionary string)."""
+    import pyarrow as pa
+    rng = np.random.default_rng(7)
+    n = CAP - 100
+    nulls = rng.random(n) < 0.05
+    rb = pa.record_batch({
+        "ss_sold_date_sk": pa.array(
+            rng.integers(2450000, 2453000, n).astype(np.int32), mask=nulls),
+        "ss_item_sk": pa.array(rng.integers(1, 18000, n).astype(np.int32)),
+        "ss_ticket_number": pa.array(
+            rng.integers(1, 1 << 40, n).astype(np.int64)),
+        "ss_sales_price": pa.array(np.round(rng.uniform(0, 200, n), 2)),
+        "ca_state": pa.array(rng.choice(["CA", "TX", "NY", "WA"], n)),
+    })
+    seen = {}
+    real = B._packed_unpack_cached
+
+    def spy(spec):
+        program = real(spec)
+
+        def call(bufs):
+            seen["program"], seen["bufs"] = program, bufs
+            return program(bufs)
+        return call
+    monkeypatch.setattr(B, "_packed_unpack_cached", spy)
+    ColumnBatch.from_arrow(rb, capacity=CAP, codec=True)
+    _compile(seen["program"].fn, _shapes(seen["bufs"], one_chip))
+
+
+def _keyed_batch(n: int, cap: int, seed: int) -> ColumnBatch:
+    from spark_rapids_tpu.host.batch import HostBatch
+    rng = np.random.default_rng(seed)
+    schema = T.Schema([
+        T.StructField("k", T.LongType(), True),
+        T.StructField("state", T.StringType(), True),
+        T.StructField("v", T.DoubleType(), True)])
+    return HostBatch.from_pydict({
+        "k": rng.integers(1, 5000, n).astype(np.int64),
+        "state": [("CA", "TX", "NY", "WA")[i % 4] for i in range(n)],
+        "v": rng.uniform(0, 100, n),
+    }, schema).to_device(capacity=cap)
+
+
+def test_join_build_and_probe_compiles_for_v5e(one_chip):
+    """Sort-based build + searchsorted probe + gather on s64 keys."""
+    from spark_rapids_tpu.ops.join import (build_prepare_fast,
+                                           gather_join_output,
+                                           join_indices_from_probe,
+                                           probe_fast)
+    lb, rb = _keyed_batch(900, CAP, 1), _keyed_batch(300, CAP >> 2, 2)
+    schema = T.Schema(list(lb.schema) + list(rb.schema))
+
+    def join_step(left, right):
+        prep = build_prepare_fast(right, 0)
+        probe, total = probe_fast(left, 0, *prep, "inner")
+        plan = join_indices_from_probe(left.capacity, probe, "inner", CAP)
+        return gather_join_output(left, right, *plan, schema, True), total
+    _compile(join_step, _shapes(lb, one_chip), _shapes(rb, one_chip))
+
+
+def test_string_key_sort_compiles_for_v5e(one_chip):
+    """lax.sort keyed on a string column (padded byte matrix + length),
+    s64 and f64 payload gathered behind it."""
+    from spark_rapids_tpu.ops.sort import SortOrder, sort_batch
+    b = _keyed_batch(900, CAP, 3)
+    orders = [SortOrder(1, True)]
+    _compile(lambda x: sort_batch(x, orders), _shapes(b, one_chip))
+
+
+def test_distributed_groupby_compiles_for_2x2_mesh(topo):
+    """partial group-by -> all-to-all -> merge as ONE shard_map program
+    over the four described devices."""
+    import __graft_entry__ as g
+    from jax.sharding import Mesh
+    from spark_rapids_tpu.parallel.mesh_shuffle import \
+        make_distributed_groupby
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    step = make_distributed_groupby(mesh, g.SCHEMA, [0], g._SPECS)
+    local = _sales_batch(cap=CAP >> 2)   # x4 shards: the same rows in all
+    stacked = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            (4,) + a.shape, a.dtype,
+            sharding=NamedSharding(mesh, P("data"))), local)
+    compiled = _compile(step.fn, stacked)
+    assert "all-to-all" in compiled.as_text()
