@@ -26,6 +26,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar import wirecodec as wc
 from spark_rapids_tpu.columnar.column import DeviceColumn, round_string_width
+from spark_rapids_tpu.obs.registry import get_registry
 
 __all__ = ["ColumnBatch", "round_capacity"]
 
@@ -197,6 +198,9 @@ class _PackBuilder:
             self.groups[k][0] if len(self.groups[k]) == 1
             else np.concatenate(self.groups[k]) for k in gkeys)
         dev_bufs = tuple(jax.device_put(b) for b in host_bufs)
+        get_registry().inc_many((
+            ("h2d_calls", len(host_bufs)),
+            ("h2d_bytes", sum(b.nbytes for b in host_bufs))))
         spec = (self.capacity, gkeys, tuple(self.leaves),
                 tuple(self.col_specs), nr, ip)
         arrays = _packed_unpack_cached(spec)(dev_bufs)
@@ -272,7 +276,7 @@ def _packed_unpack_cached(spec):
     # other engine compile/dispatch on CPU (compile_cache guard); bound
     # lazily — columnar/ sits below exec/
     from spark_rapids_tpu.exec.compile_cache import instrument
-    return instrument(jax.jit(unpack))
+    return instrument(jax.jit(unpack), "batch_unpack")
 
 # Arrow<->device conversions are serialized AND pyarrow's internal pool
 # is pinned to one thread (runtime.pin_arrow_threads): pyarrow compute
@@ -350,11 +354,16 @@ class ColumnBatch:
         return ColumnBatch(columns, self.num_rows, schema,
                            known_rows=self.known_rows)
 
-    def host_num_rows(self) -> int:
+    def host_num_rows(self, op: str = "fetch@ColumnBatch.host_num_rows") \
+            -> int:
         """Materialize the row count on host (sync point); cached into
-        ``known_rows`` so a later metrics read is free."""
+        ``known_rows`` so a later metrics read is free.  ``op`` names
+        the fetch's span (``fetch@<Operator>Exec`` where the caller is
+        an operator)."""
         if self.known_rows is None:
-            self.known_rows = int(jax.device_get(self.num_rows))
+            # bound at call time: columnar/ sits below exec/
+            from spark_rapids_tpu.exec.core import fetch_to_host
+            self.known_rows = int(fetch_to_host(self.num_rows, op))
         return self.known_rows
 
     # ------------------------------------------------------------------
@@ -414,9 +423,11 @@ class ColumnBatch:
         """
         import pyarrow as pa
         # one device_get for num_rows + leaves (one round trip, not two)
-        n, host_cols = jax.device_get(
+        from spark_rapids_tpu.exec.core import fetch_to_host
+        n, host_cols = fetch_to_host(
             (self.num_rows,
-             [(c.data, c.validity, c.lengths) for c in self.columns]))
+             [(c.data, c.validity, c.lengths) for c in self.columns]),
+            "fetch@ColumnBatch.to_arrow")
         n = int(n)
         with _arrow_guard():
             return self._to_arrow_locked(n, host_cols)

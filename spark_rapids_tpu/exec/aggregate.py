@@ -21,7 +21,8 @@ from typing import Iterator, Sequence
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.exec.core import (ExecCtx, PlanNode,
-                                        RequireSingleBatch, TargetSize)
+                                        RequireSingleBatch, TargetSize,
+                                        fetch_to_host)
 from spark_rapids_tpu.expr.aggregates import AggregateFunction
 from spark_rapids_tpu.expr.core import (Alias, BoundReference, Expression,
                                         bind, eval_device, eval_host,
@@ -32,6 +33,9 @@ from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops.segmented import AggSpec, sorted_group_by
 
 __all__ = ["HashAggregateExec"]
+
+#: span of this operator's blocking count fetches (exec/core.fetch_to_host)
+_FETCH = "fetch@HashAggregateExec"
 
 
 def _strip_alias(e: Expression) -> Expression:
@@ -325,9 +329,9 @@ class HashAggregateExec(PlanNode):
             # never observe a partially-initialized triple (the cached
             # value is the complete immutable triple)
             self._jits = cc.get_or_build(key, lambda: (
-                cc.instrument(jax.jit(update)),
-                cc.instrument(jax.jit(merge)),
-                cc.instrument(jax.jit(final))))
+                cc.instrument(jax.jit(update), "agg_update"),
+                cc.instrument(jax.jit(merge), "agg_merge"),
+                cc.instrument(jax.jit(final), "agg_final")))
         return self._jits
 
     # pending partial buffers merge once their summed capacity crosses
@@ -373,7 +377,7 @@ class HashAggregateExec(PlanNode):
             cat = _relabel_d(ctx.dispatch(dk.concat_batches, parts),
                              self._buffer_schema)
             merged = ctx.dispatch(merge_jit, cat)
-            ng = merged.host_num_rows()
+            ng = merged.host_num_rows(_FETCH)
             cap = round_capacity(max(int(ng), 1))
             merged = ctx.dispatch(dk.shrink_capacity, merged, cap)
             parts = [merged]
@@ -391,7 +395,6 @@ class HashAggregateExec(PlanNode):
         # updates from the sources through the splitting retry scope,
         # and the cross-batch merge makes the extra partial buffers
         # semantically free.
-        import jax as _jax
         import jax.numpy as _jnp
         from spark_rapids_tpu.memory.catalog import (SpillableColumnarBatch,
                                                      SpillPriority)
@@ -416,10 +419,10 @@ class HashAggregateExec(PlanNode):
 
             def sync_counts():
                 if len(chunk) == 1:
-                    return [chunk[0][1].host_num_rows()]
+                    return [chunk[0][1].host_num_rows(_FETCH)]
                 # enginelint: disable=RL003 (one stacked transfer for the whole chunk; this IS the batched sync)
-                return list(_jax.device_get(ctx.dispatch(
-                    _jnp.stack, [p.num_rows for _s, p in chunk])))
+                return list(fetch_to_host(ctx.dispatch(
+                    _jnp.stack, [p.num_rows for _s, p in chunk]), _FETCH))
 
             ngs = ctx.retry_sync(sync_counts, redo=redo, op="agg_flush")
             for (src, part), ng in zip(chunk, ngs):

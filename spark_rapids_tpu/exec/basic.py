@@ -28,14 +28,14 @@ __all__ = ["LocalScanExec", "ProjectExec", "FilterExec", "RangeExec",
            "UnionExec", "LocalLimitExec", "GlobalLimitExec"]
 
 
-@_guarded_jit(static_argnames=("cap",))
+@_guarded_jit("monotonic_id", static_argnames=("cap",))
 def _jit_miid(mask, cap: int, base):
     import jax.numpy as jnp
     data = jnp.where(mask, base + jnp.arange(cap, dtype=jnp.int64), 0)
     return DeviceColumn(data, mask, T.LongType())
 
 
-@_guarded_jit(static_argnames=("cap",))
+@_guarded_jit("spark_partition_id", static_argnames=("cap",))
 def _jit_spid(mask, cap: int, pid):
     import jax.numpy as jnp
     data = jnp.where(mask, pid.astype(jnp.int32), 0)
@@ -151,7 +151,7 @@ class ProjectExec(PlanNode):
             self._project_jit = cc.shared_jit(
                 cc.fragment_key("project", tuple(self._bound), self._schema,
                                 self.children[0].output_schema),
-                project)
+                project, name="project_batch")
         return self._project_jit
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
@@ -293,7 +293,7 @@ class FilterExec(PlanNode):
             self._filter_jit = cc.shared_jit(
                 cc.fragment_key("filter", self._cond,
                                 self.children[0].output_schema),
-                filt)
+                filt, name="filter_batch")
         return self._filter_jit
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:

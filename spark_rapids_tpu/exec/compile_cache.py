@@ -24,7 +24,9 @@ the same query compiles nothing" a testable invariant (ci/premerge.sh).
 
 Counters (MetricsRegistry): ``fusion_cache_hits`` / ``fusion_cache_misses``
 move per fragment-key lookup; ``compile_count`` / ``compile_wall_s`` per
-first invocation of a new input signature (trace + compile + first run).
+first invocation of a new input signature (trace + compile + first run);
+``program.<name>.launches`` / ``.arg_bytes`` / ``.result_bytes`` per call
+of the SharedJit program ``name`` (what it was handed and gave back).
 """
 from __future__ import annotations
 
@@ -379,22 +381,67 @@ def _purge_if_pressured() -> bool:
     return True
 
 
+def _leaf_bytes(leaves) -> int:
+    """Summed ``size * itemsize`` of the array-like leaves (shape and
+    dtype known); python scalars and static leaves count nothing."""
+    import numpy as np
+    total = 0
+    for leaf in leaves:
+        if not hasattr(leaf, "shape"):      # as _signature tells arrays
+            continue
+        try:
+            n = np.dtype(leaf.dtype).itemsize
+            for d in leaf.shape:
+                n *= d
+        # a static leaf that merely looks like an array (an expression
+        # with a ``dtype`` property) or an extended dtype: no bytes
+        except (TypeError, AttributeError, ValueError):
+            continue
+        total += n
+    return total
+
+
 class SharedJit:
-    """A process-wide jit callable with per-signature compile accounting.
+    """A process-wide jit callable with per-signature accounting.
+
+    ``name`` says whose program this is (``agg_update``,
+    ``join_probe_fast``, ``mesh_region_chain`` …): it is stamped on the
+    wrapped python function, so XLA names the module ``jit_<name>`` in
+    device traces, and it is the key of the program's counters
+    ``program.<name>.launches`` / ``.arg_bytes`` / ``.result_bytes``.
+    One name per jit site — tests/test_query_record.py holds them unique.
 
     jax compiles one executable per abstract input signature inside the
-    wrapper; this class mirrors that bookkeeping at the python level so
-    the first call for a NEW (shapes, dtypes, tree) signature — the one
-    that traces and compiles — moves ``compile_count`` and is timed into
-    ``compile_wall_s``.  Signatures already seen dispatch with no extra
-    accounting beyond one set lookup."""
+    wrapper; this class mirrors that bookkeeping at the python level in
+    ONE dict, signature -> ``[arg_bytes, result_bytes]`` (the summed
+    sizes of the call's array leaves, computed when the signature is
+    first seen).  The first call for a NEW (shapes, dtypes, tree)
+    signature — the one that traces and compiles — runs under a
+    ``program.compile@<name>`` span, moves ``compile_count`` and is
+    timed into ``compile_wall_s``; a signature already seen costs one
+    dict lookup and three adds.  ``compile_count`` / ``compile_wall_s``
+    see SharedJit programs only: the compiles of eager ``jnp``
+    operations outside any program (PERF.md Findings PR 23) reach only
+    a ``jax.monitoring`` listener on
+    ``/jax/core/compile/backend_compile_duration``
+    (benchmark/harness/compiles.py).  A call made while an enclosing
+    program is being traced (jit-of-jit) is counted like a launch; that
+    happens at compile time only, never in a warm collect."""
 
-    __slots__ = ("fn", "_sigs", "_lock", "__weakref__")
+    __slots__ = ("fn", "name", "_sigs", "_lock", "_keys", "__weakref__")
 
-    def __init__(self, fn):
+    def __init__(self, fn, name: str):
         self.fn = fn
-        self._sigs: set = set()
+        self.name = name
+        inner = getattr(fn, "__wrapped__", None)
+        if inner is not None:
+            # jax reads the name when it traces: module ``jit_<name>``
+            inner.__name__ = inner.__qualname__ = name
+        self._sigs: dict = {}
         self._lock = threading.Lock()
+        self._keys = (f"program.{name}.launches",
+                      f"program.{name}.arg_bytes",
+                      f"program.{name}.result_bytes")
         _ALL_SHARED.add(self)
 
     def signature_count(self) -> int:
@@ -408,47 +455,63 @@ class SharedJit:
             (l.shape, str(l.dtype)) if hasattr(l, "shape") else l
             for l in leaves))
         hash(sig)  # unhashable static leaf -> fall back to uncounted
-        return sig
+        return sig, leaves
+
+    def _count(self, facts) -> None:
+        launches, arg_bytes, result_bytes = self._keys
+        get_registry().inc_many(((launches, 1), (arg_bytes, facts[0]),
+                                 (result_bytes, facts[1])))
 
     def __call__(self, *args, **kwargs):
         try:
-            sig = self._signature(args, kwargs)
+            sig, leaves = self._signature(args, kwargs)
         # enginelint: disable=RL001 (unhashable static leaf falls back to an uncounted dispatch)
         except Exception:
             with dispatch_guard():
                 return self.fn(*args, **kwargs)
-        with self._lock:
-            new = sig not in self._sigs
-            if new:
-                self._sigs.add(sig)
+        facts = self._sigs.get(sig)
+        new = False
+        if facts is None:
+            with self._lock:
+                facts = self._sigs.get(sig)
+                new = facts is None
+                if new:
+                    # result bytes are known once the first call returns
+                    facts = self._sigs[sig] = [_leaf_bytes(leaves), 0]
         if not new:
+            self._count(facts)
             with dispatch_guard():
                 return self.fn(*args, **kwargs)
+        reg = get_registry()
         t0 = time.perf_counter()
         try:
-            with compile_guard():
+            with reg.span(f"program.compile@{self.name}"), compile_guard():
                 if _purge_if_pressured():
                     with self._lock:
-                        self._sigs.add(sig)  # purge cleared it
-                return self.fn(*args, **kwargs)
+                        self._sigs[sig] = facts  # purge cleared it
+                out = self.fn(*args, **kwargs)
+            import jax
+            facts[1] = _leaf_bytes(jax.tree_util.tree_leaves(out))
+            self._count(facts)
+            return out
         finally:
             elapsed = time.perf_counter() - t0
-            reg = get_registry()
             reg.inc("compile_count")
             reg.inc("compile_wall_s", elapsed)
             reg.observe("compile.wall_seconds", elapsed)
 
 
-def instrument(fn) -> SharedJit:
-    """Wrap an already-jitted callable with compile accounting."""
-    return SharedJit(fn)
+def instrument(fn, name: str) -> SharedJit:
+    """Wrap an already-jitted callable with the SharedJit accounting
+    under the program name ``name``."""
+    return SharedJit(fn, name)
 
 
-def guarded_jit(**jit_kwargs):
+def guarded_jit(name: str, **jit_kwargs):
     """``jax.jit`` + the SharedJit wrapper, as a decorator.
 
-    Module-level kernels (`@guarded_jit(static_argnames=...)`) get the
-    same compile accounting as fragment-keyed programs AND pass the
+    Module-level kernels (`@guarded_jit("join_probe", static_argnames=...)`)
+    get the same accounting as fragment-keyed programs AND pass the
     process-wide compile/dispatch guard, so on the CPU backend no raw
     kernel can compile concurrently with another engine compile or
     dispatch (the XLA-build crash class documented above).  jax already
@@ -456,7 +519,7 @@ def guarded_jit(**jit_kwargs):
     mirrors jax's own executable cache exactly."""
     def wrap(fn):
         import jax
-        return SharedJit(jax.jit(fn, **jit_kwargs))
+        return SharedJit(jax.jit(fn, **jit_kwargs), name)
     return wrap
 
 
@@ -498,13 +561,13 @@ def get_or_build(key: str, builder, *, max_entries: int | None = None):
     return val
 
 
-def shared_jit(key: str, fn, **jit_kwargs) -> SharedJit:
+def shared_jit(key: str, fn, *, name: str, **jit_kwargs) -> SharedJit:
     """``get_or_build`` specialization for the common one-function case:
     jit ``fn`` (with ``jit_kwargs``, e.g. ``donate_argnums``) behind the
-    process-wide key and wrap it with compile accounting."""
+    process-wide key and wrap it as the program ``name``."""
     def build():
         import jax
-        return SharedJit(jax.jit(fn, **jit_kwargs))
+        return SharedJit(jax.jit(fn, **jit_kwargs), name)
     return get_or_build(key, build)
 
 

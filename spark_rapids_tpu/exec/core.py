@@ -28,6 +28,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.conf import ConfEntry, TpuConf, register
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.host.batch import HostBatch
+from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.runtime import widen_thread_stacks
 
 # worker threads created from here on (drain pools, shuffle servers) get
@@ -37,7 +38,7 @@ widen_thread_stacks()
 __all__ = [
     "ExecCtx", "PlanNode", "CoalesceGoal", "TargetSize", "RequireSingleBatch",
     "collect", "collect_host", "collect_device", "Metrics",
-    "drain_partitions", "drain_partitions_indexed",
+    "drain_partitions", "drain_partitions_indexed", "fetch_to_host",
 ]
 
 CONCURRENT_TASKS = register(ConfEntry(
@@ -106,6 +107,27 @@ class Metrics:
 
     def __getitem__(self, name: str) -> float:
         return self.values.get(name, 0.0)
+
+
+class _TracedSpan:
+    """The registry's span around the Chrome tracer's (None when that
+    tracer is off); ``with`` yields the tracer's span or None."""
+
+    __slots__ = ("_span", "_cm")
+
+    def __init__(self, span, tracer_cm):
+        self._span = span
+        self._cm = tracer_cm
+
+    def __enter__(self):
+        self._span.__enter__()
+        return None if self._cm is None else self._cm.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return False if self._cm is None else self._cm.__exit__(*exc)
+        finally:
+            self._span.__exit__(*exc)
 
 
 @dataclass
@@ -325,13 +347,15 @@ class ExecCtx:
 
     def trace_span(self, name: str, cat: str = "query", *,
                    parent_id=None, **args):
-        """Context manager opening a span (yields it for annotate());
-        a no-op nullcontext (yielding None) when tracing is off."""
+        """Context manager opening the registry's span ``name`` (the
+        profiler annotation + ``span.<name>.count/seconds``) and, when
+        the Chrome tracer is on, the tracer's span too.  Yields the
+        tracer's span for annotate(), None when that tracer is off."""
         t = self.tracer
-        if t is None:
-            import contextlib
-            return contextlib.nullcontext()
-        return t.span(name, cat, parent_id=parent_id, **args)
+        return _TracedSpan(
+            get_registry().span(name),
+            None if t is None else t.span(name, cat, parent_id=parent_id,
+                                          **args))
 
     def trace_event(self, name: str, cat: str = "query", *,
                     parent_id=None, **args) -> None:
@@ -363,6 +387,12 @@ class ExecCtx:
         for t in transports:
             t.close()
         if catalog is not None:
+            lc = self.cache.get("lifecycle")
+            if lc is not None:
+                # the per-query record's spill totals (lifecycle.py)
+                lc.spill = {k: catalog.metrics.get(k, 0) for k in (
+                    "bytes_spilled_to_host", "bytes_spilled_to_disk",
+                    "device_spills")}
             catalog.close()
         if tracer is not None:
             try:
@@ -599,9 +629,13 @@ class PlanNode:
             yield from drain_partitions(ctx, self)
             return
         try:
-            with ctx.trace_span("query", "query",
-                                root=type(self).__name__,
-                                backend=ctx.backend):
+            # the Chrome tracer's root span only: the registry's
+            # ``query`` span is the session's, around the whole collect
+            import contextlib
+            t = ctx.tracer
+            with contextlib.nullcontext() if t is None else t.span(
+                    "query", "query", root=type(self).__name__,
+                    backend=ctx.backend):
                 yield from drain_partitions(ctx, self)
         except GeneratorExit:
             raise
@@ -788,9 +822,12 @@ def collect_device(plan: PlanNode, conf: TpuConf | None = None,
             prof = _prof.trace(profile_dir)
         with prof:
             out: list[tuple] = []
+            reg = get_registry()
             for b in plan.execute(ctx):
-                hb = device_to_host(b)
-                out.extend(_rows_from_host(hb))
+                # the result's phase: its fetch and the rows to python
+                with reg.span("query.fetch", query_id=ctx.query_id,
+                              parent="query"):
+                    out.extend(_rows_from_host(device_to_host(b, None)))
             return out
 
 
@@ -801,16 +838,40 @@ def collect(plan: PlanNode, backend: str = "device",
     return collect_device(plan, conf)
 
 
-def device_to_host(b: ColumnBatch) -> HostBatch:
-    """D2H: ColumnBatch -> HostBatch (reference GpuColumnarToRowExec /
-    GpuBringBackToHost transition)."""
+def fetch_to_host(tree, op: "str | None"):
+    """The engine's one blocking device fetch: ``jax.device_get(tree)``
+    under the span ``op`` (``fetch@<Operator>Exec`` for an operator's
+    own sync; None inside a span the caller holds, as ``query.fetch``
+    around the result), counting ``d2h_calls``, ``d2h_bytes`` (of the
+    fetched leaves) and ``sync_wait_s`` (seconds the calling thread was
+    blocked).  On an asynchronous backend the wait is the device
+    finishing what was queued, not only the copy."""
     import jax
+    import contextlib
+    reg = get_registry()
+    t0 = time.perf_counter()
+    with contextlib.nullcontext() if op is None else reg.span(op):
+        host = jax.device_get(tree)
+    waited = time.perf_counter() - t0
+    nbytes = 0
+    for leaf in jax.tree_util.tree_leaves(host):
+        nbytes += getattr(leaf, "nbytes", 0)
+    reg.inc_many((("d2h_calls", 1), ("d2h_bytes", nbytes),
+                  ("sync_wait_s", waited)))
+    return host
+
+
+def device_to_host(b: ColumnBatch,
+                   op: "str | None" = "fetch@device_to_host") -> HostBatch:
+    """D2H: ColumnBatch -> HostBatch (reference GpuColumnarToRowExec /
+    GpuBringBackToHost transition); ``op`` as in :func:`fetch_to_host`."""
     import numpy as np
     from spark_rapids_tpu.host.batch import HostColumn
     # ONE device_get for num_rows + all column leaves: separate fetches
     # pay a full host round trip each
-    n, host = jax.device_get(
-        (b.num_rows, [(c.data, c.validity, c.lengths) for c in b.columns]))
+    n, host = fetch_to_host(
+        (b.num_rows, [(c.data, c.validity, c.lengths) for c in b.columns]),
+        op)
     n = int(n)
     cols = []
     for f, (data, validity, lengths) in zip(b.schema, host):

@@ -27,7 +27,7 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
-from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
+from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
 from spark_rapids_tpu.exec.compile_cache import guarded_jit
 from spark_rapids_tpu.exec.partitioning import Partitioning
 from spark_rapids_tpu.host.batch import HostBatch
@@ -58,7 +58,7 @@ SKEWED_PARTITION_THRESHOLD = register(ConfEntry(
     "split).", conv=int))
 
 
-@guarded_jit(static_argnames=("num_parts",))
+@guarded_jit("shuffle_group_by_part", static_argnames=("num_parts",))
 def _jit_group_by_part(batch: ColumnBatch, ids: jax.Array, num_parts: int):
     """Sort rows by partition id; return (sorted_batch, counts[num_parts]).
 
@@ -79,7 +79,7 @@ def _jit_group_by_part(batch: ColumnBatch, ids: jax.Array, num_parts: int):
     return ColumnBatch(cols, batch.num_rows, batch.schema), counts, starts
 
 
-@guarded_jit(static_argnames=("out_cap",))
+@guarded_jit("shuffle_slice_part", static_argnames=("out_cap",))
 def _jit_slice_part(sorted_batch: ColumnBatch, starts, counts, p,
                     out_cap: int):
     """Copy partition ``p``'s rows [starts[p], starts[p]+counts[p]) into
@@ -308,7 +308,8 @@ class ShuffleExchangeExec(PlanNode):
         ids = self.partitioning.device_ids(b, bi)
         sb, counts_d, starts_d = ctx.dispatch(_jit_group_by_part, b, ids, n)
         # enginelint: disable=RL003 (per-partition counts gate host-side slicing; one sync per batch by design)
-        counts = np.asarray(jax.device_get(counts_d))
+        counts = np.asarray(fetch_to_host(counts_d,
+                                          "fetch@ShuffleExchangeExec"))
         for p in range(n):
             if counts[p] == 0:
                 continue

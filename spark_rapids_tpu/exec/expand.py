@@ -68,7 +68,7 @@ class ExpandExec(PlanNode):
                 return cc.shared_jit(
                     cc.fragment_key("expand", tuple(proj), self._schema,
                                     self.children[0].output_schema),
-                    one)
+                    one, name="expand_project")
 
             self._expand_jits = [make(p) for p in self._bound]
         return self._expand_jits

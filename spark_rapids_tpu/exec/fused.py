@@ -153,7 +153,8 @@ class FusedStageExec(PlanNode):
             from spark_rapids_tpu.exec import compile_cache as cc
             kw = {"donate_argnums": 0} if donate else {}
             self._fused_jits[donate] = cc.shared_jit(
-                self._stage_key(donate), stage_body(self._ops), **kw)
+                self._stage_key(donate), stage_body(self._ops),
+                name="fused_stage_body", **kw)
         return self._fused_jits[donate]
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:

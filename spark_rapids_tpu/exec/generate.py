@@ -25,13 +25,16 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
-from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
+from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
 from spark_rapids_tpu.exec.compile_cache import guarded_jit
 from spark_rapids_tpu.expr.core import Expression, bind, eval_device, \
     eval_host
 from spark_rapids_tpu.host.batch import HostBatch, HostColumn
 
 __all__ = ["GenerateExec", "SplitExplode"]
+
+#: span of this operator's blocking total fetches (exec/core.fetch_to_host)
+_FETCH = "fetch@GenerateExec"
 
 
 class SplitExplode(Expression):
@@ -86,7 +89,8 @@ class Explode(Expression):
         return f"Explode({self.children[0]!r})"
 
 
-@guarded_jit(static_argnames=("out_cap", "pos_col", "outer"))
+@guarded_jit("generate_array",
+             static_argnames=("out_cap", "pos_col", "outer"))
 def _jit_generate_array(batch: ColumnBatch, col: DeviceColumn,
                         out_cap: int, pos_col: bool, outer: bool):
     """Explode an array column: one output row per element, child
@@ -134,7 +138,7 @@ def _jit_generate_array(batch: ColumnBatch, col: DeviceColumn,
     return out_cols, total
 
 
-@guarded_jit(static_argnames=())
+@guarded_jit("generate_counts", static_argnames=())
 def _jit_counts(col: DeviceColumn, real: jax.Array, delim: int):
     """Per-row piece counts (0 for null/padding rows) + total."""
     w = col.max_len
@@ -145,7 +149,8 @@ def _jit_counts(col: DeviceColumn, real: jax.Array, delim: int):
     return counts, jnp.sum(counts, dtype=jnp.int64)
 
 
-@guarded_jit(static_argnames=("out_cap", "pos_col", "outer"))
+@guarded_jit("generate_split",
+             static_argnames=("out_cap", "pos_col", "outer"))
 def _jit_generate(batch: ColumnBatch, col: DeviceColumn, counts, delim: int,
                   out_cap: int, pos_col: bool, outer: bool):
     """Build the generated batch: child columns gathered per output row +
@@ -268,8 +273,8 @@ class GenerateExec(PlanNode):
                 if self.outer:
                     counts = jnp.where(real, jnp.maximum(counts, 1), 0)
                 # enginelint: disable=RL003 (total gates output allocation; single scalar sync per batch)
-                total = int(jax.device_get(
-                    jnp.sum(counts, dtype=jnp.int64)))
+                total = int(fetch_to_host(
+                    jnp.sum(counts, dtype=jnp.int64), _FETCH))
                 if total == 0:
                     continue
                 out_cap = round_capacity(total)
@@ -285,12 +290,12 @@ class GenerateExec(PlanNode):
             counts, total_d = _jit_counts(gcol, real, delim)
             if self.outer:
                 # enginelint: disable=RL003 (outer rows need a host total to size the output; single scalar sync)
-                total = int(jax.device_get(
+                total = int(fetch_to_host(
                     jnp.sum(jnp.where(real, jnp.maximum(counts, 1), 0),
-                            dtype=jnp.int64)))
+                            dtype=jnp.int64), _FETCH))
             else:
                 # enginelint: disable=RL003 (total gates output allocation; single scalar sync per batch)
-                total = int(jax.device_get(total_d))
+                total = int(fetch_to_host(total_d, _FETCH))
             if total == 0:
                 continue
             out_cap = round_capacity(total)
@@ -304,7 +309,8 @@ class GenerateExec(PlanNode):
             from spark_rapids_tpu.exec import compile_cache as cc
             self._gen_jit = cc.shared_jit(
                 cc.fragment_key("generate", self._gen_bound),
-                lambda b: eval_device(self._gen_bound, b))
+                lambda b: eval_device(self._gen_bound, b),
+                name="generate_eval")
         return self._gen_jit
 
     def _host_generate(self, b: HostBatch) -> HostBatch:

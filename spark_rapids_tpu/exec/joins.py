@@ -26,7 +26,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.compile_cache import guarded_jit
-from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
+from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
 from spark_rapids_tpu.expr.core import (BoundReference, Expression, bind,
                                         eval_device, eval_host)
 from spark_rapids_tpu.host.batch import HostBatch
@@ -39,8 +39,11 @@ from spark_rapids_tpu.ops.join import (JOIN_TYPES, build_prepare_fast,
 
 __all__ = ["JoinExec", "CrossJoinExec", "BroadcastHashJoinExec"]
 
+#: span of this operator's blocking total fetches (exec/core.fetch_to_host)
+_FETCH = "fetch@JoinExec"
 
-@guarded_jit(static_argnames=("lkeys", "rkeys", "join_type"))
+
+@guarded_jit("join_probe", static_argnames=("lkeys", "rkeys", "join_type"))
 def _jit_probe(lb, rb, lkeys, rkeys, join_type):
     """Heavy rank-path phase (all sorts): compiled once per capacity pair."""
     probe_arrays, total = join_probe(lb, rb, lkeys, rkeys, join_type)
@@ -50,20 +53,20 @@ def _jit_probe(lb, rb, lkeys, rkeys, join_type):
     return probe_arrays, total
 
 
-@guarded_jit(static_argnames=("rkey",))
+@guarded_jit("join_build_prep", static_argnames=("rkey",))
 def _jit_build_prep(rb, rkey):
     return build_prepare_fast(rb, rkey)
 
 
-@guarded_jit(static_argnames=("lkey", "join_type"))
+@guarded_jit("join_probe_fast", static_argnames=("lkey", "join_type"))
 def _jit_probe_fast(lb, prep, lkey, join_type):
     probe_arrays, total = probe_fast(lb, lkey, *prep, join_type)
     return probe_arrays[:-1], total  # drop the None placeholder
 
 
-@guarded_jit(static_argnames=("cl", "join_type", "out_cap",
-                                   "include_right", "schema",
-                                   "track_matched"))
+@guarded_jit("join_gather",
+             static_argnames=("cl", "join_type", "out_cap", "include_right",
+                              "schema", "track_matched"))
 def _jit_gather(lb, rb, probe_arrays, cl, join_type, out_cap, include_right,
                 schema, track_matched=False):
     """Light phase (gathers only): re-specialized per output capacity."""
@@ -315,10 +318,10 @@ class JoinExec(PlanNode):
             def sync_totals():
                 if len(pending) == 1:
                     # enginelint: disable=RL003 (single-entry fast path; one scalar sync)
-                    return [int(jax.device_get(pending[0][2]))]
+                    return [int(fetch_to_host(pending[0][2], _FETCH))]
                 # enginelint: disable=RL003 (stacked transfer for all pending probes; this IS the batched sync)
-                return [int(t) for t in jax.device_get(ctx.dispatch(
-                    jnp.stack, [p[2] for p in pending]))]
+                return [int(t) for t in fetch_to_host(ctx.dispatch(
+                    jnp.stack, [p[2] for p in pending]), _FETCH)]
 
             totals = ctx.retry_sync(sync_totals, redo=redo,
                                     op="join_flush")
@@ -358,7 +361,7 @@ class JoinExec(PlanNode):
             if matched is None:
                 matched = jnp.zeros(rb2.capacity, jnp.bool_)
             tail = self._unmatched_right_jit()(rb2, matched)
-            if tail.host_num_rows() > 0:
+            if tail.host_num_rows(_FETCH) > 0:
                 yield tail
 
     def _stream_aug_fields(self):
@@ -378,7 +381,8 @@ class JoinExec(PlanNode):
                 c = eval_device(self._cond_b, out)
                 return dk.compact(out, c.data & c.validity)
             self._cond_jit = cc.shared_jit(
-                cc.fragment_key("join_cond", self._cond_b), filt)
+                cc.fragment_key("join_cond", self._cond_b), filt,
+                name="join_post_filter")
         return self._cond_jit
 
     def _unmatched_right_jit(self):
@@ -416,7 +420,8 @@ class JoinExec(PlanNode):
             from spark_rapids_tpu.exec import compile_cache as cc
             self._unmatched_jit = cc.shared_jit(
                 cc.fragment_key("join_unmatched", left_fields, right_schema,
-                                self._schema), fn)
+                                self._schema), fn,
+                name="join_unmatched_right")
         return self._unmatched_jit
 
     def _run_host(self, ctx: ExecCtx, lb: HostBatch, rb: HostBatch):

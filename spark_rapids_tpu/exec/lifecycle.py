@@ -245,6 +245,10 @@ class QueryLifecycle:
         # from a recovered driver) surfaced on /queries and in history
         # records; empty for the overwhelming majority of queries
         self.annotations: dict = {}
+        # the per-query record (open_record / seal_record below)
+        self.record: "dict | None" = None
+        self.spill: dict = {}
+        self._record_open: "tuple | None" = None
 
     @classmethod
     def from_conf(cls, query_id: str, conf, timeout: "float | None" = None,
@@ -332,6 +336,52 @@ class QueryLifecycle:
         get_registry().inc("queries_deadline_exceeded")
         self._observe_wall()
         return True
+
+    # -- the per-query record ----------------------------------------------
+
+    def open_record(self) -> None:
+        """Start this query's record: the registry's counters as they
+        stand now (a copy of one dict; no pull source is walked)."""
+        self._record_open = (time.time(), get_registry().counters())
+
+    def seal_record(self, err: "BaseException | None" = None) \
+            -> "dict | None":
+        """Close the record and put it in the registry's ring
+        (``get_registry().recent_queries()``, served by ``/queries`` as
+        ``finished``): query_id, tenant, ``state`` (the terminal state,
+        or — for a query ``err`` stopped before any transition: planning
+        failed, admission shed it — REJECTED / FAILED), start/end,
+        ``spill`` (the query's own BufferCatalog totals) and
+        ``counters`` — every registry counter that moved between
+        ``open_record`` and now: the span table
+        (``span.<name>.count/seconds``), the transfer and wait counters,
+        and the per-program table
+        (``program.<name>.launches/arg_bytes/result_bytes``).  The
+        session calls this once the collect has unwound, so a cancelled
+        query's record holds all it did.  The counters are the
+        PROCESS's movement over the query's interval: exact with one
+        running query, shared among queries that overlap.  Idempotent;
+        None when no record was opened."""
+        opened, self._record_open = self._record_open, None
+        if opened is None:
+            return self.record
+        reg = get_registry()
+        started, before = opened
+        state = self._state
+        if state not in TERMINAL_STATES and err is not None:
+            state = "REJECTED" if isinstance(err, QueryRejected) \
+                else "FAILED"
+        self.record = {
+            "query_id": self.query_id,
+            "tenant": self.tenant,
+            "state": state,
+            "start_unix_s": started,
+            "end_unix_s": time.time(),
+            "spill": dict(self.spill),
+            "counters": reg.counters_since(before),
+        }
+        reg.note_query(self.record)
+        return self.record
 
     # -- cooperative checkpoints -------------------------------------------
 

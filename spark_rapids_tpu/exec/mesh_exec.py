@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.exec.aggregate import HashAggregateExec, _relabel_d
-from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
+from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
 from spark_rapids_tpu.exec.joins import JoinExec
 from spark_rapids_tpu.expr.core import Expression, bind, eval_device
 from spark_rapids_tpu.ops import kernels as dk
@@ -465,7 +465,8 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
             def prog(stacked: ColumnBatch) -> ColumnBatch:
                 return restack(step(local_view(stacked)))
             return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))))
+                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))),
+                "mesh_aggregate")
 
         fn = cc.get_or_build(key, build)
         self._jitted[memo] = fn
@@ -610,7 +611,7 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
                 return restack(out), restack(overflow)
             return cc.instrument(jax.jit(shard_map(
                 prog, mesh=mesh, in_specs=P(axis),
-                out_specs=(P(axis), P(axis)))))
+                out_specs=(P(axis), P(axis)))), "mesh_exchange")
 
         fn = cc.get_or_build(key, build)
         self._jitted[memo] = fn
@@ -629,7 +630,7 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
                 return dk.compact(b, ids == pid)
 
             from spark_rapids_tpu.exec import compile_cache as cc
-            self._pick = cc.instrument(jax.jit(pick))
+            self._pick = cc.instrument(jax.jit(pick), "mesh_exchange_pick")
         return self._pick
 
     def _outputs_cache_key(self, ctx: ExecCtx) -> tuple:
@@ -658,7 +659,8 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
         result, flags = self._program(mesh, send_cap)(stacked)
         if send_cap is not None and bool(
                 # enginelint: disable=RL003 (overflow-flag check; one scalar sync gates the recompile fallback)
-                np.asarray(jax.device_get(flags)).any()):
+                np.asarray(fetch_to_host(
+                    flags, "fetch@MeshExchangeExec")).any()):
             get_registry().inc("mesh_send_overflows")
             result, _ = self._program(mesh, None)(stacked)
         return result
@@ -703,7 +705,7 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
         shard = out[pid % self.mesh_size]
         b = ctx.dispatch(self._pick_jit(), shard,
                          jnp.asarray(pid, jnp.int32))
-        count = b.host_num_rows()
+        count = b.host_num_rows("fetch@MeshExchangeExec")
         if count > 0 or self._num_parts == 1:
             yield ctx.dispatch(dk.shrink_capacity, b,
                                round_capacity(max(count, 1)))

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
-from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
+from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
 from spark_rapids_tpu.exec.fused import (FusedStageExec, stage_body,
                                          stage_key_parts)
 from spark_rapids_tpu.exec.mesh_exec import (MeshAggregateExec,
@@ -187,7 +187,8 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
             def prog(stacked: ColumnBatch) -> ColumnBatch:
                 return restack(step(local_view(stacked)))
             return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))))
+                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))),
+                "mesh_sort")
 
         fn = cc.get_or_build(key, build)
         self._jitted[memo] = fn
@@ -351,7 +352,8 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
             def prog(stacked: ColumnBatch) -> ColumnBatch:
                 return restack(step(local_view(stacked)))
             return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))))
+                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))),
+                "mesh_window")
 
         fn = cc.get_or_build(key, build)
         self._jitted[memo] = fn
@@ -605,8 +607,11 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
                 return restack(out), aux
             in_specs = (P(axis),) * (1 + n_builds)
             out_specs = (P(axis), (P(axis),) * n_aux)
+            # a region with a join in it is another program to tune
+            # than a chain of per-shard steps: the name says which
             return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs)))
+                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs)),
+                "mesh_region_join" if n_builds else "mesh_region_chain")
 
         fn = cc.get_or_build(key, build)
         self._jitted[memo] = fn
@@ -637,7 +642,7 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
                 return result
             vals = [np.asarray(v) for v in
                     # enginelint: disable=RL003 (join totals + overflow flags; one stacked sync gates the retry)
-                    jax.device_get(aux)]
+                    fetch_to_host(aux, "fetch@MeshRegionExec")]
             retry = False
             for i in range(nj):
                 total = int(vals[i].max())

@@ -27,6 +27,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.compile_cache import guarded_jit
+from spark_rapids_tpu.exec.core import fetch_to_host
 from spark_rapids_tpu.expr.core import (Expression, bind, eval_device,
                                         eval_host)
 from spark_rapids_tpu.host.batch import HostBatch
@@ -119,7 +120,8 @@ class RoundRobinPartitioning(Partitioning):
         if is_device:
             counts = [int(c) for c in
                       # enginelint: disable=RL003 (ONE stacked round trip for all batch counts; this IS the batched sync)
-                      jax.device_get([b.num_rows for b in batches])]
+                      fetch_to_host([b.num_rows for b in batches],
+                                    "fetch@ShuffleExchangeExec")]
         else:
             counts = [b.num_rows for b in batches]
         off = 0
@@ -315,7 +317,7 @@ def _host_keys_equal(c, i: int, j: int) -> bool:
     return a == b
 
 
-@guarded_jit(static_argnames=("orders",))
+@guarded_jit("range_partition_sort", static_argnames=("orders",))
 def _jit_sorted(batch: ColumnBatch, orders):
     from spark_rapids_tpu.ops.sort import sort_batch
     return sort_batch(batch, list(orders))

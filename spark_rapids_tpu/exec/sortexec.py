@@ -94,7 +94,7 @@ class SortExec(PlanNode):
             self._sort_jit = cc.shared_jit(
                 cc.fragment_key("sort", tuple(self._orders),
                                 self.children[0].output_schema),
-                lambda b: sort_batch(b, self._orders))
+                lambda b: sort_batch(b, self._orders), name="sort_batch")
         return self._sort_jit
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
@@ -215,7 +215,7 @@ class CoalesceBatchesExec(PlanNode):
         if not ctx.is_device or not self._upstream_can_shrink():
             return b
         from spark_rapids_tpu.columnar.batch import round_capacity
-        n = b.host_num_rows()
+        n = b.host_num_rows("fetch@CoalesceBatchesExec")
         cap = round_capacity(max(n, 1))
         if cap > b.capacity // 2:
             return b

@@ -47,7 +47,7 @@ class BackendSwitchExec(PlanNode):
             if inner.backend == ctx.backend:
                 yield b
             elif ctx.backend == "host":
-                yield device_to_host(b)
+                yield device_to_host(b, "fetch@BackendSwitchExec")
             else:
                 yield host_to_device(b)
 

@@ -241,8 +241,8 @@ class WindowExec(PlanNode):
             # the pair is one process-wide entry keyed on the inputs
             self._gs_jits = cc.get_or_build(
                 cc.fragment_key("window_gs_update", tuple(inputs)),
-                lambda: (cc.instrument(jax.jit(update)),
-                         cc.instrument(jax.jit(merge))))
+                lambda: (cc.instrument(jax.jit(update), "window_update"),
+                         cc.instrument(jax.jit(merge), "window_merge")))
         upd_jit, merge_jit = self._gs_jits[:2]
 
         child = self.children[0]
@@ -296,7 +296,7 @@ class WindowExec(PlanNode):
             self._gs_jits = self._gs_jits + (cc.shared_jit(
                 cc.fragment_key("window_gs_append", tuple(self._wexprs),
                                 tuple(self._out_dtypes), self._schema),
-                append),)
+                append, name="window_append"),)
         app_jit = self._gs_jits[2]
         for sb in parked:
             b = sb.get()
@@ -585,5 +585,5 @@ def _window_body(aug: ColumnBatch, orders, part_idx, order_idx, input_idx,
 
 
 _jit_window = guarded_jit(
-    static_argnames=("orders", "part_idx", "order_idx", "input_idx",
+    "window_frame", static_argnames=("orders", "part_idx", "order_idx", "input_idx",
                      "wexprs", "nbase", "schema"))(_window_body)

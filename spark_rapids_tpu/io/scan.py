@@ -21,6 +21,7 @@ from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.conf import ConfEntry, register
 from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
 from spark_rapids_tpu.expr.core import Expression
+from spark_rapids_tpu.obs.registry import get_registry
 
 __all__ = ["FileScanExec", "ParquetScanExec", "OrcScanExec", "CsvScanExec"]
 
@@ -381,21 +382,34 @@ class FileScanExec(PlanNode):
         scan's serial CPU cost; overlapping it with device compute hides
         it entirely on multi-batch scans (reference: the multithreaded
         reader's decode-ahead does the same for the host half,
-        GpuMultiFileReader.scala).  Window of 2 bounds host+HBM usage."""
+        GpuMultiFileReader.scala).  Window of 2 bounds host+HBM usage.
+
+        The worker has no enclosing operator annotation, so its span
+        says whose work it does: ``stage@<Scan>Exec`` per batch (encode
+        + pack + ``device_put``); the seconds it is blocked on the full
+        queue go to ``scan_backpressure_s``.  What the pulling thread's
+        own ``<Scan>Exec`` annotation covers is ``q.get()``: the wait."""
         import queue
         import threading
+        import time
         q: queue.Queue = queue.Queue(maxsize=2)
         DONE = object()
         stop = threading.Event()
+        reg = get_registry()
+        stage = f"stage@{type(self).__name__}"
 
         def put(item) -> bool:
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.25)
-                    return True
-                except queue.Full:
-                    continue
-            return False
+            t0 = time.perf_counter()
+            try:
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.25)
+                        return True
+                    except queue.Full:
+                        continue
+                return False
+            finally:
+                reg.inc("scan_backpressure_s", time.perf_counter() - t0)
 
         def worker():
             try:
@@ -404,8 +418,10 @@ class FileScanExec(PlanNode):
                         return
                     if rb.num_rows == 0:
                         continue
-                    if not put(ColumnBatch.from_arrow(
-                            rb, string_widths=self._width_map(rb))):
+                    with reg.span(stage):
+                        b = ColumnBatch.from_arrow(
+                            rb, string_widths=self._width_map(rb))
+                    if not put(b):
                         return
                 put(DONE)
             # enginelint: disable=RL001 (prefetch thread forwards the exception through the queue; the consumer re-raises it)
@@ -440,13 +456,22 @@ class FileScanExec(PlanNode):
                 if isinstance(f.data_type, T.StringType)}
 
     def _decode_iter(self, ctx: ExecCtx, files: list[str], mode: str):
+        """Arrow record batches of ``files``.  Every decode runs under
+        a ``decode@<Scan>Exec`` span on the thread that does it: one per
+        file on the prefetch pool's threads (MULTITHREADED, COALESCING),
+        one per record batch where the reader is pulled lazily."""
         batch_rows = _effective_batch_rows(self._schema, ctx.conf.settings)
+        reg = get_registry()
+        decode = f"decode@{type(self).__name__}"
+
+        def read_all(p):
+            with reg.span(decode):
+                return list(self._read_file(p, batch_rows))
         try:
             # process-wide scan-volume counter (mirrors the shuffle
             # plane's shuffle.fetch.bytes): on-disk bytes this partition
             # is about to decode, metered per tenant by obs/metering
-            from spark_rapids_tpu.obs.registry import get_registry
-            get_registry().inc("scan.bytes", float(
+            reg.inc("scan.bytes", float(
                 sum(os.path.getsize(p) for p in files)))
         # enginelint: disable=RL001 (accounting must never fail a scan)
         except Exception:
@@ -462,14 +487,12 @@ class FileScanExec(PlanNode):
                 window: deque = deque()
                 it = iter(files)
                 for p in it:
-                    window.append(pool.submit(
-                        lambda p=p: list(self._read_file(p, batch_rows))))
+                    window.append(pool.submit(read_all, p))
                     if len(window) >= nthreads:
                         break
                 for p in it:
                     yield from window.popleft().result()
-                    window.append(pool.submit(
-                        lambda p=p: list(self._read_file(p, batch_rows))))
+                    window.append(pool.submit(read_all, p))
                 while window:
                     yield from window.popleft().result()
         elif mode == "COALESCING" and len(files) > 1:
@@ -480,7 +503,7 @@ class FileScanExec(PlanNode):
             import pyarrow as pa
             tables = []
             for p in files:
-                bs = list(self._read_file(p, batch_rows))
+                bs = read_all(p)
                 if bs:
                     t = pa.Table.from_batches(bs)
                     if t.num_rows:
@@ -493,7 +516,13 @@ class FileScanExec(PlanNode):
             yield from merged.to_batches(max_chunksize=batch_rows)
         else:
             for p in files:
-                yield from self._read_file(p, batch_rows)
+                it = self._read_file(p, batch_rows)
+                while True:
+                    with reg.span(decode):
+                        rb = next(it, None)
+                    if rb is None:
+                        break
+                    yield rb
 
     def node_desc(self) -> str:
         return (f"{type(self).__name__}[{self.format_name}, "

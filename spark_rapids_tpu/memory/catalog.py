@@ -442,7 +442,8 @@ class BufferCatalog:
     def _spill_one_to_host_locked(self, e: _Entry) -> None:
         self._check_cancel()
         leaves, treedef = jax.tree_util.tree_flatten(e.batch)
-        host = jax.device_get(leaves)
+        from spark_rapids_tpu.exec.core import fetch_to_host
+        host = fetch_to_host(leaves, "fetch@BufferCatalog.spill")
         metas, total = [], 0
         host = [np.asarray(a) for a in host]
         for a in host:
@@ -842,7 +843,13 @@ class DeviceSemaphore:
         self.concurrency = concurrency
 
     def __enter__(self):
-        self._sem.acquire()
+        if not self._sem.acquire(blocking=False):
+            # the chip is occupied: the seconds a dispatch waits for it
+            from spark_rapids_tpu.obs.registry import get_registry
+            t0 = time.perf_counter()
+            self._sem.acquire()
+            get_registry().inc("dispatch_wait_s",
+                               time.perf_counter() - t0)
         return self
 
     def __exit__(self, *exc):

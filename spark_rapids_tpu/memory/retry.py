@@ -105,7 +105,8 @@ def _shared_slice():
     w = _SHARED_SLICE.get("slice")
     if w is None:
         from spark_rapids_tpu.exec.compile_cache import instrument
-        w = _SHARED_SLICE.setdefault("slice", instrument(_slice_rows_jit))
+        w = _SHARED_SLICE.setdefault("slice", instrument(_slice_rows_jit,
+                                                      "batch_slice_rows"))
     return w
 
 

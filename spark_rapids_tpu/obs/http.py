@@ -16,6 +16,10 @@ same operational surface as three read-only routes:
   state/tenant/tenant wall so far), the live analog of the history log;
   with profiling on each row also carries rows-processed,
   percent-complete, and ETA against the plan's history medians.
+  ``finished`` holds the last 64 queries' records (query_id, state,
+  start/end, and the counter movement over the query: spans, transfers,
+  waits, the per-program table — "what did my last queries cost and
+  where").
 * ``/control`` — the self-driving control plane's learned state
   (current admission cap, adapted governor watermarks, per-tenant SLO
   status, last 32 decisions), or ``{"enabled": false}`` when the
@@ -284,7 +288,10 @@ class ObsHttpServer:
                 from spark_rapids_tpu.obs.profile import live_progress
                 row.update(live_progress(lc, idx))
             out[qid] = row
-        return {"active": out, "count": len(out)}
+        # what the last queries cost and where: the per-query records
+        # (exec/lifecycle.py QueryLifecycle.seal_record), newest last
+        return {"active": out, "count": len(out),
+                "finished": get_registry().recent_queries()}
 
     def close(self) -> None:
         self._server.shutdown()

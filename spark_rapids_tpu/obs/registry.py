@@ -15,7 +15,28 @@ a scrape endpoint is one ``open().write()`` away.
 
 Dependency discipline: this module imports nothing from the engine (only
 stdlib), so hot modules (shuffle/retry.py, faults.py) may import it at
-module level without creating cycles or dragging jax into light paths.
+module level without creating cycles or dragging jax into light paths
+(``span`` imports ``jax.profiler`` when it is first called, not before).
+
+Spans (``span``) are the engine's one span primitive: a
+``jax.profiler.TraceAnnotation`` — on the clock the device planes of a
+profiler trace share, free when no profiler session is open — that on
+exit moves ``span.<name>.count`` and ``span.<name>.seconds`` (host
+seconds).  Names: ``query`` > ``query.plan`` / ``query.execute`` /
+``query.fetch`` (session.py), ``program.compile@<program>``
+(exec/compile_cache.py), and ``<phase>@<Operator>Exec`` for work done
+for an operator outside its per-pull annotation — ``decode@`` /
+``stage@ParquetScanExec`` on the scan's worker threads (io/scan.py),
+``fetch@HashAggregateExec`` … around every blocking device fetch
+(exec/core.py ``fetch_to_host``).  The counters at the same seams:
+``program.<name>.launches`` / ``.arg_bytes`` / ``.result_bytes`` per
+SharedJit program, ``dispatch_wait_s`` (blocked on the DeviceSemaphore),
+``h2d_calls`` / ``h2d_bytes`` (one per ``jax.device_put``), ``d2h_calls``
+/ ``d2h_bytes`` / ``sync_wait_s`` (host blocked inside a fetch), and
+``scan_backpressure_s`` (scan worker blocked on its full queue).  One
+record per finished query — the counter movement over its interval —
+is kept in a ring of ``RECENT_QUERIES`` entries (``recent_queries``);
+exec/lifecycle.py fills it.
 
 Well-known counter families (beyond per-object sources):
 ``shuffle.fetch.*`` (retry ladder), ``faults.injected[.point]``
@@ -25,8 +46,12 @@ Well-known counter families (beyond per-object sources):
 once per query at the admission decision or the first terminal
 transition, so a delta over a run counts QUERIES, not checkpoints); and
 the compile plane's ``compile_count`` / ``compile_wall_s`` (one move per
-NEW jit input signature — a zero delta across a repeated query proves
-pure cache reuse) plus ``fusion_cache_hits`` / ``fusion_cache_misses``
+NEW jit input signature of a SharedJit program — a zero delta across a
+repeated query proves pure cache reuse; they are blind to the compiles
+of eager ``jnp`` operations outside any program, a 20 s ``argsort``
+among them, which only a ``jax.monitoring`` duration listener on
+``/jax/core/compile/backend_compile_duration`` sees:
+benchmark/harness/compiles.py, chip_smoke.py) plus ``fusion_cache_hits`` / ``fusion_cache_misses``
 (process-wide program-cache lookups, exec/compile_cache.py); and the
 adaptive-execution plane's ``aqe_broadcast_switches`` (shuffle-join ->
 broadcast-join rewrites, plan/adaptive.py) /
@@ -83,7 +108,9 @@ import bisect
 import json
 import re
 import threading
+import time
 import weakref
+from collections import deque
 
 _SAN = re.compile(r"[^a-zA-Z0-9_]")
 
@@ -255,6 +282,37 @@ class Histogram:
             self._count = merged["count"]
 
 
+#: finished-query records kept in memory (``recent_queries``)
+RECENT_QUERIES = 64
+
+
+class _Span:
+    """One open span: the profiler annotation plus the host clock that
+    feeds ``span.<name>.count`` / ``.seconds``.  ``seconds`` is readable
+    after exit."""
+
+    __slots__ = ("_reg", "name", "_ann", "_t0", "seconds")
+
+    def __init__(self, reg: "MetricsRegistry", name: str, ann):
+        self._reg = reg
+        self.name = name
+        self._ann = ann
+        self.seconds = 0.0
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        name = self.name
+        self._reg.inc_many(((f"span.{name}.count", 1),
+                            (f"span.{name}.seconds", self.seconds)))
+        return False
+
+
 class MetricsRegistry:
     """Thread-safe counters + gauges + histograms + pull sources."""
 
@@ -264,12 +322,30 @@ class MetricsRegistry:
         self._gauges: dict[str, float] = {}
         self._sources: dict[str, object] = {}
         self._histograms: dict[str, Histogram] = {}
+        self._recent: deque = deque(maxlen=RECENT_QUERIES)
 
     # -- write side --------------------------------------------------------
 
     def inc(self, name: str, value: float = 1) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + value
+
+    def inc_many(self, pairs) -> None:
+        """``inc`` for several ``(name, value)`` pairs under one lock
+        acquisition (a span's two counters, a launch's three)."""
+        with self._lock:
+            counters = self._counters
+            for name, value in pairs:
+                counters[name] = counters.get(name, 0) + value
+
+    def span(self, name: str, **args) -> _Span:
+        """Context manager: a ``jax.profiler.TraceAnnotation`` named
+        ``name`` (``args`` — query_id, parent — are its metadata) whose exit
+        adds 1 to ``span.<name>.count`` and the elapsed host seconds to
+        ``span.<name>.seconds``.  With no profiler session open the
+        annotation costs a flag check."""
+        from jax.profiler import TraceAnnotation
+        return _Span(self, name, TraceAnnotation(name, **args))
 
     def set_gauge(self, name: str, value: float) -> None:
         with self._lock:
@@ -318,6 +394,30 @@ class MetricsRegistry:
         return name
 
     # -- read side ---------------------------------------------------------
+
+    def counters(self) -> dict:
+        """A copy of the counter dict alone — what a per-query record
+        differences; unlike ``snapshot`` it walks no pull source."""
+        with self._lock:
+            return dict(self._counters)
+
+    def counters_since(self, before: dict) -> dict:
+        """Counters that moved since ``before`` (a ``counters()``)."""
+        return {k: v - before.get(k, 0)
+                for k, v in self.counters().items()
+                if v != before.get(k, 0)}
+
+    def note_query(self, record: dict) -> None:
+        """Append one finished query's record to the bounded ring."""
+        with self._lock:
+            self._recent.append(record)
+
+    def recent_queries(self, n: "int | None" = None) -> list:
+        """The last ``n`` (default: all kept, at most
+        ``RECENT_QUERIES``) finished-query records, oldest first."""
+        with self._lock:
+            out = list(self._recent)
+        return out if n is None else out[len(out) - min(n, len(out)):]
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -415,6 +515,7 @@ class MetricsRegistry:
             self._gauges.clear()
             self._sources.clear()
             self._histograms.clear()
+            self._recent.clear()
 
 
 _REGISTRY = MetricsRegistry()

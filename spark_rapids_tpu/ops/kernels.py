@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
+from spark_rapids_tpu.obs.registry import get_registry
 
 __all__ = ["compact", "take", "concat_batches", "slice_batch",
            "slice_rows", "gather_columns", "shrink_capacity",
@@ -35,7 +36,11 @@ def device_scalar(value, dtype_str: str = "int32") -> jax.Array:
     batch.  The analog
     of the reference pinning small Scalars on the GPU across kernel
     launches (GpuScalar caching, GpuExpressionsUtils.scala)."""
-    return jnp.asarray(value, jnp.dtype(dtype_str))
+    dtype = jnp.dtype(dtype_str)
+    # a miss is one tiny H2D; hits never reach this body
+    get_registry().inc_many((("h2d_calls", 1),
+                             ("h2d_bytes", dtype.itemsize)))
+    return jnp.asarray(value, dtype)
 
 
 def _gather_column(col: DeviceColumn, perm: jax.Array,
@@ -143,7 +148,7 @@ def shrink_capacity(batch: ColumnBatch, cap: int) -> ColumnBatch:
     """
     if batch.capacity <= cap:
         return batch
-    return _shared("shrink", _shrink_jit)(batch, cap)
+    return _shared("batch_shrink", _shrink_jit)(batch, cap)
 
 
 _SHARED_JITS: dict = {}
@@ -161,7 +166,7 @@ def _shared(name: str, fn):
     w = _SHARED_JITS.get(name)
     if w is None:
         from spark_rapids_tpu.exec.compile_cache import instrument
-        w = _SHARED_JITS.setdefault(name, instrument(fn))
+        w = _SHARED_JITS.setdefault(name, instrument(fn, name))
     return w
 
 
@@ -182,7 +187,7 @@ def pad_capacity(batch: ColumnBatch, cap: int) -> ColumnBatch:
     (cheap realloc; keeps compilation buckets canonical)."""
     if cap <= batch.capacity:
         return batch
-    return _shared("pad", _pad_jit)(batch, cap)
+    return _shared("batch_pad", _pad_jit)(batch, cap)
 
 
 @partial(jax.jit, static_argnames=("cap",))

@@ -114,7 +114,8 @@ def split_shards(stacked: ColumnBatch) -> list[ColumnBatch]:
 def unshard_batch(stacked: ColumnBatch) -> list[ColumnBatch]:
     """Pull a sharded batch back to P host-side ColumnBatch shards."""
     leaves, treedef = jax.tree_util.tree_flatten(stacked)
-    host = jax.device_get(leaves)
+    from spark_rapids_tpu.exec.core import fetch_to_host
+    host = fetch_to_host(leaves, "fetch@unshard_batch")
     p = host[-1].shape[0] if host else 1  # num_rows is int32[P]
     out = []
     for i in range(p):

@@ -200,7 +200,7 @@ def make_hash_exchange(mesh: Mesh, schema: T.Schema,
     mapped = shard_map(step, mesh=mesh, in_specs=P(axis_name),
                            out_specs=P(axis_name))
     from spark_rapids_tpu.exec.compile_cache import instrument
-    return instrument(jax.jit(mapped))
+    return instrument(jax.jit(mapped), "mesh_hash_exchange")
 
 
 # Merge-side op per update op (reference: CudfAggregate mergeAggregate,
@@ -262,4 +262,4 @@ def make_distributed_groupby(mesh: Mesh, schema: T.Schema,
     mapped = shard_map(step, mesh=mesh, in_specs=P(axis_name),
                            out_specs=P(axis_name))
     from spark_rapids_tpu.exec.compile_cache import instrument
-    return instrument(jax.jit(mapped))
+    return instrument(jax.jit(mapped), "mesh_distributed_group_by")

@@ -205,12 +205,18 @@ class TpuSession:
             conf = conf.set(k, v)
         return conf
 
-    def _run_query(self, node, backend: str,
+    def _run_query(self, plan,
                    timeout: float | None = None, logical=None,
                    tenant: str | None = None,
                    conf: "TpuConf | None" = None) -> list[tuple]:
-        """Result-cache lookup -> admission -> lifecycle registration
-        -> execution -> cleanup for one collect.  The lifecycle is
+        """Planning -> result-cache lookup -> admission -> lifecycle
+        registration -> execution -> cleanup for one collect.
+        ``plan()`` returns ``(exec node, backend)``: it runs here, under
+        the ``query.plan`` span, so that the query's id and its record
+        (exec/lifecycle.py ``open_record``/``seal_record``) exist from
+        the collect's entry.  Spans: ``query`` > ``query.plan``,
+        ``query.execute`` (the executor, which also holds the result's
+        ``query.fetch`` spans, one per result batch).  The lifecycle is
         registered in ``_live`` BEFORE admission so a cancel reaches a
         query still waiting in the queue (releasing its queue slot;
         counted once as cancelled, never rejected).  The ExecCtx cache
@@ -223,12 +229,16 @@ class TpuSession:
         import uuid
         from spark_rapids_tpu.exec.lifecycle import (QueryLifecycle,
                                                      QueryLifecycleError)
+        from spark_rapids_tpu.obs.registry import get_registry
         if conf is None:
             conf = self.conf
+        reg = get_registry()
         admission = self._admission_controller()
         query_id = uuid.uuid4().hex[:16]
         lc = QueryLifecycle.from_conf(query_id, conf,
                                       timeout=timeout, tenant=tenant)
+        lc.open_record()
+        node = backend = None       # set by plan(), inside the query span
         # the control plane's per-tenant SLOs are end-to-end (queue
         # wait + wall): only control-enabled sessions emit the extra
         # e2e histogram, so a static engine's counter set is untouched
@@ -243,8 +253,10 @@ class TpuSession:
             admitted = True
             lc.start()
             try:
-                out = self._execute_collect(node, backend, query_id, lc,
-                                            conf)
+                with reg.span("query.execute", query_id=query_id,
+                              parent="query"):
+                    out = self._execute_collect(node, backend, query_id,
+                                                lc, conf)
             except QueryLifecycleError:
                 raise
             except BaseException:
@@ -266,8 +278,7 @@ class TpuSession:
         submitted = None
         if hist_dir:
             import time as _time
-            from spark_rapids_tpu.obs.registry import get_registry
-            hist_before = get_registry().snapshot()
+            hist_before = reg.snapshot()
             submitted = _time.time()
         # raw-settings gated like trace/history: with profile.enabled
         # unset (the default) obs.profile/obs.metering are never
@@ -276,8 +287,7 @@ class TpuSession:
             "spark.rapids.obs.profile.enabled", "")).lower() \
             in ("true", "1", "yes")
         if prof_on and hist_before is None:
-            from spark_rapids_tpu.obs.registry import get_registry
-            hist_before = get_registry().snapshot()
+            hist_before = reg.snapshot()
         if prof_on:
             # the meter's registry baseline must predate THIS query's
             # counter movement (queries_executed incs at executor entry,
@@ -303,30 +313,39 @@ class TpuSession:
                 pass
         err: BaseException | None = None
         try:
-            rcache = None
-            key = None
-            if logical is not None and not admission.shutting_down:
-                from spark_rapids_tpu.exec.result_cache import maybe_cache
-                rcache = maybe_cache(conf)
-                if rcache is not None:
-                    # backend is part of the key: the host oracle must
-                    # never be served a device run's rows (differential
-                    # testing would silently compare a cache to itself).
-                    # The ROUTED conf is part of the key too — an
-                    # express-routed run and a full-mesh run of the
-                    # same logical plan are different computations.
-                    key = rcache.result_key(logical, backend, conf)
-            if key is None:
-                out = run()
-            else:
-                out = rcache.get_or_compute(
-                    key, run, lifecycle=lc, faults=admission.faults)
-                lc.finish()
+            with reg.span("query", query_id=query_id):
+                with reg.span("query.plan", query_id=query_id,
+                              parent="query"):
+                    node, backend = plan()
+                rcache = None
+                key = None
+                if logical is not None and not admission.shutting_down:
+                    from spark_rapids_tpu.exec.result_cache import \
+                        maybe_cache
+                    rcache = maybe_cache(conf)
+                    if rcache is not None:
+                        # backend is part of the key: the host oracle
+                        # must never be served a device run's rows
+                        # (differential testing would silently compare
+                        # a cache to itself).  The ROUTED conf is part
+                        # of the key too — an express-routed run and a
+                        # full-mesh run of the same logical plan are
+                        # different computations.
+                        key = rcache.result_key(logical, backend, conf)
+                if key is None:
+                    out = run()
+                else:
+                    out = rcache.get_or_compute(
+                        key, run, lifecycle=lc, faults=admission.faults)
+                    lc.finish()
             return out
         except BaseException as e:
             err = e
             raise
         finally:
+            # the record closes after the ``query`` span, so the span
+            # is in it
+            lc.seal_record(err)
             metered = None
             if prof_on:
                 metered = self._meter_query(lc, hist_before, conf)
@@ -388,17 +407,14 @@ class TpuSession:
         # enginelint: disable=RL001 (history is best-effort forensics)
         try:
             import time as _time
-            from spark_rapids_tpu.exec.lifecycle import (TERMINAL_STATES,
-                                                         QueryRejected)
             from spark_rapids_tpu.obs.history import history_log
             from spark_rapids_tpu.obs.registry import get_registry
             log = history_log(self.conf)
             if log is None:
                 return
-            state = lc.state
-            if state not in TERMINAL_STATES:
-                state = "REJECTED" if isinstance(err, QueryRejected) \
-                    else ("FAILED" if err is not None else state)
+            # the sealed per-query record names the state (REJECTED /
+            # FAILED for a query stopped before any transition)
+            state = lc.seal_record(err)["state"]
             started = lc._started_at
             if conf is None:
                 conf = self.conf
@@ -811,11 +827,14 @@ class DataFrame:
         # plan may run under a history-learned conf (express lane /
         # best mesh shape); otherwise this is self._s.conf unchanged
         conf = self._s._routed_conf(self._plan)
-        ov, meta = self._overridden(conf=conf)
-        backend = "device" if meta.backend == "device" else "host"
-        return self._s._run_query(meta.exec_node, backend,
-                                  timeout=timeout, logical=self._plan,
-                                  tenant=tenant, conf=conf)
+
+        def plan():
+            ov, meta = self._overridden(conf=conf)
+            return meta.exec_node, \
+                "device" if meta.backend == "device" else "host"
+        return self._s._run_query(plan, timeout=timeout,
+                                  logical=self._plan, tenant=tenant,
+                                  conf=conf)
 
     def to_arrow(self):
         import pyarrow as pa
@@ -898,7 +917,8 @@ class DataFrame:
         ov, meta = wdf._overridden()
         # logical=None: a side-effecting job must execute — it never
         # serves from (or populates) the result cache
-        self._s._run_query(meta.exec_node, meta.backend, logical=None)
+        self._s._run_query(lambda: (meta.exec_node, meta.backend),
+                           logical=None)
         return meta.exec_node.stats
 
     # -- internals -----------------------------------------------------

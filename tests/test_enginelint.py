@@ -131,6 +131,16 @@ def test_rl003_flags_sync_calls_in_exec():
     assert len(_active(_lint(src), "RL003")) == 2
 
 
+def test_rl003_flags_the_counted_fetch_helper():
+    src = """
+    from spark_rapids_tpu.exec.core import fetch_to_host
+
+    def pull(batches):
+        return fetch_to_host(batches[0], "fetch@SomeExec")
+    """
+    assert len(_active(_lint(src), "RL003")) == 1
+
+
 def test_rl003_whitelisted_modules_and_other_layers_pass():
     src = "import jax\n\ndef f(x):\n    return jax.device_get(x)\n"
     for rel in ("spark_rapids_tpu/exec/transitions.py",

@@ -144,8 +144,8 @@ def test_same_fragment_hits():
     k2 = cc.fragment_key("filter", _bound_filter_cond(5))
     assert k1 == k2
     before = get_registry().snapshot()
-    j1 = cc.shared_jit(k1, lambda b: b)
-    j2 = cc.shared_jit(k2, lambda b: b)
+    j1 = cc.shared_jit(k1, lambda b: b, name="test_identity")
+    j2 = cc.shared_jit(k2, lambda b: b, name="test_identity")
     assert j1 is j2
     moved = get_registry().delta(before)["counters"]
     assert moved.get("fusion_cache_hits", 0) >= 1
@@ -189,7 +189,7 @@ def test_capacity_bucket_is_signature_level():
     once — re-dispatching an old bucket moves nothing."""
     import jax.numpy as jnp
     key = cc.fragment_key("test_capacity_bucket", "x")
-    j = cc.shared_jit(key, lambda x: x + 1)
+    j = cc.shared_jit(key, lambda x: x + 1, name="test_add_one")
     reg = get_registry()
 
     def compiles(arr):

@@ -349,8 +349,13 @@ def test_ctx_ids_stable_and_tracer_disabled_by_default():
         assert ctx.query_id == ctx.query_id
         assert ctx.trace_id == ctx.query_id
         assert ctx.tracer is None
-        import contextlib
-        assert isinstance(ctx.trace_span("x"), contextlib.nullcontext)
+        # tracer off: the span is the registry's alone (profiler
+        # annotation + span.<name> counters) and yields no tracer span
+        from spark_rapids_tpu.obs.registry import get_registry
+        before = get_registry().counters().get("span.x.count", 0)
+        with ctx.trace_span("x") as sp:
+            assert sp is None
+        assert get_registry().counters()["span.x.count"] == before + 1
 
 
 def test_ctx_trace_export_on_close(tmp_path):

@@ -1,0 +1,405 @@
+"""The engine's own spans and counters, and the per-query record made of
+them (obs/registry.py ``span`` / ``recent_queries``, exec/lifecycle.py
+``open_record`` / ``seal_record``): deterministic on XLA:CPU — counts
+and byte sums are asserted exactly, seconds only for order and sign.
+"""
+import ast
+import math
+import os
+
+import numpy as np
+import pyarrow as pa
+import pyarrow.parquet as pq
+import pytest
+
+from spark_rapids_tpu import TpuSession
+from spark_rapids_tpu import types as T
+from spark_rapids_tpu.exec import compile_cache as cc
+from spark_rapids_tpu.exec.aggregate import HashAggregateExec
+from spark_rapids_tpu.expr.aggregates import CountStar, Sum
+from spark_rapids_tpu.expr.core import col, lit
+from spark_rapids_tpu.obs.registry import (RECENT_QUERIES, MetricsRegistry,
+                                           get_registry)
+
+PKG = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "spark_rapids_tpu")
+CONF = {"spark.rapids.sql.resultCache.enabled": "false",
+        "spark.rapids.sql.test.enabled": "true"}
+FACT_FILES = 3
+
+
+@pytest.fixture(scope="module")
+def tables(tmp_path_factory):
+    root = tmp_path_factory.mktemp("query_record")
+    rng = np.random.default_rng(7)
+    os.makedirs(root / "fact")
+    os.makedirs(root / "dim")
+    for i in range(FACT_FILES):
+        n = 4000
+        pq.write_table(pa.table({
+            "k": rng.integers(0, 50, n), "g": rng.integers(0, 5, n),
+            "v": rng.random(n)}), str(root / "fact" / f"part-{i}.parquet"))
+    pq.write_table(pa.table({"k": np.arange(50), "w": rng.random(50)}),
+                   str(root / "dim" / "part-0.parquet"))
+    return str(root)
+
+
+@pytest.fixture(scope="module")
+def session():
+    s = TpuSession(dict(CONF))
+    yield s
+    s.shutdown(drain=False)
+
+
+@pytest.fixture(scope="module")
+def query(session, tables):
+    """scan -> filter -> join -> aggregate, collected once (compiles)."""
+    fact = session.read_parquet(os.path.join(tables, "fact"))
+    dim = session.read_parquet(os.path.join(tables, "dim"))
+    df = fact.where(col("v") > lit(0.1)).join(dim, on="k") \
+        .group_by("g").agg(Sum(col("w")).alias("sw"),
+                           CountStar().alias("n"))
+    assert len(df.collect()) == 5
+    return df
+
+
+def _collect(df) -> dict:
+    """One collect; its record."""
+    df.collect()
+    return get_registry().recent_queries(1)[0]
+
+
+def _launches(counters: dict) -> int:
+    return sum(v for k, v in counters.items()
+               if k.startswith("program.") and k.endswith(".launches"))
+
+
+# ---------------------------------------------------------------- record
+
+def test_record_holds_the_query_phases(query):
+    rec = _collect(query)
+    c = rec["counters"]
+    assert rec["state"] == "FINISHED"
+    assert len(rec["query_id"]) == 16
+    assert rec["start_unix_s"] <= rec["end_unix_s"]
+    assert set(rec["spill"]) == {"bytes_spilled_to_host",
+                                 "bytes_spilled_to_disk", "device_spills"}
+    for phase in ("query", "query.plan", "query.execute"):
+        assert c[f"span.{phase}.count"] == 1, phase
+    assert c["span.query.fetch.count"] >= 1     # one per result batch
+    # query holds plan and execute; execute holds the result's fetches
+    assert c["span.query.seconds"] >= (c["span.query.plan.seconds"]
+                                       + c["span.query.execute.seconds"])
+    assert c["span.query.execute.seconds"] >= c["span.query.fetch.seconds"]
+    # the seams of this plan: scan workers, both chunked fetches
+    for span in ("decode@ParquetScanExec", "stage@ParquetScanExec",
+                 "fetch@JoinExec", "fetch@HashAggregateExec"):
+        assert c[f"span.{span}.count"] >= 1, span
+    assert c["queries_executed"] == 1
+    assert c["sync_wait_s"] > 0 and c["scan_backpressure_s"] >= 0
+    assert "compile_count" not in c     # a warm collect compiles nothing
+
+
+def test_h2d_counters_equal_the_buffers_put(query, monkeypatch):
+    import jax
+    put = []
+    real = jax.device_put
+
+    def spy(x, *a, **k):
+        if isinstance(x, np.ndarray):     # _PackBuilder.build's buffers
+            put.append(x.nbytes)
+        return real(x, *a, **k)
+    monkeypatch.setattr(jax, "device_put", spy)
+    c = _collect(query)["counters"]
+    assert put and c["h2d_calls"] == len(put)
+    assert c["h2d_bytes"] == sum(put)
+    # what was put is exactly what the unpack program was handed
+    assert c["program.batch_unpack.arg_bytes"] == sum(put)
+    assert c["program.batch_unpack.launches"] == FACT_FILES + 1
+
+
+def test_counts_repeat_exactly_over_collects(query):
+    a, b = _collect(query)["counters"], _collect(query)["counters"]
+    assert _launches(a) == _launches(b) > 0
+    for key in ("h2d_calls", "h2d_bytes", "d2h_calls", "d2h_bytes"):
+        assert a[key] == b[key] > 0, key
+    programs = {k for k in a if k.startswith("program.")}
+    assert programs == {k for k in b if k.startswith("program.")}
+    assert all(a[k] == b[k] for k in programs)
+
+
+@pytest.fixture(scope="module")
+def small_batch_session():
+    """Coalescing off in effect: a 64-row batch is over the target, so
+    the aggregate sees the scan's batches one by one."""
+    s = TpuSession(dict(CONF, **{"spark.rapids.sql.batchSizeBytes": "512"}))
+    yield s
+    s.shutdown(drain=False)
+
+
+@pytest.mark.parametrize("batches", [1, 5, 8, 20])
+def test_aggregate_fetches_once_per_sync_chunk(small_batch_session, batches):
+    rows = 64
+    n = rows * batches
+    df = small_batch_session.from_pydict(
+        {"g": [i % 7 for i in range(n)], "v": [1.0] * n},
+        T.Schema([T.StructField("g", T.IntegerType()),
+                  T.StructField("v", T.DoubleType())]),
+        rows_per_batch=rows).group_by("g").agg(Sum(col("v")).alias("s"))
+    df.collect()                        # compiles
+    c = _collect(df)["counters"]
+    flushes = math.ceil(batches / HashAggregateExec._SYNC_CHUNK)
+    merges = 1 if batches > 1 else 0    # the merged buffer's row count
+    assert c["span.fetch@HashAggregateExec.count"] == flushes + merges
+    assert c["program.agg_update.launches"] == batches
+    assert c["d2h_calls"] >= flushes + merges + 1   # + the result
+
+
+def test_a_failed_query_leaves_its_record(tables):
+    s = TpuSession(dict(CONF, **{
+        "spark.rapids.test.faults": "memory.oom:oom,times=0"}))
+    try:
+        df = s.read_parquet(os.path.join(tables, "fact")) \
+            .group_by("g").agg(Sum(col("v")).alias("s"))
+        before = len(get_registry().recent_queries())
+        with pytest.raises(Exception) as err:
+            df.collect()
+        assert not getattr(err.value, "terminal", False)
+        rec = get_registry().recent_queries(1)[0]
+        assert rec["state"] == "FAILED"
+        assert rec["counters"]["span.query.count"] == 1
+        assert rec["counters"]["faults.injected.memory.oom"] >= 1
+        assert len(get_registry().recent_queries()) == \
+            min(before + 1, RECENT_QUERIES)
+    finally:
+        s.shutdown(drain=False)
+
+
+def test_record_walks_no_pull_source(query):
+    walked = []
+    get_registry().register_source("probe_source",
+                                   lambda: walked.append(1) or {})
+    try:
+        _collect(query)
+    finally:
+        get_registry().unregister_source("probe_source")
+    assert not walked
+
+
+def test_queries_endpoint_serves_the_finished_records(session, query):
+    from spark_rapids_tpu.obs.http import ObsHttpServer
+    rec = _collect(query)
+    server = ObsHttpServer(session, 0)
+    try:
+        body = server.queries()
+    finally:
+        server.close()
+    assert body["count"] == 0 and body["active"] == {}
+    assert body["finished"][-1]["query_id"] == rec["query_id"]
+    assert body["finished"][-1]["counters"] == rec["counters"]
+
+
+def test_ring_is_bounded():
+    reg = MetricsRegistry()
+    for i in range(RECENT_QUERIES + 6):
+        reg.note_query({"query_id": str(i)})
+    kept = reg.recent_queries()
+    assert RECENT_QUERIES == 64 and len(kept) == 64
+    assert kept[0]["query_id"] == "6" and kept[-1]["query_id"] == "69"
+    assert [r["query_id"] for r in reg.recent_queries(3)] == \
+        ["67", "68", "69"]
+    assert len(reg.recent_queries(1000)) == 64
+
+
+def test_span_counts_and_times_once():
+    reg = MetricsRegistry()
+    with reg.span("outer", query_id="q") as outer:
+        with reg.span("inner@SomeExec"):
+            pass
+    c = reg.counters()
+    assert c["span.outer.count"] == c["span.inner@SomeExec.count"] == 1
+    assert c["span.outer.seconds"] == outer.seconds
+    assert outer.seconds >= c["span.inner@SomeExec.seconds"] >= 0
+    assert reg.counters_since(c) == {}
+    with pytest.raises(KeyError):
+        with reg.span("outer"):
+            raise KeyError("propagates, and is still counted")
+    assert reg.counters_since(c)["span.outer.count"] == 1
+
+
+# -------------------------------------------------------------- programs
+
+def test_program_bytes_are_the_avals_sizes():
+    import jax.numpy as jnp
+    prog = cc.shared_jit(cc.fragment_key("test_bytes", "record"),
+                         lambda x, y, scale: (x * scale + y, y[:16]),
+                         name="test_record_bytes")
+    x = jnp.ones((128, 4), jnp.float32)         # 2048 bytes
+    y = jnp.ones((128, 4), jnp.int8)            # 512 bytes
+    before = get_registry().counters()
+    for _ in range(3):
+        prog(x, y, 2)       # the python scalar is a leaf with no bytes
+    moved = get_registry().counters_since(before)
+    assert moved["program.test_record_bytes.launches"] == 3
+    assert moved["program.test_record_bytes.arg_bytes"] == 3 * (2048 + 512)
+    assert moved["program.test_record_bytes.result_bytes"] == \
+        3 * (2048 * 4 // 4 + 16 * 4)            # f32[128,4] + int8[16,4]
+    assert moved["compile_count"] == 1
+    assert moved["span.program.compile@test_record_bytes.count"] == 1
+    assert prog.signature_count() == 1 and prog.name == "test_record_bytes"
+    # the trace's module name is the program's name
+    assert "module @jit_test_record_bytes" in prog.fn.lower(x, y, 2).as_text()
+
+
+def _jit_sites(path: str) -> list:
+    """``(name or None, line)`` of every program a module hands to
+    SharedJit: ``instrument(fn, NAME)``, ``shared_jit(key, fn,
+    name=NAME)``, ``guarded_jit(NAME, ...)``, kernels' ``_shared(NAME,
+    fn)``.  A conditional between two literals yields both."""
+    def literals(node):
+        if isinstance(node, ast.Constant) and isinstance(node.value, str):
+            return [node.value]
+        if isinstance(node, ast.IfExp):
+            return literals(node.body) + literals(node.orelse)
+        return [None]
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    found = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        fn = node.func
+        called = fn.attr if isinstance(fn, ast.Attribute) else \
+            fn.id if isinstance(fn, ast.Name) else ""
+        if called in ("instrument", "SharedJit") and len(node.args) == 2:
+            arg = node.args[1]
+        elif called in ("guarded_jit", "_guarded_jit", "_shared") \
+                and node.args:
+            arg = node.args[0]
+        elif called == "shared_jit":
+            arg = next((k.value for k in node.keywords
+                        if k.arg == "name"), ast.Constant(None))
+        elif called in ("instrument", "guarded_jit", "_guarded_jit"):
+            arg = ast.Constant(None)    # a site that names nothing
+        else:
+            continue
+        found.extend((name, node.lineno) for name in literals(arg))
+    return found
+
+
+JIT_MODULES = sorted(
+    os.path.relpath(os.path.join(d, f), PKG)
+    for sub in ("exec", "ops", "columnar", "parallel", "memory")
+    for d, _, files in os.walk(os.path.join(PKG, sub))
+    for f in files if f.endswith(".py") and f != "compile_cache.py"
+    and _jit_sites(os.path.join(d, f)))
+
+
+def test_every_jit_module_is_found():
+    assert len(JIT_MODULES) >= 16
+    for must in ("exec/aggregate.py", "exec/joins.py", "exec/mesh_region.py",
+                 "columnar/batch.py", "ops/kernels.py", "memory/retry.py",
+                 "parallel/mesh_shuffle.py"):
+        assert must in JIT_MODULES
+
+
+@pytest.mark.parametrize("module", JIT_MODULES)
+def test_program_names_are_unique(module):
+    """One name per jit site, over every module: a second ``prog`` or an
+    unnamed site fails here, by name."""
+    mine = _jit_sites(os.path.join(PKG, module))
+    # kernels' _shared(name, fn) passes its name on to instrument()
+    mine = [(n, ln) for n, ln in mine
+            if not (module == "ops/kernels.py" and n is None)]
+    names = [n for n, _ in mine]
+    assert all(names), f"{module}: a jit site without a literal name " \
+        f"at lines {[ln for n, ln in mine if not n]}"
+    assert len(set(names)) == len(names), f"{module}: {sorted(names)}"
+    others = {n for m in JIT_MODULES if m != module
+              for n, _ in _jit_sites(os.path.join(PKG, m))}
+    assert not set(names) & others, sorted(set(names) & others)
+    # a bare python name would come back: the name says whose it is
+    assert not set(names) & {"update", "merge", "final", "filt", "prog",
+                             "body", "unpack", "gather", "step", "fn"}
+
+
+def test_live_programs_carry_their_names(query):
+    """Over compile_cache._ALL_SHARED after real queries: the name is
+    on the traced function (the trace's ``jit_<name>``), and one python
+    function is never behind two names."""
+    _collect(query)
+    by_code = {}
+    live = list(cc._ALL_SHARED)
+    assert {"batch_unpack", "filter_batch", "join_probe_fast",
+            "join_gather", "agg_update", "agg_merge",
+            "agg_final"} <= {sj.name for sj in live}
+    for sj in live:
+        inner = getattr(sj.fn, "__wrapped__", None)
+        if inner is None:
+            continue
+        assert inner.__name__ == sj.name
+        code = getattr(inner, "__code__", None)
+        if code is not None and PKG in code.co_filename:
+            by_code.setdefault((code.co_filename, code.co_firstlineno),
+                               set()).add(sj.name)
+    assert by_code and all(len(v) == 1 for v in by_code.values()), \
+        {k: v for k, v in by_code.items() if len(v) > 1}
+
+
+# ----------------------------------------------------------------- trace
+
+def test_worker_and_fetch_spans_share_the_collects_clock(query, tmp_path):
+    """A CPU ``jax.profiler`` trace of one collect, loaded as the
+    benchmark loads it: the scan workers' and the fetches' spans are
+    there (their names pass its ``…Exec`` filter) and lie inside the
+    ``bench.collect`` annotation — one clock."""
+    import jax
+    from benchmark.harness import reduce_trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation(reduce_trace.COLLECT):
+            rec = _collect(query)
+    finally:
+        jax.profiler.stop_trace()
+    path = reduce_trace.find_xplane(str(tmp_path))
+    planes = reduce_trace.load(path)
+    events = [e for ln in planes["host"] for e in ln["events"]]
+    (_, c0, cdur), = [e for e in events if e[0] == reduce_trace.COLLECT]
+    by_name = {}
+    for name, start, dur in events:
+        by_name.setdefault(name, []).append((start, start + dur))
+    counters = rec["counters"]
+    for span in ("decode@ParquetScanExec", "stage@ParquetScanExec",
+                 "fetch@JoinExec", "fetch@HashAggregateExec"):
+        assert len(by_name[span]) == counters[f"span.{span}.count"], span
+        for start, end in by_name[span]:
+            assert c0 <= start <= end <= c0 + cdur, span
+    # the worker threads' spans are on other lines than the puller's
+    lines = {i for i, ln in enumerate(planes["host"])
+             if any(e[0] == "stage@ParquetScanExec" for e in ln["events"])}
+    pullers = {i for i, ln in enumerate(planes["host"])
+               if any(e[0] == reduce_trace.COLLECT for e in ln["events"])}
+    assert lines and not lines & pullers
+    # the query's own spans carry its id (the benchmark's load drops
+    # them: it keeps bench.collect and …Exec names only)
+    data = jax.profiler.ProfileData.from_file(path)
+    ids = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("query", "query.plan", "query.execute",
+                              "query.fetch"):
+                    stats = dict(e.stats)
+                    # (an id of digits alone would be read as a number)
+                    ids.setdefault(e.name, set()).add(
+                        str(stats["query_id"]))
+                    if e.name != "query":
+                        assert stats["parent"] == "query"
+    assert set(ids) == {"query", "query.plan", "query.execute",
+                        "query.fetch"}
+    if not rec["query_id"].replace("e", "").isdigit():
+        assert all(v == {rec["query_id"]} for v in ids.values()), ids
+    assert not any(n.startswith("query") for n in by_name)
