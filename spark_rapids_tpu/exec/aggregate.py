@@ -7,6 +7,18 @@ over the aggregation buffer.  The device kernel here is sort-based
 (:mod:`spark_rapids_tpu.ops.segmented`, the TPU-idiomatic substitute for
 cuDF's hash groupby — see SURVEY.md §7 hard parts).
 
+When the update sorts and when it does not: the per-batch update is ONE
+program (``agg_update``) around ``segmented.group_by_update``.  It
+discovers up to 64 distinct keys in the batch; a batch with that few is
+reduced by mask, group by group, with no sort, gather or scatter over the
+batch, and a batch with more takes the ``sorted_group_by`` branch of the
+same ``lax.cond`` (as do ``percentile`` and string ``min``/``max``,
+always).  The program returns which branch ran beside the group count;
+both ride in the chunk's one stacked fetch and are counted as
+``agg.update.dense`` / ``agg.update.sorted`` in the metrics registry,
+hence in the per-query record.  The cross-batch merge and the final mode
+always sort: they see a handful of small buffers.
+
 Modes mirror Spark's aggregate modes:
 * ``complete`` — one exec does update + cross-batch merge + result;
 * ``partial``  — update only, emits the aggregation buffer (keys +
@@ -28,9 +40,11 @@ from spark_rapids_tpu.expr.core import (Alias, BoundReference, Expression,
                                         bind, eval_device, eval_host,
                                         output_name)
 from spark_rapids_tpu.host.batch import HostBatch
+from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops import kernels as dk
-from spark_rapids_tpu.ops.segmented import AggSpec, sorted_group_by
+from spark_rapids_tpu.ops.segmented import (AggSpec, group_by_update,
+                                            sorted_group_by)
 
 __all__ = ["HashAggregateExec"]
 
@@ -302,12 +316,15 @@ class HashAggregateExec(PlanNode):
             presorted = self._child_presorted() and not self._holistic
 
             def update(b):
+                # -> (buffer batch, int32[2] = group count, took the
+                # sort-free branch): the pair flush_chunk fetches
                 cols = [eval_device(e, b) for e in self._pre_exprs]
                 pre = ColumnBatch(cols, b.num_rows, self._pre_schema)
-                return _relabel_d(
-                    sorted_group_by(pre, key_idx, self._update_specs,
-                                    presorted=presorted),
-                    self._buffer_schema)
+                out, dense = group_by_update(pre, key_idx,
+                                             self._update_specs,
+                                             presorted=presorted)
+                head = jnp.stack([out.num_rows, dense.astype(jnp.int32)])
+                return _relabel_d(out, self._buffer_schema), head
 
             def merge(cat):
                 return _relabel_d(
@@ -319,6 +336,7 @@ class HashAggregateExec(PlanNode):
                 return ColumnBatch(cols, run.num_rows, self._output_schema)
 
             import jax
+            import jax.numpy as jnp
             from spark_rapids_tpu.exec import compile_cache as cc
             key = cc.fragment_key(
                 "agg", presorted, len(key_idx), tuple(self._pre_exprs),
@@ -399,9 +417,13 @@ class HashAggregateExec(PlanNode):
         from spark_rapids_tpu.memory.catalog import (SpillableColumnarBatch,
                                                      SpillPriority)
 
-        def update_pairs(src) -> list:
-            return ctx.dispatch_retry(update_jit, src, op="agg_update",
-                                      pairs=True)
+        # a chunk entry is (source, buffer, head): ``head`` is the device
+        # value the flush fetches — the update program's [group count,
+        # dense flag], or in final mode the incoming buffer's num_rows
+        def update_entries(src) -> list:
+            return [(piece, part, head) for piece, (part, head) in
+                    ctx.dispatch_retry(update_jit, src, op="agg_update",
+                                       pairs=True)]
 
         def flush_chunk(chunk: list) -> None:
             nonlocal total_cap
@@ -410,23 +432,30 @@ class HashAggregateExec(PlanNode):
 
             def redo() -> None:
                 new = []
-                for src, part in chunk:
-                    if src is None:     # final mode: no dispatch to redo
-                        new.append((None, part))
+                for entry in chunk:
+                    if entry[0] is None:  # final mode: no dispatch to redo
+                        new.append(entry)
                     else:
-                        new.extend(update_pairs(src))
+                        new.extend(update_entries(entry[0]))
                 chunk[:] = new
 
-            def sync_counts():
-                if len(chunk) == 1:
-                    return [chunk[0][1].host_num_rows(_FETCH)]
+            def sync_heads():
+                heads = [head for _s, _p, head in chunk]
+                one = len(heads) == 1
+                dev = heads[0] if one else ctx.dispatch(_jnp.stack, heads)
                 # enginelint: disable=RL003 (one stacked transfer for the whole chunk; this IS the batched sync)
-                return list(fetch_to_host(ctx.dispatch(
-                    _jnp.stack, [p.num_rows for _s, p in chunk]), _FETCH))
+                host = fetch_to_host(dev, _FETCH)
+                return [host] if one else list(host)
 
-            ngs = ctx.retry_sync(sync_counts, redo=redo, op="agg_flush")
-            for (src, part), ng in zip(chunk, ngs):
-                ng = int(ng)
+            heads = ctx.retry_sync(sync_heads, redo=redo, op="agg_flush")
+            for (src, part, _h), head in zip(chunk, heads):
+                if src is None:
+                    ng = int(head)
+                else:
+                    ng = int(head[0])
+                    get_registry().inc("agg.update.dense" if head[1]
+                                       else "agg.update.sorted")
+                part.known_rows = ng
                 if isinstance(src, SpillableColumnarBatch):
                     src.close()
                 if ng == 0 and key_idx:
@@ -441,11 +470,12 @@ class HashAggregateExec(PlanNode):
         chunk: list = []
         for b in child_it:
             if self.mode == "final":
-                chunk.append((None, _relabel_d(b, self._buffer_schema)))
+                chunk.append((None, _relabel_d(b, self._buffer_schema),
+                              b.num_rows))
             else:
                 src = SpillableColumnarBatch(b, ctx.catalog,
                                              SpillPriority.READ_SHUFFLE)
-                chunk.extend(update_pairs(src))
+                chunk.extend(update_entries(src))
             if len(chunk) >= self._SYNC_CHUNK:
                 flush_chunk(chunk)
                 chunk = []

@@ -1,10 +1,27 @@
-"""Sort-based group-by aggregation (cuDF groupBy().aggregate analog).
+"""Group-by aggregation: a sort-based kernel (cuDF groupBy().aggregate
+analog) and, for the per-batch update, a sort-free path for few groups.
 
 Reference: GpuHashAggregateExec computes cuDF hash-group-by per batch then
 merges (aggregate.scala:348-560).  XLA has no device hash tables, so the
-TPU-idiomatic design (SURVEY §7 "hard parts") is *sort-based*: sort rows by
-the grouping keys, mark segment boundaries, and reduce with XLA segment ops
-— fully static shapes, group count as a traced scalar.
+TPU-idiomatic design (SURVEY §7 "hard parts") is *sort-based*
+(:func:`sorted_group_by`): sort rows by the grouping keys, mark segment
+boundaries, and reduce with XLA segment ops — fully static shapes, group
+count as a traced scalar.
+
+When the update sorts and when it does not (:func:`group_by_update`, the
+entry point of ``HashAggregateExec``'s per-batch update): the program
+first *discovers* up to ``_DENSE_MAX_GROUPS`` (64) distinct keys by
+repeated equality passes over the unsorted key columns.  If every real
+row found its group, each group is reduced by a mask over the unsorted,
+ungathered input columns and the ≤ 64-row group table alone is sorted —
+cost *groups × one read of the columns*, no ``capacity``-row sort, gather
+or scatter.  If rows remain after 64 groups, the other branch of the same
+``lax.cond`` runs :func:`sorted_group_by` unchanged.  ``percentile`` and
+string ``min``/``max`` need the sort and are routed to it at trace time.
+Which branch ran is returned beside the batch; ``HashAggregateExec``
+counts it as ``agg.update.dense`` / ``agg.update.sorted``.  This is a
+bounded linear probe over at most 64 keys, not a hash table: the merge,
+the mesh aggregates and every high-cardinality input stay sort-based.
 
 Null keys form their own group (Spark semantics); key equality treats
 null == null.  Padding rows are forced into one trailing segment whose
@@ -16,13 +33,20 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
-from spark_rapids_tpu.ops.sort import SortOrder, sort_batch, normalize_floats
+from spark_rapids_tpu.ops.kernels import _pad_jit, gather_columns
+from spark_rapids_tpu.ops.sort import (SortOrder, normalize_floats,
+                                       sort_batch, string_key_words)
 
-__all__ = ["AggSpec", "sorted_group_by"]
+__all__ = ["AggSpec", "sorted_group_by", "group_by_update"]
+
+#: most distinct keys the update's sort-free path holds; an input with
+#: more takes the sort branch of the same program (group_by_update)
+_DENSE_MAX_GROUPS = 64
 
 # supported aggregate ops (reference AggregateFunctions.scala:531 CudfAggregate)
 _AGG_OPS = ("sum", "count", "count_star", "min", "max", "avg", "first", "last",
@@ -119,7 +143,6 @@ def sorted_group_by(batch: ColumnBatch, key_indices: list[int],
 
     out_mask = jnp.arange(cap, dtype=jnp.int32) < num_groups
     out_cols: list[DeviceColumn] = []
-    out_fields: list[T.StructField] = []
 
     # --- key columns: value at each segment start -------------------------
     for ki in key_indices:
@@ -139,48 +162,97 @@ def sorted_group_by(batch: ColumnBatch, key_indices: list[int],
             out_cols.append(DeviceColumn(
                 jnp.where(validity, data, jnp.zeros((), data.dtype)),
                 validity, col.dtype))
-        out_fields.append(batch.schema.fields[ki])
 
     # --- aggregates -------------------------------------------------------
-    seg_real_cnt = _seg_sum(real.astype(jnp.int64), seg_id, cap)
+    out_cols += _agg_columns(sb, aggs, _Segments(seg_id, cap), real, out_mask)
+    return ColumnBatch(out_cols, num_groups,
+                       _output_schema(batch.schema, key_indices, aggs))
+
+
+def _output_schema(schema: T.Schema, key_indices: list[int],
+                   aggs: list[AggSpec]) -> T.Schema:
+    """Key fields (original names/types) then one field per agg."""
+    fields = [schema.fields[ki] for ki in key_indices]
     for spec in aggs:
-        col = sb.columns[spec.child_index] if spec.op != "count_star" else None
-        res_col, res_type = _compute_agg(spec, col, seg_id, real, cap,
-                                         out_mask, seg_real_cnt)
-        out_cols.append(res_col)
-        in_t = col.dtype if col is not None else T.LongType()
-        arg = "1" if spec.op == "count_star" else batch.schema.names[spec.child_index]
-        name = f"count({arg})" if spec.op == "count_star" else f"{spec.op}({arg})"
-        out_fields.append(T.StructField(name, spec.result_type(in_t)))
-
-    return ColumnBatch(out_cols, num_groups, T.Schema(out_fields))
+        if spec.op == "count_star":
+            fields.append(T.StructField("count(1)", T.LongType()))
+            continue
+        in_f = schema.fields[spec.child_index]
+        fields.append(T.StructField(f"{spec.op}({in_f.name})",
+                                    spec.result_type(in_f.data_type)))
+    return T.Schema(fields)
 
 
-def _seg_sum(x, seg_id, cap):
-    return jax.ops.segment_sum(x, seg_id, num_segments=cap)
+class _Segments:
+    """The sorted path's reductions: slot ``s`` of a result reduces the
+    rows whose ``seg_id`` is ``s`` (``cap`` slots)."""
+
+    def __init__(self, seg_id: jax.Array, cap: int):
+        self.seg_id, self.cap = seg_id, cap
+
+    def sum(self, x):
+        return jax.ops.segment_sum(x, self.seg_id, num_segments=self.cap)
+
+    def min(self, x):
+        return jax.ops.segment_min(x, self.seg_id, num_segments=self.cap)
+
+    def max(self, x):
+        return jax.ops.segment_max(x, self.seg_id, num_segments=self.cap)
 
 
-def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
-                 out_mask, seg_real_cnt):
+class _OneGroup:
+    """The dense path's reductions: the whole column is one group and a
+    result has one slot.  ``_compute_agg`` already holds every row that is
+    not ``real`` at the reduction's identity, so passing the group's
+    member mask as ``real`` is all the masking there is."""
+
+    @staticmethod
+    def sum(x):
+        return jnp.sum(x, dtype=x.dtype, keepdims=True)
+
+    @staticmethod
+    def min(x):
+        return jnp.min(x, keepdims=True)
+
+    @staticmethod
+    def max(x):
+        return jnp.max(x, keepdims=True)
+
+
+def _agg_columns(batch: ColumnBatch, aggs: list[AggSpec], red, real,
+                 out_mask) -> list[DeviceColumn]:
+    """One result column per spec; ``red`` reduces rows to result slots
+    (:class:`_Segments` or :class:`_OneGroup`), ``real`` marks the rows
+    that count and ``out_mask`` the result slots that hold a group."""
+    real_cnt = red.sum(real.astype(jnp.int64))
+    return [_compute_agg(
+        spec, None if spec.op == "count_star" else
+        batch.columns[spec.child_index], red, real, out_mask, real_cnt)
+        for spec in aggs]
+
+
+def _compute_agg(spec: AggSpec, col: DeviceColumn | None, red, real,
+                 out_mask, seg_real_cnt) -> DeviceColumn:
     op = spec.op
+    cap = real.shape[0]
     if op == "count_star":
         validity = out_mask
         return DeviceColumn(jnp.where(validity, seg_real_cnt, 0), validity,
-                            T.LongType()), T.LongType()
+                            T.LongType())
 
     contributes = col.validity & real
-    cnt_valid = _seg_sum(contributes.astype(jnp.int64), seg_id, cap)
+    cnt_valid = red.sum(contributes.astype(jnp.int64))
 
     if op == "count":
         validity = out_mask
         return DeviceColumn(jnp.where(validity, cnt_valid, 0), validity,
-                            T.LongType()), T.LongType()
+                            T.LongType())
 
     if op in ("sum", "avg"):
         acc_dt = jnp.int64 if (col.dtype.integral and op == "sum") else jnp.float64
         contrib = jnp.where(contributes, col.data.astype(acc_dt),
                             jnp.zeros((), acc_dt))
-        s = _seg_sum(contrib, seg_id, cap)
+        s = red.sum(contrib)
         if op == "avg":
             data = s.astype(jnp.float64) / jnp.maximum(cnt_valid, 1).astype(jnp.float64)
             rtype = T.DoubleType()
@@ -190,7 +262,7 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
             data, rtype = s.astype(jnp.float64), T.DoubleType()
         validity = (cnt_valid > 0) & out_mask
         return DeviceColumn(jnp.where(validity, data, jnp.zeros((), data.dtype)),
-                            validity, rtype), rtype
+                            validity, rtype)
 
     if op in ("min", "max"):
         if col.dtype.fractional:
@@ -198,19 +270,19 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
             # mask NaNs to +/-inf identities and patch the all/any-NaN cases.
             x = normalize_floats(col.data)
             isnan = jnp.isnan(x)
-            nan_cnt = _seg_sum((contributes & isnan).astype(jnp.int32), seg_id, cap)
-            nonnan_cnt = _seg_sum((contributes & ~isnan).astype(jnp.int32), seg_id, cap)
+            nan_cnt = red.sum((contributes & isnan).astype(jnp.int32))
+            nonnan_cnt = red.sum((contributes & ~isnan).astype(jnp.int32))
             if op == "min":
                 masked = jnp.where(contributes & ~isnan, x,
                                    jnp.full((), jnp.inf, x.dtype))
-                r = jax.ops.segment_min(masked, seg_id, num_segments=cap)
+                r = red.min(masked)
                 # min is NaN only when every contributing value is NaN
                 data = jnp.where((nonnan_cnt == 0) & (nan_cnt > 0),
                                  jnp.full((), jnp.nan, x.dtype), r)
             else:
                 masked = jnp.where(contributes & ~isnan, x,
                                    jnp.full((), -jnp.inf, x.dtype))
-                r = jax.ops.segment_max(masked, seg_id, num_segments=cap)
+                r = red.max(masked)
                 # max is NaN when any contributing value is NaN
                 data = jnp.where(nan_cnt > 0, jnp.full((), jnp.nan, x.dtype), r)
         elif isinstance(col.dtype, T.StringType):
@@ -218,8 +290,8 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
             # (segment, non-contributing-last, string key words) and take
             # each segment's first row (reference: cudf groupby min/max
             # string aggregations)
-            from jax import lax
             from spark_rapids_tpu.ops.sort import encode_key_operands
+            seg_id = red.seg_id     # the sort path only (_dense_covers)
             words = encode_key_operands(col, ascending=(op == "min"))
             flag = (~contributes).astype(jnp.uint8)
             iota = jnp.arange(cap, dtype=jnp.int32)
@@ -239,25 +311,23 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
             validity = (cnt_valid > 0) & out_mask
             return DeviceColumn(jnp.where(validity[:, None], data, 0),
                                 validity, col.dtype,
-                                jnp.where(validity, lens, 0)), col.dtype
+                                jnp.where(validity, lens, 0))
         else:
             info = jnp.iinfo(col.data.dtype) if col.data.dtype != jnp.bool_ else None
             if col.data.dtype == jnp.bool_:
                 d = col.data.astype(jnp.int32)
                 ident = 1 if op == "min" else 0
                 masked = jnp.where(contributes, d, ident)
-                r = (jax.ops.segment_min if op == "min" else jax.ops.segment_max)(
-                    masked, seg_id, num_segments=cap)
+                r = (red.min if op == "min" else red.max)(masked)
                 data = r.astype(jnp.bool_)
             else:
                 ident = info.max if op == "min" else info.min
                 masked = jnp.where(contributes, col.data, ident)
-                data = (jax.ops.segment_min if op == "min" else jax.ops.segment_max)(
-                    masked, seg_id, num_segments=cap)
+                data = (red.min if op == "min" else red.max)(masked)
         validity = (cnt_valid > 0) & out_mask
         zero = jnp.zeros((), data.dtype)
         return DeviceColumn(jnp.where(validity, data, zero), validity,
-                            col.dtype), col.dtype
+                            col.dtype)
 
     if op == "percentile":
         # rows arrive sorted (keys, value asc, value-nulls last), so each
@@ -266,8 +336,7 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
         q = spec.param
         assert q is not None, "percentile AggSpec needs param=q"
         idx = jnp.arange(cap, dtype=jnp.int32)
-        starts = jax.ops.segment_min(jnp.where(real, idx, cap), seg_id,
-                                     num_segments=cap)
+        starts = red.min(jnp.where(real, idx, cap))
         pos = (cnt_valid - 1).astype(jnp.float64) * q
         lo = jnp.floor(pos).astype(jnp.int32)
         hi = jnp.ceil(pos).astype(jnp.int32)
@@ -279,7 +348,7 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
         data = vlo + (vhi - vlo) * frac
         validity = (cnt_valid > 0) & out_mask
         return DeviceColumn(jnp.where(validity, data, 0.0), validity,
-                            T.DoubleType()), T.DoubleType()
+                            T.DoubleType())
 
     if op in ("first", "last", "first_non_null", "last_non_null"):
         # index of first/last row per segment; *_non_null picks among valid
@@ -290,18 +359,153 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, seg_id, real, cap,
         idx = jnp.arange(cap, dtype=jnp.int32)
         if op.startswith("first"):
             masked_idx = jnp.where(eligible, idx, cap)
-            pick = jax.ops.segment_min(masked_idx, seg_id, num_segments=cap)
+            pick = red.min(masked_idx)
         else:
             masked_idx = jnp.where(eligible, idx, -1)
-            pick = jax.ops.segment_max(masked_idx, seg_id, num_segments=cap)
+            pick = red.max(masked_idx)
         pick = jnp.clip(pick, 0, cap - 1)
         has_eligible = cnt_valid > 0 if ignore_nulls else seg_real_cnt > 0
         validity = col.validity[pick] & out_mask & has_eligible
         if col.is_var_width:
             data = jnp.where(validity[:, None], col.data[pick], 0)
             return DeviceColumn(data, validity, col.dtype,
-                                jnp.where(validity, col.lengths[pick], 0)), col.dtype
+                                jnp.where(validity, col.lengths[pick], 0))
         data = jnp.where(validity, col.data[pick], jnp.zeros((), col.data.dtype))
-        return DeviceColumn(data, validity, col.dtype), col.dtype
+        return DeviceColumn(data, validity, col.dtype)
 
     raise NotImplementedError(f"aggregate op {op}")
+
+
+# ---------------------------------------------------------------------------
+# the update's entry point: sort-free for few groups, the sort otherwise
+# ---------------------------------------------------------------------------
+
+def _dense_covers(batch: ColumnBatch, aggs: list[AggSpec]) -> bool:
+    """False for the specs that need the rows sorted: ``percentile``
+    (value order inside a group) and string ``min``/``max`` (a
+    lexicographic order no masked reduction gives)."""
+    for spec in aggs:
+        if spec.op == "percentile":
+            return False
+        if spec.op in ("min", "max") and batch.columns[spec.child_index].is_string:
+            return False
+    return True
+
+
+def _key_eq_operands(col: DeviceColumn) -> list[jax.Array]:
+    """1-D operands whose element-wise equality is ``_cols_differ``'s
+    key equality: null == null, NaN == NaN, -0.0 == 0.0, strings by
+    bytes and length.  A null's data is held at zero so only its
+    validity speaks."""
+    if col.is_string:
+        ops = string_key_words(col) + [col.lengths]
+    elif col.dtype.fractional:
+        x = normalize_floats(col.data)
+        isnan = jnp.isnan(x)
+        ops = [isnan, jnp.where(isnan, jnp.zeros((), x.dtype), x)]
+    else:
+        ops = [col.data]
+    v = col.validity
+    return [v] + [jnp.where(v, o, jnp.zeros((), o.dtype)) for o in ops]
+
+
+def _discover_groups(batch: ColumnBatch, key_indices: list[int], table: int):
+    """Assign each real row the id of its key among the first ``table``
+    distinct keys, in order of first appearance.
+
+    Each pass of the ``while_loop`` takes the first real row without a
+    group, reads its key and marks every such row whose key equals it;
+    it stops when no real row is left or ``table`` groups are taken.
+    Returns ``(gid, reps, n, overflow)``: ``gid`` int32[capacity] (the
+    group id; ``table`` on padding rows, -1 on rows left over), ``reps``
+    int32[table] (each group's first row), ``n`` groups found, and
+    ``overflow`` — real rows remain, so the input has more than
+    ``table`` keys."""
+    cap = batch.capacity
+    operands = [o for ki in key_indices
+                for o in _key_eq_operands(batch.columns[ki])]
+    idx = jnp.arange(cap, dtype=jnp.int32)
+
+    def first_unassigned(gid):
+        r = jnp.min(jnp.where(gid < 0, idx, cap))
+        return jnp.minimum(r, cap - 1), r < cap
+
+    def body(state):
+        gid, reps, n, r, _more = state
+        member = gid < 0
+        for o in operands:
+            member = member & (o == o[r])
+        gid = jnp.where(member, n, gid)
+        return (gid, reps.at[n].set(r), n + 1, *first_unassigned(gid))
+
+    gid = jnp.where(batch.row_mask(), -1, table).astype(jnp.int32)
+    init = (gid, jnp.zeros(table, jnp.int32), jnp.asarray(0, jnp.int32),
+            *first_unassigned(gid))
+    gid, reps, n, _r, more = lax.while_loop(
+        lambda s: s[4] & (s[2] < table), body, init)
+    return gid, reps, n, more
+
+
+def _dense_group_by(batch: ColumnBatch, key_indices: list[int],
+                    aggs: list[AggSpec], gid, reps, n) -> ColumnBatch:
+    """The group rows from discovered groups: one masked reduction of the
+    unsorted input columns per group, the group table sorted ascending
+    by key (nulls first) and padded to the input's capacity — what
+    ``sorted_group_by`` emits."""
+    cap, table = batch.capacity, reps.shape[0]
+    one_slot = jnp.ones(1, jnp.bool_)
+
+    def group_row(member):
+        return _agg_columns(batch, aggs, _OneGroup, member, one_slot)
+
+    def body(g, acc):
+        return jax.tree.map(
+            lambda a, r: lax.dynamic_update_slice_in_dim(a, r, g, 0),
+            acc, group_row(gid == g))
+
+    row = jax.eval_shape(group_row, jax.ShapeDtypeStruct((cap,), jnp.bool_))
+    acc = jax.tree.map(
+        lambda r: jnp.zeros((table,) + r.shape[1:], r.dtype), row)
+    agg_cols = lax.fori_loop(0, n, body, acc)
+    key_cols = gather_columns([batch.columns[ki] for ki in key_indices],
+                              reps, n)
+    groups = ColumnBatch(key_cols + agg_cols, n,
+                         _output_schema(batch.schema, key_indices, aggs))
+    if key_indices:
+        groups = sort_batch(groups, [SortOrder(i, True, True)
+                                     for i in range(len(key_indices))])
+    return _pad_jit(groups, cap)
+
+
+def group_by_update(batch: ColumnBatch, key_indices: list[int],
+                    aggs: list[AggSpec], presorted: bool = False):
+    """``sorted_group_by``'s rows, without the sort where the groups are
+    few: returns ``(group_rows, dense)``.
+
+    ``group_rows`` is what ``sorted_group_by(batch, key_indices, aggs,
+    presorted)`` returns — same schema, capacity, validity
+    canonicalisation and row order (ascending by key, nulls first).
+    ``dense`` (bool scalar) says which branch of the program produced
+    it: True when the input held at most ``_DENSE_MAX_GROUPS`` distinct
+    keys, so discovery assigned every real row and the groups were
+    reduced by mask; False when rows remained and ``lax.cond`` ran
+    ``sorted_group_by`` (discovery's passes over the keys are then the
+    price, at most 64), or when a spec needs the sort (``_dense_covers``,
+    decided at trace time, no ``cond``).  No keys — a grand aggregate —
+    is one group and never sorts."""
+    if not _dense_covers(batch, aggs):
+        return (sorted_group_by(batch, key_indices, aggs, presorted),
+                jnp.asarray(False))
+    if not key_indices:
+        real = batch.row_mask()
+        out = _dense_group_by(
+            batch, key_indices, aggs, jnp.where(real, 0, 1),
+            jnp.zeros(1, jnp.int32), jnp.asarray(1, jnp.int32))
+        return out, jnp.asarray(True)
+    table = min(_DENSE_MAX_GROUPS, batch.capacity)
+    gid, reps, n, overflow = _discover_groups(batch, key_indices, table)
+    out = lax.cond(
+        overflow,
+        lambda: sorted_group_by(batch, key_indices, aggs, presorted),
+        lambda: _dense_group_by(batch, key_indices, aggs, gid, reps, n))
+    return out, ~overflow

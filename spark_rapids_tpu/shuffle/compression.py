@@ -45,14 +45,16 @@ class ZstdCodec(Codec):
                 "'zstandard' package, which is not installed in this "
                 "environment; install zstandard or pick codec 'lz4' or "
                 "'none'") from e
-        self._c = zstandard.ZstdCompressor()
-        self._d = zstandard.ZstdDecompressor()
+        # a zstandard (de)compressor is not thread-safe and one codec
+        # serves every partition thread: a context per call
+        self._zstd = zstandard
 
     def compress(self, data: bytes) -> bytes:
-        return self._c.compress(data)
+        return self._zstd.ZstdCompressor().compress(data)
 
     def decompress(self, data: bytes, out_size: int) -> bytes:
-        out = self._d.decompress(data, max_output_size=out_size)
+        out = self._zstd.ZstdDecompressor().decompress(
+            data, max_output_size=out_size)
         if len(out) != out_size:
             raise ValueError(
                 f"zstd decompression size mismatch ({len(out)} != "

@@ -148,6 +148,22 @@ def test_string_key_sort_compiles_for_v5e(one_chip):
     _compile(lambda x: sort_batch(x, orders), _shapes(b, one_chip))
 
 
+def test_group_by_update_compiles_for_v5e(one_chip):
+    """The aggregate update with both branches of its ``lax.cond``: key
+    discovery (a ``while_loop`` of equality passes over an s64 key),
+    masked f64/s64 reductions per group, the 64-row table sort, and
+    ``sorted_group_by`` as the fall-back (a string key costs what
+    ``test_string_key_sort_compiles_for_v5e`` already pays)."""
+    from spark_rapids_tpu.ops.segmented import AggSpec, group_by_update
+    b = _keyed_batch(900, CAP, 4)
+    specs = [AggSpec("sum", 2), AggSpec("min", 2), AggSpec("first", 0),
+             AggSpec("count_star", 0)]
+    compiled = _compile(lambda x: group_by_update(x, [0], specs),
+                        _shapes(b, one_chip))
+    hlo = compiled.as_text()
+    assert " conditional(" in hlo and " while(" in hlo
+
+
 def test_distributed_groupby_compiles_for_2x2_mesh(topo):
     """partial group-by -> all-to-all -> merge as ONE shard_map program
     over the four described devices."""

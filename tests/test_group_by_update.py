@@ -1,0 +1,287 @@
+"""``ops/segmented.group_by_update``: the aggregate update's sort-free
+path for at most 64 groups, and its fall-back to ``sorted_group_by``
+inside the same program.
+
+Every case compares the group rows with ``hk.host_group_by`` row by row
+WITHOUT sorting either side (ascending by key, nulls first, on both
+branches), and leaf by leaf with ``sorted_group_by`` (same capacity,
+dtypes and validity canonicalisation).  The exec-level cases read the
+``agg.update.dense`` / ``agg.update.sorted`` counters from the per-query
+record.
+"""
+import datetime
+import math
+import os
+
+import numpy as np
+import pytest
+
+import jax
+
+from spark_rapids_tpu import TpuSession
+from spark_rapids_tpu import types as T
+from spark_rapids_tpu.exec.core import device_to_host, host_to_device
+from spark_rapids_tpu.host.batch import HostBatch
+from spark_rapids_tpu.obs.registry import get_registry
+from spark_rapids_tpu.ops import host_kernels as hk
+from spark_rapids_tpu.ops import segmented
+from spark_rapids_tpu.ops.segmented import (AggSpec, group_by_update,
+                                            sorted_group_by)
+
+N = 600
+NAN = float("nan")
+
+
+def _with_nulls(rng, values, share=0.12):
+    return [None if rng.random() < share else v for v in values]
+
+
+def _table(rng, n=N) -> HostBatch:
+    """One column per key type, and the inputs of every covered op."""
+    schema = T.Schema([
+        T.StructField("i", T.IntegerType(), True),
+        T.StructField("s", T.StringType(), True),
+        T.StructField("d", T.DoubleType(), True),
+        T.StructField("b", T.BooleanType(), True),
+        T.StructField("t", T.DateType(), True),
+        T.StructField("x", T.LongType(), True),
+        T.StructField("y", T.DoubleType(), True),
+        T.StructField("u", T.LongType(), True),
+        T.StructField("w", T.StringType(), True),
+    ])
+    day = datetime.date(1998, 9, 2)
+    return HostBatch.from_pydict({
+        "i": _with_nulls(rng, rng.integers(-2, 3, n).tolist()),
+        # "a" / "ab" / "abc" share a prefix; "" is not null
+        "s": _with_nulls(rng, rng.choice(["a", "ab", "abc", "b", ""],
+                                         n).tolist()),
+        # no negative key but -0.0: the host oracle orders doubles by
+        # their bits, which puts a negative after NaN
+        "d": _with_nulls(rng, rng.choice(
+            [NAN, -0.0, 0.0, 1.5, 2.25, math.inf], n).tolist()),
+        "b": _with_nulls(rng, (rng.random(n) < 0.5).tolist()),
+        "t": _with_nulls(rng, [day - datetime.timedelta(int(k))
+                               for k in rng.integers(0, 4, n)]),
+        "x": _with_nulls(rng, rng.integers(-1000, 1000, n).tolist()),
+        "y": _with_nulls(rng, rng.choice(
+            [NAN, -0.0, 3.25, -7.5, 1e12, -math.inf], n).tolist()),
+        "u": np.arange(n).tolist(),           # unique: the fallback's key
+        "w": _with_nulls(rng, rng.choice(["p", "q", "rs"], n).tolist()),
+    }, schema)
+
+
+I, S, D, B, DT, X, Y, U, W = range(9)
+
+#: every op the dense path covers, over an int, a double and a string
+COVERED = [
+    AggSpec("sum", X), AggSpec("sum", Y), AggSpec("avg", X),
+    AggSpec("avg", Y), AggSpec("count", Y), AggSpec("count_star", 0),
+    AggSpec("min", X), AggSpec("max", X), AggSpec("min", Y),
+    AggSpec("max", Y), AggSpec("min", B), AggSpec("max", B),
+    AggSpec("first", Y), AggSpec("last", X), AggSpec("first", W),
+    AggSpec("last", W), AggSpec("first_non_null", Y),
+    AggSpec("last_non_null", X), AggSpec("first_non_null", W),
+    AggSpec("last_non_null", W),
+]
+
+
+def _same(a, b) -> bool:
+    if a is None or b is None:
+        return a is b
+    if isinstance(a, float) or isinstance(b, float):
+        a, b = float(a), float(b)
+        if math.isnan(a) or math.isnan(b):
+            return math.isnan(a) and math.isnan(b)
+        return a == b or math.isclose(a, b, rel_tol=1e-12)
+    return a == b
+
+
+def _assert_rows(got: list, want: list) -> None:
+    """Row by row, in the order they came."""
+    assert len(got) == len(want), (len(got), len(want))
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert len(g) == len(w) and all(map(_same, g, w)), (i, g, w)
+
+
+def _run(db, keys, aggs, presorted=False):
+    out, dense = jax.jit(
+        lambda b: group_by_update(b, keys, aggs, presorted))(db)
+    return out, bool(dense)
+
+
+def _check(hb: HostBatch, keys, aggs, capacity=None, dense=True,
+           presorted=False) -> None:
+    db = host_to_device(hb, capacity)
+    out, took_dense = _run(db, keys, aggs, presorted)
+    assert took_dense is dense
+    _assert_rows(device_to_host(out).to_rows(),
+                 hk.host_group_by(hb, keys, aggs).to_rows())
+    ref = jax.jit(lambda b: sorted_group_by(b, keys, aggs, presorted))(db)
+    assert out.schema == ref.schema and out.capacity == db.capacity
+    for mine, theirs in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
+        assert mine.shape == theirs.shape and mine.dtype == theirs.dtype
+        mine, theirs = np.asarray(mine), np.asarray(theirs)
+        if mine.dtype.kind == "f":
+            np.testing.assert_allclose(mine, theirs, rtol=1e-12)
+        else:
+            np.testing.assert_array_equal(mine, theirs)
+
+
+def _many_groups(groups: int, n: int = 400) -> HostBatch:
+    """``groups`` distinct (i, s) keys, rows in a shuffled order."""
+    rng = np.random.default_rng(groups)
+    schema = T.Schema([T.StructField("i", T.LongType(), True),
+                       T.StructField("s", T.StringType(), True),
+                       T.StructField("v", T.DoubleType(), True)])
+    g = np.concatenate([np.arange(groups),
+                        rng.integers(0, groups, n - groups)])
+    rng.shuffle(g)
+    return HostBatch.from_pydict({
+        "i": [None if k == 7 else int(k) // 2 for k in g],
+        "s": [("even", "odd")[int(k) % 2] for k in g],
+        "v": rng.random(n).tolist()}, schema)
+
+
+CASES = {
+    "int_key": lambda t: _check(t, [I], COVERED),
+    "string_key": lambda t: _check(t, [S], COVERED),
+    "double_key_nan_negzero": lambda t: _check(t, [D], COVERED),
+    "bool_key": lambda t: _check(t, [B], COVERED),
+    "date_key": lambda t: _check(t, [DT], COVERED),
+    "two_keys": lambda t: _check(t, [S, I], COVERED),
+    "three_keys_past_64": lambda t: _check(t, [S, I, D], COVERED,
+                                           dense=False),
+    "grand": lambda t: _check(t, [], COVERED),
+    "num_rows_below_capacity": lambda t: _check(t, [I, B], COVERED,
+                                                capacity=4096),
+    "presorted": lambda t: _check(
+        HostBatch([c.take(np.argsort(
+            np.where(t.columns[I].validity, t.columns[I].data, -99),
+            kind="stable")) for c in t.columns], t.schema),
+        [I], COVERED, presorted=True),
+    "empty_keyed": lambda t: _check(
+        HostBatch([c.take(np.zeros(0, np.int64)) for c in t.columns],
+                  t.schema), [S], COVERED),
+    "empty_grand": lambda t: _check(
+        HostBatch([c.take(np.zeros(0, np.int64)) for c in t.columns],
+                  t.schema), [], COVERED),
+    "groups_64_dense": lambda t: _check(
+        _many_groups(64), [0, 1], [AggSpec("sum", 2),
+                                   AggSpec("count_star", 0)]),
+    "groups_65_fallback": lambda t: _check(
+        _many_groups(65), [0, 1], [AggSpec("sum", 2),
+                                   AggSpec("count_star", 0)], dense=False),
+    "unique_key_fallback": lambda t: _check(t, [U], COVERED, dense=False),
+    "percentile_sorts": lambda t: _check(
+        t, [I], [AggSpec("percentile", Y, 0.5), AggSpec("count", Y)],
+        dense=False),
+    "string_min_sorts": lambda t: _check(
+        t, [I], [AggSpec("min", W), AggSpec("max", W)], dense=False),
+}
+
+
+@pytest.fixture(scope="module")
+def table():
+    return _table(np.random.default_rng(25))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_group_by_update_matches_host_and_sort_path(table, case):
+    CASES[case](table)
+
+
+def test_all_padding_batch_has_no_groups(table):
+    """num_rows == 0 at a real capacity: no group on a keyed update, the
+    default row on a grand one — and the stale rows never count."""
+    db = host_to_device(table, 1024)
+    hollow = type(db)(db.columns, jax.numpy.asarray(0, jax.numpy.int32),
+                      db.schema)
+    out, dense = _run(hollow, [S], COVERED)
+    assert dense and int(out.num_rows) == 0
+    for leaf in jax.tree.leaves(out.columns):
+        assert not np.asarray(leaf).any()
+    out, dense = _run(hollow, [], [AggSpec("count_star", 0),
+                                   AggSpec("sum", X)])
+    assert dense
+    assert device_to_host(out).to_rows() == [(0, None)]
+
+
+def test_strings_differing_only_in_length_are_two_groups():
+    """``"a"`` and ``"a\\0"`` pad to the same bytes; the length tells
+    them apart, as in ``_cols_differ`` (the host oracle's numpy strings
+    drop the trailing NUL, so the sort path is the reference here)."""
+    schema = T.Schema([T.StructField("s", T.StringType(), True),
+                       T.StructField("v", T.LongType(), True)])
+    hb = HostBatch.from_pydict(
+        {"s": ["a\0", "a", "a\0", "a", "a"], "v": [1, 2, 4, 8, 16]}, schema)
+    db = host_to_device(hb)
+    out, dense = _run(db, [0], [AggSpec("sum", 1)])
+    ref = sorted_group_by(db, [0], [AggSpec("sum", 1)])
+    assert dense and int(out.num_rows) == 2
+    for mine, theirs in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+    assert sorted(np.asarray(out.columns[1].data)[:2].tolist()) == [5, 26]
+
+
+def test_table_never_wider_than_the_batch():
+    """A capacity under 64 holds fewer rows than the table has slots:
+    every row its own group still fits, so the dense branch answers."""
+    schema = T.Schema([T.StructField("k", T.LongType(), True)])
+    hb = HostBatch.from_pydict({"k": list(range(8, 0, -1))}, schema)
+    db = host_to_device(hb, 8)
+    assert db.capacity < segmented._DENSE_MAX_GROUPS
+    out, dense = _run(db, [0], [AggSpec("count_star", 0)])
+    assert dense
+    assert device_to_host(out).to_rows() == [(k, 1) for k in range(1, 9)]
+
+
+# ---------------------------------------------------------------------------
+# through the session: which branch ran is in the query's record
+# ---------------------------------------------------------------------------
+
+CONF = {"spark.rapids.sql.resultCache.enabled": "false",
+        "spark.rapids.sql.test.enabled": "true"}
+#: blocking fetches of a TPC-H q1 collect at sf 0.01 at the parent commit
+#: (a93499f, measured there): the flag rides in the count's fetch
+Q1_D2H_CALLS_AT_PARENT = 3
+
+
+@pytest.fixture(scope="module")
+def tpch_dir(tmp_path_factory):
+    from spark_rapids_tpu.bench.tpch_gen import generate_tpch
+    d = str(tmp_path_factory.mktemp("tpch_group_by_update") / "sf001")
+    generate_tpch(d, sf=0.01, tables=["lineitem"])
+    return d
+
+
+@pytest.fixture(scope="module")
+def session():
+    s = TpuSession(dict(CONF))
+    yield s
+    s.shutdown(drain=False)
+
+
+def _counters_of(df) -> tuple:
+    rows = df.collect()
+    return rows, get_registry().recent_queries(1)[0]["counters"]
+
+
+def test_tpch_q1_updates_without_the_sort(session, tpch_dir):
+    from spark_rapids_tpu.bench.tpch_queries import build_tpch_query
+    rows, counters = _counters_of(build_tpch_query("q1", session, tpch_dir))
+    assert len(rows) == 4
+    assert counters["agg.update.dense"] >= 1
+    assert counters.get("agg.update.sorted", 0) == 0
+    assert counters["d2h_calls"] == Q1_D2H_CALLS_AT_PARENT
+
+
+def test_unique_key_group_by_takes_the_sort(session, tpch_dir):
+    from spark_rapids_tpu.expr.aggregates import Sum
+    from spark_rapids_tpu.expr.core import col
+    li = session.read_parquet(os.path.join(tpch_dir, "lineitem"),
+                              columns=["l_orderkey", "l_quantity"])
+    rows, counters = _counters_of(
+        li.group_by("l_orderkey").agg(Sum(col("l_quantity")).alias("q")))
+    assert len(rows) > segmented._DENSE_MAX_GROUPS
+    assert counters["agg.update.sorted"] >= 1
+    assert counters.get("agg.update.dense", 0) == 0
