@@ -15,8 +15,9 @@ batch, and a batch with more takes the ``sorted_group_by`` branch of the
 same ``lax.cond`` (as do ``percentile`` and string ``min``/``max``,
 always).  The program returns which branch ran beside the group count;
 both ride in the chunk's one stacked fetch and are counted as
-``agg.update.dense`` / ``agg.update.sorted`` in the metrics registry,
-hence in the per-query record.  The cross-batch merge and the final mode
+``agg.update.dense`` / ``agg.update.sorted`` (and the group counts
+summed as ``agg.update.groups``) in the metrics registry, hence in the
+per-query record.  The cross-batch merge and the final mode
 always sort: they see a handful of small buffers.
 
 Modes mirror Spark's aggregate modes:
@@ -453,8 +454,10 @@ class HashAggregateExec(PlanNode):
                     ng = int(head)
                 else:
                     ng = int(head[0])
-                    get_registry().inc("agg.update.dense" if head[1]
-                                       else "agg.update.sorted")
+                    get_registry().inc_many((
+                        ("agg.update.dense" if head[1]
+                         else "agg.update.sorted", 1),
+                        ("agg.update.groups", ng)))
                 part.known_rows = ng
                 if isinstance(src, SpillableColumnarBatch):
                     src.close()

@@ -30,6 +30,7 @@ from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
 from spark_rapids_tpu.expr.core import (BoundReference, Expression, bind,
                                         eval_device, eval_host)
 from spark_rapids_tpu.host.batch import HostBatch
+from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops.join import (JOIN_TYPES, build_prepare_fast,
@@ -293,6 +294,8 @@ class JoinExec(PlanNode):
         # two gathers instead of one.
         def probe(piece):
             lb2, lkeys = self._augment_device(piece, self._lkeys_b)
+            if jt == "cross":
+                get_registry().inc("join.cross.launches")
             if prep is not None:
                 probe_arrays, total_dev = _jit_probe_fast(
                     lb2, prep, lkeys[0], stream_jt)

@@ -33,6 +33,7 @@ from spark_rapids_tpu.expr.window import (DenseRank, Lag, Lead, Rank,
                                           RowNumber, WindowExpression,
                                           window_agg_op)
 from spark_rapids_tpu.host.batch import HostBatch, HostColumn
+from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops import window as W
@@ -175,6 +176,12 @@ class WindowExec(PlanNode):
             for p in range(child.num_partitions(ctx)):
                 batches.extend(child.partition_iter(ctx, p))
         if ctx.is_device:
+            # the gather's witnesses: inputs, concats into one batch, and
+            # windows with no partition-by holding their whole input
+            get_registry().inc_many((
+                ("window.batches_in", len(batches)),
+                ("window.concat", int(len(batches) > 1)),
+                ("window.global", int(not self.spec.partition_by))))
             if not batches:
                 from spark_rapids_tpu.exec.core import host_to_device
                 big = host_to_device(HostBatch.empty(child.output_schema))

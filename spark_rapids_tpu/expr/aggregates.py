@@ -18,7 +18,7 @@ from spark_rapids_tpu.expr.core import Expression, BoundReference, Literal
 
 __all__ = ["AggregateFunction", "Sum", "Count", "CountStar", "Min", "Max",
            "Percentile",
-           "Average", "First", "Last", "CountDistinct", "stddev_samp",
+           "Average", "MeanOf", "First", "Last", "CountDistinct", "stddev_samp",
            "is_aggregate", "has_aggregate"]
 
 
@@ -188,12 +188,33 @@ class Average(AggregateFunction):
         return [T.DoubleType(), T.LongType()]
 
     def final_expr(self, offsets):
-        from spark_rapids_tpu.expr.arithmetic import Divide
-        from spark_rapids_tpu.expr.cast import Cast
         s = BoundReference(offsets[0], T.DoubleType(), True)
         c = BoundReference(offsets[1], T.LongType(), True)
-        # Divide yields null when count == 0 (DivModLike) — exactly Spark avg
-        return Divide(s, Cast(c, T.DoubleType()))
+        return MeanOf(s, c)
+
+
+class MeanOf(Expression):
+    """Average's last step, ``sum / count`` (double, long): null when
+    the count is zero — exactly Spark avg — and, where the sum is whole
+    cents, divided in lowest terms so that equal averages are equal
+    doubles (ops/cents.py)."""
+    sql_name = "MeanOf"
+
+    def __init__(self, total: Expression, count: Expression):
+        self.children = (total, count)
+
+    @property
+    def dtype(self):
+        return T.DoubleType()
+
+    def _eval(self, vals, ctx):
+        from spark_rapids_tpu.ops import cents
+        s, c = vals
+        xp = ctx.xp
+        some = c.data > 0
+        data = cents.mean(xp, s.data, xp.where(some, c.data, 1))
+        return ctx.canonical(data, s.validity & c.validity & some,
+                             self.dtype)
 
 
 class CountDistinct(Expression):

@@ -328,9 +328,11 @@ class FileScanExec(PlanNode):
                         fkey = ("scan", self.scan_fingerprint(), snap,
                                 conf_fingerprint(ctx.conf), pid)
                         entry = rc.fragment_entry(
-                            fkey, lambda: list(self._device_batches(rbs)),
+                            fkey, lambda: list(self._staged_shared(rbs)),
                             lifecycle=ctx.cache.get("lifecycle"))
                         try:
+                            get_registry().inc("scan.shared.handed_batches",
+                                               len(entry.value))
                             yield from entry.value
                         finally:
                             rc.fragment_release(entry)
@@ -342,7 +344,8 @@ class FileScanExec(PlanNode):
                     key,
                     lambda: [SpillableColumnarBatch(
                         b, ctx.catalog, SpillPriority.READ_SHUFFLE)
-                        for b in self._device_batches(rbs)])
+                        for b in self._staged_shared(rbs)])
+                get_registry().inc("scan.shared.handed_batches", len(parked))
                 for sb in parked:
                     b = sb.get()
                     # unpin immediately: the yielded pytree keeps the
@@ -374,6 +377,21 @@ class FileScanExec(PlanNode):
                 if rb.num_rows == 0:
                     continue
                 yield _arrow_to_host(rb, self._schema)
+
+    def _staged_shared(self, rbs) -> Iterator:
+        """``_device_batches`` of a shared scan, which stages the whole
+        partition before its first consumer sees a batch: counted as
+        ``scan.shared.staged_batches`` and ``scan.shared.parked_bytes``
+        (device bytes held for replay).  Every consumer, the first too,
+        counts what it is handed as ``scan.shared.handed_batches``:
+        handed less staged were replays."""
+        n = nbytes = 0
+        for b in self._device_batches(rbs):
+            n += 1
+            nbytes += b.device_size_bytes()
+            yield b
+        get_registry().inc_many((("scan.shared.staged_batches", n),
+                                 ("scan.shared.parked_bytes", nbytes)))
 
     def _device_batches(self, rbs) -> Iterator:
         """Stage-and-transfer pipeline: a worker thread encodes and

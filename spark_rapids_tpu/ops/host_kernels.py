@@ -16,6 +16,7 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.host.batch import HostBatch, HostColumn
+from spark_rapids_tpu.ops import cents
 from spark_rapids_tpu.ops.segmented import AggSpec
 from spark_rapids_tpu.ops.sort import SortOrder
 
@@ -125,6 +126,18 @@ def _group_codes(col: HostColumn) -> list[np.ndarray]:
     return [v.astype(np.uint8), np.where(v, codes, np.int64(0))]
 
 
+def _sum_doubles(vals: np.ndarray) -> np.float64:
+    """One group's sum as doubles: whole cents summed as integers and
+    rounded once (ops/cents.py), as the device's sum is."""
+    x = vals.astype(np.float64)
+    c, whole = cents.as_cents(np, x)
+    total = int(c.sum(dtype=np.int64))
+    if whole.all() and abs(total) < cents.SUM_LIMIT \
+            and len(x) * cents.ROW_LIMIT <= 1 << 62:
+        return cents.from_cents(np, np.asarray(total, np.int64))
+    return np.sum(x)
+
+
 def _agg_reduce(spec: AggSpec, col: HostColumn | None, seg_starts: np.ndarray,
                 seg_lens: np.ndarray, perm: np.ndarray,
                 in_type: T.DataType) -> HostColumn:
@@ -164,7 +177,7 @@ def _agg_reduce(spec: AggSpec, col: HostColumn | None, seg_starts: np.ndarray,
             if res_type.integral:
                 out[g] = np.int64(np.sum(vals.astype(np.int64), dtype=np.int64))
             else:
-                out[g] = np.sum(vals.astype(np.float64))
+                out[g] = _sum_doubles(vals)
             out_valid[g] = True
         elif spec.op == "min":
             out[g] = _nan_aware_min(vals, in_type)
@@ -173,7 +186,8 @@ def _agg_reduce(spec: AggSpec, col: HostColumn | None, seg_starts: np.ndarray,
             out[g] = _nan_aware_max(vals, in_type)
             out_valid[g] = True
         elif spec.op == "avg":
-            out[g] = np.sum(vals.astype(np.float64)) / len(vals)
+            out[g] = cents.mean(np, np.asarray(_sum_doubles(vals)),
+                                np.asarray(len(vals), np.int64))
             out_valid[g] = True
         elif spec.op == "first_non_null":
             out[g] = vals[0]

@@ -38,6 +38,7 @@ from jax import lax
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
+from spark_rapids_tpu.ops import cents
 from spark_rapids_tpu.ops.kernels import _pad_jit, gather_columns
 from spark_rapids_tpu.ops.sort import (SortOrder, normalize_floats,
                                        sort_batch, string_key_words)
@@ -219,6 +220,19 @@ class _OneGroup:
         return jnp.max(x, keepdims=True)
 
 
+def _sum_doubles(red, x: jax.Array) -> jax.Array:
+    """``red.sum(x)`` for float64 ``x`` (rows that do not count held at
+    zero): exact where every row of a slot is whole cents, summed as
+    integers and rounded once, whatever the order of the rows
+    (ops/cents.py); the plain sum elsewhere."""
+    limit = min(cents.ROW_LIMIT, (1 << 62) // max(x.shape[0], 1))
+    c, whole = cents.as_cents(jnp, x, limit)
+    total = red.sum(c)
+    exact = (red.sum((~whole).astype(jnp.int32)) == 0) \
+        & (jnp.abs(total) < cents.SUM_LIMIT)
+    return jnp.where(exact, cents.from_cents(jnp, total), red.sum(x))
+
+
 def _agg_columns(batch: ColumnBatch, aggs: list[AggSpec], red, real,
                  out_mask) -> list[DeviceColumn]:
     """One result column per spec; ``red`` reduces rows to result slots
@@ -252,9 +266,10 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, red, real,
         acc_dt = jnp.int64 if (col.dtype.integral and op == "sum") else jnp.float64
         contrib = jnp.where(contributes, col.data.astype(acc_dt),
                             jnp.zeros((), acc_dt))
-        s = red.sum(contrib)
+        s = red.sum(contrib) if acc_dt == jnp.int64 else \
+            _sum_doubles(red, contrib)
         if op == "avg":
-            data = s.astype(jnp.float64) / jnp.maximum(cnt_valid, 1).astype(jnp.float64)
+            data = cents.mean(jnp, s, jnp.maximum(cnt_valid, 1))
             rtype = T.DoubleType()
         elif col.dtype.integral:
             data, rtype = s, T.LongType()
