@@ -33,10 +33,13 @@ from spark_rapids_tpu.host.batch import HostBatch
 from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops import host_kernels as hk
-from spark_rapids_tpu.ops.join import (JOIN_TYPES, build_prepare_fast,
+from spark_rapids_tpu.ops.join import (JOIN_TYPES, DirectBuild,
+                                       build_direct_table, build_key_stats,
+                                       build_prepare_fast, direct_table_size,
                                        gather_join_output,
                                        join_indices_from_probe, join_probe,
-                                       matched_build_rows, probe_fast)
+                                       matched_build_rows, probe_direct,
+                                       probe_fast)
 
 __all__ = ["JoinExec", "CrossJoinExec", "BroadcastHashJoinExec"]
 
@@ -56,13 +59,43 @@ def _jit_probe(lb, rb, lkeys, rkeys, join_type):
 
 @guarded_jit("join_build_prep", static_argnames=("rkey",))
 def _jit_build_prep(rb, rkey):
-    return build_prepare_fast(rb, rkey)
+    prep = build_prepare_fast(rb, rkey)
+    return prep, build_key_stats(prep[0], prep[2])
+
+
+@guarded_jit("join_build_table", static_argnames=("size",))
+def _jit_build_table(prep, size):
+    return build_direct_table(*prep, size)
 
 
 @guarded_jit("join_probe_fast", static_argnames=("lkey", "join_type"))
 def _jit_probe_fast(lb, prep, lkey, join_type):
     probe_arrays, total = probe_fast(lb, lkey, *prep, join_type)
     return probe_arrays[:-1], total  # drop the None placeholder
+
+
+@guarded_jit("join_probe_direct", static_argnames=("lkey", "join_type"))
+def _jit_probe_direct(lb, build, lkey, join_type):
+    probe_arrays, total = probe_direct(lb, lkey, build, join_type)
+    return probe_arrays[:-1], total  # drop the None placeholder
+
+
+def prepare_fast_build(rb, rkey: int):
+    """Prepare a build side for the streaming probe, once per build:
+    sort it by its key, look at the keys it holds (``nv``, smallest,
+    largest: ONE blocking fetch) and, where they are dense
+    (ops/join.direct_table_size), make the direct-address table.  Returns
+    a :class:`DirectBuild` for ``join_probe_direct`` or the sorted
+    ``(sorted_key, perm, nv)`` for ``join_probe_fast``; both are pytrees
+    of device arrays."""
+    prep, stats = _jit_build_prep(rb, rkey)
+    # enginelint: disable=RL003 (once per build, before any stream batch: the probe's program is chosen from it)
+    nv, kmin, kmax = (int(x) for x in fetch_to_host(stats, _FETCH))
+    size = direct_table_size(nv, kmin, kmax, rb.capacity)
+    if size is None:
+        return prep
+    get_registry().inc("join.build.table_entries", size)
+    return _jit_build_table(prep, size)
 
 
 @guarded_jit("join_gather",
@@ -234,10 +267,11 @@ class JoinExec(PlanNode):
             yield from self._run_host(ctx, lb, rb)
 
     # ------------------------------------------------------------------
-    # Device path: build side prepared once (sorted keys for the fast
-    # searchsorted probe, reference GpuHashJoin's build-side table,
-    # GpuHashJoin.scala:193-249), then the stream side is joined PER
-    # BATCH — no whole-side concat, no per-batch sort on the fast path.
+    # Device path: build side prepared once (sorted keys, and a
+    # direct-address table where they are dense: prepare_fast_build;
+    # reference GpuHashJoin's build-side table, GpuHashJoin.scala:193-249),
+    # then the stream side is joined PER BATCH — no whole-side concat, no
+    # per-batch sort on the fast path.
     def _use_fast_path(self) -> bool:
         if len(self._lkeys_b) != 1:
             return False
@@ -251,7 +285,7 @@ class JoinExec(PlanNode):
         def build():
             rb = self._materialize(ctx, 1)
             rb2, rkeys = self._augment_device(rb, self._rkeys_b)
-            prep = _jit_build_prep(rb2, rkeys[0]) \
+            prep = prepare_fast_build(rb2, rkeys[0]) \
                 if self.join_type != "cross" and self._use_fast_path() \
                 else None
             return rb2, rkeys, prep
@@ -296,7 +330,12 @@ class JoinExec(PlanNode):
             lb2, lkeys = self._augment_device(piece, self._lkeys_b)
             if jt == "cross":
                 get_registry().inc("join.cross.launches")
-            if prep is not None:
+            if isinstance(prep, DirectBuild):
+                get_registry().inc("join.probe.direct")
+                probe_arrays, total_dev = _jit_probe_direct(
+                    lb2, prep, lkeys[0], stream_jt)
+            elif prep is not None:
+                get_registry().inc("join.probe.search")
                 probe_arrays, total_dev = _jit_probe_fast(
                     lb2, prep, lkeys[0], stream_jt)
             else:

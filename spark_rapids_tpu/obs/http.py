@@ -291,7 +291,7 @@ class ObsHttpServer:
         # what the last queries cost and where: the per-query records
         # (exec/lifecycle.py QueryLifecycle.seal_record), newest last
         return {"active": out, "count": len(out),
-                "finished": get_registry().recent_queries()}
+                "finished": get_registry().recent_queries(64)}
 
     def close(self) -> None:
         self._server.shutdown()

@@ -282,8 +282,11 @@ class Histogram:
             self._count = merged["count"]
 
 
-#: finished-query records kept in memory (``recent_queries``)
-RECENT_QUERIES = 64
+#: finished-query records kept in memory (``recent_queries``): room for
+#: every collect of a benchmark window (48 s of a 0.5 s query is 90; with
+#: 64 the window's first, traced, collects had left the ring when the
+#: benchmark read them, PERF.md PR 28), at about 10 KB a record
+RECENT_QUERIES = 512
 
 
 class _Span:

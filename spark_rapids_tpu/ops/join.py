@@ -21,23 +21,41 @@ sort-based (SURVEY.md §7 "hard parts"):
 
 Right outer join is the exec layer's job (swap sides, reorder columns,
 exec/joins.py), matching the reference's build-side flip.
+
+A join on ONE integral key streams: the build side is sorted once
+(:func:`build_prepare_fast`) and each stream batch is probed against it
+with no sort.  How the probe finds a key's run in the sorted build is
+chosen once per build, on the host, from the key range the build holds
+(:func:`direct_table_size`):
+
+* **dense keys** (surrogate keys, day numbers: the range is no wider
+  than a table the engine can always afford): :func:`build_direct_table`
+  makes ``table[k - kmin] = (run start, run length)`` once, and
+  :func:`probe_direct` reads it by address: one gather of table rows a
+  stream batch, whatever the build's size;
+* **anything else** (a hashed id, a natural key): :func:`probe_fast`,
+  two ``searchsorted`` over the sorted keys, each ``log2(capacity)``
+  dependent gathers over every stream row.
+
+Both return the same ``(start, cnt, perm, out_cnt)`` and ``total``.
 """
 from __future__ import annotations
 
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnBatch
+from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.ops.segmented import _cols_differ
 from spark_rapids_tpu.ops.sort import encode_key_operands
 
 __all__ = ["join_probe", "join_total", "join_indices_from_probe",
-           "gather_join_output", "JOIN_TYPES"]
+           "gather_join_output", "JOIN_TYPES", "DirectBuild",
+           "direct_table_size"]
 
 JOIN_TYPES = ("inner", "left", "semi", "anti", "full", "cross")
 
@@ -165,6 +183,109 @@ def probe_fast(lbatch: ColumnBatch, lkey: int, sorted_key, perm, nv,
     out_cnt = _out_cnt(cnt, lbatch.row_mask(), join_type)
     total = jnp.sum(out_cnt, dtype=jnp.int64)
     return (start, cnt, perm, out_cnt, None), total
+
+
+def _key_range(sorted_key, nv):
+    """Smallest and largest valid key of a prepared build side (with
+    ``nv == 0`` both are the padding value and mean nothing)."""
+    return sorted_key[0], sorted_key[jnp.maximum(nv - 1, 0)]
+
+
+def build_key_stats(sorted_key, nv):
+    """int64[3] ``[nv, kmin, kmax]`` of a prepared build side: what the
+    host needs to choose the probe (:func:`direct_table_size`), in one
+    array so it is one fetch."""
+    kmin, kmax = _key_range(sorted_key, nv)
+    return jnp.stack([nv, kmin, kmax]).astype(jnp.int64)
+
+
+#: a direct-address table may always have this many entries (4 MB of
+#: int32 a column), however small the build side is
+_TABLE_FLOOR = 1 << 20
+
+
+def direct_table_size(nv: int, kmin: int, kmax: int,
+                      capacity: int) -> int | None:
+    """The one rule that picks the probe: entries of the direct-address
+    table for a build side holding ``nv`` valid keys in ``[kmin, kmax]``
+    (python ints: an int64 range does not overflow) in a batch of
+    ``capacity`` slots, or None where the keys are not dense and the
+    probe searches.  Dense = the key range, rounded to a capacity
+    bucket, is at most ``max(2^20, 8 x capacity)``: the table then costs
+    no more than a few of the build's own columns."""
+    size = round_capacity(kmax - kmin + 1)
+    dense = nv > 0 and size <= max(_TABLE_FLOOR, 8 * capacity)
+    return size if dense else None
+
+
+class DirectBuild(NamedTuple):
+    """A prepared build side whose keys are dense (a pytree of arrays:
+    ``jax.device_put`` ships it; its type is the choice of probe)."""
+    perm: jax.Array    # int32[capacity]: sorted position -> build row
+    table: jax.Array   # int32[T, 2]: for key kmin + j, the sorted position
+    #                    of its run and the run's length (0 = key absent)
+    kmin: jax.Array    # scalars, key dtype
+    kmax: jax.Array
+
+
+def _offset_dtype(key_dtype):
+    """Keys are subtracted in at least 32 bits: a dense int8/int16 range
+    can be wider than its own dtype holds."""
+    return jnp.int64 if jnp.dtype(key_dtype).itemsize > 4 else jnp.int32
+
+
+def build_direct_table(sorted_key, perm, nv, size: int) -> DirectBuild:
+    """Direct-address table over a prepared build side (the output of
+    :func:`build_prepare_fast`) whose key range fits ``size`` entries.
+
+    Every run of equal keys in the sorted build writes its first
+    position, and its end, at ``key - kmin``: two scatters from the
+    build's rows, once per build, so duplicates keep their run.  Rows
+    that are not a run's first (or last) are sent past the table's end,
+    each to an index of its own: unique indices are what lets XLA scatter
+    without first sorting an operand of the build's size (a search of
+    the ``size`` candidate keys instead costs 151 ms at 2^19, the two
+    scatters 6 ms; PERF.md, PR 28)."""
+    wide = _offset_dtype(sorted_key.dtype)
+    i = jnp.arange(sorted_key.shape[0], dtype=jnp.int32)
+    live = i < nv
+    kmin, kmax = _key_range(sorted_key, nv)
+    first = live & ((i == 0) | (sorted_key != jnp.roll(sorted_key, 1)))
+    last = live & ((i == nv - 1) | (sorted_key != jnp.roll(sorted_key, -1)))
+    offset = (sorted_key.astype(wide) - kmin.astype(wide)).astype(jnp.int32)
+    nowhere = size + i
+
+    def scatter(at, values):
+        return jnp.zeros(size, jnp.int32).at[
+            jnp.where(at, offset, nowhere)].set(
+                values, unique_indices=True, mode="drop")
+    start = scatter(first, i)
+    end = scatter(last, i + 1)
+    return DirectBuild(perm, jnp.stack([start, end - start], axis=1),
+                       kmin, kmax)
+
+
+def probe_direct(lbatch: ColumnBatch, lkey: int, build: DirectBuild,
+                 join_type: str):
+    """:func:`probe_fast`'s contract against a :class:`DirectBuild`: the
+    stream key's run is read at ``key - kmin``, one gather of table rows
+    over the stream rows (4 ms for 2^20 rows whatever the table's size;
+    two gathers from two columns cost 18-25 ms; PERF.md, PR 28).  The
+    range test comes before the subtraction, so a far key cannot wrap
+    into the table."""
+    col = lbatch.columns[lkey]
+    lvalid = col.validity & lbatch.row_mask()
+    wide = _offset_dtype(col.data.dtype)
+    in_range = lvalid & (col.data >= build.kmin) & (col.data <= build.kmax)
+    idx = jnp.where(in_range,
+                    col.data.astype(wide) - build.kmin.astype(wide),
+                    0).astype(jnp.int32)
+    run = build.table[idx]
+    start = run[:, 0]
+    cnt = jnp.where(in_range, run[:, 1], 0)
+    out_cnt = _out_cnt(cnt, lbatch.row_mask(), join_type)
+    total = jnp.sum(out_cnt, dtype=jnp.int64)
+    return (start, cnt, build.perm, out_cnt, None), total
 
 
 def _out_cnt(cnt, real_l, join_type):

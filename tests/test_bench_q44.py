@@ -200,4 +200,10 @@ def test_q6_record_leaves_the_q44_counters_alone(session, data, monkeypatch):
     # the same batch handed to both
     assert c["scan.shared.staged_batches"] == _batches(path, "item")
     assert c["scan.shared.handed_batches"] == 2 * _batches(path, "item")
+    # each of q6's four streaming joins is on a surrogate key: every
+    # stream batch is probed by address, none by search
+    assert c["join.probe.direct"] \
+        == c["program.join_probe_direct.launches"] >= 4
+    assert c["program.join_build_table.launches"] == 4
+    assert "join.probe.search" not in c
     assert left == []

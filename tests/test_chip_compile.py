@@ -139,6 +139,33 @@ def test_join_build_and_probe_compiles_for_v5e(one_chip):
     _compile(join_step, _shapes(lb, one_chip), _shapes(rb, one_chip))
 
 
+@pytest.mark.parametrize("table", [32, 1 << 19])
+def test_direct_probe_compiles_for_v5e(one_chip, table):
+    """The probe by address at q6's sizes: a 2^20-row stream batch against
+    a 32-entry table (one month of ``date_dim`` in its 2^17 slots) and a
+    2^19-entry one (``customer`` at SF10), and the table's own build from
+    the sorted keys.  s64 keys: the key offset is taken in 64 bits.  The
+    build's sort and the gather behind the probe are the case above's."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.join import build_direct_table, probe_direct
+    build_cap = max(table, 1 << 17)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    prep = (on_chip((build_cap,), jnp.int64), on_chip((build_cap,), jnp.int32),
+            on_chip((), jnp.int32))
+    _compile(lambda k, p, n: build_direct_table(k, p, n, table), *prep)
+    build = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda k, p, n: build_direct_table(k, p, n, table),
+                       *prep))
+    assert build.table.shape == (table, 2)
+    lb = _keyed_batch(900, 1 << 20, 1)
+    for jt in ("inner", "left"):
+        _compile(lambda left, b: probe_direct(left, 0, b, jt)[0][:-1],
+                 _shapes(lb, one_chip), build)
+
+
 def test_string_key_sort_compiles_for_v5e(one_chip):
     """lax.sort keyed on a string column (padded byte matrix + length),
     s64 and f64 payload gathered behind it."""

@@ -191,3 +191,145 @@ def test_session_right_join_asymmetric_schemas():
     matched = [r for r in dev if r[0] is not None]
     assert all(r[1] == "a" and r[4] in ("x", "y") for r in matched)
     assert len(matched) == 20
+
+
+# ------------------------------------------------------------------
+# The streaming probe's two ways to find a key's run in the sorted build
+# (ops/join.py): by address in a table where the build's keys are dense,
+# by binary search otherwise.  Same contract, same rows.
+
+I32 = np.iinfo(np.int32)
+I64 = np.iinfo(np.int64)
+_Q6_DAYS = list(range(2451000, 2451031))
+
+
+def _probe_cases():
+    """name -> (stream keys, build keys, key type, path that must run,
+    filter keeping only the first N build rows or None)."""
+    rng = np.random.default_rng(11)
+    ri = lambda lo, hi, n: [int(x) for x in rng.integers(lo, hi, n)]
+    holes = lambda ks: [None if rng.random() < 0.1 else k for k in ks]
+    it, lt = T.IntegerType(), T.LongType()
+    return {
+        "unique_dense": (ri(90, 160, 64), list(range(100, 150)), it,
+                         "direct", None),
+        "dense_dups": (ri(0, 25, 64), ri(0, 25, 60), it, "direct", None),
+        "nulls_both_sides": (holes(ri(0, 25, 64)), holes(ri(0, 25, 60)), it,
+                             "direct", None),
+        "stream_out_of_range": (
+            [I32.min, I32.max, 9, 41, 10, 40, -1, 0] + ri(0, 50, 56),
+            list(range(10, 41)), it, "direct", None),
+        # key - kmin wraps for the far keys unless the range test is first
+        "int64_wrap": (
+            [I64.min, I64.max, I64.min + 1, 5_000_000_007,
+             -5_000_000_007, 0] + ri(5_000_000_000, 5_000_000_040, 58),
+            [5_000_000_000 + k for k in range(0, 40, 2)] * 2, lt,
+            "direct", None),
+        # table entries past kmax wrap around the dtype's end
+        "build_at_int32_max": ([I32.max, I32.max - 5, I32.min, 7] * 16,
+                               [I32.max - k for k in range(6)], it,
+                               "direct", None),
+        "build_at_int64_min": ([I64.min, I64.min + 3, I64.max, 0] * 16,
+                               [I64.min + k for k in range(5)], lt,
+                               "direct", None),
+        "int64_whole_range": ([I64.min, I64.max, 0, 1] * 16,
+                              [I64.min, I64.max, 0], lt, "search", None),
+        "negative": (ri(-30, 20, 64), ri(-20, 11, 40), it, "direct", None),
+        # a dense int16 range wider than int16 itself holds
+        "int16_wide": ([-30000, 30000, 0, 12, -12, 29999] * 10,
+                       [-30000, 0, 30000, 0], T.ShortType(), "direct", None),
+        "one_row_build": (ri(0, 6, 64), [3], it, "direct", None),
+        "empty_build": (ri(0, 6, 64), [], it, "search", None),
+        "all_null_build": (ri(0, 6, 64), [None] * 9, it, "search", None),
+        "sparse_build": (ri(0, 50, 60) + [7, 40_000_000, 900_000_000, 8],
+                         [7, 40_000_000, 900_000_000, 7], it, "search",
+                         None),
+        # q6's innermost join: date_dim filtered to one month keeps its
+        # capacity (2^17 slots, 31 keys)
+        "capacity_far_above_rows": (
+            ri(2450990, 2451050, 64),
+            _Q6_DAYS + list(range(2415022, 2415022 + 73_049 - 31)), it,
+            "direct", 31),
+    }
+
+
+_PROBE_CASES = _probe_cases()
+
+
+@pytest.mark.parametrize("jt", ["inner", "left", "semi", "anti", "full"])
+@pytest.mark.parametrize("case", list(_PROBE_CASES))
+def test_direct_and_search_probe_agree(case, jt, monkeypatch):
+    from spark_rapids_tpu.exec import joins as J
+    from spark_rapids_tpu.exec.basic import FilterExec
+    from spark_rapids_tpu.obs.registry import get_registry
+    lkeys, rkeys, ktype, path, keep = _PROBE_CASES[case]
+    lschema = T.Schema([T.StructField("lk", ktype, True),
+                        T.StructField("lv", T.LongType(), True)])
+    rschema = T.Schema([T.StructField("rk", ktype, True),
+                        T.StructField("rv", T.LongType(), True)])
+    left = LocalScanExec.from_pydict(
+        {"lk": lkeys, "lv": list(range(len(lkeys)))}, lschema,
+        rows_per_batch=37)          # 37 + 27 rows: padding in both batches
+    right = LocalScanExec.from_pydict(
+        {"rk": rkeys, "rv": list(range(len(rkeys)))}, rschema)
+    if keep is not None:
+        right = FilterExec(col("rv") < lit(keep), right)
+    plan = JoinExec(left, right, [col("lk")], [col("rk")], jt)
+
+    # every direct probe is checked against the search probe of the same
+    # stream batch and build: same runs, same counts, same total
+    seen, probes = {}, []
+    real_table, real_direct = J._jit_build_table, J._jit_probe_direct
+
+    def spy_table(prep, size):
+        seen["prep"], seen["size"] = prep, size
+        return real_table(prep, size)
+
+    def checked_direct(lb, build, lkey, join_type):
+        (start, cnt, perm, out_cnt), total = real_direct(
+            lb, build, lkey, join_type)
+        (s2, c2, p2, o2), t2 = J._jit_probe_fast(
+            lb, seen["prep"], lkey, join_type)
+        hit = np.asarray(c2) > 0   # a run's start means nothing at cnt 0
+        assert np.array_equal(cnt, c2) and np.array_equal(out_cnt, o2)
+        assert np.array_equal(np.asarray(start)[hit], np.asarray(s2)[hit])
+        assert np.array_equal(perm, p2) and int(total) == int(t2)
+        probes.append(int(total))
+        return (start, cnt, perm, out_cnt), total
+    monkeypatch.setattr(J, "_jit_build_table", spy_table)
+    monkeypatch.setattr(J, "_jit_probe_direct", checked_direct)
+
+    before = get_registry().counters()
+    rows = collect_device(plan)
+    moved = get_registry().counters_since(before)
+    other = "search" if path == "direct" else "direct"
+    assert moved.get(f"join.probe.{path}") == 2          # 2 stream batches
+    assert f"join.probe.{other}" not in moved
+    if path == "direct":
+        assert len(probes) == 2
+        assert moved["join.build.table_entries"] == seen["size"]
+        if keep is not None:
+            assert seen["size"] == 32 and seen["prep"][0].shape == (1 << 17,)
+    else:
+        assert "join.build.table_entries" not in moved and not probes
+
+    # the same plan, made to search: the same rows in the same order
+    monkeypatch.setattr(J, "direct_table_size", lambda *a: None)
+    assert collect_device(plan) == rows
+    assert sorted(collect_host(plan), key=repr) == sorted(rows, key=repr)
+
+
+def test_direct_table_rule():
+    from spark_rapids_tpu.ops.join import direct_table_size
+    assert direct_table_size(31, 2451000, 2451030, 1 << 17) == 32
+    assert direct_table_size(1, 5, 5, 8) == 8
+    assert direct_table_size(0, I32.max, I32.max, 8) is None
+    # q6's filtered item (102k ids in 2^15 rows) and customer at 2^19
+    assert direct_table_size(30_000, 1, 102_000, 1 << 15) == 1 << 17
+    assert direct_table_size(500_000, 1, 500_000, 1 << 19) == 1 << 19
+    # the floor, then eight entries a build slot
+    assert direct_table_size(3, 0, (1 << 20) - 1, 8) == 1 << 20
+    assert direct_table_size(3, 0, 1 << 20, 8) is None
+    assert direct_table_size(3, 0, 1 << 20, 1 << 18) == 1 << 21
+    assert direct_table_size(3, 0, 1 << 21, 1 << 18) is None
+    assert direct_table_size(2, I64.min, I64.max, 1 << 20) is None

@@ -26,6 +26,7 @@ PKG = os.path.join(os.path.dirname(os.path.dirname(
 CONF = {"spark.rapids.sql.resultCache.enabled": "false",
         "spark.rapids.sql.test.enabled": "true"}
 FACT_FILES = 3
+DAY0, DAYS = 2450000, 2000
 
 
 @pytest.fixture(scope="module")
@@ -38,9 +39,21 @@ def tables(tmp_path_factory):
         n = 4000
         pq.write_table(pa.table({
             "k": rng.integers(0, 50, n), "g": rng.integers(0, 5, n),
-            "v": rng.random(n)}), str(root / "fact" / f"part-{i}.parquet"))
+            "v": rng.random(n),
+            "d": rng.integers(DAY0, DAY0 + DAYS, n).astype(np.int32)}),
+            str(root / "fact" / f"part-{i}.parquet"))
     pq.write_table(pa.table({"k": np.arange(50), "w": rng.random(50)}),
                    str(root / "dim" / "part-0.parquet"))
+    # a date dimension (consecutive day numbers, a month of 31) and a
+    # dimension keyed by ids a hundred million apart
+    os.makedirs(root / "days")
+    os.makedirs(root / "sparse")
+    day = np.arange(DAY0, DAY0 + DAYS, dtype=np.int32)
+    pq.write_table(pa.table({"d": day, "month": (day - DAY0) // 31}),
+                   str(root / "days" / "part-0.parquet"))
+    pq.write_table(pa.table({"k": np.arange(50) * 10**8,
+                             "w": rng.random(50)}),
+                   str(root / "sparse" / "part-0.parquet"))
     return str(root)
 
 
@@ -204,11 +217,14 @@ def test_ring_is_bounded():
     for i in range(RECENT_QUERIES + 6):
         reg.note_query({"query_id": str(i)})
     kept = reg.recent_queries()
-    assert RECENT_QUERIES == 64 and len(kept) == 64
-    assert kept[0]["query_id"] == "6" and kept[-1]["query_id"] == "69"
+    # a 48 s benchmark window of a half-second query, traced collects
+    # first, must still be whole when the benchmark reads it
+    assert RECENT_QUERIES >= 256 and len(kept) == RECENT_QUERIES
+    last = RECENT_QUERIES + 5
+    assert kept[0]["query_id"] == "6" and kept[-1]["query_id"] == str(last)
     assert [r["query_id"] for r in reg.recent_queries(3)] == \
-        ["67", "68", "69"]
-    assert len(reg.recent_queries(1000)) == 64
+        [str(last - 2), str(last - 1), str(last)]
+    assert len(reg.recent_queries(10 * RECENT_QUERIES)) == RECENT_QUERIES
 
 
 def test_span_counts_and_times_once():
@@ -330,7 +346,8 @@ def test_live_programs_carry_their_names(query):
     _collect(query)
     by_code = {}
     live = list(cc._ALL_SHARED)
-    assert {"batch_unpack", "filter_batch", "join_probe_fast",
+    assert {"batch_unpack", "filter_batch", "join_build_prep",
+            "join_build_table", "join_probe_fast", "join_probe_direct",
             "join_gather", "agg_update", "agg_merge",
             "agg_final"} <= {sj.name for sj in live}
     for sj in live:
@@ -344,6 +361,49 @@ def test_live_programs_carry_their_names(query):
                                set()).add(sj.name)
     assert by_code and all(len(v) == 1 for v in by_code.values()), \
         {k: v for k, v in by_code.items() if len(v) > 1}
+
+
+# ------------------------------------------------------ the join's probe
+
+@pytest.mark.parametrize("shape", ["dense_dim", "sparse_dim", "q6_shaped"])
+def test_probe_counters_say_which_probe_ran(session, tables, shape):
+    """``join.probe.direct`` / ``join.probe.search``: one per stream
+    batch probed, counted where the host picks the program, so they
+    equal that program's launches; ``join.build.table_entries``: the
+    direct-address tables built, and one more fetch a fast build."""
+    fact = session.read_parquet(os.path.join(tables, "fact"))
+    if shape == "q6_shaped":
+        # the fact stream through two surrogate-key joins, the inner one
+        # against a date dimension filtered to one month (it keeps its
+        # capacity: 2048 slots, 31 keys), into a small group-by
+        days = session.read_parquet(os.path.join(tables, "days")) \
+            .where(col("month") == lit(7)).select("d")
+        dim = session.read_parquet(os.path.join(tables, "dim"))
+        df = fact.join(days, on="d").join(dim, on="k")
+        tables_built, entries = 2, 32 + 64
+    else:
+        dim = session.read_parquet(os.path.join(
+            tables, "dim" if shape == "dense_dim" else "sparse"))
+        df = fact.join(dim, on="k")
+        tables_built, entries = (1, 64) if shape == "dense_dim" else (0, 0)
+    df = df.group_by("g").agg(CountStar().alias("n"))
+    assert len(df.collect()) == 5
+    c = _collect(df)["counters"]
+    ran, idle = ("search", "direct") if shape == "sparse_dim" \
+        else ("direct", "search")
+    program = {"direct": "join_probe_direct", "search": "join_probe_fast"}
+    assert c[f"join.probe.{ran}"] == c[f"program.{program[ran]}.launches"]
+    assert c[f"join.probe.{ran}"] >= FACT_FILES
+    assert f"join.probe.{idle}" not in c
+    assert f"program.{program[idle]}.launches" not in c
+    assert c.get("join.build.table_entries", 0) == entries
+    assert c.get("program.join_build_table.launches", 0) == tables_built
+    builds = 2 if shape == "q6_shaped" else 1
+    assert c["program.join_build_prep.launches"] == builds
+    # one fetch a build (its key range), and at most one a probe (the
+    # totals, chunked by partition)
+    assert builds < c["span.fetch@JoinExec.count"] \
+        <= builds + c[f"join.probe.{ran}"]
 
 
 # ----------------------------------------------------------------- trace
