@@ -1,5 +1,5 @@
 #!/bin/bash
-# Nightly tier: the full sweeps premerge defers.
+# Nightly tier: the full sweeps tier-1 defers.
 #
 # Reference model: jenkins/spark-tests.sh + the nightly integration
 # Jenkinsfiles run every TPC-DS/TPC-H query and the fuzz suites against

@@ -97,8 +97,9 @@ run_both(Murmur3Hash(col("s")), SDATA, SSCH); print("murmur3 ok")
 # f32 exponent range — docs/compatibility.md).  Quantify the actual
 # aggregate-level error at TPC-DS-like scale: sum/avg/min/max over
 # doubles of several magnitude distributions, device vs the host numpy
-# oracle, max relative error per op recorded in
-# artifacts/f64_pair_error.json.  The reference ships the analogous
+# oracle, max relative error per op written to
+# chiprun_out/verify_exprs_f64.json (what a chip run brings back).  The
+# reference ships the analogous
 # caveat as `incompat` flags + approximate_float test marks
 # (RapidsConf.scala:461-492).
 # ---------------------------------------------------------------------------
@@ -161,8 +162,10 @@ def quantify_f64_pair():
         "diverged_rows": diverged, "total_rows": len(vals),
         "diverged_frac": diverged / len(vals)}
     print(f"murmur3 over >48-bit doubles: {diverged}/{len(vals)} diverge")
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "artifacts", "f64_pair_error.json")
+    out = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    path = os.path.join(out, "verify_exprs_f64.json")
     with open(path, "w") as f:
         json.dump({"backend": jax.default_backend(), "report": report},
                   f, indent=1, sort_keys=True)

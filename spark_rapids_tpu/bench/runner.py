@@ -239,7 +239,6 @@ def run_benchmark(data_dir: str, sf: float, queries, iterations: int = 1,
                 t0 = time.perf_counter()
                 oracle = _collect_rows(df, "host", plan)
                 rec["oracle_s"] = round(time.perf_counter() - t0, 4)
-                rec["speedup"] = round(rec["oracle_s"] / rec["device_s"], 3)
                 rec["ok"] = _rows_match(rows, oracle)
             else:
                 rec["ok"] = True

@@ -82,9 +82,9 @@ class WorkerFetchFailed(Exception):
 
 class WorkerShuffleReaderExec(PlanNode):
     """Leaf that streams an upstream cluster shuffle's reduce
-    partitions from the workers that hold them (the in-fragment analog
-    of RemoteShuffleReaderExec, with a slot-ranged run list per output
-    partition instead of one home address).
+    partitions from the workers that hold them, through the retrying
+    fetch (shuffle/retry.py), with a slot-ranged run list per output
+    partition.
 
     ``groups[pid]`` is a list of ``(address, fetch_pid, lo, hi)`` runs:
     fetch slots [lo, hi) of the peer's reduce partition ``fetch_pid``.
@@ -187,8 +187,8 @@ class ClusterMapOutputTracker:
     ``cpid * MAP_ID_STRIDE + k`` — the same (child partition, batch)
     lexicographic order the single-process path's flat map indices
     produce — so the merged fetch stream is batch-for-batch identical
-    to one process (the exactness argument behind the premerge equality
-    gate)."""
+    to one process (the exactness argument behind
+    tests/test_cluster.py's equality with the single-process rows)."""
 
     def __init__(self, cluster, ctx: ExecCtx, shuffle_id, num_parts: int):
         from spark_rapids_tpu.faults import FaultRegistry

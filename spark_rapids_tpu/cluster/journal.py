@@ -40,7 +40,9 @@ degrades to flush-only rather than failing the query.
 Dependency discipline: stdlib + obs.registry only (faults is injected
 by the driver), and the module is imported ONLY by cluster-mode
 drivers with the journal enabled — single-process sessions never load
-it (premerge-asserted).
+it (tests/test_driver_recovery.py::test_journal_disabled_is_inert and
+the journal case of
+tests/test_telemetry.py::test_disabled_path_never_imports).
 """
 from __future__ import annotations
 

@@ -71,8 +71,7 @@ def scrub_worker_conf(settings: dict) -> dict:
 
 class WorkerRuntime:
     """Everything one worker process owns; also constructible in-process
-    for tests (the premerge gate spot-checks fragment execution without
-    paying subprocess startup)."""
+    for tests (fragment execution without paying subprocess startup)."""
 
     def __init__(self, worker_id: str, driver_addr=None,
                  settings: dict | None = None):

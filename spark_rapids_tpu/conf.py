@@ -139,8 +139,8 @@ EXACT_DOUBLE_AGG = bool_conf(
     "Force aggregations over DOUBLE columns to the host engine: TPU f64 "
     "is a float32-pair emulation (~48 mantissa bits, f32 exponent range "
     "— docs/compatibility.md) and sums/averages can deviate from exact "
-    "f64; artifacts/f64_pair_error.json quantifies the measured error "
-    "per op. float32 aggregations are exact on TPU and stay on device. "
+    "f64; scripts/verify_exprs_tpu.py measures the error per op on "
+    "the chip. float32 aggregations are exact on TPU and stay on device. "
     "(ref RapidsConf.scala incompat machinery :461-492)")
 
 REPLACE_SORT_MERGE_JOIN = bool_conf(

@@ -38,7 +38,9 @@ dropped actuation is simply re-derived from fresh signals next tick),
 and reversible: with ``spark.rapids.control.enabled=false`` (the
 default) this package is NEVER imported — the session gates on the raw
 conf string, so plans, confs, and counters are byte-identical to the
-static engine (ci/premerge.sh asserts it).
+static engine (tests/test_control.py::
+test_disabled_is_byte_identical_to_static, and the control case of
+tests/test_telemetry.py::test_disabled_path_never_imports).
 """
 from __future__ import annotations
 

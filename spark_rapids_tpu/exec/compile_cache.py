@@ -20,7 +20,8 @@ bucket — one wrapper serves every bucket, and the (capacity, dtype)
 signature selects the executable inside jax.  ``SharedJit`` tracks the
 signatures it has seen so ``compile_count`` / ``compile_wall_s`` move
 exactly when a new executable is built, which makes "a second run of
-the same query compiles nothing" a testable invariant (ci/premerge.sh).
+the same query compiles nothing" a testable invariant
+(tests/test_fusion.py::test_second_run_zero_new_compiles).
 
 Counters (MetricsRegistry): ``fusion_cache_hits`` / ``fusion_cache_misses``
 move per fragment-key lookup; ``compile_count`` / ``compile_wall_s`` per

@@ -523,56 +523,3 @@ class BroadcastExchangeExec(PlanNode):
 
     def node_desc(self) -> str:
         return "BroadcastExchangeExec"
-
-
-class RemoteShuffleReaderExec(PlanNode):
-    """Reduce-side scan of a REMOTE peer's map output over the TCP
-    transport: the cross-process half of the accelerated shuffle
-    (reference read path: RapidsCachingReader -> RapidsShuffleIterator
-    -> transport client fetch, RapidsShuffleInternalManager.scala:307-345
-    + RapidsShuffleClient.scala).  The map side runs in another process
-    serving its partitions through TcpShuffleServer; this exec streams
-    them into the local pipeline, so a full plan executes with map tasks
-    in one process and reduce tasks in another.
-    """
-
-    def __init__(self, address, shuffle_id: "int | str", num_parts: int,
-                 schema: T.Schema):
-        super().__init__([])
-        self.address = tuple(address)
-        self.shuffle_id = shuffle_id
-        self._num_parts = num_parts
-        self._schema = schema
-
-    @property
-    def output_schema(self) -> T.Schema:
-        return self._schema
-
-    def num_partitions(self, ctx: ExecCtx) -> int:
-        return self._num_parts
-
-    def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
-        # the retrying fetch (shuffle/retry.py): transient peer failures
-        # reconnect and resume mid-partition instead of killing the
-        # whole reduce-side pull (reference: RapidsShuffleIterator
-        # surfacing fetch failures to stage retry).  One fault registry
-        # per execution so nth/times counters span all pulls.
-        from spark_rapids_tpu.faults import FaultRegistry
-        from spark_rapids_tpu.shuffle.retry import fetch_remote_with_retry
-        faults = ctx.cached(("fault_registry",),
-                            lambda: FaultRegistry.from_conf(ctx.conf))
-        # propagate the originating query's trace across the wire so the
-        # serving peer's "shuffle.serve" event parents onto THIS span —
-        # one trace covers the fetch, its retries, and any recovery
-        tracer = ctx.tracer
-        trace = tracer.trace_header() if tracer is not None else None
-        yield from fetch_remote_with_retry(self.address, self.shuffle_id,
-                                           pid, device=ctx.is_device,
-                                           conf=ctx.conf, faults=faults,
-                                           tracer=tracer, trace=trace,
-                                           lifecycle=ctx.lifecycle)
-
-    def node_desc(self) -> str:
-        return (f"RemoteShuffleReaderExec[{self.address[0]}:"
-                f"{self.address[1]}, shuffle={self.shuffle_id}, "
-                f"parts={self._num_parts}]")

@@ -4,7 +4,8 @@ one per operator.
 
 This is the engine's analog of whole-stage codegen, the reference
 plugin's biggest small-query lever (PAPER.md §L3, GpuTransitionOverrides):
-BENCH_r05 showed per-operator dispatch dominating below sf10.  The
+per-operator dispatch is most of a small query's wait (PERF.md §5,
+``tpcds-sf1-chip1.q6``).  The
 planner (plan/overrides.py ``_fuse_stages``) collapses runs of
 elementwise operators into a ``FusedStageExec`` whose body chains the
 member programs inside a single ``jax.jit`` region, letting XLA fuse the

@@ -279,8 +279,8 @@ __all__ = ["FaultRegistry", "FaultRule", "FaultAction", "InjectedFault",
 #: every injection point wired into the engine (the module docstring
 #: documents each).  enginelint RL005 cross-checks this registry against
 #: the live ``.check("point", ...)`` call sites in both directions, so a
-#: renamed site or a stale entry fails premerge instead of silently
-#: turning a fault plan into a no-op.
+#: renamed site or a stale entry fails tests/test_enginelint.py instead
+#: of silently turning a fault plan into a no-op.
 KNOWN_POINTS = frozenset({
     "tcp.server.frame",
     "tcp.client.connect",

@@ -559,7 +559,7 @@ class MemoryGovernor:
 
     def reserved_bytes(self) -> int:
         """Outstanding grant reservations (must be 0 when no query is
-        mid-wait — the premerge gate's leak check)."""
+        mid-wait — the leak check of tests/test_governor_chaos.py)."""
         with self._cond:
             return sum(s.reserved_bytes for s in self._states.values())
 

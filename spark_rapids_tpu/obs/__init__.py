@@ -33,8 +33,9 @@ headless engine unifies its equivalents here:
 Import discipline: the hot path must stay obs-free when observability is
 disabled, so this package __init__ resolves submodule attributes LAZILY
 — ``spark_rapids_tpu.obs.trace`` / ``obs.diag`` are only imported when a
-tracer is enabled or a query actually fails (ci/premerge.sh asserts the
-disabled path leaves them out of sys.modules).
+tracer is enabled or a query actually fails
+(tests/test_telemetry.py::test_disabled_path_never_imports holds the
+disabled path to it).
 """
 from __future__ import annotations
 

@@ -16,8 +16,8 @@ see the old or the new file, never a torn one).
 
 Import discipline: the session gates on the raw conf string, so with
 ``spark.rapids.obs.history.dir`` unset this module is never imported
-(ci/premerge.sh asserts it).  ``python -m tools.history`` reads the log
-with NO engine imports at all.
+(tests/test_telemetry.py::test_disabled_path_never_imports).
+``python -m tools.history`` reads the log with NO engine imports at all.
 """
 from __future__ import annotations
 

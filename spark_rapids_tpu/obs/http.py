@@ -37,7 +37,7 @@ detail (tenant names, peer addresses, plan fingerprints) that must not
 face a network; operators who need remote scrape should sidecar a real
 exporter.  Off by default (``spark.rapids.obs.http.port`` = 0) and the
 module is never imported on the disabled path (session gates on the raw
-conf string; ci/premerge.sh asserts sys.modules stays clean).
+conf string; tests/test_telemetry.py::test_disabled_path_never_imports).
 """
 from __future__ import annotations
 

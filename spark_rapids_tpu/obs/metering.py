@@ -22,12 +22,12 @@ and the HBM sampler's process integration), so ``conservation()`` is a
 real cross-check — tenant sums within 5% of process totals — not a
 tautology.  Under concurrent queries the registry-delta byte charges
 can overlap (two in-flight queries each observe the other's counter
-movement); the invariant is asserted on serial runs (tests,
-ci/premerge.sh) where the two paths must agree.
+movement); the invariant holds on serial runs, where the two paths
+must agree (tests/test_profile.py asserts it on books of its own).
 
 Import discipline: this module is only imported when the raw conf
-string enables profiling (ci/premerge.sh asserts ``obs.metering``
-stays out of sys.modules on the disabled path).
+string enables profiling
+(tests/test_telemetry.py::test_disabled_path_never_imports).
 """
 from __future__ import annotations
 

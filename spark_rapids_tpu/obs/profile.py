@@ -6,7 +6,7 @@ went (PAPER.md §L5, GpuExec.scala:27-56); this engine's analog rides
 the instrumentation that already exists — the per-(operator, partition)
 summary the base PlanNode wrapper records at iterator exhaustion — so
 profiling adds ONE bounded record per operator-partition, never
-per-batch work (the <3% warm-overhead budget ci/premerge.sh enforces).
+per-batch work.
 
 Three surfaces per query:
 
@@ -25,8 +25,8 @@ Three surfaces per query:
 
 Import discipline: ExecCtx gates on the RAW conf string, so with
 ``spark.rapids.obs.profile.enabled`` unset this module (and
-``obs.metering``) is never imported — ci/premerge.sh asserts
-sys.modules stays clean and the disabled path stays byte-identical.
+``obs.metering``) is never imported
+(tests/test_telemetry.py::test_disabled_path_never_imports).
 """
 from __future__ import annotations
 
@@ -51,7 +51,7 @@ PROFILE_ENABLED = register(ConfEntry(
     "(fused-stage and mesh-region members included), HBM occupancy "
     "timeline, and per-tenant metering (/profile, /tenants). Off by "
     "default: the disabled path never imports obs.profile/obs.metering "
-    "and adds no per-batch work (premerge gates overhead < 3%).",
+    "and adds no per-batch work.",
     conv=_bool))
 PROFILE_DIR = register(ConfEntry(
     "spark.rapids.obs.profile.dir", "",

@@ -13,7 +13,8 @@ hook (`spark.rapids.tpu.profile.dir`).
 
 This module is only imported when `spark.rapids.obs.trace.enabled` is set
 (ExecCtx checks the raw conf string first) or when a diagnostic bundle is
-being emitted — the disabled path never touches it (ci/premerge.sh gate).
+being emitted — the disabled path never touches it
+(tests/test_telemetry.py::test_disabled_path_never_imports).
 """
 from __future__ import annotations
 

@@ -5,8 +5,8 @@ The reference uses CPU Spark itself as the differential-test oracle
 integration_tests asserts.py:290 ``assert_gpu_and_cpu_are_equal_collect``).
 This framework is standalone, so the CPU engine lives here: numpy-vectorized
 implementations with exactly Spark's ordering/equality semantics (null
-ordering, NaN largest + NaN==NaN for keys, -0.0==0.0).  These also serve as
-the CPU baseline that `bench.py` compares the TPU path against.
+ordering, NaN largest + NaN==NaN for keys, -0.0==0.0).  The benchmark
+(benchmark/run.py) checks every cell's rows against this engine.
 """
 from __future__ import annotations
 

@@ -619,7 +619,7 @@ class TpuOverrides:
         Default (``spark.rapids.sql.verify.plan`` on): one walk after
         the FINAL rewrite pass — the interim hooks are no-ops, so the
         steady state pays a single O(nodes) pass per prepare.  With
-        ``spark.rapids.sql.verify.plan.everyPass`` (tests, premerge)
+        ``spark.rapids.sql.verify.plan.everyPass`` (tests)
         every hook verifies, so a violation names the pass that
         introduced it.  A no-op callable when verification is off."""
         from spark_rapids_tpu.plan.verify import (PLAN_VERIFY,
@@ -1037,7 +1037,7 @@ class TpuOverrides:
             # (reduction order varies); exactDoubleAggregation=true
             # refuses DOUBLE ones specifically — TPU f64 is a
             # float32-pair emulation and sums can deviate from exact
-            # f64 (quantified in artifacts/f64_pair_error.json).
+            # f64 (measured by scripts/verify_exprs_tpu.py).
             # Mesh lowering (MeshAggregateExec) shares the layout, so
             # the gates cover both single-chip and mesh aggregates.
             from spark_rapids_tpu.conf import (ALLOW_FLOAT_AGG,

@@ -37,13 +37,13 @@ Two gates (docs/developer-guide.md):
 * ``spark.rapids.sql.verify.plan`` (default ON): ONE full walk after
   the final rewrite pass plus one after runtime AQE re-planning — the
   walk is a single fused tree pass (no per-node string building, no
-  per-call imports), well under 2% of plan-prepare time, so it stays
-  on everywhere including the bench path.
+  per-call imports), so it stays on everywhere including the
+  benchmark's path.
 * ``spark.rapids.sql.verify.plan.everyPass`` (default off): verify
   after EVERY rewrite pass, so a violation names the pass that
-  introduced it rather than the end of the pipeline.  The test suite
-  and ci/premerge.sh run with this on; the steady state does not pay
-  the 9 extra walks.
+  introduced it rather than the end of the pipeline.
+  tests/test_plan_verify.py plans the TPC-H ladder with this on; the
+  steady state does not pay the 9 extra walks.
 """
 from __future__ import annotations
 
@@ -70,8 +70,8 @@ PLAN_VERIFY_EVERY_PASS = bool_conf(
     "mesh alignment, shared scans, lineage stamping, cluster lowering, "
     "stage boundaries, fusion, mesh regions) instead of once at the "
     "end, so a violation "
-    "names the pass that introduced it. The test suite and premerge "
-    "gate run with this on; requires spark.rapids.sql.verify.plan.")
+    "names the pass that introduced it. tests/test_plan_verify.py "
+    "runs with this on; requires spark.rapids.sql.verify.plan.")
 
 #: rewrite passes in execution order; a check only arms once the pass
 #: that establishes its invariant has run (e.g. lineage stamps exist
@@ -554,8 +554,8 @@ def verify_plan(root, conf=None, pass_name: str = "mesh_regions") -> None:
 def verify_governor_ledger(gov) -> None:
     """Runtime sibling of :func:`verify_plan` for the cross-query memory
     governor (memory/governor.py): check the invariants the arbitration
-    logic only promises.  Called by the governor test suite and the
-    premerge governor gate after ``shutdown(drain=True)``; raises
+    logic only promises.  Called by the governor test suites, also
+    after ``shutdown(drain=True)``; raises
     :class:`PlanInvariantError` (node path ``<governor>``, pass
     ``governor_ledger``) on the first violation:
 

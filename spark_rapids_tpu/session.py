@@ -50,7 +50,8 @@ class TpuSession:
         self._http = None            # ObsHttpServer when the conf is on
         self._control = None         # ControlLoop when the conf is on
         # raw-settings gated: with the port conf absent/0 (the default)
-        # obs.http is never imported (premerge asserts sys.modules)
+        # obs.http is never imported (tests/test_telemetry.py::
+        # test_disabled_path_never_imports, as for every gate below)
         port = self.conf.settings.get("spark.rapids.obs.http.port")
         if port and int(port) > 0:
             from spark_rapids_tpu.obs.http import ObsHttpServer
@@ -58,7 +59,7 @@ class TpuSession:
         # raw-settings gated like http/history/cluster: with
         # control.enabled unset (the default) the control package is
         # never imported — plans, confs, and counters stay
-        # byte-identical to the static engine (premerge asserts it)
+        # byte-identical to the static engine
         if str(self.conf.settings.get(
                 "spark.rapids.control.enabled", "")).lower() \
                 in ("true", "1", "yes"):
@@ -153,7 +154,8 @@ class TpuSession:
         # control loop first: a controller actuating knobs while the
         # session tears them down would race, and stop() restores every
         # adapted knob to its static conf value (no thread survives
-        # shutdown — premerge asserts it)
+        # shutdown: tests/test_control.py::
+        # test_loop_thread_lifecycle_and_no_leak)
         control, self._control = self._control, None
         if control is not None:
             control.stop()
@@ -272,7 +274,7 @@ class TpuSession:
             return out
 
         # raw-settings gated: with history.dir unset (the default)
-        # obs.history is never imported (premerge asserts sys.modules)
+        # obs.history is never imported
         hist_dir = self.conf.settings.get("spark.rapids.obs.history.dir")
         hist_before = None
         submitted = None
@@ -282,7 +284,7 @@ class TpuSession:
             submitted = _time.time()
         # raw-settings gated like trace/history: with profile.enabled
         # unset (the default) obs.profile/obs.metering are never
-        # imported (premerge asserts sys.modules)
+        # imported
         prof_on = str(conf.settings.get(
             "spark.rapids.obs.profile.enabled", "")).lower() \
             in ("true", "1", "yes")

@@ -439,6 +439,12 @@ class TcpShuffleServer:
         self._closed.set()
         get_registry().unregister_source(self._reg_source)
         try:
+            # close() alone leaves the accept loop blocked in accept()
+            # on the dead fd for good; shutdown wakes it
+            self._sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
+        try:
             self._sock.close()
         except OSError:
             pass

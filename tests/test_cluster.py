@@ -258,7 +258,7 @@ def test_local2_groupby_exact_lz4_and_clean_shutdown():
 
 # ---------------------------------------------------------------------------
 # TPC-H over the worker pool (slow: worker pools recompile per query on a
-# cold process; ci/premerge.sh runs the same q3 + worker-death paths)
+# cold process; tier-1 runs the same paths on the in-memory group-by)
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -316,6 +316,33 @@ _CHAOS_CONF = {
     "spark.rapids.shuffle.tcp.maxRetries": 1,
     "spark.rapids.shuffle.tcp.retryWaitSeconds": 0.1,
 }
+
+
+def test_local2_worker_death_recovers_exact():
+    """A worker SIGKILLed on the driver's first reduce-side pull:
+    lineage recovery recomputes the lost map outputs on the survivor
+    and the rows are still exactly the single-process rows."""
+    data = _mkdata()
+
+    def rows(s):
+        df = s.from_pydict(data, SCHEMA, partitions=3, rows_per_batch=64)
+        return sorted(df.group_by("k").agg(Sum(col("v")).alias("sv"))
+                      .collect())
+    s0 = TpuSession()
+    want = rows(s0)
+    s0.shutdown()
+    s = TpuSession(dict(_CHAOS_CONF))
+    try:
+        before = get_registry().snapshot()
+        got = rows(s)
+        d = get_registry().delta(before)["counters"]
+        assert got == want
+        assert d.get("faults.injected.cluster.worker.dead", 0) >= 1, d
+        assert d.get("cluster_workers_lost", 0) >= 1, d
+        assert d.get("stage_recomputes", 0) > 0, d
+        assert d.get("map_outputs_recomputed", 0) > 0, d
+    finally:
+        s.shutdown(drain=True)
 
 
 # ---------------------------------------------------------------------------

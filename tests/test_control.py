@@ -420,9 +420,23 @@ def test_loop_survives_a_bad_tick():
 # reversibility: disabled = byte-identical
 # ---------------------------------------------------------------------------
 
-def test_disabled_is_byte_identical_to_static():
+def test_disabled_is_byte_identical_to_static(monkeypatch):
     import sys
-    assert "spark_rapids_tpu.control.loop" not in sys.modules or True
+    # this file imported the control package; drop it so that a
+    # disabled session re-importing it shows (the whole-interpreter
+    # half is tests/test_telemetry.py::test_disabled_path_never_imports)
+    for m in [m for m in sys.modules
+              if m.startswith("spark_rapids_tpu.control")]:
+        monkeypatch.delitem(sys.modules, m)
+    s = TpuSession({"spark.rapids.control.enabled": "false"})
+    try:
+        assert s._control is None
+        assert not [m for m in sys.modules
+                    if m.startswith("spark_rapids_tpu.control")]
+        assert dict(s.conf.settings) == \
+            {"spark.rapids.control.enabled": "false"}
+    finally:
+        s.shutdown()
     s = TpuSession({})
     try:
         df = _df(s)

@@ -386,6 +386,36 @@ def test_shutdown_gates_late_death_verdicts():
 # recovery preconditions
 # ---------------------------------------------------------------------------
 
+def test_journal_disabled_is_inert(tmp_path, monkeypatch):
+    """journal.enabled=false: a live driver never imports
+    cluster/journal.py, never touches journal.dir, and plans the same
+    (the default-conf half is
+    tests/test_telemetry.py::test_disabled_path_never_imports[journal])."""
+    from spark_rapids_tpu.cluster.driver import ClusterDriver
+    from spark_rapids_tpu.conf import TpuConf
+    monkeypatch.delitem(sys.modules, "spark_rapids_tpu.cluster.journal",
+                        raising=False)
+    jdir = tmp_path / "never-touched"
+    off = {"spark.rapids.cluster.mode": "local[1]",
+           "spark.rapids.cluster.journal.enabled": "false",
+           "spark.rapids.cluster.journal.dir": str(jdir)}
+    driver = ClusterDriver(TpuConf(off))
+
+    def plan(conf):
+        s = TpuSession(conf).attach_cluster(driver)
+        df = s.from_pydict(_mkdata(), SCHEMA, partitions=4,
+                           rows_per_batch=64)
+        return df.group_by("k").agg(Sum(col("v")).alias("sv")).explain()
+    try:
+        assert driver.journal is None
+        on = dict(off, **{"spark.rapids.cluster.journal.enabled": "true"})
+        assert plan(off) == plan(on), "journal changed the plan"
+        assert "spark_rapids_tpu.cluster.journal" not in sys.modules
+        assert not jdir.exists(), "disabled journal still did I/O"
+    finally:
+        driver.shutdown()
+
+
 def test_recover_requires_journal_dir():
     from spark_rapids_tpu.cluster.driver import ClusterDriver
     from spark_rapids_tpu.conf import TpuConf

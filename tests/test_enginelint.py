@@ -6,8 +6,7 @@ snippet through :func:`lint_source` with an engine-looking path — the
 rules scope themselves by path, so the snippets never touch real
 engine files.  The meta-test lints the REAL spark_rapids_tpu tree and
 asserts it is clean under ``--strict`` semantics: zero unsuppressed
-findings and zero suppressions without a written reason — the same
-gate ci/premerge.sh runs.
+findings and zero suppressions without a written reason.
 """
 import os
 import textwrap

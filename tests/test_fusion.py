@@ -71,8 +71,7 @@ def test_fused_vs_unfused_exact(data_dir, query):
 
 def test_fusion_changes_and_restores_plan_shape(data_dir):
     """q3's filter/project chain feeding a join build side must fuse,
-    and disabling fusion must restore the per-operator chain — the
-    premerge shape gate's contract.  q6 (single filter under the
+    and disabling fusion must restore the per-operator chain.  q6 (single filter under the
     aggregate) has no run of >=2 and must come out UNTOUCHED: fusion
     never wraps a lone operator."""
     _, fused_plan = _tpch_rows(data_dir, "q3")

@@ -155,7 +155,7 @@ def test_oom_storm_denial_converges(data_dir):
 def test_cancel_during_grant_stall_releases_reservation():
     """memory.grant.stall holds a reclaim in the grant-wait window; a
     cancel landing there must unwind with the terminal error, leaving
-    no reservation behind (the leak the premerge gate checks)."""
+    no reservation behind."""
     from spark_rapids_tpu.conf import TpuConf
     from spark_rapids_tpu.exec.lifecycle import QueryCancelled, QueryLifecycle
     from spark_rapids_tpu.memory import BufferCatalog

@@ -699,15 +699,22 @@ def test_cancel_mid_query_under_storm(data_dir, tmp_path, monkeypatch):
         except BaseException as e:  # noqa: BLE001 - recorded for asserts
             outcome.append(("err", e))
 
+    started = get_registry().snapshot()
     t = threading.Thread(target=run)
     t.start()
-    deadline = time.monotonic() + 30.0
+    deadline = time.monotonic() + 60.0
     while not session.active_queries() and t.is_alive() \
             and time.monotonic() < deadline:
         time.sleep(0.002)
     qids = session.active_queries()
     assert qids, "query never became active"
-    time.sleep(0.3)        # let it get into the storm
+    # cancel once the query is seen IN the storm (on the device, first
+    # fault fired), not a guessed interval after it was admitted: under
+    # load it can still be planning long after, and a cancel that lands
+    # there unwinds nothing this test is about
+    while not (sems and _counter_delta(started, "faults.injected") >= 1) \
+            and t.is_alive() and time.monotonic() < deadline:
+        time.sleep(0.002)
     before = get_registry().snapshot()
     cancelled = session.cancel(qids[0])
     if not cancelled:
@@ -821,6 +828,47 @@ def test_shutdown_drain_finishes_inflight_then_rejects(data_dir):
     with pytest.raises(QueryRejected, match="shutting down"):
         df.collect()
     assert session.active_queries() == []
+
+
+def test_shutdown_leaves_no_engine_threads(data_dir):
+    """After shutdown(drain=True) none of the threads a query started
+    (partition tasks, the TCP shuffle server) is still alive."""
+    from spark_rapids_tpu.bench.tpch_queries import build_tpch_query
+    from spark_rapids_tpu.session import TpuSession
+
+    def engine_threads():
+        return {t for t in threading.enumerate()
+                if t.name.startswith(("tpu-task", "tpu-shuffle-srv"))}
+
+    # threads other tests of this process left behind are not this
+    # session's to stop
+    inherited = engine_threads()
+    seen: set = set()
+    stop = threading.Event()
+
+    def sample():
+        while not stop.is_set():
+            seen.update(t.name for t in engine_threads() - inherited)
+            time.sleep(0.002)
+
+    sampler = threading.Thread(target=sample, daemon=True)
+    sampler.start()
+    session = TpuSession({
+        "spark.rapids.sql.resultCache.enabled": "false",
+        "spark.rapids.shuffle.transport.class":
+            "spark_rapids_tpu.shuffle.tcp.TcpShuffleTransport"})
+    try:
+        assert build_tpch_query("q3", session, data_dir).collect()
+    finally:
+        session.shutdown(drain=True, timeout=60.0)
+        stop.set()
+        sampler.join(5.0)
+    assert any(n.startswith("tpu-shuffle-srv") for n in seen), seen
+    deadline = time.monotonic() + 5.0
+    while engine_threads() - inherited and time.monotonic() < deadline:
+        time.sleep(0.05)
+    leaked = sorted(t.name for t in engine_threads() - inherited)
+    assert not leaked, f"engine threads alive after shutdown: {leaked}"
 
 
 def test_shutdown_no_drain_cancels_inflight(data_dir):

@@ -340,6 +340,22 @@ def test_background_watermark_spill(gov):
     assert after > before
 
 
+def test_background_thread_exits_with_last_catalog(gov):
+    """The watermark daemon lives exactly as long as a catalog is
+    registered: a drained session leaves no tpu-mem-governor thread."""
+    gov._poll_s = 0.02
+    a, b = _SpillCat(), _SpillCat()
+    gov.register(a, "qa", None, {})
+    gov.register(b, "qb", None, {})
+    t = gov._bg_thread
+    assert t is not None and t.is_alive() and t.name == "tpu-mem-governor"
+    gov.unregister(a)
+    assert gov._bg_thread is t and t.is_alive()
+    gov.unregister(b)
+    t.join(5.0)
+    assert not t.is_alive() and gov._bg_thread is None
+
+
 def test_pressure_shed_pauses_admissions(gov):
     from spark_rapids_tpu.exec.lifecycle import (AdmissionController,
                                                  QueryRejected)

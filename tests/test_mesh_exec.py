@@ -364,3 +364,11 @@ def test_mesh_join_threshold_keeps_replicated(rng):
         # neither exchange computed its outputs (replicated path only)
         for ex in node._exchanges:
             assert ("meshex", id(ex), ctx.backend) not in ctx.cache
+
+
+def test_graft_entry_dryrun_multichip():
+    """The driver's multichip entry point: replicated and partitioned
+    mesh joins into a mesh aggregate over the 8 virtual devices, rows
+    checked against the single-process engine inside the dryrun."""
+    import __graft_entry__ as g
+    g.dryrun_multichip(8)

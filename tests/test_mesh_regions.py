@@ -87,8 +87,7 @@ def _executed_plan(df):
 
 # q1 (wide agg) and q13 (string-heavy) take minutes under the 8-way
 # virtual mesh on one physical CPU, so like the 2/4-device rungs they
-# run in the full (premerge) suite; the 8-device q3/q6/q12/q18 rungs
-# are the tier-1 gate
+# are marked slow; the 8-device q3/q6/q12/q18 rungs are the tier-1 gate
 GATE_QUERIES = (
     pytest.param("q1", marks=pytest.mark.slow),
     "q3", "q6", "q12",

@@ -17,8 +17,8 @@ Covers the satellite guarantees, not just happy paths:
   and fault injections land in the process metrics registry.
 
 The import-discipline guarantee (obs.trace/obs.diag never imported on
-the disabled path) is enforced by ci/premerge.sh in a FRESH interpreter
-— it cannot be asserted here because these tests enable tracing.
+the disabled path) needs a FRESH interpreter — these tests enable
+tracing — and is tests/test_telemetry.py::test_disabled_path_never_imports.
 """
 import json
 import os
@@ -38,6 +38,17 @@ SCHEMA = T.Schema([
     T.StructField("k", T.IntegerType(), True),
     T.StructField("v", T.LongType(), True),
 ])
+
+
+def _schema_errors(doc, name: str) -> list:
+    """Violations of ``doc`` against ci/obs_schema.json's ``name``."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
+                                    "..", "scripts"))
+    try:
+        from validate_obs import load_schema, validate
+        return validate(doc, load_schema(name))
+    finally:
+        sys.path.pop(0)
 DATA = {"k": [i % 7 for i in range(400)], "v": list(range(400))}
 
 
@@ -380,7 +391,9 @@ def test_query_execution_traced_end_to_end(tmp_path):
     rows, ctx, plan = _run_device(_agg_df(_session()), conf)
     files = list(tmp_path.glob("trace_*.json"))
     assert len(files) == 1
-    evs = json.load(open(files[0]))["traceEvents"]
+    doc = json.load(open(files[0]))
+    assert _schema_errors(doc, "trace") == []
+    evs = doc["traceEvents"]
     names = {e["name"] for e in evs}
     assert {"query", "partition", "stage.map", "shuffle.fetch"} <= names
     assert len({e["args"]["query_id"] for e in evs}) == 1
@@ -475,6 +488,7 @@ def test_query_metrics_snapshot_shape():
     assert "operators" in snap and "registry" in snap
     assert any(k.startswith("LocalScanExec") for k in snap["operators"])
     assert {"counters", "gauges"} <= set(snap["registry"])
+    assert _schema_errors(snap, "metrics") == []
 
 
 # ---------------------------------------------------------------------------
@@ -507,13 +521,7 @@ def test_diagnostic_bundle_on_forced_failure(tmp_path):
     assert any(k.startswith("spark.rapids") for k in doc["conf"])
     assert doc["metrics"]["operators"]
     # the bundle validates against the checked-in CI schema
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                    "..", "scripts"))
-    try:
-        from validate_obs import load_schema, validate
-        assert validate(doc, load_schema("bundle")) == []
-    finally:
-        sys.path.pop(0)
+    assert _schema_errors(doc, "bundle") == []
 
 
 def test_no_bundle_when_dir_unset(tmp_path):

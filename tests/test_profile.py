@@ -6,14 +6,14 @@ Covers the plane's contracts, not just happy paths:
 
 * fused-stage / mesh-region time is attributed to member ops as child
   rows that never double-count in top-level sums;
-* the per-query artifact validates against ci/obs_schema.json (the
-  same check ci/premerge.sh runs on a real q3@mesh-8 export);
+* the per-query artifact validates against ci/obs_schema.json, a
+  synthetic one and the export of a real query over the 8-device mesh;
 * the two accounting paths (per-tenant charges vs. instrumentation
   totals) conserve, and the cross-check catches books that DON'T;
 * worker drain/merge deltas move tenant charges exactly once;
 * the profiler is inert when disabled (ExecCtx.profiler is None) —
   the stronger sys.modules guarantee needs a fresh interpreter and is
-  enforced by ci/premerge.sh;
+  tests/test_telemetry.py::test_disabled_path_never_imports;
 * Prometheus label escaping survives hostile tenant names, and
   histogram snapshot merges are exact under scrape-while-observe.
 """
@@ -261,7 +261,8 @@ def test_live_progress_uses_row_medians_then_wall_fallback():
 
 
 # ---------------------------------------------------------------------------
-# disabled path (in-process half; fresh-interpreter half in premerge)
+# disabled path (in-process half; fresh-interpreter half in
+# tests/test_telemetry.py::test_disabled_path_never_imports)
 # ---------------------------------------------------------------------------
 
 def test_exec_ctx_profiler_is_none_when_disabled():
@@ -364,6 +365,64 @@ def test_history_entry_has_metering_rows_and_profile(tmp_path):
     assert e["profile"]["device_seconds"] == pytest.approx(
         sum(o["device_s"] for o in e["profile"]["operators"].values()
             if o["parent"] is None), abs=1e-6)
+
+
+def test_profiled_mesh_query_exports_members_and_counter_tracks(tmp_path):
+    """A real profiled query over the 8-device mesh: the exported
+    artifact is schema-valid, the region's time is attributed to member
+    rows that never exceed their container, the flamegraph is written,
+    and the operator counter tracks are merged into the trace."""
+    import glob
+    import os
+    import sys
+
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.expr.aggregates import Sum
+    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.session import TpuSession
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "scripts"))
+    from validate_obs import load_schema, validate
+    pdir, tdir = tmp_path / "profiles", tmp_path / "traces"
+    s = TpuSession(dict(PROF_CONF, **{
+        "spark.rapids.tpu.mesh.deviceCount": 8,
+        "spark.rapids.sql.resultCache.enabled": "false",
+        "spark.rapids.obs.profile.dir": str(pdir),
+        "spark.rapids.obs.trace.enabled": "true",
+        "spark.rapids.obs.trace.dir": str(tdir)}))
+    try:
+        schema = T.Schema([T.StructField("k", T.IntegerType(), True),
+                           T.StructField("v", T.LongType(), True)])
+        df = s.from_pydict({"k": [i % 7 for i in range(512)],
+                            "v": list(range(512))}, schema, partitions=4)
+        rows = df.where(col("v") > 3).group_by("k") \
+            .agg(Sum(col("v")).alias("sv")).collect()
+        assert len(rows) == 7
+    finally:
+        s.shutdown()
+    exported = glob.glob(str(pdir / "profile_*.json"))
+    assert len(exported) == 1, exported
+    prof = json.load(open(exported[0]))
+    assert validate(prof, load_schema("profile")) == []
+    ops = prof["operators"]
+    shares: dict = {}
+    for e in ops.values():
+        if e["parent"]:
+            shares[e["parent"]] = shares.get(e["parent"], 0.0) \
+                + e["device_s"]
+    assert shares, f"no member-attributed rows: {sorted(ops)}"
+    for parent, total in shares.items():
+        assert total <= ops[parent]["device_s"] + 1e-6, (parent, total)
+    assert prof["flamegraph"].strip()
+    flame = glob.glob(str(pdir / "flamegraph_*.txt"))
+    assert flame and open(flame[0]).read().strip()
+    traces = glob.glob(str(tdir / "trace_*.json"))
+    assert len(traces) == 1, traces
+    doc = json.load(open(traces[0]))
+    assert validate(doc, load_schema("trace")) == []
+    assert any(ev.get("ph") == "C"
+               and ev["name"] == "operator.device_seconds"
+               for ev in doc["traceEvents"])
 
 
 # ---------------------------------------------------------------------------

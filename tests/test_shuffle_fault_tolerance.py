@@ -74,7 +74,7 @@ def _transport(ctx, conf):
 
 def test_fault_registry_inert_when_unset():
     """With spark.rapids.test.faults unset nothing is built: every
-    injection site is one is-None check (CI asserts this too)."""
+    injection site is one is-None check."""
     assert FaultRegistry.from_conf(TpuConf({})) is None
     assert FaultRegistry.from_conf(None) is None
     assert FaultRegistry.from_conf({}) is None
@@ -427,10 +427,10 @@ def test_injected_oom_exhausting_retries_raises():
 # ---------------------------------------------------------------------------
 
 def test_remote_reader_exec_survives_chaos():
-    """RemoteShuffleReaderExec (the reduce-side exec) pulls through the
-    retrying fetch: a chaos plan on the serving transport is invisible
-    to the query result."""
-    from spark_rapids_tpu.exec.exchange import RemoteShuffleReaderExec
+    """WorkerShuffleReaderExec (the reduce-side exec of the cluster
+    path) pulls through the retrying fetch: a chaos plan on the serving
+    transport is invisible to the query result."""
+    from spark_rapids_tpu.cluster.exec import WorkerShuffleReaderExec
 
     serve_conf = TpuConf({"spark.rapids.test.faults":
                           "tcp.server.frame:reset,nth=2,times=1"})
@@ -439,7 +439,8 @@ def test_remote_reader_exec_survives_chaos():
         t = _transport(sctx, serve_conf)
         try:
             oracle = _fill(t, shuffle_id=7, n_batches=4)
-            reader = RemoteShuffleReaderExec(t.address, 7, 1, SCHEMA)
+            reader = WorkerShuffleReaderExec(
+                7, SCHEMA, [[(t.address, 0, 0, None)]])
             with ExecCtx(backend="device", conf=read_conf) as rctx:
                 got = []
                 for b in reader.partition_iter(rctx, 0):

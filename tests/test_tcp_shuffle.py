@@ -269,7 +269,7 @@ def test_distributed_query_two_processes():
 
     import jax
 
-    from spark_rapids_tpu.exec.exchange import RemoteShuffleReaderExec
+    from spark_rapids_tpu.cluster.exec import WorkerShuffleReaderExec
     from spark_rapids_tpu.exec.aggregate import HashAggregateExec
     from spark_rapids_tpu.expr.aggregates import CountStar, Sum
     from spark_rapids_tpu.expr.core import col
@@ -295,8 +295,9 @@ def test_distributed_query_two_processes():
 
         # reduce side: remote scan of the peer's map output -> final agg
         s = TpuSession({})
-        reader = RemoteShuffleReaderExec(("127.0.0.1", port), 777, 4,
-                                         schema)
+        reader = WorkerShuffleReaderExec(
+            777, schema, [[(("127.0.0.1", port), pid, 0, None)]
+                          for pid in range(4)])
         agg = HashAggregateExec(
             [col("k")], [col("k"), Sum(col("v")).alias("sv"),
                          CountStar().alias("cnt")], reader)

@@ -472,6 +472,42 @@ def test_trace_ship_and_ingest_one_timeline(tmp_path):
         + 1e4
 
 
+def test_local2_query_exports_one_trace_with_worker_lanes(tmp_path):
+    """A real local[2] query: ONE exported trace, spans from the driver
+    and from both worker processes, each pid on a named lane."""
+    import glob
+
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.expr.aggregates import Sum
+    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.session import TpuSession
+    schema = T.Schema([T.StructField("k", T.IntegerType(), True),
+                       T.StructField("v", T.LongType(), True)])
+    s = TpuSession({"spark.rapids.cluster.mode": "local[2]",
+                    "spark.rapids.obs.trace.enabled": "true",
+                    "spark.rapids.obs.trace.dir": str(tmp_path)})
+    try:
+        worker_pids = {h.pid for h in s._cluster().workers()}
+        df = s.from_pydict({"k": [i % 13 for i in range(400)],
+                            "v": list(range(400))}, schema,
+                           partitions=4, rows_per_batch=64)
+        assert len(df.group_by("k").agg(Sum(col("v"))).collect()) == 13
+    finally:
+        s.shutdown()
+    traces = glob.glob(str(tmp_path / "trace_*.json"))
+    assert len(traces) == 1, traces
+    doc = json.load(open(traces[0]))
+    assert validate(doc, load_schema("trace")) == []
+    lanes = {ev["pid"]: ev["args"]["name"] for ev in doc["traceEvents"]
+             if ev.get("ph") == "M" and ev["name"] == "process_name"}
+    span_pids = {ev["pid"] for ev in doc["traceEvents"]
+                 if ev.get("ph") == "X"}
+    assert len(worker_pids) == 2 and worker_pids <= span_pids, \
+        (worker_pids, span_pids)
+    assert worker_pids <= set(lanes), (worker_pids, lanes)
+    assert os.getpid() in span_pids and lanes.get(os.getpid()) == "driver"
+
+
 def test_trace_lanes_survive_buffer_rotation(tmp_path):
     from spark_rapids_tpu.obs.trace import Tracer
     tr = Tracer(query_id="q2", max_events=4)
@@ -508,28 +544,66 @@ def test_cluster_span_buffer_bounds():
 # import discipline
 # ---------------------------------------------------------------------------
 
-def test_disabled_path_never_imports_http_or_history():
-    """With both confs off, a full query leaves obs.http / obs.history
-    out of sys.modules — zero overhead on the disabled path."""
-    import subprocess
-    code = """
-import sys
+_DISABLED_PATH_SCRIPT = """
+import json, sys, threading
 from spark_rapids_tpu.session import TpuSession
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.expr.aggregates import Sum
+from spark_rapids_tpu.expr.core import col
 s = TpuSession({})
-schema = T.Schema([T.StructField("a", T.IntegerType())])
-s.from_pydict({"a": [1, 2, 3]}, schema).collect()
+schema = T.Schema([T.StructField("k", T.IntegerType(), True),
+                   T.StructField("v", T.LongType(), True)])
+df = s.from_pydict({"k": [i % 5 for i in range(200)],
+                    "v": list(range(200))}, schema, partitions=2)
+assert len(df.group_by("k").agg(Sum(col("v"))).collect(tenant="t")) == 5
+threads = sorted(t.name for t in threading.enumerate())
 s.shutdown()
-bad = [m for m in sys.modules
-       if m in ("spark_rapids_tpu.obs.http", "spark_rapids_tpu.obs.history")]
-sys.exit(1 if bad else 0)
+assert s._http is None
+# nor may the cluster driver pull the journal in when it is imported
+import spark_rapids_tpu.cluster.driver
+mods = sorted(m for m in sys.modules if m.startswith("spark_rapids_tpu"))
+print(json.dumps({"modules": mods, "threads": threads}))
 """
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, "-c", code],
+
+# module prefixes a default-conf session must leave unimported, and the
+# background threads it must not start
+_DISABLED_GROUPS = {
+    "tracer": (("spark_rapids_tpu.obs.trace", "spark_rapids_tpu.obs.diag"),
+               ()),
+    "telemetry": (("spark_rapids_tpu.obs.http",
+                   "spark_rapids_tpu.obs.history"), ()),
+    "profiler": (("spark_rapids_tpu.obs.profile",
+                  "spark_rapids_tpu.obs.metering"), ("obs-hbm-sampler",)),
+    "control": (("spark_rapids_tpu.control",), ("control-loop",)),
+    "journal": (("spark_rapids_tpu.cluster.journal",), ()),
+}
+
+
+@pytest.fixture(scope="module")
+def disabled_path_record():
+    """One fresh interpreter runs a shuffled group-by on a default-conf
+    session and reports what it imported (this process has imported
+    everything, so sys.modules here proves nothing)."""
+    import subprocess
+    r = subprocess.run([sys.executable, "-c", _DISABLED_PATH_SCRIPT],
                        cwd=os.path.join(os.path.dirname(__file__), ".."),
                        capture_output=True, text=True, timeout=300,
-                       env=env)
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stdout + r.stderr
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("group", sorted(_DISABLED_GROUPS))
+def test_disabled_path_never_imports(disabled_path_record, group):
+    """With every conf at its default a full query leaves the gated
+    subsystem out of sys.modules and starts none of its threads — the
+    disabled path costs nothing by construction."""
+    prefixes, threads = _DISABLED_GROUPS[group]
+    bad = [m for m in disabled_path_record["modules"]
+           if any(m == p or m.startswith(p + ".") for p in prefixes)]
+    assert not bad, f"{group} modules imported on the default path: {bad}"
+    live = [t for t in disabled_path_record["threads"] if t in threads]
+    assert not live, f"{group} threads running on the default path: {live}"
 
 
 def test_obs_package_lazy_exports():

@@ -47,10 +47,10 @@ def test_date_dim_keys(data_dir):
     assert rows["d_moy"][i] == 1
 
 
-# Default (premerge) runs a representative cross-section of plan
+# Default (tier-1) runs a representative cross-section of plan
 # shapes; TPCDS_FULL=1 sweeps all 99 (the nightly tier — the committed
 # artifact artifacts/tpcds_99_sf001_verify.txt records a full pass).
-# Mirrors the reference's premerge-vs-nightly split (jenkins/).
+# Mirrors the reference's per-change-vs-nightly split (jenkins/).
 _SMOKE = ["q1", "q6", "q14", "q23", "q36", "q47", "q49", "q51", "q64",
           "q67", "q72", "q77", "q87", "q95"]
 _SUITE = sorted(QUERIES) if os.environ.get("TPCDS_FULL") == "1" else _SMOKE

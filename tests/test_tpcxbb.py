@@ -35,7 +35,7 @@ def test_unsupported_refused_like_reference():
         build_tpcxbb_query("q10", None, "")
 
 
-# default (premerge) smoke runs the cross-section with non-empty
+# default (tier-1) smoke runs the cross-section with non-empty
 # results at SF0.01; TPCXBB_FULL=1 sweeps all 19
 _SMOKE = ["q5", "q6", "q11", "q12", "q14", "q24", "q25", "q28"]
 _SUITE = sorted(set(TPCXBB_QUERIES) - {"q20"}) \

@@ -300,15 +300,44 @@ def test_cluster_worker_death_mid_write(tmp_path):
 # attempt's manifests are discarded, exactly one winner per task
 # ---------------------------------------------------------------------------
 
-def test_speculative_duplicate_write_exact(tmp_path):
+_STALL_S = 1234.5
+
+
+def _hold_slow_worker_until_speculated(monkeypatch):
+    """Make the ``cluster.worker.slow`` stall (the one sleep of
+    ``_STALL_S``) last until the speculative copy has been launched,
+    not for a length of time: whether 2 s outlasts
+    ``multiplier`` x the median fragment depends on how loaded the host
+    is, and on a loaded one the straggler used to wake first."""
+    import time
+    import types
+
+    import spark_rapids_tpu.cluster.exec as cexec
+    base = get_registry().snapshot()
+
+    def sleep(seconds):
+        if seconds != _STALL_S:
+            return time.sleep(seconds)
+        deadline = time.monotonic() + 120.0
+        while not get_registry().delta(base)["counters"].get(
+                "speculative_launched") and time.monotonic() < deadline:
+            time.sleep(0.02)
+
+    monkeypatch.setattr(cexec, "time", types.SimpleNamespace(
+        **{**vars(time), "sleep": sleep}))
+
+
+def test_speculative_duplicate_write_exact(tmp_path, monkeypatch):
     data = _mkdata(8000)
+    _hold_slow_worker_until_speculated(monkeypatch)
     s = TpuSession({
         "spark.rapids.cluster.mode": "local[2]",
         "spark.rapids.cluster.speculation.enabled": "true",
         "spark.rapids.cluster.speculation.multiplier": "2.0",
         "spark.rapids.cluster.speculation.minRuntimeSeconds": "0.2",
         "spark.rapids.test.faults":
-            "cluster.worker.slow:slow,seconds=2.0,worker=w1,times=1",
+            f"cluster.worker.slow:slow,seconds={_STALL_S},worker=w1,"
+            "times=1",
     })
     try:
         out = str(tmp_path / "spec")

@@ -7,7 +7,7 @@ wrappers, hot exec paths must not sync to host, dispatch/drain/retry
 loops must hit a cancellation checkpoint, and fault-injection point
 names must match the registry.  Each rule here encodes one of those
 contracts over the Python AST — stdlib only, no engine import, so the
-lint runs in any environment (including premerge before jax loads).
+lint runs in any environment (before, or without, jax).
 
 Usage::
 
