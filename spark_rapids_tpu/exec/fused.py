@@ -46,10 +46,12 @@ from spark_rapids_tpu.exec.basic import FilterExec, ProjectExec
 from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
 from spark_rapids_tpu.expr.core import eval_device, eval_host
 from spark_rapids_tpu.host.batch import HostBatch
+from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops import kernels as dk
 
-__all__ = ["FusedStageExec", "fusible", "stage_body", "stage_key_parts"]
+__all__ = ["FusedStageExec", "fusible", "stage_body", "stage_key_parts",
+           "filters_merged"]
 
 # donation is best-effort by design: a dtype-changing projection leaves
 # some input buffers unreusable and jax warns per compile — expected here
@@ -79,15 +81,28 @@ def stage_body(ops):
     (exec/mesh_region.py), where the same filter/projection chain runs
     shard-resident with no extra dispatch."""
     def body(b):
+        # rows are front-packed once, at the end: a filter ands its
+        # condition into ``keep`` and later members evaluate on the
+        # uncompacted rows (members are elementwise and total, so a row
+        # an earlier filter dropped costs a wasted lane, never an error,
+        # and ``keep`` masks it out whatever a later condition says)
+        keep = None
         for op in ops:
             if type(op) is FilterExec:
                 c = eval_device(op._cond, b)
-                b = dk.compact(b, c.data & c.validity)
+                k = c.data & c.validity
+                keep = k if keep is None else keep & k
             else:
                 cols = [eval_device(e, b) for e in op._bound]
                 b = ColumnBatch(cols, b.num_rows, op._schema)
-        return b
+        return b if keep is None else dk.compact(b, keep)
     return body
+
+
+def filters_merged(ops) -> int:
+    """Filters of a chain beyond its first: the compactions
+    ``stage_body`` saves a launch (counter ``fused.filters_merged``)."""
+    return max(sum(type(op) is FilterExec for op in ops) - 1, 0)
 
 
 def stage_key_parts(ops) -> list:
@@ -115,6 +130,7 @@ class FusedStageExec(PlanNode):
         assert len(ops) >= 2 and all(fusible(op) for op in ops)
         super().__init__([ops[0].children[0]])
         self._ops = tuple(ops)
+        self._merged = filters_merged(self._ops)
         # cleared by the fusion pass when the stage input is shared by
         # another consumer: donating a shared batch deletes the buffers
         # under the sibling (e.g. a CTE scanned once, consumed twice)
@@ -185,6 +201,8 @@ class FusedStageExec(PlanNode):
             cap = round_capacity(b.capacity)
             if cap != b.capacity:
                 b = ctx.dispatch(dk.pad_capacity, b, cap)
+            if self._merged:
+                get_registry().inc("fused.filters_merged", self._merged)
             try:
                 yield from ctx.dispatch_retry(fn, b, op="fused_stage")
             except Exception as e:

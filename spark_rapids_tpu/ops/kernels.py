@@ -4,10 +4,13 @@ Reference seams: ``Table.filter`` (GpuFilterExec,
 basicPhysicalOperators.scala), ``Table.concatenate`` (ConcatAndConsumeAll,
 GpuCoalesceBatches.scala:40), batch slicing (limit.scala).
 
-TPU-first: filter does NOT change the array shape.  It computes a stable
-permutation that front-packs kept rows (argsort of the drop-flag; jax sorts
-are stable) and updates the traced ``num_rows`` scalar — everything stays
-inside one compiled program, no host sync on the data-dependent row count.
+TPU-first: filter does NOT change the array shape.  ``compact`` front-packs
+kept rows (exclusive scan of the keep flags + one scatter a column) and
+updates the traced ``num_rows`` scalar — everything stays inside one
+compiled program, no host sync on the data-dependent row count.  Every
+batch is therefore front-packed with zeroed padding, and ``concat_batches``
+leans on it: it places each input at the sum of the row counts before it,
+with no sort and no gather.
 """
 from __future__ import annotations
 
@@ -157,7 +160,7 @@ _SHARED_JITS: dict = {}
 def _shared(name: str, fn):
     """Compile-accounted wrapper for a capacity-changing kernel.
 
-    These two kernels compile NEW executables mid-query (every distinct
+    These kernels compile NEW executables mid-query (every distinct
     capacity is a fresh signature, and spill/retry storms churn
     capacities across drain threads), so they go through the shared-jit
     wrapper, which serializes CPU compiles process-wide.  kernels sits
@@ -212,64 +215,77 @@ def concat_batches(batches: Sequence[ColumnBatch],
     """Concatenate batches (GpuCoalesceBatches / Table.concatenate).
 
     Shapes are static: the output capacity is the pow2 bucket of the summed
-    input capacities unless given.  Rows are front-packed via compaction of
-    the concatenated row masks.
+    input capacities unless given.  Inputs are front-packed, so batch i
+    is placed at the sum of the row counts before it (``_place_batches``);
+    rows keep list order, then batch order.  Called outside a trace it is
+    one launch of the ``concat_batches`` program (signature: schema +
+    the tuple of input capacities); inside one it is the plain body.
     """
     assert batches, "concat of zero batches"
-    schema = batches[0].schema
+    total = sum(b.capacity for b in batches)
+    cap = out_capacity or round_capacity(total)
+    if cap < total:
+        # dynamic_update_slice clamps a start that would overrun: with
+        # cap >= total, offset i + capacity i <= total and nothing clamps
+        raise ValueError("out_capacity smaller than concatenated capacities")
+    batches = tuple(batches)
+    if any(isinstance(b.num_rows, jax.core.Tracer) for b in batches):
+        return _place_batches(batches, cap)
     # align devices: inputs committed to different mesh devices (e.g. a
     # mesh join's per-device probe outputs consumed by a non-mesh
     # operator) cannot feed one jitted concat; move strays to the first
-    # batch's device (no-op when aligned, impossible-and-unneeded when
-    # already tracing inside a jit — tracers carry no placement)
-    if batches[0].columns and not isinstance(
-            batches[0].columns[0].data, jax.core.Tracer):
+    # batch's device (no-op when aligned)
+    if batches[0].columns:
         devs = {repr(d) for b in batches if b.columns
                 for d in [next(iter(b.columns[0].data.devices()))]
                 if getattr(b.columns[0].data, "committed", False)}
         if len(devs) > 1:
             target = next(iter(batches[0].columns[0].data.devices()))
-            batches = [jax.device_put(b, target) for b in batches]
-    cap = out_capacity or round_capacity(sum(b.capacity for b in batches))
-    ncols = batches[0].num_columns
-    # per-column concat with per-batch real-row masks
-    masks = jnp.concatenate([b.row_mask() for b in batches])
-    total = sum(b.capacity for b in batches)
-    pad = cap - total
-    if pad < 0:
-        raise ValueError("out_capacity smaller than concatenated capacities")
-    if pad:
-        masks = jnp.concatenate([masks, jnp.zeros(pad, jnp.bool_)])
-    perm = jnp.argsort(~masks, stable=True)
-    new_count = jnp.sum(masks, dtype=jnp.int32)
+            batches = tuple(jax.device_put(b, target) for b in batches)
+    get_registry().inc_many((("concat.launches", 1),
+                             ("concat.batches_in", len(batches))))
+    return _shared("concat_batches", _concat_jit)(batches, cap)
+
+
+def _place_batches(batches: tuple, cap: int) -> ColumnBatch:
+    """Write each input at its row offset into zeroed ``cap``-row
+    outputs; a later input overwrites the padding of the one before it.
+    One elementwise pass then canonicalizes (validity under the output's
+    row mask, data and lengths zeroed where invalid), so an input whose
+    padding was not zeroed still gives a canonical batch."""
+    zero = jnp.zeros((), jnp.int32)  # x64 is on: a python 0 is int64
+    offsets = [zero]
+    for b in batches:
+        offsets.append(offsets[-1] + jnp.asarray(b.num_rows, jnp.int32))
+    new_count = offsets.pop()
     out_mask = jnp.arange(cap, dtype=jnp.int32) < new_count
+
+    def place(leaves):
+        # strings and arrays: trailing width of the widest input
+        tail = tuple(max(d) for d in zip(*(x.shape[1:] for x in leaves)))
+        out = jnp.zeros((cap,) + tail, leaves[0].dtype)
+        for x, off in zip(leaves, offsets):
+            if x.shape[1:] != tail:
+                x = jnp.pad(x, ((0, 0),) + tuple(
+                    (0, t - w) for w, t in zip(x.shape[1:], tail)))
+            out = jax.lax.dynamic_update_slice(
+                out, x, (off,) + (zero,) * len(tail))
+        return out
+
     cols = []
-    for ci in range(ncols):
-        parts = [b.columns[ci] for b in batches]
-        dtype = parts[0].dtype
+    for parts in zip(*(b.columns for b in batches)):
+        validity = place([p.validity for p in parts]) & out_mask
+        data = place([p.data for p in parts])
+        data = jnp.where(validity[(...,) + (None,) * (data.ndim - 1)],
+                         data, jnp.zeros((), data.dtype))
+        lengths = None
         if parts[0].is_var_width:
-            w = max(p.max_len for p in parts)
-            datas = [jnp.pad(p.data, ((0, 0), (0, w - p.max_len))) for p in parts]
-            data = jnp.concatenate(datas)
-            lengths = jnp.concatenate([p.lengths for p in parts])
-            validity = jnp.concatenate([p.validity for p in parts])
-            if pad:
-                data = jnp.concatenate([data,
-                                        jnp.zeros((pad, w), data.dtype)])
-                lengths = jnp.concatenate([lengths, jnp.zeros(pad, jnp.int32)])
-                validity = jnp.concatenate([validity, jnp.zeros(pad, jnp.bool_)])
-            validity = validity[perm] & out_mask
-            cols.append(DeviceColumn(jnp.where(validity[:, None], data[perm], 0),
-                                     validity, dtype,
-                                     jnp.where(validity, lengths[perm], 0)))
-        else:
-            data = jnp.concatenate([p.data for p in parts])
-            validity = jnp.concatenate([p.validity for p in parts])
-            if pad:
-                data = jnp.concatenate([data, jnp.zeros(pad, data.dtype)])
-                validity = jnp.concatenate([validity, jnp.zeros(pad, jnp.bool_)])
-            validity = validity[perm] & out_mask
-            cols.append(DeviceColumn(
-                jnp.where(validity, data[perm], jnp.zeros((), data.dtype)),
-                validity, dtype))
-    return ColumnBatch(cols, new_count, schema)
+            lengths = jnp.where(
+                validity, place([p.lengths for p in parts]), 0)
+        cols.append(DeviceColumn(data, validity, parts[0].dtype, lengths))
+    return ColumnBatch(cols, new_count, batches[0].schema)
+
+
+@partial(jax.jit, static_argnames=("cap",))
+def _concat_jit(batches: tuple, cap: int) -> ColumnBatch:
+    return _place_batches(batches, cap)

@@ -166,6 +166,34 @@ def test_direct_probe_compiles_for_v5e(one_chip, table):
                  _shapes(lb, one_chip), build)
 
 
+def test_concat_batches_compiles_for_v5e(one_chip):
+    """Three packed batches (s64, string, f64) of 2^20, 2^19 and 2^20
+    slots placed into a 2^22 bucket: offsets are traced scalars, so the
+    chip's program is dynamic-update-slices and one elementwise pass —
+    no sort and no gather (f64 is stored, never computed on)."""
+    from spark_rapids_tpu.ops import kernels as dk
+    parts = [_shapes(_keyed_batch(900, cap, 5), one_chip)
+             for cap in (1 << 20, 1 << 19, 1 << 20)]
+    hlo = _compile(lambda bs: dk.concat_batches(bs), parts).as_text()
+    assert " dynamic-update-slice(" in hlo
+    assert " sort(" not in hlo and " gather(" not in hlo
+
+
+def test_two_filter_stage_compiles_for_v5e(one_chip):
+    """q44's fused Filter -> Filter at its batch size (2^20 rows, an s64,
+    a string and an f64 column): both conditions and one compaction."""
+    from spark_rapids_tpu import TpuSession
+    from spark_rapids_tpu.exec.fused import FusedStageExec, stage_body
+    from spark_rapids_tpu.expr.core import col
+    b = _keyed_batch(900, 1 << 20, 6)
+    q = TpuSession({}).from_pydict(
+        {"k": [1], "state": ["CA"], "v": [1.0]}, b.schema) \
+        .filter(col("k") == 4).filter(col("v").is_null())
+    ov, meta = q._overridden(quiet=True)
+    assert isinstance(meta.exec_node, FusedStageExec), meta.exec_node
+    _compile(stage_body(meta.exec_node.fused_ops), _shapes(b, one_chip))
+
+
 def test_string_key_sort_compiles_for_v5e(one_chip):
     """lax.sort keyed on a string column (padded byte matrix + length),
     s64 and f64 payload gathered behind it."""

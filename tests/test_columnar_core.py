@@ -7,9 +7,12 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar import ColumnBatch
+from spark_rapids_tpu.columnar.batch import round_capacity
+from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu import ops
 from spark_rapids_tpu.ops.segmented import AggSpec, sorted_group_by
 from spark_rapids_tpu.ops.sort import SortOrder
@@ -71,6 +74,125 @@ def test_concat_batches():
     t = out.to_arrow()
     assert t.column(0).to_pylist() == [1, None, 3]
     assert t.column(1).to_pylist() == ["x", "yy", None]
+
+
+def _concat_input(n, cap, seed, width=3, null_every=3, junk=False):
+    """``n`` rows of (int32, float64, string of up to ``width`` bytes) in
+    ``cap`` slots; ``null_every`` = 1 makes every value null.  ``junk``
+    fills the padding slots with data and sets their validity: a batch no
+    kernel of the engine makes, and one concat must still canonicalize."""
+    rng = np.random.default_rng(seed)
+    null = (np.arange(n) % null_every) == 0
+    strs = ["x" * int(k) for k in rng.integers(0, width + 1, n)]
+    if n:
+        strs[-1] = "w" * width      # the widest string is really there
+    b = ColumnBatch.from_arrow(_rb(
+        a=pa.array(rng.integers(-99, 99, n).astype(np.int32), mask=null),
+        v=pa.array(rng.normal(size=n), mask=np.roll(null, 1)),
+        s=pa.array(strs, type=pa.string(), mask=np.roll(null, 2))),
+        capacity=cap)
+    if not junk:
+        return b
+    pad = ~b.row_mask()
+    cols = [DeviceColumn(
+        jnp.where(pad[(...,) + (None,) * (c.data.ndim - 1)],
+                  jnp.ones((), c.data.dtype) * 7, c.data),
+        c.validity | pad, c.dtype,
+        None if c.lengths is None else jnp.where(pad, 2, c.lengths))
+        for c in b.columns]
+    return ColumnBatch(cols, b.num_rows, b.schema)
+
+
+def _concat_reference(batches, cap):
+    """NumPy: the real rows of each input in list order, zero padding,
+    data and lengths zeroed where invalid; ``(validity, data, lengths)``
+    per column."""
+    ns = [int(b.num_rows) for b in batches]
+    out = []
+    for parts in zip(*(b.columns for b in batches)):
+        def stack(leaves, width=None):
+            rows = [np.asarray(x)[:n] for x, n in zip(leaves, ns)]
+            if width is not None:
+                rows = [np.pad(r, ((0, 0), (0, width - r.shape[1])))
+                        for r in rows]
+            cat = np.concatenate(rows)
+            return np.concatenate(
+                [cat, np.zeros((cap - len(cat),) + cat.shape[1:], cat.dtype)])
+        validity = stack([p.validity for p in parts])
+        if parts[0].is_var_width:
+            data = stack([p.data for p in parts],
+                         max(p.max_len for p in parts))
+            out.append((validity, np.where(validity[:, None], data, 0),
+                        np.where(validity,
+                                 stack([p.lengths for p in parts]), 0)))
+        else:
+            data = stack([p.data for p in parts])
+            out.append((validity, np.where(validity, data, 0), None))
+    return sum(ns), out
+
+
+_CONCAT_CASES = {
+    # name: (inputs as _concat_input kwargs, out_capacity)
+    "fixed_and_strings": ([dict(n=5, cap=8, width=2), dict(n=16, cap=16, width=9),
+                           dict(n=3, cap=32, width=4)], None),
+    "all_null": ([dict(n=6, cap=8, null_every=1),
+                  dict(n=2, cap=8, null_every=1)], None),
+    "empty_first": ([dict(n=0, cap=8), dict(n=7, cap=8), dict(n=4, cap=16)],
+                    None),
+    "empty_middle": ([dict(n=7, cap=8), dict(n=0, cap=16), dict(n=4, cap=8)],
+                     None),
+    "empty_last": ([dict(n=7, cap=8), dict(n=4, cap=8), dict(n=0, cap=32)],
+                   None),
+    "all_empty": ([dict(n=0, cap=8), dict(n=0, cap=8)], None),
+    "out_capacity_larger": ([dict(n=8, cap=8), dict(n=1, cap=8)], 128),
+    "small_after_large": ([dict(n=2, cap=64), dict(n=3, cap=8)], None),
+    "junk_padding": ([dict(n=3, cap=16, junk=True), dict(n=0, cap=8, junk=True),
+                      dict(n=5, cap=8, junk=True)], None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CONCAT_CASES))
+def test_concat_batches_matches_numpy_reference(case):
+    specs, out_capacity = _CONCAT_CASES[case]
+    batches = [_concat_input(seed=i, **kw) for i, kw in enumerate(specs)]
+    out = ops.concat_batches(batches, out_capacity=out_capacity)
+    cap = out_capacity or round_capacity(sum(b.capacity for b in batches))
+    assert out.capacity == cap and out.schema == batches[0].schema
+    rows, want = _concat_reference(batches, cap)
+    assert int(out.num_rows) == rows
+    for c, (validity, data, lengths) in zip(out.columns, want):
+        np.testing.assert_array_equal(np.asarray(c.validity), validity)
+        np.testing.assert_array_equal(np.asarray(c.data), data)
+        if lengths is not None:
+            np.testing.assert_array_equal(np.asarray(c.lengths), lengths)
+    # traced (a caller already inside a program) = launched
+    traced = jax.jit(lambda bs: ops.concat_batches(bs, out_capacity))(batches)
+    for got, eager in zip(jax.tree.leaves(traced), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(eager))
+
+
+def test_concat_batches_refuses_a_capacity_that_would_clamp():
+    """``dynamic_update_slice`` clamps a start that would overrun the
+    output; with ``out_capacity`` >= the summed capacities none can."""
+    batches = [_concat_input(n=1, cap=8, seed=0),
+               _concat_input(n=1, cap=8, seed=1)]
+    with pytest.raises(ValueError, match="out_capacity"):
+        ops.concat_batches(batches, out_capacity=8)
+    assert ops.concat_batches(batches, out_capacity=16).capacity == 16
+
+
+def test_concat_batches_places_without_sort_or_gather():
+    """Inputs are front-packed, so concatenation is placement by offset:
+    the lowered program holds no sort, no gather and no scatter."""
+    batches = [ColumnBatch.from_arrow(_rb(
+        a=pa.array(np.arange(n, dtype=np.int32)),
+        v=pa.array(np.arange(n, dtype=np.float64))), capacity=cap)
+        for n, cap in ((5, 8), (16, 16), (3, 32))]
+    text = jax.jit(lambda bs: ops.concat_batches(bs)).lower(batches).as_text()
+    for op in ("sort", "gather", "scatter"):
+        assert f"stablehlo.{op}" not in text, op
+    # one placement a leaf (validity + data) and input
+    assert text.count("stablehlo.dynamic_update_slice") == 2 * 2 * 3
 
 
 @pytest.mark.parametrize("asc", [True, False])

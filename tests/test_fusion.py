@@ -128,6 +128,93 @@ def test_min_operators_conf():
 
 
 # ---------------------------------------------------------------------------
+# one compaction a stage: filters and their masks, rows packed at the end
+# ---------------------------------------------------------------------------
+
+_XY = T.Schema([T.StructField("x", T.DoubleType(), True),
+                T.StructField("y", T.DoubleType(), True),
+                T.StructField("tag", T.StringType(), True)])
+
+
+def _xy_data(n=240):
+    # y is 0 or NULL on two rows in five: ``x / y`` is NULL there, and
+    # ``x / y > 1`` is true on rows with a small y that ``y > 2`` drops
+    x = [None if i % 11 == 0 else float(i % 13) - 2.0 for i in range(n)]
+    y = [None if i % 5 == 0 else float(i % 5 - 1) for i in range(n)]
+    return {"x": x, "y": y, "tag": [f"t{i % 7}" for i in range(n)]}
+
+
+def _chain(df, name):
+    from spark_rapids_tpu.expr.core import col
+    ratio = col("x") / col("y")
+    if name == "filter_filter":
+        return df.filter(col("y") != 0.0).filter(ratio > 1.0)
+    if name == "filter_filter_second_true_on_dropped":
+        return df.filter(col("y") > 2.0).filter(ratio > 1.0)
+    if name == "filter_project_filter":
+        return df.filter(col("y") != 0.0) \
+            .select(ratio.alias("r"), col("tag"), col("x")) \
+            .filter(col("r") > 1.0)
+    if name == "filter_project_narrowing":
+        return df.filter(col("y") != 0.0).select(ratio.alias("r"))
+    if name == "filter_filter_empty":
+        return df.filter(col("y") != 0.0).filter(ratio > 1e9)
+    assert name == "filter_project_filter_empty", name
+    return df.filter(col("y") != 0.0) \
+        .select(ratio.alias("r"), col("tag")).filter(col("r") > 1e9)
+
+
+_CHAINS = ("filter_filter", "filter_filter_second_true_on_dropped",
+           "filter_project_filter", "filter_project_narrowing",
+           "filter_filter_empty", "filter_project_filter_empty")
+
+
+@pytest.mark.parametrize("chain", _CHAINS)
+def test_fused_chain_equals_unfused_row_for_row(chain):
+    """A later member sees the real values of rows an earlier filter
+    dropped (not zeros): a condition that is NULL or true there must not
+    bring them back, and row order is the input's."""
+    data = _xy_data()
+    fused = _chain(TpuSession({}).from_pydict(data, _XY), chain)
+    plain = _chain(TpuSession({"spark.rapids.sql.fusion.enabled": "false"})
+                   .from_pydict(data, _XY), chain)
+    assert "FusedStageExec" in _exec_classes(_plan_of(fused))
+    assert "FusedStageExec" not in _exec_classes(_plan_of(plain))
+    got, want = fused.collect(), plain.collect()
+    assert got == want
+    assert (len(got) == 0) == chain.endswith("_empty"), len(got)
+
+
+def test_two_filter_stage_compacts_once():
+    """The lowered body of Filter -> Project -> Filter -> Project holds as
+    many scatters as ONE compact of the stage's output columns (two of
+    the input's three; a compaction a filter would be twice three)."""
+    import jax
+
+    from spark_rapids_tpu.exec.fused import filters_merged, stage_body
+    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.host.batch import HostBatch
+    from spark_rapids_tpu.ops import kernels as dk
+    q = TpuSession({}).from_pydict(_xy_data(), _XY) \
+        .filter(col("y") != 0.0) \
+        .select((col("x") / col("y")).alias("r"), col("tag"), col("x")) \
+        .filter(col("r") > 1.0).select(col("r"), col("tag"))
+    stage = next(n for n in _walk(_plan_of(q))
+                 if isinstance(n, FusedStageExec))
+    assert filters_merged(stage.fused_ops) == 1
+    body = stage_body(stage.fused_ops)
+    batch = HostBatch.from_pydict(_xy_data(), _XY).to_device()
+
+    def scatters(fn, arg):
+        return jax.jit(fn).lower(arg).as_text().count("stablehlo.scatter")
+    out = jax.eval_shape(body, batch)
+    assert len(out.columns) == 2
+    one_compact = scatters(lambda b: dk.compact(b, b.row_mask()), out)
+    assert one_compact > 0
+    assert scatters(body, batch) == one_compact
+
+
+# ---------------------------------------------------------------------------
 # cache keys
 # ---------------------------------------------------------------------------
 

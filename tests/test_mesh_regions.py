@@ -186,6 +186,30 @@ def test_region_absorbs_filter_into_aggregate(rng):
     assert "MeshAggregateExec" in region.node_desc()
 
 
+def test_region_two_filter_stage_matches_single_chip(rng):
+    """A region splices ``fused.stage_body``: its two filters and their
+    masks, one compaction a shard.  The second condition is NULL
+    (``v = 0``) or true on rows the first drops; rows equal the
+    single-chip plan's."""
+    data = _data(rng)
+
+    def q(s):
+        return s.from_pydict(data, SCHEMA, partitions=4) \
+            .where(col("v") > 100).where(col("f") / col("v") > -0.001) \
+            .group_by("k").agg(Sum(col("v")).alias("sv"),
+                               CountStar().alias("n"))
+    mesh = q(TpuSession(MESH8))
+    region = next(n for n in _walk(_executed_plan(mesh))
+                  if type(n).__name__ == "MeshRegionExec")
+    assert region.node_desc().count("FilterExec") == 2, region.node_desc()
+    before = get_registry().snapshot()
+    got = sorted(mesh.collect())
+    moved = get_registry().delta(before)["counters"]
+    assert moved.get("fused.filters_merged", 0) >= 1, moved
+    want = sorted(q(TpuSession({})).collect())
+    assert got == want and 0 < len(got) <= 17
+
+
 def test_regions_disabled_keeps_island_shape_and_rows(rng):
     data = _data(rng)
     son = TpuSession(MESH8)

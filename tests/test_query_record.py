@@ -406,6 +406,36 @@ def test_probe_counters_say_which_probe_ran(session, tables, shape):
         <= builds + c[f"join.probe.{ran}"]
 
 
+# ------------------------------------------------------- the front-pack
+
+def test_front_pack_counters_follow_the_plan(session, tables):
+    """scan (3 files) -> Filter -> Filter -> coalesce -> sort: the two
+    filters run as one fused stage a file and compact once
+    (``fused.filters_merged``: one filter beyond the first a launch);
+    the coalesce places the three packed batches in one launch of
+    ``concat_batches`` (``concat.launches`` / ``concat.batches_in``)."""
+    df = session.read_parquet(os.path.join(tables, "fact")) \
+        .where(col("v") > lit(0.1)).where(col("d") > lit(DAY0 + 100)) \
+        .order_by("v")
+    ov, meta = df._overridden(quiet=True)
+
+    def descs(node):
+        yield node.node_desc()
+        for c in node.children:
+            yield from descs(c)
+    plan = list(descs(meta.exec_node))
+    assert any(d.startswith("FusedStageExec[2 ops: FilterExec") and
+               d.count("FilterExec") == 2 for d in plan), plan
+    assert any(d.startswith("CoalesceBatchesExec") for d in plan), plan
+    rows = df.collect()
+    assert rows and rows == sorted(rows, key=lambda r: r[2])
+    c = _collect(df)["counters"]
+    assert c["program.fused_stage_body.launches"] == FACT_FILES
+    assert c["fused.filters_merged"] == FACT_FILES
+    assert c["concat.launches"] == c["program.concat_batches.launches"] == 1
+    assert c["concat.batches_in"] == FACT_FILES
+
+
 # ----------------------------------------------------------------- trace
 
 def test_worker_and_fetch_spans_share_the_collects_clock(query, tmp_path):
