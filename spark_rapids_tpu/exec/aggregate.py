@@ -476,6 +476,8 @@ class HashAggregateExec(PlanNode):
                 chunk.append((None, _relabel_d(b, self._buffer_schema),
                               b.num_rows))
             else:
+                if b.known_rows is not None:
+                    get_registry().inc("agg.update.rows", b.known_rows)
                 src = SpillableColumnarBatch(b, ctx.catalog,
                                              SpillPriority.READ_SHUFFLE)
                 chunk.extend(update_entries(src))

@@ -21,6 +21,7 @@ from spark_rapids_tpu.exec.compile_cache import guarded_jit as _guarded_jit
 from spark_rapids_tpu.expr.core import (Alias, Expression, bind, eval_device,
                                         eval_host, output_name)
 from spark_rapids_tpu.host.batch import HostBatch, HostColumn
+from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops import kernels as dk
 
@@ -445,4 +446,8 @@ class GlobalLimitExec(PlanNode):
         child = self.children[0]
         all_parts = (b for cpid in range(child.num_partitions(ctx))
                      for b in child.partition_iter(ctx, cpid))
-        yield from _limited(ctx, all_parts, self._limit)
+        for b in _limited(ctx, all_parts, self._limit):
+            # _limited has the count on the host already
+            get_registry().inc("limit.rows_out", b.known_rows
+                               if ctx.is_device else b.num_rows)
+            yield b

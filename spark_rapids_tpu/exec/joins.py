@@ -91,6 +91,7 @@ def prepare_fast_build(rb, rkey: int):
     prep, stats = _jit_build_prep(rb, rkey)
     # enginelint: disable=RL003 (once per build, before any stream batch: the probe's program is chosen from it)
     nv, kmin, kmax = (int(x) for x in fetch_to_host(stats, _FETCH))
+    get_registry().inc("join.build.rows", nv)
     size = direct_table_size(nv, kmin, kmax, rb.capacity)
     if size is None:
         return prep
@@ -330,6 +331,8 @@ class JoinExec(PlanNode):
             lb2, lkeys = self._augment_device(piece, self._lkeys_b)
             if jt == "cross":
                 get_registry().inc("join.cross.launches")
+            elif jt in ("semi", "anti"):
+                get_registry().inc("join.semi.batches")
             if isinstance(prep, DirectBuild):
                 get_registry().inc("join.probe.direct")
                 probe_arrays, total_dev = _jit_probe_direct(
@@ -339,6 +342,8 @@ class JoinExec(PlanNode):
                 probe_arrays, total_dev = _jit_probe_fast(
                     lb2, prep, lkeys[0], stream_jt)
             else:
+                if jt != "cross":
+                    get_registry().inc("join.probe.sorted")
                 probe_arrays, total_dev = _jit_probe(
                     lb2, rb2, lkeys, rkeys, stream_jt)
             return lb2, total_dev, probe_arrays
@@ -390,7 +395,11 @@ class JoinExec(PlanNode):
                     out = self._condition_jit()(out)
                 if self._swapped and self.include_right:
                     out = self._reorder_device(out, lb.num_columns)
-                yield ColumnBatch(out.columns, out.num_rows, self._schema)
+                # the fetched total IS the gather's row count unless a
+                # residual condition filtered after it
+                yield ColumnBatch(out.columns, out.num_rows, self._schema,
+                                  known_rows=total if self._condition is None
+                                  else None)
 
         pending = []
         for lb in self._stream_batches(ctx, pid):

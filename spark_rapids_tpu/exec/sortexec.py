@@ -15,6 +15,7 @@ from spark_rapids_tpu.exec.core import (CoalesceGoal, ExecCtx, PlanNode,
                                         TargetSize)
 from spark_rapids_tpu.expr.core import Expression, bind
 from spark_rapids_tpu.host.batch import HostBatch
+from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops.sort import SortOrder, sort_batch
@@ -117,6 +118,7 @@ class SortExec(PlanNode):
             # sorted halves would break it, so on OOM this scope only
             # spills and retries whole (no merge kernel exists to
             # recombine split outputs; see ops/sort.py)
+            get_registry().inc("sort.launches")
             yield ctx.dispatch_retry(self._jit_fn(), b, split=False,
                                      op="sort")[0]
         else:

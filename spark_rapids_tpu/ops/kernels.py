@@ -244,7 +244,11 @@ def concat_batches(batches: Sequence[ColumnBatch],
             batches = tuple(jax.device_put(b, target) for b in batches)
     get_registry().inc_many((("concat.launches", 1),
                              ("concat.batches_in", len(batches))))
-    return _shared("concat_batches", _concat_jit)(batches, cap)
+    out = _shared("concat_batches", _concat_jit)(batches, cap)
+    if all(b.known_rows is not None for b in batches):
+        # the jit boundary strips the host-side count; the sum is free
+        out.known_rows = sum(b.known_rows for b in batches)
+    return out
 
 
 def _place_batches(batches: tuple, cap: int) -> ColumnBatch:

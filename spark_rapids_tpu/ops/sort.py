@@ -3,7 +3,8 @@
 Reference: GpuSortExec.scala:51 / SortUtils.scala build cuDF orderBy args
 (ascending/descending, null ordering).  TPU-first design: one stable
 multi-operand ``lax.sort`` handles any mix of key types, directions and null
-orders.  Per key column the operands are:
+orders (a key of more than ``MAX_SORT_KEYS`` operands: stable passes of
+narrower sorts, ``_lexsort_permutation``).  Per key column the operands are:
 
 * a leading null-indicator byte (0/1 by nulls-first/last),
 * for floats: a NaN-indicator byte (Spark: NaN is the largest value; for
@@ -122,10 +123,40 @@ def sort_permutation(batch: ColumnBatch, orders: list[SortOrder],
                              jnp.uint8(0 if o.resolved_nulls_first else 1))
         operands.append(null_ind)
         operands.extend(encode_key_operands(col, o.ascending))
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    nk = len(operands)
-    sorted_ops = lax.sort(operands + [iota], num_keys=nk, is_stable=True)
-    return sorted_ops[-1]
+    return _lexsort_permutation(operands, cap)
+
+
+#: key operands one ``lax.sort`` may take, and how many a pass takes
+#: where a key has more.  The chip's compiler takes seconds that grow
+#: with the SQUARE of a sort's operand count (2^16 rows, compiled for a
+#: described v5e: 17 s for one key and the row index, 38 s for two keys,
+#: 143 s for four).  The 21 of TPC-H Q18's five-column group key with an
+#: 18-byte string in it did not compile on the chip in 1200 s as one
+#: sort and take 73 s in passes of two (PERF.md Findings PR 31).  Every
+#: single integer, date, boolean or float key, alone, stays the one
+#: sort it was.
+MAX_SORT_KEYS = 4
+PASS_SORT_KEYS = 2
+
+
+def _lexsort_permutation(operands: list[jax.Array], cap: int) -> jax.Array:
+    """The stable permutation that orders rows by ``operands``, most
+    significant first.  Up to ``MAX_SORT_KEYS`` of them: one multi-operand
+    ``lax.sort`` carrying the row index.  More: the same order from
+    stable passes over groups of ``PASS_SORT_KEYS`` operands, the least
+    significant group first, each pass gathering its operands into the
+    order the passes before it left (a stable sort by the more
+    significant group keeps ties in that order: a radix sort whose digits
+    are operand groups)."""
+    perm = jnp.arange(cap, dtype=jnp.int32)
+    step = len(operands) if len(operands) <= MAX_SORT_KEYS \
+        else PASS_SORT_KEYS
+    groups = [operands[i:i + step] for i in range(0, len(operands), step)]
+    for n, group in enumerate(reversed(groups)):
+        keys = group if n == 0 else [op[perm] for op in group]
+        perm = lax.sort(keys + [perm], num_keys=len(keys),
+                        is_stable=True)[-1]
+    return perm
 
 
 def sort_batch(batch: ColumnBatch, orders: list[SortOrder]) -> ColumnBatch:

@@ -107,3 +107,103 @@ def test_a_group_with_one_other_double_sums_as_doubles():
     _, s, _, m = _group_by(keys, vals, update)
     assert s[0] == pytest.approx(0.1 + 0.2 + 1 / 3, rel=1e-15)
     assert s[1] == 30 * 0.01 and m[1] == 0.15   # not (0.1 + 0.2) / 2
+
+
+# ------------------------------------------- the chip's f64, on the CPU
+
+class _Pair:
+    """A float64 array as the chip computes on it: an unevaluated sum of
+    two float32 (``hi`` the nearest float32, ``lo`` what is left, about
+    48 bits together; PERF.md Findings PR 23 and 31).  Products are an
+    exact two-product of the high parts plus the cross terms in float32,
+    renormalised; a comparison looks at ``hi``, then at ``lo``."""
+
+    def __init__(self, hi, lo):
+        self.hi = np.asarray(hi, np.float32)
+        self.lo = np.asarray(lo, np.float32)
+
+    @classmethod
+    def of(cls, x):
+        """What a float64 (a literal, a column from HBM) turns into."""
+        x = np.asarray(x, np.float64)
+        hi = x.astype(np.float32)
+        return cls(hi, (x - hi.astype(np.float64)).astype(np.float32))
+
+    def stored(self):
+        """The float64 it is stored as (HBM keeps real float64)."""
+        return self.hi.astype(np.float64) + self.lo.astype(np.float64)
+
+    def __mul__(self, other):
+        other = other if isinstance(other, _Pair) else _Pair.of(other)
+        exact = self.hi.astype(np.float64) * other.hi.astype(np.float64)
+        hi = exact.astype(np.float32)
+        lo = (exact - hi.astype(np.float64)).astype(np.float32)
+        lo = lo + (self.hi * other.lo + self.lo * other.hi)
+        s = hi + lo                                  # fast two-sum
+        return _Pair(s, lo - (s - hi))
+
+    def _cmp(self, other):
+        other = other if isinstance(other, _Pair) else _Pair.of(other)
+        return np.where(self.hi != other.hi, np.sign(self.hi - other.hi),
+                        np.sign(self.lo - other.lo))
+
+    def __gt__(self, other):
+        return self._cmp(other) > 0
+
+    def __ge__(self, other):
+        return self._cmp(other) >= 0
+
+
+class _Int64s:
+    """int64 values whose ``astype(float64)`` is a ``_Pair`` (exact below
+    2^48: the high float32 and the remainder)."""
+
+    def __init__(self, v):
+        self.v = np.asarray(v, np.int64)
+
+    def astype(self, dtype):
+        assert dtype is _PairXP.float64
+        hi = self.v.astype(np.float32)
+        return _Pair(hi, (self.v - hi.astype(np.int64)).astype(np.float32))
+
+
+class _PairXP:
+    """Stands in for ``xp`` where a function only converts and
+    multiplies, as ``cents.from_cents`` does."""
+    float64 = object()
+
+
+def test_pair_arithmetic_is_the_chips():
+    # what is known of the chip: real float64 in, the same out
+    x = np.array([300.0, 0.05, 1 / 3, 499095.24])
+    assert np.abs(_Pair.of(x).stored() - x).max() <= 2.0 ** -47 * x.max()
+    assert (_Pair.of(np.array([300.0, 1e6])).lo == 0).all()
+    # and TPC-H Q6's loss (PERF.md Findings PR 23): five hundredths
+    # rebuilt as 5 * 0.01 is under the literal 0.05, six are not under
+    # 0.06 — so ``l_discount >= 0.05`` drops the rows at exactly 0.05
+    d = cents.from_cents(_PairXP, _Int64s([4, 5, 6, 7]))
+    assert (d >= 0.05).tolist() == [False, False, True, True]
+    assert (np.array([4, 5, 6, 7]) * 0.01 >= 0.05).tolist() \
+        == [False, True, True, True]                # real float64 keeps them
+
+
+@pytest.mark.parametrize("scale", [1, 100], ids=["300", "30000"])
+def test_a_sum_at_a_whole_literal_compares_exactly_in_f32_pairs(scale):
+    """TPC-H Q18's ``having sum(l_quantity) > 300``: the sum is 30000
+    hundredths given back as ``30000 * 0.01``, and 160 orders of SF1 sum
+    to exactly 300.  In the chip's arithmetic that product is the pair
+    (300.0, 0.0): not greater than the literal, and not less."""
+    c = np.array([29999, 30000, 30001]) * scale
+    lit = 300.0 * scale
+    x = cents.from_cents(_PairXP, _Int64s(c))
+    assert (x > lit).tolist() == [False, False, True]
+    assert (x >= lit).tolist() == [False, True, True]
+    assert x.hi[1] == lit and x.lo[1] == 0.0
+    # every whole number of dollars up to 2^22 comes back as itself (past
+    # 2^29 hundredths the cross terms leave a last-bit remainder: a sum
+    # over five million dollars against a whole literal is not pinned)
+    dollars = np.arange(1, 1 << 22, 97, dtype=np.int64)
+    whole = cents.from_cents(_PairXP, _Int64s(dollars * 100))
+    assert (whole.hi == dollars).all() and (whole.lo == 0).all()
+    # and real float64 agrees at the boundary
+    assert (cents.from_cents(np, c) > lit).tolist() == [False, False, True]

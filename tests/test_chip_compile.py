@@ -166,6 +166,40 @@ def test_direct_probe_compiles_for_v5e(one_chip, table):
                  _shapes(lb, one_chip), build)
 
 
+def test_sparse_build_and_semi_probe_compile_for_v5e(one_chip):
+    """TPC-H Q18's semi-join at SF1: a few thousand int32 keys spread
+    over 1.5M values, in the 2^21 slots the aggregate above it left them
+    in (a 2^21-entry table for under 2^13 rows: the key range and the
+    build's capacity size it, not its row count; shrunk to 2^13 slots
+    the same build would be searched) and the ``semi`` probe of a
+    2^22-row stream batch by address."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.host.batch import HostBatch
+    from spark_rapids_tpu.ops.join import (build_direct_table,
+                                           direct_table_size, probe_direct)
+    build_cap = table = 1 << 21
+    assert direct_table_size(4401, 7, 1_499_990, build_cap) == table
+    assert direct_table_size(4401, 7, 1_499_990, 1 << 13) is None
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    prep = (on_chip((build_cap,), jnp.int32), on_chip((build_cap,), jnp.int32),
+            on_chip((), jnp.int32))
+    _compile(lambda k, p, n: build_direct_table(k, p, n, table), *prep)
+    build = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda k, p, n: build_direct_table(k, p, n, table),
+                       *prep))
+    assert build.table.shape == (table, 2)
+    schema = T.Schema([T.StructField("l_orderkey", T.IntegerType(), True),
+                       T.StructField("l_quantity", T.DoubleType(), True)])
+    lb = HostBatch.from_pydict(
+        {"l_orderkey": np.arange(900, dtype=np.int32),
+         "l_quantity": np.ones(900)}, schema).to_device(capacity=1 << 22)
+    _compile(lambda left, b: probe_direct(left, 0, b, "semi")[0][:-1],
+             _shapes(lb, one_chip), build)
+
+
 def test_concat_batches_compiles_for_v5e(one_chip):
     """Three packed batches (s64, string, f64) of 2^20, 2^19 and 2^20
     slots placed into a 2^22 bucket: offsets are traced scalars, so the

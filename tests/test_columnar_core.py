@@ -235,6 +235,54 @@ def test_sort_multi_key():
     assert t.column(1).to_pylist() == [5.0, 4.0, 1.0, 0.5]
 
 
+@pytest.mark.parametrize("directions", [(True,) * 5,
+                                        (False, True, False, True, False)],
+                         ids=["ascending", "mixed"])
+def test_sort_long_key_in_passes_is_the_one_sort(directions, monkeypatch):
+    """A key of more operands than one ``lax.sort`` takes
+    (``MAX_SORT_KEYS``) is ordered by stable passes: the same
+    permutation as the single sort of all operands, ties, nulls, NaNs
+    and padding included, and the order the host oracle gives."""
+    from spark_rapids_tpu.ops import host_kernels as hk
+    from spark_rapids_tpu.ops import sort as sort_mod
+    rng = np.random.default_rng(11)
+    n = 500
+
+    def nulls(values, share=0.1):
+        return [None if rng.random() < share else v for v in values]
+    names = [f"Customer#{i:09d}" for i in rng.integers(0, 12, n)]
+    rb = _rb(name=pa.array(nulls(names)),
+             a=pa.array(nulls(rng.integers(0, 3, n).tolist()), pa.int32()),
+             b=pa.array(nulls(rng.integers(-2, 2, n).tolist()), pa.int64()),
+             d=pa.array(nulls(rng.integers(9000, 9003, n).tolist()),
+                        pa.date32()),
+             p=pa.array(nulls(rng.choice([1.5, -0.0, 0.0, float("nan"),
+                                          2.25], n).tolist()), pa.float64()),
+             row=pa.array(np.arange(n), pa.int32()))
+    batch = ColumnBatch.from_arrow(rb, capacity=1 << 10)
+    orders = [SortOrder(i, asc) for i, asc in enumerate(directions)]
+    calls = []
+    real_sort = sort_mod.lax.sort
+
+    def spy(operands, **kw):
+        calls.append(len(operands))
+        return real_sort(operands, **kw)
+    monkeypatch.setattr(sort_mod.lax, "sort", spy)
+    passes = np.asarray(sort_mod.sort_permutation(batch, orders))
+    assert len(calls) > 1 and max(calls) <= sort_mod.PASS_SORT_KEYS + 1
+    calls.clear()
+    monkeypatch.setattr(sort_mod, "MAX_SORT_KEYS", 1 << 10)
+    single = np.asarray(sort_mod.sort_permutation(batch, orders))
+    assert len(calls) == 1 and calls[0] > 5
+    assert (passes == single).all()
+    # ties keep their input order (``row`` ascends within equal keys) and
+    # the host oracle agrees on the real rows
+    from spark_rapids_tpu.host.batch import HostBatch
+    host = hk.host_sort_permutation(HostBatch.from_arrow(rb), orders)
+    assert (passes[:n] == np.asarray(host)).all()
+    assert sorted(passes[n:].tolist()) == list(range(n, 1 << 10))
+
+
 def test_group_by_sum_count_min_max_avg():
     batch = ColumnBatch.from_arrow(_rb(
         k=pa.array([1, 2, 1, None, 2, 1], type=pa.int32()),

@@ -52,6 +52,31 @@ def test_join_types_match_oracle(rng, jt):
     assert rows  # non-degenerate
 
 
+@pytest.mark.parametrize("jt,condition", [
+    ("inner", False), ("left", False), ("right", False), ("full", False),
+    ("semi", False), ("anti", False), ("inner", True)])
+def test_join_output_carries_its_row_count(rng, jt, condition):
+    """A join's output batch carries the count the host fetched to size
+    its gather (``known_rows``), which must BE its row count; a residual
+    condition filters after that fetch, and the count then stays on the
+    device."""
+    from spark_rapids_tpu.exec.core import ExecCtx
+    left, right = _sides(rng)
+    cond = (col("lv") > lit(0)) if condition else None
+    plan = JoinExec(left, right, [col("lk")], [col("rk")], jt,
+                    condition=cond)
+    with ExecCtx(backend="device") as ctx:
+        batches = list(plan.execute(ctx))
+        assert batches
+        known = [b.known_rows for b in batches]
+        if condition:
+            assert any(k is None for k in known)
+        for b, k in zip(batches, known):
+            assert k is None or k == int(b.num_rows)
+        if not condition and jt != "full":
+            assert None not in known
+
+
 def test_inner_join_row_semantics(rng):
     left = LocalScanExec.from_pydict(
         {"lk": [1, 2, 2, None], "lv": [10, 20, 21, 30],
