@@ -304,6 +304,7 @@ class FilterExec(PlanNode):
             for b in child_it:
                 # row-wise predicate: split pieces filter to the same
                 # surviving rows in order (GpuFilterExec withRetry)
+                dk.count_compaction(b.capacity)
                 yield from ctx.dispatch_retry(fn, b, op="filter")
         else:
             for b in child_it:

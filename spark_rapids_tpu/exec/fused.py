@@ -51,7 +51,7 @@ from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops import kernels as dk
 
 __all__ = ["FusedStageExec", "fusible", "stage_body", "stage_key_parts",
-           "filters_merged"]
+           "filters_merged", "has_filter"]
 
 # donation is best-effort by design: a dtype-changing projection leaves
 # some input buffers unreusable and jax warns per compile — expected here
@@ -99,6 +99,11 @@ def stage_body(ops):
     return body
 
 
+def has_filter(ops) -> bool:
+    """Whether ``stage_body`` over ``ops`` ends in a compaction."""
+    return any(type(op) is FilterExec for op in ops)
+
+
 def filters_merged(ops) -> int:
     """Filters of a chain beyond its first: the compactions
     ``stage_body`` saves a launch (counter ``fused.filters_merged``)."""
@@ -131,6 +136,7 @@ class FusedStageExec(PlanNode):
         super().__init__([ops[0].children[0]])
         self._ops = tuple(ops)
         self._merged = filters_merged(self._ops)
+        self._compacts = has_filter(self._ops)
         # cleared by the fusion pass when the stage input is shared by
         # another consumer: donating a shared batch deletes the buffers
         # under the sibling (e.g. a CTE scanned once, consumed twice)
@@ -203,6 +209,8 @@ class FusedStageExec(PlanNode):
                 b = ctx.dispatch(dk.pad_capacity, b, cap)
             if self._merged:
                 get_registry().inc("fused.filters_merged", self._merged)
+            if self._compacts:
+                dk.count_compaction(cap)
             try:
                 yield from ctx.dispatch_retry(fn, b, op="fused_stage")
             except Exception as e:

@@ -392,6 +392,7 @@ class JoinExec(PlanNode):
                     out, lb.num_columns, lb2.num_columns, n_right_raw,
                     device=True)
                 if self._condition is not None:
+                    dk.count_compaction(out.capacity)
                     out = self._condition_jit()(out)
                 if self._swapped and self.include_right:
                     out = self._reorder_device(out, lb.num_columns)
@@ -411,6 +412,7 @@ class JoinExec(PlanNode):
         if jt == "full":
             if matched is None:
                 matched = jnp.zeros(rb2.capacity, jnp.bool_)
+            dk.count_compaction(rb2.capacity)
             tail = self._unmatched_right_jit()(rb2, matched)
             if tail.host_num_rows(_FETCH) > 0:
                 yield tail

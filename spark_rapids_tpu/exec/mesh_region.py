@@ -39,7 +39,8 @@ from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
 from spark_rapids_tpu.exec.fused import (FusedStageExec, filters_merged,
-                                         stage_body, stage_key_parts)
+                                         has_filter, stage_body,
+                                         stage_key_parts)
 from spark_rapids_tpu.exec.mesh_exec import (MeshAggregateExec,
                                              MeshExchangeExec,
                                              MeshJoinExec,
@@ -475,6 +476,8 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
         self._segs = tuple(segs)
         self._merged = sum(filters_merged(seg) for kind, seg in segs
                            if kind == "stage")
+        self._compacts = any(has_filter(seg) for kind, seg in segs
+                             if kind == "stage")
         self._joins = tuple(op for k, op in segs if k == "join")
         super().__init__([members[0].children[0]]
                          + [j.children[1] for j in self._joins])
@@ -640,6 +643,9 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
             caps = self._caps(leaf_cap, modes, send_cap, floors)
             if self._merged:
                 get_registry().inc("fused.filters_merged", self._merged)
+            if self._compacts:
+                # the slots the region was handed, over all its devices
+                dk.count_compaction(leaf_cap * self.mesh_size)
             result, aux = self._program(mesh, send_cap, modes, caps)(
                 stacked, *builds)
             if not aux or (nj == 0 and send_cap is None):

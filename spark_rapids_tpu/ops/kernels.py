@@ -5,12 +5,14 @@ basicPhysicalOperators.scala), ``Table.concatenate`` (ConcatAndConsumeAll,
 GpuCoalesceBatches.scala:40), batch slicing (limit.scala).
 
 TPU-first: filter does NOT change the array shape.  ``compact`` front-packs
-kept rows (exclusive scan of the keep flags + one scatter a column) and
-updates the traced ``num_rows`` scalar — everything stays inside one
-compiled program, no host sync on the data-dependent row count.  Every
-batch is therefore front-packed with zeroed padding, and ``concat_batches``
-leans on it: it places each input at the sum of the row counts before it,
-with no sort and no gather.
+kept rows — a scan of the keep flags, one int32 scatter that turns the
+destinations into a source index, then one row gather a dtype over the
+leaves stacked side by side, through a bucket an eighth of the capacity
+when the kept count fits it — and updates the traced ``num_rows`` scalar:
+everything stays inside one compiled program, no host sync on the
+data-dependent row count.  Every batch is therefore front-packed with
+zeroed padding, and ``concat_batches`` leans on it: it places each input at
+the sum of the row counts before it, with no sort and no gather.
 """
 from __future__ import annotations
 
@@ -19,14 +21,15 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.obs.registry import get_registry
 
-__all__ = ["compact", "take", "concat_batches", "slice_batch",
-           "slice_rows", "gather_columns", "shrink_capacity",
+__all__ = ["compact", "count_compaction", "take", "concat_batches",
+           "slice_batch", "slice_rows", "gather_columns", "shrink_capacity",
            "pad_capacity", "device_scalar"]
 
 
@@ -66,39 +69,107 @@ def gather_columns(cols: Sequence[DeviceColumn], perm: jax.Array,
     return [_gather_column(c, perm, out_mask) for c in cols]
 
 
+# A compaction moves ``capacity // SMALL_BUCKET_DIVISOR`` slots instead
+# of ``capacity`` when the kept count fits them.  Below
+# ``COND_MIN_CAPACITY`` slots either move costs what the launch costs
+# (1.4-1.6 ms at 2^13 x 4 columns on the chip, PERF.md PR 32) and only
+# the full one is traced: half the program to compile.
+SMALL_BUCKET_DIVISOR = 8
+COND_MIN_CAPACITY = 1 << 14
+
+
 def compact(batch: ColumnBatch, keep: jax.Array) -> ColumnBatch:
     """Filter: keep rows where ``keep`` (bool[capacity]) is True.
 
-    Order-preserving front-pack via exclusive-scan + scatter: kept row i
-    lands at cumsum(keep)[i]-1, dropped rows scatter out of bounds and
-    are discarded (mode='drop').  O(n) — the previous stable-argsort
-    formulation cost a full O(n log n) multi-pass sort per filter, which
-    dominated multi-branch scan-filter-agg plans (TPC-DS q28: 12
-    filtered branches).  Padding and rows beyond ``num_rows`` are
-    always dropped; scatter into zero-initialized outputs reproduces
-    the zeroed-padding invariant directly.
+    Order-preserving front-pack, rows moved once: an inclusive scan of
+    the keep flags gives every kept row its destination, one int32
+    scatter inverts that into ``src`` (destination -> source row), and
+    one row gather a dtype moves all leaves of that dtype together
+    (``_move_rows``).  The size of the move follows the count the
+    program can see: under ``lax.cond`` a batch that keeps at most
+    ``capacity // SMALL_BUCKET_DIVISOR`` rows builds and gathers that
+    many slots and pads the rest with zeros, any other batch moves
+    ``capacity`` slots.  Output shapes are the input's either way.
+    Padding and rows beyond ``num_rows`` are always dropped; everything
+    at and beyond the new count is zeroed, validity canonical.
     """
     keep = keep & batch.row_mask()
     cap = batch.capacity
     dest = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    idx = jnp.where(keep, dest, cap)  # cap = out of bounds -> dropped
     new_count = jnp.sum(keep, dtype=jnp.int32)
-    cols = []
-    for c in batch.columns:
-        validity = jnp.zeros(cap, jnp.bool_).at[idx].set(
-            c.validity, mode="drop")
-        data = jnp.zeros_like(c.data).at[idx].set(
-            jnp.where((keep & c.validity)[(...,) + (None,) *
-                                          (c.data.ndim - 1)],
-                      c.data, jnp.zeros((), c.data.dtype)),
-            mode="drop")
-        if c.is_var_width:
-            lengths = jnp.zeros(cap, jnp.int32).at[idx].set(
-                jnp.where(keep & c.validity, c.lengths, 0), mode="drop")
-            cols.append(DeviceColumn(data, validity, c.dtype, lengths))
-        else:
-            cols.append(DeviceColumn(data, validity, c.dtype))
+    if cap < COND_MIN_CAPACITY:
+        cols = _move_rows(batch.columns, keep, dest, new_count, cap)
+    else:
+        small = cap // SMALL_BUCKET_DIVISOR
+        cols = jax.lax.cond(
+            new_count <= small,
+            partial(_move_rows, slots=small),
+            partial(_move_rows, slots=cap),
+            batch.columns, keep, dest, new_count)
     return ColumnBatch(cols, new_count, batch.schema)
+
+
+def count_compaction(capacity: int) -> None:
+    """Account one dispatch of a program whose body compacts a
+    ``capacity``-slot batch (counters ``compact.launches`` /
+    ``compact.slots``).  Which branch of the ``cond`` ran is decided on
+    the device and not worth a fetch: the seconds a launch show it."""
+    get_registry().inc_many((("compact.launches", 1),
+                             ("compact.slots", capacity)))
+
+
+def _move_rows(columns: Sequence[DeviceColumn], keep: jax.Array,
+               dest: jax.Array, new_count: jax.Array,
+               slots: int) -> list[DeviceColumn]:
+    """``compact``'s move through a ``slots``-row bucket (``new_count <=
+    slots <= capacity``): columns of the input's capacity holding the
+    kept rows at ``dest``, zeros from ``new_count`` on.
+
+    Every dropped row scatters to an index of its own past the bucket's
+    end: unique indices are what lets XLA scatter without sorting
+    (``ops/join.build_direct_table``; a bucket of 2^22 slots or more no
+    longer fits the chip's fast memory and is scattered behind one
+    two-operand sort all the same).  Leaves of one dtype -- validity
+    flags; each width of number; string lengths with the int32 data;
+    byte matrices side by side -- are stacked ``[capacity, k]`` and read
+    by ONE gather of rows: a gather's cost on the chip is its index
+    count, hardly its row width (PERF.md, PRs 28 and 32)."""
+    cap = keep.shape[0]
+    row = jnp.arange(cap, dtype=jnp.int32)
+    src = jnp.zeros(slots, jnp.int32).at[
+        jnp.where(keep, dest, slots + row)].set(
+            row, unique_indices=True, mode="drop")
+    live = jnp.arange(slots, dtype=jnp.int32) < new_count
+
+    stacks: dict = {}
+    for c in columns:
+        for leaf in (c.validity, c.data, c.lengths):
+            if leaf is not None:
+                stacks.setdefault(leaf.dtype, []).append(
+                    leaf.reshape(cap, -1))
+    moved = {}
+    for dtype, leaves in stacks.items():
+        rows = jnp.concatenate(leaves, axis=1)[src]
+        bounds = np.cumsum([x.shape[1] for x in leaves])[:-1]
+        moved[dtype] = iter(jnp.split(rows, bounds, axis=1))
+
+    def pad(x):
+        return x if slots == cap else jnp.pad(
+            x, ((0, cap - slots),) + ((0, 0),) * (x.ndim - 1))
+
+    out = []
+    for c in columns:
+        validity = next(moved[c.validity.dtype]).reshape(slots) & live
+        data = next(moved[c.data.dtype]).reshape(
+            (slots,) + c.data.shape[1:])
+        data = jnp.where(validity[(...,) + (None,) * (data.ndim - 1)],
+                         data, jnp.zeros((), data.dtype))
+        lengths = None
+        if c.is_var_width:
+            lengths = pad(jnp.where(
+                validity, next(moved[c.lengths.dtype]).reshape(slots), 0))
+        out.append(DeviceColumn(pad(data), pad(validity), c.dtype, lengths))
+    return out
 
 
 def take(batch: ColumnBatch, indices: jax.Array,

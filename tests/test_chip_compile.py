@@ -215,7 +215,9 @@ def test_concat_batches_compiles_for_v5e(one_chip):
 
 def test_two_filter_stage_compiles_for_v5e(one_chip):
     """q44's fused Filter -> Filter at its batch size (2^20 rows, an s64,
-    a string and an f64 column): both conditions and one compaction."""
+    a string and an f64 column): both conditions and one compaction --
+    a ``cond`` over two moves, each one index scatter and row gathers;
+    the chip's compiler turns none of it into a sort."""
     from spark_rapids_tpu import TpuSession
     from spark_rapids_tpu.exec.fused import FusedStageExec, stage_body
     from spark_rapids_tpu.expr.core import col
@@ -225,7 +227,42 @@ def test_two_filter_stage_compiles_for_v5e(one_chip):
         .filter(col("k") == 4).filter(col("v").is_null())
     ov, meta = q._overridden(quiet=True)
     assert isinstance(meta.exec_node, FusedStageExec), meta.exec_node
-    _compile(stage_body(meta.exec_node.fused_ops), _shapes(b, one_chip))
+    hlo = _compile(stage_body(meta.exec_node.fused_ops),
+                   _shapes(b, one_chip)).as_text()
+    assert hlo.count(" conditional(") == 1
+    assert 1 <= hlo.count(" scatter(") <= 2       # at most one a branch
+    assert " gather(" in hlo and " sort(" not in hlo
+
+
+def test_q1_shaped_compaction_compiles_for_v5e(one_chip):
+    """``compact`` at q1's first batch (2^22 slots: four f64, two
+    one-character strings, a date): the stacks of a dtype stay a few
+    hundred MB of temporaries, and the 2^22-slot index scatter of the full
+    branch is the one the chip's compiler still sorts first (the bucket
+    no longer fits its fast memory; the small branch's does)."""
+    import pyarrow as pa
+
+    from spark_rapids_tpu.ops import kernels as dk
+    n, cap = 900, 1 << 22
+    rng = np.random.default_rng(3)
+    b = ColumnBatch.from_arrow(pa.record_batch({
+        **{name: pa.array(np.round(rng.uniform(0, 1e4, n), 2))
+           for name in ("l_quantity", "l_extendedprice", "l_discount",
+                        "l_tax")},
+        "l_returnflag": pa.array(rng.choice(["A", "N", "R"], n)),
+        "l_linestatus": pa.array(rng.choice(["F", "O"], n)),
+        "l_shipdate": pa.array(rng.integers(8000, 10600, n).astype(np.int32),
+                               type=pa.date32()),
+    }), capacity=cap)
+    compiled = _compile(lambda x: dk.compact(x, x.columns[6].data <= 10471),
+                        _shapes(b, one_chip))
+    hlo = compiled.as_text()
+    assert hlo.count(" conditional(") == 1
+    assert hlo.count(" scatter(") == 2 and hlo.count(" sort(") <= 1
+    # one gather a stack a branch: flags, f64 as two f32 halves, bytes, s32
+    assert hlo.count(" gather(") == 2 * 5
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * args
 
 
 def test_string_key_sort_compiles_for_v5e(one_chip):

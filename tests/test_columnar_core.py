@@ -58,6 +58,155 @@ def test_compact_filter():
     assert out.to_arrow().column(0).to_pylist() == [1, 3, 5]
 
 
+def _compact_input(cap, n, seed, junk=False):
+    """``n`` rows in ``cap`` slots of (int32, float64, int64, an all-null
+    int32, a string in 4 bytes, a string in 16), nulls scattered;
+    ``junk`` fills the padding with data and sets its validity."""
+    from spark_rapids_tpu import types as T
+    rng = np.random.default_rng(seed)
+    real = np.arange(cap) < n
+
+    def leaf(values, valid):
+        fill = np.full_like(values, 7) if junk else np.zeros_like(values)
+        shaped = valid.reshape((cap,) + (1,) * (values.ndim - 1))
+        return np.where(shaped, values, fill)
+    cols, fields = [], []
+    for name, dtype, values in (
+            ("a", T.IntegerType(), rng.integers(1, 99, cap).astype(np.int32)),
+            ("v", T.DoubleType(), rng.normal(size=cap)),
+            ("k", T.LongType(), rng.integers(1, 1 << 40, cap)),
+            ("n", T.IntegerType(), np.zeros(cap, np.int32))):
+        valid = real & (rng.random(cap) > 0.2) & (name != "n")
+        cols.append(DeviceColumn(
+            jnp.asarray(leaf(values, valid)),
+            jnp.asarray(valid | (junk & ~real)), dtype))
+        fields.append(T.StructField(name, dtype, True))
+    for name, width in (("s", 4), ("t", 16)):
+        valid = real & (rng.random(cap) > 0.2)
+        lengths = rng.integers(0, width + 1, cap).astype(np.int32)
+        chars = rng.integers(97, 123, (cap, width)).astype(np.uint8)
+        chars = np.where(np.arange(width)[None, :] < lengths[:, None],
+                         chars, 0).astype(np.uint8)
+        cols.append(DeviceColumn(
+            jnp.asarray(leaf(chars, valid)),
+            jnp.asarray(valid | (junk & ~real)), T.StringType(),
+            jnp.asarray(leaf(lengths, valid))))
+        fields.append(T.StructField(name, T.StringType(), True))
+    return ColumnBatch(cols, jnp.asarray(n, jnp.int32), T.Schema(fields))
+
+
+def _compact_reference(batch, keep):
+    """NumPy: the kept real rows in order at the front, zeros behind,
+    data and lengths zeroed where invalid."""
+    cap = batch.capacity
+    keep = np.asarray(keep) & (np.arange(cap) < int(batch.num_rows))
+    idx = np.flatnonzero(keep)
+    out = []
+    for c in batch.columns:
+        leaves = []
+        valid = np.asarray(c.validity)[idx]
+        for x in (c.validity, c.data, c.lengths):
+            if x is None:
+                leaves.append(None)
+                continue
+            x = np.asarray(x)
+            packed = np.zeros_like(x)
+            packed[:len(idx)] = np.where(
+                valid.reshape((-1,) + (1,) * (x.ndim - 1)), x[idx], 0)
+            leaves.append(packed)
+        out.append(tuple(leaves))
+    return len(idx), out
+
+
+def _keep_spread(cap, count, seed=5):
+    keep = np.zeros(cap, bool)
+    keep[np.random.default_rng(seed).choice(cap, count, replace=False)] = True
+    return keep
+
+
+_BIG = ops.kernels.COND_MIN_CAPACITY        # the least capacity with a cond
+_EIGHTH = _BIG // ops.kernels.SMALL_BUCKET_DIVISOR
+_COMPACT_CASES = {
+    # name: (capacity, num_rows, junk padding, keep mask)
+    "keep_none": (_BIG, _BIG - 3, False, lambda: np.zeros(_BIG, bool)),
+    "keep_one": (_BIG, _BIG - 3, False,
+                 lambda: np.arange(_BIG) == _BIG - 4),
+    "keep_exactly_an_eighth": (_BIG, _BIG, False,
+                               lambda: _keep_spread(_BIG, _EIGHTH)),
+    "keep_an_eighth_and_one": (_BIG, _BIG, False,
+                               lambda: _keep_spread(_BIG, _EIGHTH + 1)),
+    "keep_all": (_BIG, _BIG, False, lambda: np.ones(_BIG, bool)),
+    "keep_first_rows": (_BIG, _BIG - 3, False,
+                        lambda: np.arange(_BIG) < 1000),
+    "junk_padding_few": (_BIG, 5000, True, lambda: np.arange(_BIG) % 3 == 0),
+    "junk_padding_most": (_BIG, _BIG - 100, True,
+                          lambda: np.ones(_BIG, bool)),
+    "under_the_static_floor": (64, 50, True, lambda: np.arange(64) % 2 == 1),
+    "under_the_static_floor_none": (8, 8, False, lambda: np.zeros(8, bool)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COMPACT_CASES))
+def test_compact_matches_numpy_reference(case):
+    """Rows, order, ``num_rows``, zeroed padding and every leaf's dtype
+    and shape, whichever branch of the ``cond`` the count picks; traced
+    inside a program it is the same leaves."""
+    cap, n, junk, mask = _COMPACT_CASES[case]
+    batch = _compact_input(cap, n, seed=len(case), junk=junk)
+    keep = jnp.asarray(mask())
+    out = ops.compact(batch, keep)
+    rows, want = _compact_reference(batch, keep)
+    assert int(out.num_rows) == rows
+    assert out.capacity == cap and out.schema == batch.schema
+    for c, src, leaves in zip(out.columns, batch.columns, want):
+        for got, was, ref in zip((c.validity, c.data, c.lengths),
+                                 (src.validity, src.data, src.lengths),
+                                 leaves):
+            assert (got is None) == (ref is None)
+            if ref is not None:
+                assert got.dtype == was.dtype and got.shape == was.shape
+                np.testing.assert_array_equal(np.asarray(got), ref)
+    traced = jax.jit(ops.compact)(batch, keep)
+    for got, eager in zip(jax.tree.leaves(traced), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(eager))
+
+
+def test_compact_branches_agree_leaf_for_leaf():
+    """The small-bucket move and the full move, called directly on an
+    input both can hold, give the same leaves; a program over a capacity
+    at or above the floor holds both under one ``cond``, one below it
+    neither the ``cond`` nor a second scatter."""
+    from spark_rapids_tpu.ops.kernels import _move_rows
+    batch = _compact_input(_BIG, _BIG - 7, seed=11, junk=True)
+    keep = jnp.asarray(_keep_spread(_BIG, _EIGHTH - 5)) & batch.row_mask()
+    scan = jnp.cumsum(keep.astype(jnp.int32))
+    args = (batch.columns, keep, scan - 1, scan[-1])
+    small = _move_rows(*args, slots=_EIGHTH)
+    full = _move_rows(*args, slots=_BIG)
+    whole = ops.compact(batch, keep).columns
+    for a, b, c in zip(*(jax.tree.leaves(x) for x in (small, full, whole))):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+
+    def lowered(cap):
+        b = _compact_input(cap, cap, seed=1)
+        return jax.jit(ops.compact).lower(b, b.row_mask()).as_text()
+    # one index scatter and one gather a dtype (bool, s32, f64, s64, u8)
+    # a branch; no sort
+    big, little = lowered(_BIG), lowered(_BIG // 2)
+
+    def ops_named(text, op):
+        return text.count(f'"stablehlo.{op}"')
+    assert ops_named(big, "case") + ops_named(big, "if") == 1
+    assert ops_named(big, "scatter") == 2
+    assert ops_named(big, "gather") == 2 * 5
+    assert ops_named(little, "case") + ops_named(little, "if") == 0
+    assert ops_named(little, "scatter") == 1
+    assert ops_named(little, "gather") == 5
+    assert "stablehlo.sort" not in big + little
+
+
 def test_slice_limit():
     rb = _rb(a=pa.array(list(range(6)), type=pa.int32()))
     out = ops.slice_batch(ColumnBatch.from_arrow(rb), 4)

@@ -186,9 +186,11 @@ def test_fused_chain_equals_unfused_row_for_row(chain):
 
 
 def test_two_filter_stage_compacts_once():
-    """The lowered body of Filter -> Project -> Filter -> Project holds as
-    many scatters as ONE compact of the stage's output columns (two of
-    the input's three; a compaction a filter would be twice three)."""
+    """The lowered body of Filter -> Project -> Filter -> Project holds
+    exactly ONE compaction of the stage's output columns (two of the
+    input's three): as many scatters as one ``compact`` -- the index
+    scatter, once a branch where a ``cond`` picks the bucket, never one a
+    leaf or one a filter -- and as many gathers (one a dtype a branch)."""
     import jax
 
     from spark_rapids_tpu.exec.fused import filters_merged, stage_body
@@ -203,15 +205,21 @@ def test_two_filter_stage_compacts_once():
                  if isinstance(n, FusedStageExec))
     assert filters_merged(stage.fused_ops) == 1
     body = stage_body(stage.fused_ops)
-    batch = HostBatch.from_pydict(_xy_data(), _XY).to_device()
 
-    def scatters(fn, arg):
-        return jax.jit(fn).lower(arg).as_text().count("stablehlo.scatter")
-    out = jax.eval_shape(body, batch)
-    assert len(out.columns) == 2
-    one_compact = scatters(lambda b: dk.compact(b, b.row_mask()), out)
-    assert one_compact > 0
-    assert scatters(body, batch) == one_compact
+    def count(fn, arg, op):
+        return jax.jit(fn).lower(arg).as_text().count(f'"stablehlo.{op}"')
+    for cap, branches in ((None, 1), (dk.COND_MIN_CAPACITY, 2)):
+        batch = HostBatch.from_pydict(_xy_data(), _XY).to_device(
+            capacity=cap)
+        out = jax.eval_shape(body, batch)
+        assert len(out.columns) == 2
+
+        def one(b):
+            return dk.compact(b, b.row_mask())
+        assert count(one, out, "scatter") == branches
+        assert count(body, batch, "scatter") == branches
+        assert count(body, batch, "gather") == count(one, out, "gather") > 0
+        assert count(body, batch, "sort") == 0
 
 
 # ---------------------------------------------------------------------------

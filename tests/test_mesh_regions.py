@@ -210,6 +210,60 @@ def test_region_two_filter_stage_matches_single_chip(rng):
     assert got == want and 0 < len(got) <= 17
 
 
+@pytest.mark.parametrize("threshold", [990, -990])
+def test_region_compaction_keeps_its_cond_under_shard_map(
+        rng, monkeypatch, threshold):
+    """The compaction's ``cond`` (which bucket to move) inside a region's
+    per-device program: every shard picks its branch from its own count.
+    The floor is lowered so 2^6-slot shards carry the ``cond``; a filter
+    that keeps a few rows (the small bucket) and one that keeps nearly
+    all (the full move) both equal the single-chip plan, and the lowered
+    ``shard_map`` body still holds the ``case``."""
+    import jax
+
+    from spark_rapids_tpu.host.batch import HostBatch
+    from spark_rapids_tpu.ops import kernels as dk
+    from spark_rapids_tpu.parallel import mesh as pm
+    monkeypatch.setattr(dk, "COND_MIN_CAPACITY", 16)
+    data = _data(rng)
+
+    def q(s):
+        # literals no other test's region shares: a program traced under
+        # this floor must not answer for another's fragment key
+        return s.from_pydict(data, SCHEMA, partitions=4) \
+            .where(col("v") > threshold).where(col("f") > -7.25) \
+            .group_by("k").agg(Sum(col("v")).alias("sv"),
+                               CountStar().alias("n"))
+    before = get_registry().snapshot()
+    got = sorted(q(TpuSession(MESH8)).collect())
+    moved = get_registry().delta(before)["counters"]
+    assert moved.get("compact.launches", 0) >= 1, moved
+    assert moved["compact.slots"] >= 8 * 16 * moved["compact.launches"]
+    want = sorted(q(TpuSession({})).collect())
+    assert got == want and len(got) > 0
+
+    mesh = pm.make_mesh(8)
+    shards = [HostBatch.from_pydict(
+        {k: v[i::8] for k, v in data.items()}, SCHEMA).to_device(capacity=64)
+        for i in range(8)]
+    stacked = pm.shard_batches(shards, mesh)
+
+    def region(st):
+        b = pm.local_view(st)
+        c = b.columns[2]
+        return pm.restack(dk.compact(b, c.validity & (c.data > threshold)))
+    prog = jax.jit(pm.shard_map(region, mesh=mesh, in_specs=pm.stacked_spec(),
+                                out_specs=pm.stacked_spec()))
+    text = prog.lower(stacked).as_text()
+    assert text.count('"stablehlo.case"') + text.count('"stablehlo.if"') == 1
+    assert text.count('"stablehlo.scatter"') == 2
+    for got_b, b in zip(pm.split_shards(prog(stacked)), shards):
+        c = b.columns[2]
+        alone = dk.compact(b, c.validity & (c.data > threshold))
+        for x, y in zip(jax.tree.leaves(got_b), jax.tree.leaves(alone)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 def test_regions_disabled_keeps_island_shape_and_rows(rng):
     data = _data(rng)
     son = TpuSession(MESH8)

@@ -436,6 +436,47 @@ def test_front_pack_counters_follow_the_plan(session, tables):
     assert c["concat.batches_in"] == FACT_FILES
 
 
+@pytest.mark.parametrize("shape", ["one_filter", "two_filters", "no_filter",
+                                   "join_condition"])
+def test_compaction_counters_follow_the_plan(session, tables, shape):
+    """``compact.launches`` / ``compact.slots``: one count, and the
+    batch's capacity, a dispatch of a program whose body compacts -- a
+    filter a file, a fused two-filter stage a file (one compaction, not
+    two), a join's residual condition a probe chunk; a plan that filters
+    nothing moves neither.  What the host already holds: a collect with
+    them fetches no more than the same plan's programs ask for."""
+    fact = session.read_parquet(os.path.join(tables, "fact"))
+    dim = session.read_parquet(os.path.join(tables, "dim"))
+    rows = {
+        "one_filter": lambda: fact.where(col("v") > lit(0.25)),
+        "two_filters": lambda: fact.where(col("v") > lit(0.25))
+        .where(col("d") > lit(DAY0 + 7)),
+        "no_filter": lambda: fact,
+        "join_condition": lambda: fact.join(
+            dim, on="k", condition=col("v") > col("w")),
+    }[shape]()
+    df = rows.group_by("g").agg(CountStar().alias("n"))
+    assert len(df.collect()) == 5
+    c = _collect(df)["counters"]
+    program = {"one_filter": "filter_batch", "two_filters": "fused_stage_body",
+               "join_condition": "join_post_filter"}.get(shape)
+    if program is None:
+        assert "compact.launches" not in c and "compact.slots" not in c
+        return
+    launches = c[f"program.{program}.launches"]
+    assert c["compact.launches"] == launches
+    assert launches == FACT_FILES or shape == "join_condition"
+    # 4,000 rows a file in a 4,096-slot batch
+    assert c["compact.slots"] >= 4096 * launches
+    assert c["compact.slots"] % 4096 == 0
+    # no fetch beyond the aggregate's and the join's own
+    fetches = sum(v for k, v in c.items()
+                  if k.startswith("span.fetch@") and k.endswith(".count"))
+    assert c["d2h_calls"] == fetches + c["span.query.fetch.count"]
+    assert not any(k.startswith("span.fetch@Filter")
+                   or k.startswith("span.fetch@FusedStage") for k in c)
+
+
 # ----------------------------------------------------------------- trace
 
 def test_worker_and_fetch_spans_share_the_collects_clock(query, tmp_path):
