@@ -33,13 +33,14 @@ from spark_rapids_tpu.host.batch import HostBatch
 from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops import host_kernels as hk
-from spark_rapids_tpu.ops.join import (JOIN_TYPES, DirectBuild,
+from spark_rapids_tpu.ops.join import (JOIN_TYPES, DirectBuild, PackedBuild,
                                        build_direct_table, build_key_stats,
-                                       build_prepare_fast, direct_table_size,
-                                       gather_join_output,
+                                       build_prepare_fast,
+                                       build_prepare_packed,
+                                       direct_table_size, gather_join_output,
                                        join_indices_from_probe, join_probe,
-                                       matched_build_rows, probe_direct,
-                                       probe_fast)
+                                       matched_build_rows, packed_key_span,
+                                       probe_direct, probe_fast)
 
 __all__ = ["JoinExec", "CrossJoinExec", "BroadcastHashJoinExec"]
 
@@ -59,44 +60,70 @@ def _jit_probe(lb, rb, lkeys, rkeys, join_type):
 
 @guarded_jit("join_build_prep", static_argnames=("rkey",))
 def _jit_build_prep(rb, rkey):
+    """``rkey``: one key column, or a tuple of them to pack into one."""
+    if isinstance(rkey, tuple):
+        prep, packing = build_prepare_packed(rb, rkey)
+        return (PackedBuild(prep, packing),
+                build_key_stats(prep[0], prep[2], packing))
     prep = build_prepare_fast(rb, rkey)
     return prep, build_key_stats(prep[0], prep[2])
 
 
 @guarded_jit("join_build_table", static_argnames=("size",))
 def _jit_build_table(prep, size):
-    return build_direct_table(*prep, size)
+    return build_direct_table(*prep[:3], size)
+
+
+def _unpacked(prep) -> tuple:
+    """``(build, packing)`` of a prepared build side; the packing is None
+    where it was prepared from one key."""
+    return prep if isinstance(prep, PackedBuild) else (prep, None)
 
 
 @guarded_jit("join_probe_fast", static_argnames=("lkey", "join_type"))
 def _jit_probe_fast(lb, prep, lkey, join_type):
-    probe_arrays, total = probe_fast(lb, lkey, *prep, join_type)
+    build, packing = _unpacked(prep)
+    probe_arrays, total = probe_fast(lb, lkey, *build, join_type, packing)
     return probe_arrays[:-1], total  # drop the None placeholder
 
 
 @guarded_jit("join_probe_direct", static_argnames=("lkey", "join_type"))
-def _jit_probe_direct(lb, build, lkey, join_type):
-    probe_arrays, total = probe_direct(lb, lkey, build, join_type)
+def _jit_probe_direct(lb, prep, lkey, join_type):
+    build, packing = _unpacked(prep)
+    probe_arrays, total = probe_direct(lb, lkey, build, join_type, packing)
     return probe_arrays[:-1], total  # drop the None placeholder
 
 
-def prepare_fast_build(rb, rkey: int):
+def prepare_fast_build(rb, rkeys: tuple):
     """Prepare a build side for the streaming probe, once per build:
-    sort it by its key, look at the keys it holds (``nv``, smallest,
-    largest: ONE blocking fetch) and, where they are dense
-    (ops/join.direct_table_size), make the direct-address table.  Returns
-    a :class:`DirectBuild` for ``join_probe_direct`` or the sorted
-    ``(sorted_key, perm, nv)`` for ``join_probe_fast``; both are pytrees
-    of device arrays."""
-    prep, stats = _jit_build_prep(rb, rkey)
+    sort it by its key (several integral keys packed into one,
+    ops/join.build_prepare_packed), look at the keys it holds (``nv``,
+    each key's smallest and largest: ONE blocking fetch) and, where they
+    are dense (ops/join.direct_table_size), make the direct-address
+    table.  Returns a :class:`DirectBuild` for ``join_probe_direct`` or
+    the sorted ``(sorted_key, perm, nv, run_len)`` for
+    ``join_probe_fast``, inside a :class:`PackedBuild` where the keys
+    were packed (all pytrees of device arrays), or None where several
+    keys span more than an ``int64`` holds: that build stays on the sort
+    path."""
+    prep, stats = _jit_build_prep(
+        rb, rkeys if len(rkeys) > 1 else rkeys[0])
+    build, packing = _unpacked(prep)
     # enginelint: disable=RL003 (once per build, before any stream batch: the probe's program is chosen from it)
-    nv, kmin, kmax = (int(x) for x in fetch_to_host(stats, _FETCH))
+    nv, *ranges = (int(x) for x in fetch_to_host(stats, _FETCH))
     get_registry().inc("join.build.rows", nv)
-    size = direct_table_size(nv, kmin, kmax, rb.capacity)
+    if packing is not None:
+        span = packed_key_span(nv, ranges)
+        if span is None:
+            return None
+        get_registry().inc("join.keys.packed")
+        ranges = (0, span - 1)
+    size = direct_table_size(nv, *ranges, rb.capacity)
     if size is None:
         return prep
     get_registry().inc("join.build.table_entries", size)
-    return _jit_build_table(prep, size)
+    table = _jit_build_table(build, size)
+    return table if packing is None else PackedBuild(table, packing)
 
 
 @guarded_jit("join_gather",
@@ -107,8 +134,10 @@ def _jit_gather(lb, rb, probe_arrays, cl, join_type, out_cap, include_right,
     """Light phase (gathers only): re-specialized per output capacity."""
     if len(probe_arrays) == 4:
         probe_arrays = probe_arrays + (None,)
-    plan = join_indices_from_probe(cl, probe_arrays, join_type, out_cap)
-    out = gather_join_output(lb, rb, *plan, schema, include_right)
+    plan = join_indices_from_probe(cl, probe_arrays, join_type, out_cap,
+                                   stacked=True)
+    out = gather_join_output(lb, rb, *plan, schema, include_right,
+                             stacked=True)
     if track_matched:
         li, ri, l_take, r_take, total = plan
         return out, matched_build_rows(ri, r_take, rb.capacity)
@@ -274,22 +303,31 @@ class JoinExec(PlanNode):
     # then the stream side is joined PER BATCH — no whole-side concat, no
     # per-batch sort on the fast path.
     def _use_fast_path(self) -> bool:
-        if len(self._lkeys_b) != 1:
-            return False
-        lt, rt = self._lkeys_b[0].dtype, self._rkeys_b[0].dtype
-        return (not lt.fractional and not rt.fractional
-                and not isinstance(lt, (T.StringType, T.BooleanType))
-                and not isinstance(rt, (T.StringType, T.BooleanType))
-                and type(lt) is type(rt))
+        """Every key integral (a cross join has none)."""
+        def integral(lt, rt):
+            return (not lt.fractional and not rt.fractional
+                    and not isinstance(lt, (T.StringType, T.BooleanType))
+                    and not isinstance(rt, (T.StringType, T.BooleanType))
+                    and type(lt) is type(rt))
+        return bool(self._lkeys_b) and all(
+            integral(a.dtype, b.dtype)
+            for a, b in zip(self._lkeys_b, self._rkeys_b))
+
+    def _prepare_build(self, rb2: ColumnBatch, rkeys: tuple):
+        """The build side prepared for the streaming probes, or None for
+        the sort path: a key that is a string, fractional or boolean, or
+        several keys whose spans' product no ``int64`` holds."""
+        prep = prepare_fast_build(rb2, rkeys) \
+            if self._use_fast_path() else None
+        if prep is None and len(rkeys) > 1:
+            get_registry().inc("join.keys.unpackable")
+        return prep
 
     def _build_device(self, ctx: ExecCtx):
         def build():
             rb = self._materialize(ctx, 1)
             rb2, rkeys = self._augment_device(rb, self._rkeys_b)
-            prep = prepare_fast_build(rb2, rkeys[0]) \
-                if self.join_type != "cross" and self._use_fast_path() \
-                else None
-            return rb2, rkeys, prep
+            return rb2, rkeys, self._prepare_build(rb2, rkeys)
         return ctx.cached((id(self), "build"), build)
 
     def _stream_batches(self, ctx: ExecCtx, pid: int):
@@ -333,19 +371,20 @@ class JoinExec(PlanNode):
                 get_registry().inc("join.cross.launches")
             elif jt in ("semi", "anti"):
                 get_registry().inc("join.semi.batches")
-            if isinstance(prep, DirectBuild):
-                get_registry().inc("join.probe.direct")
-                probe_arrays, total_dev = _jit_probe_direct(
-                    lb2, prep, lkeys[0], stream_jt)
-            elif prep is not None:
-                get_registry().inc("join.probe.search")
-                probe_arrays, total_dev = _jit_probe_fast(
-                    lb2, prep, lkeys[0], stream_jt)
-            else:
+            if prep is None:
                 if jt != "cross":
                     get_registry().inc("join.probe.sorted")
                 probe_arrays, total_dev = _jit_probe(
                     lb2, rb2, lkeys, rkeys, stream_jt)
+            else:
+                # a packed build takes all the key columns, any other its one
+                build, packing = _unpacked(prep)
+                lkey = lkeys[0] if packing is None else lkeys
+                direct = isinstance(build, DirectBuild)
+                get_registry().inc(
+                    "join.probe.direct" if direct else "join.probe.search")
+                run = _jit_probe_direct if direct else _jit_probe_fast
+                probe_arrays, total_dev = run(lb2, prep, lkey, stream_jt)
             return lb2, total_dev, probe_arrays
 
         def probe_entries(lb) -> list:
@@ -372,6 +411,7 @@ class JoinExec(PlanNode):
 
             totals = ctx.retry_sync(sync_totals, redo=redo,
                                     op="join_flush")
+            get_registry().inc("join.probe.rows_out", sum(totals))
             for (lb, lb2, _td, probe_arrays), total in zip(pending, totals):
                 if total == 0:
                     if jt == "full" and matched is None:

@@ -943,10 +943,7 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
             rb = concat_or_empty(list(rex.partition_iter(ctx, pid)),
                                  self.children[1].output_schema)
             rb2, rkeys = self._augment_device(rb, self._rkeys_b)
-            from spark_rapids_tpu.exec.joins import prepare_fast_build
-            prep = prepare_fast_build(rb2, rkeys[0]) \
-                if self._use_fast_path() else None
-            return rb2, rkeys, prep
+            return rb2, rkeys, self._prepare_build(rb2, rkeys)
         return ctx.cached((id(self), "mesh_part_build", pid), build)
 
     def _device_build_replicated(self, ctx: ExecCtx, pid: int):

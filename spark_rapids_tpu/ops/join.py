@@ -16,8 +16,10 @@ sort-based (SURVEY.md §7 "hard parts"):
    is materialized to host ONCE at the batch boundary to pick a static
    pow2 output capacity (XLA static-shape discipline, columnar/batch.py).
 4. **gather** (phase 2): output slot j -> (left row, right row) via
-   cumsum + searchsorted; full-outer appends unmatched right rows by
-   scatter.  Gathers build the output columns.
+   cumsum + searchsorted (``stacked``, the one-chip executor's plan: one
+   scatter of the rows' offsets and a running maximum); full-outer
+   appends unmatched right rows by scatter.  Gathers build the output
+   columns (``stacked``: one gather a dtype over stacked leaves).
 
 Right outer join is the exec layer's job (swap sides, reorder columns,
 exec/joins.py), matching the reference's build-side flip.
@@ -34,15 +36,33 @@ chosen once per build, on the host, from the key range the build holds
   :func:`probe_direct` reads it by address: one gather of table rows a
   stream batch, whatever the build's size;
 * **anything else** (a hashed id, a natural key): :func:`probe_fast`,
-  two ``searchsorted`` over the sorted keys, each ``log2(capacity)``
-  dependent gathers over every stream row.
+  one ``searchsorted`` over the sorted keys (``log2(capacity)``
+  dependent gathers over every stream row) and the run length the build
+  keeps at each run's first row.
 
 Both return the same ``(start, cnt, perm, out_cnt)`` and ``total``.
+
+A join on SEVERAL integral keys streams the same way: the keys are packed
+into ONE mixed-radix ``int64`` key, ``sum((k_i - lo_i) * radix_i)`` with
+``radix_i`` the product of the later keys' spans ``hi_j - lo_j + 1``, and
+everything after that is the single-key path (a dense product is probed
+by address, anything else searched).  The ranges ``[lo_i, hi_i]`` are
+the build side's own, found on the device while the build is sorted
+(:func:`build_prepare_packed`) and carried to every probe as arrays
+(:class:`KeyPacking`: traced, so another build compiles nothing); the
+build's one fetch brings them to the host too, where python ints decide
+whether the spans' product fits 63 bits (:func:`packed_key_span`).  A
+stream row with a NULL in any key, or with any key outside the build's
+range for it, matches nothing: the range test comes before the
+subtraction, so no such row wraps into a match.  Strings, fractional
+and boolean keys, and a product that does not fit, stay on the sort
+path (:func:`join_probe`).
 """
 from __future__ import annotations
 
 from typing import NamedTuple, Sequence
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -54,8 +74,8 @@ from spark_rapids_tpu.ops.segmented import _cols_differ
 from spark_rapids_tpu.ops.sort import encode_key_operands
 
 __all__ = ["join_probe", "join_total", "join_indices_from_probe",
-           "gather_join_output", "JOIN_TYPES", "DirectBuild",
-           "direct_table_size"]
+           "gather_join_output", "JOIN_TYPES", "DirectBuild", "KeyPacking",
+           "PackedBuild", "direct_table_size", "packed_key_span"]
 
 JOIN_TYPES = ("inner", "left", "semi", "anti", "full", "cross")
 
@@ -145,41 +165,134 @@ def _probe(lbatch: ColumnBatch, rbatch: ColumnBatch,
 def build_prepare_fast(rbatch: ColumnBatch, rkey: int):
     """Sort the build side ONCE by its (single, integral) key.
 
-    Returns ``(sorted_key, perm, nv)``: the build keys sorted ascending
-    with the ``nv`` valid entries first and every invalid/padding slot
-    rewritten to the dtype max so the array stays globally sorted (probe
-    ranges are clipped to ``nv``, which keeps genuine max-valued keys —
-    they live at positions < nv).  This is the streaming-join analog of
+    Returns ``(sorted_key, perm, nv, run_len)``: the build keys sorted
+    ascending with the ``nv`` valid entries first and every
+    invalid/padding slot rewritten to the dtype max so the array stays
+    globally sorted (a probe's hit must lie below ``nv``, which keeps
+    genuine max-valued keys — they live at positions < nv), and at the
+    first row of every run of equal keys the run's length, so that ONE
+    search finds a key's whole run.  This is the streaming-join analog of
     the reference's build-side hash table (GpuHashJoin build side,
     GpuHashJoin.scala:193-249): built once, probed per stream batch with
     no per-batch sort.
     """
     col = rbatch.columns[rkey]
-    valid = col.validity & rbatch.row_mask()
-    cr = rbatch.capacity
-    iota = jnp.arange(cr, dtype=jnp.int32)
+    return _sort_build(col.data, col.validity & rbatch.row_mask())
+
+
+def _sort_build(key, valid):
+    cap = key.shape[0]
+    iota = jnp.arange(cap, dtype=jnp.int32)
     flag = (~valid).astype(jnp.uint8)
-    _, skey, perm = lax.sort([flag, col.data, iota], num_keys=2,
-                             is_stable=True)
+    _, skey, perm = lax.sort([flag, key, iota], num_keys=2, is_stable=True)
     nv = jnp.sum(valid, dtype=jnp.int32)
-    maxv = jnp.iinfo(col.data.dtype).max
-    skey = jnp.where(iota < nv, skey, maxv)
-    return skey, perm, nv
+    skey = jnp.where(iota < nv, skey, jnp.iinfo(key.dtype).max)
+    # the length of each run of equal keys, at the run's first row (0 at
+    # the others): the next run's first row, found by a running minimum
+    # from the end, less this one
+    first = (iota < nv) & ((iota == 0) | (skey != jnp.roll(skey, 1)))
+    nxt = lax.cummin(jnp.where(first, iota, cap), reverse=True)
+    after = jnp.concatenate([nxt[1:], jnp.full(1, cap, jnp.int32)])
+    run_len = jnp.where(first, jnp.minimum(after, nv) - iota, 0)
+    return skey, perm, nv, run_len
 
 
-def probe_fast(lbatch: ColumnBatch, lkey: int, sorted_key, perm, nv,
-               join_type: str):
-    """Per-stream-batch probe against a prepared build side: two
-    searchsorted passes, zero sorts.  Same contract as the heavy phase of
-    :func:`join_probe` (without full-outer bookkeeping — streaming full
-    outer tracks matched build rows in the gather phase instead)."""
+class KeyPacking(NamedTuple):
+    """How ``k`` integral keys become one: the build side's range of each
+    key and its place value, ``int64[k]`` device arrays (arguments of the
+    probe programs, never static)."""
+    lo: jax.Array
+    hi: jax.Array
+    radix: jax.Array
+
+
+class PackedBuild(NamedTuple):
+    """A build side prepared from several keys: what a single-key probe
+    takes (a :class:`DirectBuild` or ``(sorted_key, perm, nv, run_len)``,
+    over the packed key) and the packing the stream side has to repeat."""
+    build: tuple
+    packing: KeyPacking
+
+
+def _pack_keys(batch: ColumnBatch, keys: Sequence[int], packing: KeyPacking):
+    """``(packed int64[capacity], valid)``: rows that are padding, hold a
+    NULL key or a key outside ``[lo_i, hi_i]`` are not valid (their
+    packed value means nothing).  The range test comes first: only an
+    in-range key is subtracted, so nothing wraps."""
+    valid = batch.row_mask()
+    packed = jnp.zeros(batch.capacity, jnp.int64)
+    for i, k in enumerate(keys):
+        col = batch.columns[k]
+        data = col.data.astype(jnp.int64)
+        ok = col.validity & (data >= packing.lo[i]) & (data <= packing.hi[i])
+        valid = valid & ok
+        packed = packed + jnp.where(ok, data - packing.lo[i], 0) \
+            * packing.radix[i]
+    return packed, valid
+
+
+def build_prepare_packed(rbatch: ColumnBatch, rkeys: Sequence[int]):
+    """:func:`build_prepare_fast` for several integral keys: the range of
+    each key over the rows whose keys are all valid, the mixed-radix
+    packing they give (last key fastest), and the build sorted by the
+    packed key.  Returns ``((sorted_key, perm, nv, run_len), packing)``.
+    Where the spans' product does not fit 63 bits the radices have
+    wrapped and the result is to be dropped: the host sees that from the
+    ranges (:func:`packed_key_span`)."""
+    valid = rbatch.row_mask()
+    for k in rkeys:
+        valid = valid & rbatch.columns[k].validity
+    wide = [rbatch.columns[k].data.astype(jnp.int64) for k in rkeys]
+    i64 = jnp.iinfo(jnp.int64)
+    lo = jnp.stack([jnp.min(jnp.where(valid, d, i64.max)) for d in wide])
+    hi = jnp.stack([jnp.max(jnp.where(valid, d, i64.min)) for d in wide])
+    span = hi - lo + 1
+    radix = jnp.concatenate(
+        [jnp.cumprod(span[:0:-1])[::-1], jnp.ones(1, jnp.int64)])
+    packing = KeyPacking(lo, hi, radix)
+    packed, _ = _pack_keys(rbatch, rkeys, packing)
+    return _sort_build(packed, valid), packing
+
+
+def packed_key_span(nv: int, ranges: Sequence[int]) -> int | None:
+    """Values a packed key can take, from the ``[lo_0, hi_0, lo_1, ...]``
+    of a build's fetch (python ints: nothing overflows), or None where
+    that does not fit an ``int64`` and the keys cannot be packed.  An
+    empty build packs: nothing can match it whatever its ranges say."""
+    if nv == 0:
+        return 1
+    product = 1
+    for lo, hi in zip(ranges[0::2], ranges[1::2]):
+        product *= hi - lo + 1
+    return product if product < (1 << 63) else None
+
+
+def _stream_key(lbatch: ColumnBatch, lkey, packing: KeyPacking | None):
+    """The stream side's key and which rows may match: column ``lkey``,
+    or the columns ``lkey`` packed as the build was."""
+    if packing is not None:
+        return _pack_keys(lbatch, lkey, packing)
     col = lbatch.columns[lkey]
-    lvalid = col.validity & lbatch.row_mask()
-    start = jnp.searchsorted(sorted_key, col.data, side="left").astype(jnp.int32)
-    end = jnp.searchsorted(sorted_key, col.data, side="right").astype(jnp.int32)
-    end = jnp.minimum(end, nv)
-    start = jnp.minimum(start, end)
-    cnt = jnp.where(lvalid, end - start, 0)
+    return col.data, col.validity & lbatch.row_mask()
+
+
+def probe_fast(lbatch: ColumnBatch, lkey, sorted_key, perm, nv, run_len,
+               join_type: str, packing: KeyPacking | None = None):
+    """Per-stream-batch probe against a prepared build side: ONE
+    ``searchsorted`` (``log2(capacity)`` dependent gathers a stream row;
+    a second one for the run's end cost as much again, 1,071 against 586
+    ms for 2^20 rows in a 2^22-entry ``int64`` build, PERF.md PR 33),
+    then the key and the run length read where it landed.  Zero sorts.
+    Same contract as the heavy phase of :func:`join_probe` (without
+    full-outer bookkeeping — streaming full outer tracks matched build
+    rows in the gather phase instead); ``start`` means nothing where
+    ``cnt`` is 0.  With a ``packing``, ``lkey`` names the key columns to
+    pack first."""
+    data, lvalid = _stream_key(lbatch, lkey, packing)
+    start = jnp.searchsorted(sorted_key, data, side="left").astype(jnp.int32)
+    start = jnp.minimum(start, sorted_key.shape[0] - 1)
+    hit = lvalid & (start < nv) & (sorted_key[start] == data)
+    cnt = jnp.where(hit, run_len[start], 0)
     out_cnt = _out_cnt(cnt, lbatch.row_mask(), join_type)
     total = jnp.sum(out_cnt, dtype=jnp.int64)
     return (start, cnt, perm, out_cnt, None), total
@@ -191,12 +304,16 @@ def _key_range(sorted_key, nv):
     return sorted_key[0], sorted_key[jnp.maximum(nv - 1, 0)]
 
 
-def build_key_stats(sorted_key, nv):
-    """int64[3] ``[nv, kmin, kmax]`` of a prepared build side: what the
-    host needs to choose the probe (:func:`direct_table_size`), in one
-    array so it is one fetch."""
-    kmin, kmax = _key_range(sorted_key, nv)
-    return jnp.stack([nv, kmin, kmax]).astype(jnp.int64)
+def build_key_stats(sorted_key, nv, packing: KeyPacking | None = None):
+    """int64[1 + 2k] ``[nv, lo_0, hi_0, ...]`` of a prepared build side:
+    the valid rows and each key's smallest and largest value, what the
+    host needs to choose the probe (:func:`packed_key_span`,
+    :func:`direct_table_size`), in one array so it is one fetch."""
+    if packing is None:
+        kmin, kmax = _key_range(sorted_key, nv)
+        return jnp.stack([nv, kmin, kmax]).astype(jnp.int64)
+    ranges = jnp.stack([packing.lo, packing.hi], axis=1).reshape(-1)
+    return jnp.concatenate([nv.astype(jnp.int64)[None], ranges])
 
 
 #: a direct-address table may always have this many entries (4 MB of
@@ -235,8 +352,9 @@ def _offset_dtype(key_dtype):
 
 
 def build_direct_table(sorted_key, perm, nv, size: int) -> DirectBuild:
-    """Direct-address table over a prepared build side (the output of
-    :func:`build_prepare_fast`) whose key range fits ``size`` entries.
+    """Direct-address table over a prepared build side (the first three
+    outputs of :func:`build_prepare_fast`) whose key range fits ``size``
+    entries.
 
     Every run of equal keys in the sorted build writes its first
     position, and its end, at ``key - kmin``: two scatters from the
@@ -265,20 +383,19 @@ def build_direct_table(sorted_key, perm, nv, size: int) -> DirectBuild:
                        kmin, kmax)
 
 
-def probe_direct(lbatch: ColumnBatch, lkey: int, build: DirectBuild,
-                 join_type: str):
+def probe_direct(lbatch: ColumnBatch, lkey, build: DirectBuild,
+                 join_type: str, packing: KeyPacking | None = None):
     """:func:`probe_fast`'s contract against a :class:`DirectBuild`: the
     stream key's run is read at ``key - kmin``, one gather of table rows
     over the stream rows (4 ms for 2^20 rows whatever the table's size;
     two gathers from two columns cost 18-25 ms; PERF.md, PR 28).  The
     range test comes before the subtraction, so a far key cannot wrap
-    into the table."""
-    col = lbatch.columns[lkey]
-    lvalid = col.validity & lbatch.row_mask()
-    wide = _offset_dtype(col.data.dtype)
-    in_range = lvalid & (col.data >= build.kmin) & (col.data <= build.kmax)
+    into the table.  ``packing`` as in :func:`probe_fast`."""
+    data, lvalid = _stream_key(lbatch, lkey, packing)
+    wide = _offset_dtype(data.dtype)
+    in_range = lvalid & (data >= build.kmin) & (data <= build.kmax)
     idx = jnp.where(in_range,
-                    col.data.astype(wide) - build.kmin.astype(wide),
+                    data.astype(wide) - build.kmin.astype(wide),
                     0).astype(jnp.int32)
     run = build.table[idx]
     start = run[:, 0]
@@ -335,7 +452,7 @@ def join_total(lbatch: ColumnBatch, rbatch: ColumnBatch,
 
 
 def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
-                            out_cap: int):
+                            out_cap: int, stacked: bool = False):
     """Phase 2: gather plan into a static ``out_cap`` output from
     precomputed probe arrays (no sorts here).
 
@@ -343,6 +460,14 @@ def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
       li/ri: int32[out_cap] source row per output slot (clamped in range),
       l_take/r_take: bool[out_cap] — False means that side is all-null for
       the slot (outer non-matches) or the slot is padding.
+
+    ``stacked`` (here and in :func:`gather_join_output`) picks the plan
+    the one-chip executor runs since PR 33: the left row of each slot by
+    one scatter and a running maximum, per-row numbers and column leaves
+    moved in stacked gathers (501.7 -> 99.5 ms at 2^20 slots x 9 columns,
+    PERF.md PR 33).  A mesh region's join body keeps the plan it was
+    measured with (a search of the offsets, a gather a leaf) until a PR
+    measures the four-chip cell with the other one and deletes this one.
     """
     start, cnt, rsort_perm, out_cnt, unmatched_r = probe_arrays
     offsets = jnp.concatenate(
@@ -352,12 +477,31 @@ def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
 
     j = jnp.arange(out_cap, dtype=jnp.int32)
     in_left = j < total_left
-    # left row for slot j: last offset <= j. offsets is non-decreasing.
-    li = (jnp.searchsorted(offsets, j, side="right") - 1).astype(jnp.int32)
-    li = jnp.clip(li, 0, cl - 1)
-    k = j - offsets[li]
-    matched = in_left & (k < cnt[li])
-    pos = jnp.clip(start[li] + k, 0, rsort_perm.shape[0] - 1)
+    if stacked:
+        # left row for slot j: the last row with output whose offset is
+        # <= j.  Those offsets rise strictly, so each such row writes its
+        # index at its offset (unique indices: no sort; the others go
+        # past the end, each to an index of its own) and a running
+        # maximum fills the slots between.  A search of the offsets is
+        # log2(cl) dependent gathers a slot.
+        row = jnp.arange(cl, dtype=jnp.int32)
+        at = jnp.where((out_cnt > 0) & (offsets < out_cap), offsets,
+                       out_cap + row)
+        li = lax.cummax(jnp.zeros(out_cap, jnp.int32).at[at].set(
+            row, unique_indices=True, mode="drop"))
+        # one gather of rows for the three per-row numbers a slot needs
+        off, n, first = jnp.stack([offsets, cnt, start], axis=1)[li].T
+        k = j - off
+        matched = in_left & (k < n)
+        pos = jnp.clip(first + k, 0, rsort_perm.shape[0] - 1)
+    else:
+        # left row for slot j: last offset <= j. offsets is non-decreasing.
+        li = (jnp.searchsorted(offsets, j, side="right") - 1).astype(
+            jnp.int32)
+        li = jnp.clip(li, 0, cl - 1)
+        k = j - offsets[li]
+        matched = in_left & (k < cnt[li])
+        pos = jnp.clip(start[li] + k, 0, rsort_perm.shape[0] - 1)
     ri = rsort_perm[pos]
     l_take = in_left
     r_take = matched
@@ -381,14 +525,18 @@ def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
 
 def gather_join_output(lbatch: ColumnBatch, rbatch: ColumnBatch,
                        li, ri, l_take, r_take, total,
-                       schema: T.Schema, include_right: bool) -> ColumnBatch:
-    """Build the output batch from a join_indices plan."""
-    out_cols: list[DeviceColumn] = []
-    for c in lbatch.columns:
-        out_cols.append(_take_side(c, li, l_take))
+                       schema: T.Schema, include_right: bool,
+                       stacked: bool = False) -> ColumnBatch:
+    """Build the output batch from a join_indices plan; ``stacked``: each
+    side's leaves move in one gather of rows a dtype
+    (:func:`_stacked_side`) instead of a gather a leaf."""
+    def side(columns, idx, take):
+        if stacked:
+            return _stacked_side(columns, idx, take)
+        return [_take_side(c, idx, take) for c in columns]
+    out_cols = side(lbatch.columns, li, l_take)
     if include_right:
-        for c in rbatch.columns:
-            out_cols.append(_take_side(c, ri, r_take))
+        out_cols += side(rbatch.columns, ri, r_take)
     return ColumnBatch(out_cols, total.astype(jnp.int32), schema)
 
 
@@ -400,3 +548,37 @@ def _take_side(c: DeviceColumn, idx, take) -> DeviceColumn:
                             jnp.where(validity, c.lengths[idx], 0))
     data = jnp.where(validity, c.data[idx], jnp.zeros((), c.data.dtype))
     return DeviceColumn(data, validity, c.dtype)
+
+
+def _stacked_side(columns: Sequence[DeviceColumn], idx, take):
+    """:func:`_take_side` over all of a side's columns at once.  Leaves of
+    one dtype -- validity flags; each width of number; string lengths
+    with the int32 data; byte matrices side by side -- are stacked
+    ``[capacity, k]`` and read by ONE gather of rows: a gather's cost on
+    the chip is its index count, hardly its row width (the move
+    ``ops/kernels._move_rows`` makes for ``compact``; PERF.md, PRs 32
+    and 33)."""
+    n = idx.shape[0]
+    stacks: dict = {}
+    for c in columns:
+        for leaf in (c.validity, c.data, c.lengths):
+            if leaf is not None:
+                stacks.setdefault(leaf.dtype, []).append(
+                    leaf.reshape(leaf.shape[0], -1))
+    moved = {}
+    for dtype, leaves in stacks.items():
+        rows = jnp.concatenate(leaves, axis=1)[idx]
+        bounds = np.cumsum([x.shape[1] for x in leaves])[:-1]
+        moved[dtype] = iter(jnp.split(rows, bounds, axis=1))
+    out = []
+    for c in columns:
+        validity = next(moved[c.validity.dtype]).reshape(n) & take
+        data = next(moved[c.data.dtype]).reshape((n,) + c.data.shape[1:])
+        data = jnp.where(validity[(...,) + (None,) * (data.ndim - 1)],
+                         data, jnp.zeros((), data.dtype))
+        lengths = None
+        if c.is_var_width:
+            lengths = jnp.where(
+                validity, next(moved[c.lengths.dtype]).reshape(n), 0)
+        out.append(DeviceColumn(data, validity, c.dtype, lengths))
+    return out

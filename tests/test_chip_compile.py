@@ -166,6 +166,43 @@ def test_direct_probe_compiles_for_v5e(one_chip, table):
                  _shapes(lb, one_chip), build)
 
 
+def test_packed_two_key_build_and_search_probe_compile_for_v5e(one_chip):
+    """TPC-DS q93's join at SF10: ``store_returns`` (2.88M rows in 2^22
+    slots) prepared from (item int32, ticket number int64) packed into
+    one int64 key (ranges, packing and the sort by it in one program,
+    with the stats of its one fetch), and the ``left`` search probe of a
+    2^20-row ``store_sales`` batch that packs its own two keys first."""
+    from spark_rapids_tpu.host.batch import HostBatch
+    from spark_rapids_tpu.ops.join import (build_key_stats,
+                                           build_prepare_packed, probe_fast)
+    it, lt = T.IntegerType(), T.LongType()
+
+    def batch(prefix, extra, cap):
+        schema = T.Schema(
+            [T.StructField(prefix + "item_sk", it, True),
+             T.StructField(prefix + "ticket_number", lt, True)]
+            + [T.StructField(prefix + n, t, True) for n, t in extra])
+        return HostBatch.from_pydict(
+            {f.name: np.arange(9).astype(f.data_type.np_dtype)
+             for f in schema}, schema).to_device(capacity=cap)
+    sales = batch("ss_", [("customer_sk", it), ("quantity", it),
+                          ("sales_price", T.DoubleType())], 1 << 20)
+    returns = batch("sr_", [("reason_sk", it), ("return_quantity", it)],
+                    1 << 22)
+
+    def build(right):
+        prep, packing = build_prepare_packed(right, (0, 1))
+        return prep, packing, build_key_stats(prep[0], prep[2], packing)
+    _compile(build, _shapes(returns, one_chip))
+    prep, packing, stats = jax.eval_shape(build, returns)
+    assert prep[0].shape == (1 << 22,) and str(prep[0].dtype) == "int64"
+    assert stats.shape == (5,)
+    _compile(lambda left, p, k: probe_fast(left, (0, 1), *p, "left",
+                                           k)[0][:-1],
+             _shapes(sales, one_chip), _shapes(prep, one_chip),
+             _shapes(packing, one_chip))
+
+
 def test_sparse_build_and_semi_probe_compile_for_v5e(one_chip):
     """TPC-H Q18's semi-join at SF1: a few thousand int32 keys spread
     over 1.5M values, in the 2^21 slots the aggregate above it left them

@@ -4,6 +4,8 @@ Mirrors the reference's join coverage (integration_tests join_test.py:
 all join types x key types x nulls; tests/GpuHashJoinSuite) with fuzzed
 key data including nulls, NaN, -0.0 and duplicate keys.
 """
+import datetime as dt
+
 import numpy as np
 import pytest
 
@@ -344,6 +346,47 @@ def test_direct_and_search_probe_agree(case, jt, monkeypatch):
     assert sorted(collect_host(plan), key=repr) == sorted(rows, key=repr)
 
 
+@pytest.mark.parametrize("room", ["tight", "padded"])
+@pytest.mark.parametrize("jt", ["inner", "left", "semi", "anti", "full"])
+def test_the_two_gather_plans_agree_leaf_for_leaf(rng, jt, room):
+    """The one-chip executor's plan (a scatter and a running maximum for
+    each slot's left row, stacked gathers) against the one a mesh
+    region's join body keeps (a search of the offsets, a gather a leaf):
+    the same batch, leaf for leaf, padding included."""
+    import jax
+    from spark_rapids_tpu.columnar.batch import round_capacity
+    from spark_rapids_tpu.host.batch import HostBatch
+    from spark_rapids_tpu.ops.join import (gather_join_output,
+                                           join_indices_from_probe,
+                                           join_probe)
+    nl, nr = 70, 50
+    lb = HostBatch.from_pydict({
+        "lk": [None if i % 11 == 0 else int(x)
+               for i, x in enumerate(rng.integers(0, 9, nl))],
+        "lv": [int(x) for x in rng.integers(-50, 50, nl)],
+        "ls": [f"s{x}" if x % 4 else None for x in rng.integers(0, 30, nl)],
+    }, L_SCHEMA).to_device()
+    rb = HostBatch.from_pydict({
+        "rk": [None if i % 7 == 0 else int(x)
+               for i, x in enumerate(rng.integers(0, 12, nr))],
+        "rv": [None if i % 5 == 0 else float(i) for i in range(nr)],
+    }, R_SCHEMA).to_device()
+    probe, total = join_probe(lb, rb, (0,), (0,), jt)
+    out_cap = round_capacity(int(total)) * (1 if room == "tight" else 4)
+    include_right = jt not in ("semi", "anti")
+    outs = []
+    for stacked in (False, True):
+        plan = join_indices_from_probe(lb.capacity, probe, jt, out_cap,
+                                       stacked=stacked)
+        outs.append(gather_join_output(lb, rb, *plan, None, include_right,
+                                       stacked=stacked))
+    assert int(outs[0].num_rows) == int(total) > 0
+    a, b = (jax.tree_util.tree_leaves(o) for o in outs)
+    assert len(a) == len(b)
+    for x, y in zip(a, b):
+        assert x.dtype == y.dtype and np.array_equal(x, y, equal_nan=True)
+
+
 def test_direct_table_rule():
     from spark_rapids_tpu.ops.join import direct_table_size
     assert direct_table_size(31, 2451000, 2451030, 1 << 17) == 32
@@ -358,3 +401,253 @@ def test_direct_table_rule():
     assert direct_table_size(3, 0, 1 << 20, 1 << 18) == 1 << 21
     assert direct_table_size(3, 0, 1 << 21, 1 << 18) is None
     assert direct_table_size(2, I64.min, I64.max, 1 << 20) is None
+
+
+# ------------------------------------------------------------------
+# A join on several integral keys streams: the keys are packed into one
+# mixed-radix int64 key from the build side's own ranges (ops/join.py
+# build_prepare_packed), and the single-key probes do the rest.
+
+def _packed_cases():
+    """name -> (key types, stream key tuples, build key tuples, path that
+    must run, filter keeping only the first N build rows or None).  Every
+    stream side reaches one below and one above the build's range of
+    each key, so a packing that subtracts before it tests the range
+    wraps into a neighbouring run and invents a match."""
+    rng = np.random.default_rng(33)
+    it, lt, dt = T.IntegerType(), T.LongType(), T.DateType()
+
+    def draw(n, *ranges, holes=0.08):
+        return [tuple(None if rng.random() < holes
+                      else int(rng.integers(lo, hi + 1)) for lo, hi in ranges)
+                for _ in range(n)]
+    big = 1 << 40
+    wide = [-big, -3, 0, 7, big, big + 1]
+
+    def far(n):       # an int32 key beside an int64 one: 11 x 2^41 pairs
+        return [(None if rng.random() < 0.08 else int(rng.integers(-6, 6)),
+                 None if rng.random() < 0.08 else int(rng.choice(wide)))
+                for _ in range(n)]
+    grid = [(a, b) for a in range(-3, 4) for b in range(10, 15)]
+    return {
+        # the stream holds (a, hi + 1) and (a, lo - 1) for every a: the
+        # build holds their wrapped neighbours (a + 1, lo) and (a - 1, hi)
+        "int_int_dense": ((it, it),
+                          [(a, b) for a in range(-4, 5) for b in (9, 15)]
+                          + draw(46, (-4, 4), (9, 15)),
+                          grid + draw(25, (-3, 3), (10, 14)), "direct", None),
+        "int_long_sparse": ((it, lt),
+                            [(-6, -big - 1), (5, big + 2), (-5, big + 2),
+                             (6, -big)] + far(60),
+                            [(-5, -big), (5, big + 1), (-4, -big)] + far(50),
+                            "search", None),
+        "date_int": ((dt, it),
+                     draw(64, (10956, 10990), (-2, 3)),
+                     draw(50, (10957, 10989), (-1, 2)), "direct", None),
+        "three_keys": ((it, lt, it),
+                       draw(64, (0, 4), (99, 103), (-2, 1)),
+                       draw(60, (1, 3), (100, 102), (-1, 0)), "direct",
+                       None),
+        "long_long_sparse": ((lt, lt),
+                             draw(64, (-2, 1 << 21), (0, 1 << 20)),
+                             draw(50, (0, 1 << 21), (1, 1 << 20)), "search",
+                             None),
+        "one_row_build": ((it, lt), draw(64, (0, 3), (5, 7)),
+                          [(2, 6), (1, 5), (3, 7)], "direct", 1),
+        "empty_build": ((it, lt), draw(64, (0, 3), (5, 7)),
+                        [(2, 6), (1, 5)], "search", 0),
+        "null_keyed_build": ((it, it), draw(64, (0, 3), (5, 7)),
+                             [(None, 6), (2, None), (None, None)], "search",
+                             None),
+    }
+
+
+_PACKED_CASES = _packed_cases()
+
+
+def _packed_sides(case):
+    from spark_rapids_tpu.exec.basic import FilterExec
+    ktypes, lrows, rrows, path, keep = _PACKED_CASES[case]
+    names = [f"k{i}" for i in range(len(ktypes))]
+    lschema = T.Schema([T.StructField("l" + n, t, True)
+                        for n, t in zip(names, ktypes)]
+                       + [T.StructField("lv", T.LongType(), True)])
+    rschema = T.Schema([T.StructField("r" + n, t, True)
+                        for n, t in zip(names, ktypes)]
+                       + [T.StructField("rv", T.LongType(), True)])
+
+    def side(prefix, rows, schema, **kw):
+        data = {prefix + n: [r[i] for r in rows]
+                for i, n in enumerate(names)}
+        data[prefix + "v"] = list(range(len(rows)))
+        return LocalScanExec.from_pydict(data, schema, **kw)
+    left = side("l", lrows, lschema, rows_per_batch=37)
+    right = side("r", rrows, rschema)
+    if keep is not None:
+        right = FilterExec(col("rv") < lit(keep), right)
+        rrows = rrows[:keep]
+    return (left, right, [col("l" + n) for n in names],
+            [col("r" + n) for n in names], lrows, rrows, path)
+
+
+def _pandas_pairs(lrows, rrows, jt):
+    """(lv, rv) of every output row, from pandas: an inner merge of the
+    rows whose keys are all there, and the join types' rules around it."""
+    import pandas as pd
+    keys = list(range(len(lrows[0])))
+
+    def frame(rows, name):
+        df = pd.DataFrame([list(r) for r in rows], columns=keys,
+                          dtype=object)
+        df[name] = range(len(rows))
+        return df.dropna(subset=keys)
+    m = frame(lrows, "lv").merge(frame(rrows, "rv"), on=keys)
+    inner = sorted(zip(m.lv.tolist(), m.rv.tolist()))
+    lhit, rhit = {a for a, _ in inner}, {b for _, b in inner}
+    lmiss = [(a, None) for a in range(len(lrows)) if a not in lhit]
+    rmiss = [(None, b) for b in range(len(rrows)) if b not in rhit]
+    return {"inner": inner, "left": inner + lmiss,
+            "right": inner + rmiss, "full": inner + lmiss + rmiss,
+            "semi": [(a, None) for a in sorted(lhit)],
+            "anti": lmiss}[jt]
+
+
+@pytest.mark.parametrize("jt", ["inner", "left", "right", "semi", "anti",
+                                "full"])
+@pytest.mark.parametrize("case", list(_PACKED_CASES))
+def test_packed_multi_key_join(case, jt, monkeypatch):
+    from spark_rapids_tpu.obs.registry import get_registry
+    left, right, lk, rk, lrows, rrows, path = _packed_sides(case)
+    plan = JoinExec(left, right, lk, rk, jt)
+    before = get_registry().counters()
+    rows = collect_device(plan)
+    moved = get_registry().counters_since(before)
+    assert moved.get("join.keys.packed") == 1
+    assert "join.keys.unpackable" not in moved
+    assert "join.probe.sorted" not in moved
+    if jt == "right":       # runs side-swapped: the stream is `right`
+        assert moved.get("join.probe.direct", 0) \
+            + moved.get("join.probe.search", 0) == 1
+    else:
+        other = "search" if path == "direct" else "direct"
+        assert moved.get(f"join.probe.{path}") == 2      # 2 stream batches
+        assert f"join.probe.{other}" not in moved
+    # the probes' totals: every row but a full join's unmatched build rows
+    tail = sum(1 for a, _ in _pandas_pairs(lrows, rrows, "full")
+               if a is None) if jt == "full" else 0
+    assert moved.get("join.probe.rows_out", 0) == len(rows) - tail
+
+    nk = len(lk)
+    lv = nk
+    rv = None if jt in ("semi", "anti") else 2 * nk + 1
+    got = sorted(((r[lv], None if rv is None else r[rv]) for r in rows),
+                 key=repr)
+    assert got == sorted(_pandas_pairs(lrows, rrows, jt), key=repr)
+
+    def keys(cells):        # a date comes back as one, it went in as days
+        return tuple((c - dt.date(1970, 1, 1)).days
+                     if isinstance(c, dt.date) else c for c in cells)
+    for r in rows:          # the columns of a row come from its rows
+        if r[lv] is not None:
+            assert keys(r[:nk]) == lrows[r[lv]]
+        if rv is not None and r[rv] is not None:
+            assert keys(r[nk + 1:rv]) == rrows[r[rv]]
+
+    # the same plan on the sort path, and on the host: the same rows
+    assert sorted(collect_host(plan), key=repr) == sorted(rows, key=repr)
+    monkeypatch.setattr(JoinExec, "_use_fast_path", lambda self: False)
+    before = get_registry().counters()
+    assert sorted(collect_device(plan), key=repr) == sorted(rows, key=repr)
+    moved = get_registry().counters_since(before)
+    assert moved.get("join.probe.sorted") and "join.keys.packed" not in moved
+
+
+@pytest.mark.parametrize("why", ["product_past_int64", "string_key",
+                                 "double_key", "boolean_key"])
+def test_unpackable_keys_stay_on_the_sort_path(why):
+    from spark_rapids_tpu.obs.registry import get_registry
+    ktype, lvals, rvals = {
+        "product_past_int64": (T.LongType(), [I64.min, 5, I64.max, 0],
+                               [0, I64.max, I64.min, 5]),
+        "string_key": (T.StringType(), ["a", "b", None, "c"],
+                       ["c", "b", "d", None]),
+        "double_key": (T.DoubleType(), [1.5, 2.0, None, -0.0],
+                       [0.0, 2.0, 3.5, None]),
+        "boolean_key": (T.BooleanType(), [True, False, None, True],
+                        [True, None, True, True]),
+    }[why]
+    first = [1, 2, 3, 4]
+    lschema = T.Schema([T.StructField("a", T.LongType(), True),
+                        T.StructField("b", ktype, True)])
+    rschema = T.Schema([T.StructField("c", T.LongType(), True),
+                        T.StructField("d", ktype, True)])
+    if why == "product_past_int64":     # both keys span the whole int64
+        first, rfirst = lvals, rvals
+    else:
+        rfirst = [4, 2, 9, 3]
+    left = LocalScanExec.from_pydict({"a": first, "b": lvals}, lschema)
+    right = LocalScanExec.from_pydict({"c": rfirst, "d": rvals}, rschema)
+    plan = JoinExec(left, right, [col("a"), col("b")],
+                    [col("c"), col("d")], "left")
+    before = get_registry().counters()
+    rows = collect_device(plan)
+    moved = get_registry().counters_since(before)
+    assert moved.get("join.keys.unpackable") == 1
+    assert moved.get("join.probe.sorted") == 1
+    assert not any(k in moved for k in (
+        "join.keys.packed", "join.probe.direct", "join.probe.search"))
+    assert sorted(rows, key=repr) == sorted(collect_host(plan), key=repr)
+    assert len(rows) == 4 and any(r[2] is not None for r in rows)
+
+
+def test_packed_key_span_rule():
+    from spark_rapids_tpu.ops.join import packed_key_span
+    assert packed_key_span(5, [1, 10, -2, 2]) == 50
+    assert packed_key_span(1, [7, 7, 3, 3, 9, 9]) == 1
+    # q93 at SF10: 56,920 items x 9.6M tickets, 40 bits
+    assert packed_key_span(2_880_404, [1, 56_920, 1, 9_601_343]) \
+        == 56_920 * 9_601_343
+    assert packed_key_span(2, [0, (1 << 31) - 1, 0, (1 << 31) - 1]) == 1 << 62
+    assert packed_key_span(2, [0, (1 << 31) - 1, 0, (1 << 32) - 1]) is None
+    assert packed_key_span(2, [I64.min, I64.max, 0, 0]) is None
+    # an empty build packs whatever its (meaningless) ranges say
+    assert packed_key_span(0, [I64.max, I64.min, I64.max, I64.min]) == 1
+
+
+def test_builds_of_other_ranges_run_the_same_programs():
+    """The ranges and radices of a packing are arguments of the programs,
+    not part of them: a build with other keys compiles nothing."""
+    from spark_rapids_tpu.exec import joins as J
+    from spark_rapids_tpu.obs.registry import get_registry
+    schema = lambda p: T.Schema([
+        T.StructField(p + "a", T.IntegerType(), True),
+        T.StructField(p + "b", T.LongType(), True),
+        T.StructField(p + "v", T.LongType(), True)])
+
+    def run(shift, stride):
+        rng = np.random.default_rng(abs(shift))
+        ra = [int(x) + shift for x in rng.integers(0, 9, 50)]
+        rb = [int(x) * stride - shift for x in rng.integers(0, 40, 50)]
+        pick = rng.integers(0, 50, 64)
+        left = LocalScanExec.from_pydict(
+            {"la": [ra[i] for i in pick], "lb": [rb[i] for i in pick],
+             "lv": list(range(64))}, schema("l"))
+        right = LocalScanExec.from_pydict(
+            {"ra": ra, "rb": rb, "rv": list(range(50))}, schema("r"))
+        plan = JoinExec(left, right, [col("la"), col("lb")],
+                        [col("ra"), col("rb")], "inner")
+        rows = collect_device(plan)
+        assert len(rows) >= 64 and all(
+            (r[0], r[1]) == (r[3], r[4]) for r in rows)
+
+    run(3, 1 << 33)                     # warms every program at this shape
+    programs = (J._jit_build_prep, J._jit_probe_fast, J._jit_gather)
+    seen = [p.signature_count() for p in programs]
+    before = get_registry().counters()
+    run(-1_000_000, 1 << 35)
+    run(77, 1 << 30)
+    moved = get_registry().counters_since(before)
+    assert moved.get("join.probe.search") == 2 and \
+        moved.get("join.keys.packed") == 2
+    assert "compile_count" not in moved
+    assert [p.signature_count() for p in programs] == seen

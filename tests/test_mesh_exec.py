@@ -319,6 +319,45 @@ def test_mesh_join_partitioned_matches_oracle(rng, how):
     assert dev == host and len(dev) > 0
 
 
+@pytest.mark.parametrize("threshold", [None, 0])
+@pytest.mark.parametrize("how", ["inner", "left", "semi"])
+def test_mesh_join_on_two_keys_streams(rng, how, threshold):
+    """A MeshJoinExec island on two integral keys takes the packed-key
+    probes through the methods it inherits (JoinExec._prepare_build),
+    with the build replicated and with it partitioned: no stream shard
+    is sorted together with the build."""
+    from spark_rapids_tpu.exec.core import collect_host
+    from spark_rapids_tpu.obs.registry import get_registry
+    conf = dict(MESH_CONF)
+    if threshold is not None:
+        conf["spark.rapids.tpu.mesh.join.buildThresholdBytes"] = threshold
+    sm = TpuSession(conf)
+    fact = sm.from_pydict(_data(rng), SCHEMA, partitions=4,
+                          rows_per_batch=64)
+    dim_schema = T.Schema([T.StructField("dk", T.IntegerType(), True),
+                           T.StructField("dv", T.LongType(), True),
+                           T.StructField("name", T.StringType(), True)])
+    keys = [(k, v) for k in range(0, 17, 2) for v in (-7, 0, 1 << 40)]
+    dim = sm.from_pydict(
+        {"dk": [k for k, _ in keys] + [None], "dv": [v for _, v in keys] + [3],
+         "name": [f"n{i}" for i in range(len(keys) + 1)]},
+        dim_schema, partitions=1)
+    fact = fact.select(col("k"), (col("v") % 2 * (1 << 40)).alias("v2"),
+                       col("g"))
+    out = fact.join(dim, on=[("k", "dk"), ("v2", "dv")], how=how)
+    assert "MeshJoinExec" in out.explain()
+    before = get_registry().counters()
+    dev = _sorted_rows(out.collect())
+    moved = get_registry().counters_since(before)
+    assert moved.get("join.keys.packed", 0) >= 1
+    assert "join.probe.sorted" not in moved
+    assert "join.keys.unpackable" not in moved
+    assert moved.get("join.probe.search", 0) >= 1
+    ov, meta = out._overridden(quiet=True)
+    host = _sorted_rows(collect_host(meta.exec_node, sm.conf))
+    assert dev == host and len(dev) > 0
+
+
 def test_mesh_join_partitioned_large_build(rng):
     """Build side larger than one device's fair shard still joins
     correctly: every build row is present exactly once across the mesh
