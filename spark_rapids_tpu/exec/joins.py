@@ -40,7 +40,7 @@ from spark_rapids_tpu.ops.join import (JOIN_TYPES, DirectBuild, PackedBuild,
                                        direct_table_size, gather_join_output,
                                        join_indices_from_probe, join_probe,
                                        matched_build_rows, packed_key_span,
-                                       probe_direct, probe_fast)
+                                       probe_direct, probe_fast, probe_merges)
 
 __all__ = ["JoinExec", "CrossJoinExec", "BroadcastHashJoinExec"]
 
@@ -102,10 +102,12 @@ def prepare_fast_build(rb, rkeys: tuple):
     are dense (ops/join.direct_table_size), make the direct-address
     table.  Returns a :class:`DirectBuild` for ``join_probe_direct`` or
     the sorted ``(sorted_key, perm, nv, run_len)`` for
-    ``join_probe_fast``, inside a :class:`PackedBuild` where the keys
-    were packed (all pytrees of device arrays), or None where several
-    keys span more than an ``int64`` holds: that build stays on the sort
-    path."""
+    ``join_probe_fast`` (which merges each stream batch into the sorted
+    keys, or steps through them where the batch is far smaller than the
+    build: ops/join.probe_merges), inside a :class:`PackedBuild` where
+    the keys were packed (all pytrees of device arrays), or None where
+    several keys span more than an ``int64`` holds: that build stays on
+    the sort path."""
     prep, stats = _jit_build_prep(
         rb, rkeys if len(rkeys) > 1 else rkeys[0])
     build, packing = _unpacked(prep)
@@ -383,6 +385,9 @@ class JoinExec(PlanNode):
                 direct = isinstance(build, DirectBuild)
                 get_registry().inc(
                     "join.probe.direct" if direct else "join.probe.search")
+                if not direct and probe_merges(lb2.capacity,
+                                               build[0].shape[0]):
+                    get_registry().inc("join.probe.search.merged")
                 run = _jit_probe_direct if direct else _jit_probe_fast
                 probe_arrays, total_dev = run(lb2, prep, lkey, stream_jt)
             return lb2, total_dev, probe_arrays

@@ -26,21 +26,26 @@ exec/joins.py), matching the reference's build-side flip.
 
 A join on ONE integral key streams: the build side is sorted once
 (:func:`build_prepare_fast`) and each stream batch is probed against it
-with no sort.  How the probe finds a key's run in the sorted build is
-chosen once per build, on the host, from the key range the build holds
-(:func:`direct_table_size`):
+(never ranked together with it, as the sort path does).  How the probe
+finds a key's run in the sorted build is chosen once per build, on the
+host, from the key range the build holds (:func:`direct_table_size`):
 
 * **dense keys** (surrogate keys, day numbers: the range is no wider
   than a table the engine can always afford): :func:`build_direct_table`
   makes ``table[k - kmin] = (run start, run length)`` once, and
   :func:`probe_direct` reads it by address: one gather of table rows a
   stream batch, whatever the build's size;
-* **anything else** (a hashed id, a natural key): :func:`probe_fast`,
-  one ``searchsorted`` over the sorted keys (``log2(capacity)``
-  dependent gathers over every stream row) and the run length the build
-  keeps at each run's first row.
+* **anything else** (a hashed id, a natural key): :func:`probe_fast`
+  finds each stream key's run in the sorted keys, by a **merge** where
+  the shapes say that is cheaper (:func:`probe_merges`: the batch's keys
+  are sorted in among the build's, two scans give every run's start and
+  end, one sort puts them back in stream order: scans and sorts only,
+  no gather), and by **steps** where a small stream batch meets a far
+  larger build (one ``searchsorted``, ``log2(capacity)`` dependent
+  gathers a stream row, and the run length the build keeps at each
+  run's first row).
 
-Both return the same ``(start, cnt, perm, out_cnt)`` and ``total``.
+All return the same ``(start, cnt, perm, out_cnt)`` and ``total``.
 
 A join on SEVERAL integral keys streams the same way: the keys are packed
 into ONE mixed-radix ``int64`` key, ``sum((k_i - lo_i) * radix_i)`` with
@@ -75,7 +80,8 @@ from spark_rapids_tpu.ops.sort import encode_key_operands
 
 __all__ = ["join_probe", "join_total", "join_indices_from_probe",
            "gather_join_output", "JOIN_TYPES", "DirectBuild", "KeyPacking",
-           "PackedBuild", "direct_table_size", "packed_key_span"]
+           "PackedBuild", "direct_table_size", "packed_key_span",
+           "probe_merges"]
 
 JOIN_TYPES = ("inner", "left", "semi", "anti", "full", "cross")
 
@@ -171,10 +177,11 @@ def build_prepare_fast(rbatch: ColumnBatch, rkey: int):
     globally sorted (a probe's hit must lie below ``nv``, which keeps
     genuine max-valued keys — they live at positions < nv), and at the
     first row of every run of equal keys the run's length, so that ONE
-    search finds a key's whole run.  This is the streaming-join analog of
-    the reference's build-side hash table (GpuHashJoin build side,
-    GpuHashJoin.scala:193-249): built once, probed per stream batch with
-    no per-batch sort.
+    search finds a key's whole run (:func:`probe_fast`'s stepping
+    branch; its merge counts the run itself).  This is the streaming-join
+    analog of the reference's build-side hash table (GpuHashJoin build
+    side, GpuHashJoin.scala:193-249): built once, probed per stream
+    batch.
     """
     col = rbatch.columns[rkey]
     return _sort_build(col.data, col.validity & rbatch.row_mask())
@@ -276,23 +283,84 @@ def _stream_key(lbatch: ColumnBatch, lkey, packing: KeyPacking | None):
     return col.data, col.validity & lbatch.row_mask()
 
 
-def probe_fast(lbatch: ColumnBatch, lkey, sorted_key, perm, nv, run_len,
-               join_type: str, packing: KeyPacking | None = None):
-    """Per-stream-batch probe against a prepared build side: ONE
-    ``searchsorted`` (``log2(capacity)`` dependent gathers a stream row;
-    a second one for the run's end cost as much again, 1,071 against 586
-    ms for 2^20 rows in a 2^22-entry ``int64`` build, PERF.md PR 33),
-    then the key and the run length read where it landed.  Zero sorts.
-    Same contract as the heavy phase of :func:`join_probe` (without
-    full-outer bookkeeping — streaming full outer tracks matched build
-    rows in the gather phase instead); ``start`` means nothing where
-    ``cnt`` is 0.  With a ``packing``, ``lkey`` names the key columns to
-    pack first."""
-    data, lvalid = _stream_key(lbatch, lkey, packing)
+#: :func:`probe_fast` merges while the build has at most this many slots a
+#: stream-batch slot.  The merge costs 6-7 ns a SORTED row (``cl + cr`` of
+#: them), the steps 0.34-0.55 us a STREAM row against a 2^22-entry build:
+#: they break even near 57-78 build slots a stream slot, and at 32 the
+#: merge still wins twice over for ``int32`` and ``int64`` keys alike
+#: (PERF.md, PR 35: both branches on the chip at five shapes).
+MERGE_MAX_BUILD_RATIO = 32
+
+
+def probe_merges(cl: int, cr: int) -> bool:
+    """The one rule that picks :func:`probe_fast`'s branch, from the
+    capacities of the stream batch and of the build alone (static, so the
+    choice is made while tracing): merge, unless the build is so much
+    larger than the batch that sorting it again costs more than stepping
+    through it.  The executor counts by the same rule
+    (``join.probe.search.merged``)."""
+    return cr <= MERGE_MAX_BUILD_RATIO * cl
+
+
+def _runs_by_merge(sorted_key, nv, data):
+    """``(start, cnt)`` of each stream key's run in the sorted build, by a
+    merge: sorts and scans, no gather.
+
+    Build keys then stream keys are sorted together by key, stably, so
+    within a run of equal keys the build's rows come first, in their
+    order (a tie broken by a second sort key instead gave the same
+    arrays 10 % slower, PERF.md PR 35).  The running count of build rows is then, at a stream row, the
+    number of build keys <= its key (its run's end); that count *before*
+    the first element of a run of equal keys is the number of build keys
+    below them (the run's start), carried along the run by a running
+    maximum (it never falls).  Both are clamped to ``nv``: the build's
+    padding slots (rewritten to the dtype's maximum, after every valid
+    row) never count, and a genuine maximum-valued key below ``nv`` still
+    matches.  One sort by position puts the answers back in stream order
+    (not a scatter: into this many slots the TPU compiler sorts before
+    it anyway)."""
+    cr = sorted_key.shape[0]
+    keys = jnp.concatenate([sorted_key, data])
+    iota = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    skey, src = lax.sort([keys, iota], num_keys=1, is_stable=True)
+    from_build = src < cr
+    seen = jnp.cumsum(from_build, dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), skey[1:] != skey[:-1]])
+    below = lax.cummax(jnp.where(first, seen - from_build, 0))
+    _, start, end = lax.sort(
+        [src, jnp.minimum(below, nv), jnp.minimum(seen, nv)], num_keys=1)
+    return start[cr:], end[cr:] - start[cr:]
+
+
+def _runs_by_steps(sorted_key, nv, run_len, data):
+    """:func:`_runs_by_merge`'s answer by ONE ``searchsorted``
+    (``log2(capacity)`` dependent gathers a stream row, 64-bit words
+    emulated: 586 ms for 2^20 rows in a 2^22-entry ``int64`` build,
+    whatever the build's size nearly; a second search for the run's end
+    cost as much again, PERF.md PR 33), then the key and the run length
+    read where it landed."""
     start = jnp.searchsorted(sorted_key, data, side="left").astype(jnp.int32)
     start = jnp.minimum(start, sorted_key.shape[0] - 1)
-    hit = lvalid & (start < nv) & (sorted_key[start] == data)
-    cnt = jnp.where(hit, run_len[start], 0)
+    hit = (start < nv) & (sorted_key[start] == data)
+    return start, jnp.where(hit, run_len[start], 0)
+
+
+def probe_fast(lbatch: ColumnBatch, lkey, sorted_key, perm, nv, run_len,
+               join_type: str, packing: KeyPacking | None = None):
+    """Per-stream-batch probe against a prepared build side: each stream
+    key's run in the sorted build, found by a merge or by steps as the
+    two capacities say (:func:`probe_merges`; the same ``start`` and
+    ``cnt`` either way).  Same contract as the heavy phase of
+    :func:`join_probe` (without full-outer bookkeeping — streaming full
+    outer tracks matched build rows in the gather phase instead);
+    ``start`` means nothing where ``cnt`` is 0.  With a ``packing``,
+    ``lkey`` names the key columns to pack first."""
+    data, lvalid = _stream_key(lbatch, lkey, packing)
+    if probe_merges(data.shape[0], sorted_key.shape[0]):
+        start, cnt = _runs_by_merge(sorted_key, nv, data)
+    else:
+        start, cnt = _runs_by_steps(sorted_key, nv, run_len, data)
+    cnt = jnp.where(lvalid, cnt, 0)
     out_cnt = _out_cnt(cnt, lbatch.row_mask(), join_type)
     total = jnp.sum(out_cnt, dtype=jnp.int64)
     return (start, cnt, perm, out_cnt, None), total

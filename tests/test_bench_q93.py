@@ -121,6 +121,20 @@ def test_q93_record_follows_the_plan(session, data):
     assert c["span.fetch@JoinExec.count"] == 4
 
 
+def test_q93_search_batches_take_the_merge(session, data):
+    """Every stream batch q93 searches has the shapes of the merge (a
+    2^19-slot batch against 2^15 build slots at SF0.1; 2^20 against 2^22
+    at SF10): ``join.probe.search.merged`` moves with
+    ``join.probe.search``, under the program's one name."""
+    from spark_rapids_tpu.ops.join import probe_merges
+    df = _limited(session, data(0.1, 7))
+    df.collect()
+    c = get_registry().recent_queries(1)[0]["counters"]
+    assert c["join.probe.search.merged"] == c["join.probe.search"] == 1
+    assert c["program.join_probe_fast.launches"] == 1
+    assert probe_merges(1 << 19, 1 << 15) and probe_merges(1 << 20, 1 << 22)
+
+
 # ------------------------------------------------------ hand-made cases
 
 def _write(path, sales, returns, reasons=((28, "reason 28"),

@@ -123,7 +123,7 @@ def _keyed_batch(n: int, cap: int, seed: int) -> ColumnBatch:
 
 
 def test_join_build_and_probe_compiles_for_v5e(one_chip):
-    """Sort-based build + searchsorted probe + gather on s64 keys."""
+    """Sort-based build + merge probe + gather on s64 keys."""
     from spark_rapids_tpu.ops.join import (build_prepare_fast,
                                            gather_join_output,
                                            join_indices_from_probe,
@@ -171,7 +171,9 @@ def test_packed_two_key_build_and_search_probe_compile_for_v5e(one_chip):
     slots) prepared from (item int32, ticket number int64) packed into
     one int64 key (ranges, packing and the sort by it in one program,
     with the stats of its one fetch), and the ``left`` search probe of a
-    2^20-row ``store_sales`` batch that packs its own two keys first."""
+    2^20-row ``store_sales`` batch that packs its own two keys first:
+    at these shapes the probe's merge (two sorts of 5.2M rows with a
+    64-bit key, no stepping loop)."""
     from spark_rapids_tpu.host.batch import HostBatch
     from spark_rapids_tpu.ops.join import (build_key_stats,
                                            build_prepare_packed, probe_fast)
@@ -197,10 +199,33 @@ def test_packed_two_key_build_and_search_probe_compile_for_v5e(one_chip):
     prep, packing, stats = jax.eval_shape(build, returns)
     assert prep[0].shape == (1 << 22,) and str(prep[0].dtype) == "int64"
     assert stats.shape == (5,)
-    _compile(lambda left, p, k: probe_fast(left, (0, 1), *p, "left",
-                                           k)[0][:-1],
-             _shapes(sales, one_chip), _shapes(prep, one_chip),
-             _shapes(packing, one_chip))
+    probe = jax.jit(lambda left, p, k: probe_fast(left, (0, 1), *p, "left",
+                                                  k)[0][:-1])
+    args = (_shapes(sales, one_chip), _shapes(prep, one_chip),
+            _shapes(packing, one_chip))
+    text = probe.lower(*args).as_text()
+    assert "stablehlo.sort" in text and "stablehlo.while" not in text
+    _compile(probe, *args)
+
+
+def test_search_probe_of_a_small_batch_steps_and_compiles_for_v5e(one_chip):
+    """A 2^10-row remnant against the same 2^22-entry int64 build keeps
+    ``probe_fast``'s stepping branch (one ``searchsorted``: a loop of
+    gathers, no sort of 4M rows): both branches stay compilable."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.join import probe_fast, probe_merges
+    cl, cr = 1 << 10, 1 << 22
+    assert not probe_merges(cl, cr) and probe_merges(1 << 20, cr)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    prep = (on_chip((cr,), jnp.int64), on_chip((cr,), jnp.int32),
+            on_chip((), jnp.int32), on_chip((cr,), jnp.int32))
+    probe = jax.jit(lambda left, p: probe_fast(left, 0, *p, "left")[0][:-1])
+    args = (_shapes(_keyed_batch(900, cl, 1), one_chip), prep)
+    text = probe.lower(*args).as_text()
+    assert "stablehlo.while" in text and "stablehlo.sort" not in text
+    _compile(probe, *args)
 
 
 def test_sparse_build_and_semi_probe_compile_for_v5e(one_chip):

@@ -404,6 +404,191 @@ def test_direct_table_rule():
 
 
 # ------------------------------------------------------------------
+# probe_fast's two branches (ops/join.py): a merge of the stream batch
+# into the sorted build, or steps through it.  One contract: the same
+# arrays from both, whatever the shapes would choose.
+
+def _merge_cases():
+    """name -> (key types, stream key tuples, build key tuples).  Batches
+    are padded to a capacity above their rows; the build's padding slots
+    are rewritten to the dtype's maximum by its preparation."""
+    it, lt = T.IntegerType(), T.LongType()
+    one = lambda ks: [(k,) for k in ks]
+    return {
+        "build_runs_int32": ((it,), one([5, 7, 7, 8, 1, 9, 3, 5, 5, 5, 2]),
+                             one([5] * 9 + [7, 7, 3] + [8] * 4 + [5, 1])),
+        "build_runs_int64": ((lt,), one([5, 1 << 40, 7, -(1 << 40), 5]),
+                             one([1 << 40] * 5 + [5, 5, -(1 << 40), 6] * 3)),
+        "stream_below_and_above": (
+            (it,), one([I32.min, -1, 9, 10, 25, 40, 41, I32.max]),
+            one(list(range(10, 41, 5)) * 2)),
+        # a genuine maximum-valued key sits below nv, the padding above it
+        "int32_max_beside_padding": (
+            (it,), one([I32.max, I32.max - 1, 0, I32.max]),
+            one([I32.max, 3, I32.max, I32.max - 2, None])),
+        "int64_max_beside_padding": (
+            (lt,), one([I64.max, I64.min, I64.max - 1, 0]),
+            one([I64.max, I64.min, I64.max, None, 0])),
+        "empty_build": ((lt,), one([1, 2, I64.max, None]), []),
+        "all_null_stream": ((it,), one([None] * 9), one([0, 1, 2, 2])),
+        "all_null_both": ((lt,), one([None] * 5), one([None] * 3)),
+        # packed: (6, 11) and (1, 15) each hold ONE key outside the
+        # build's ranges ([2, 5] x [10, 14]) and would wrap into a run
+        "two_keys_one_out_of_range": (
+            (it, lt),
+            [(2, 10), (6, 11), (1, 15), (5, 14), (3, 9), (3, None),
+             (None, 12), (4, 12), (2, 10), (5, 15)],
+            [(2, 10), (5, 14), (4, 12), (4, 12), (3, 11), (None, 10),
+             (2, 10), (4, None)]),
+        "two_keys_wide": ((lt, lt),
+                          [(1 << 30, 1 << 31), (0, 0), (1 << 30, 0), (7, 7)],
+                          [(0, 0), (1 << 30, 1 << 31), (7, 7), (7, 7)]),
+    }
+
+
+_MERGE_CASES = _merge_cases()
+
+
+def _probe_batches(ktypes, lrows, rrows, lcap=None, rcap=None):
+    """Device batches of key columns plus a payload, padded past their
+    rows, and the key column indices."""
+    from spark_rapids_tpu.columnar.batch import round_capacity
+    from spark_rapids_tpu.host.batch import HostBatch
+
+    def batch(prefix, rows, cap):
+        schema = T.Schema([T.StructField(f"{prefix}k{i}", t, True)
+                           for i, t in enumerate(ktypes)]
+                          + [T.StructField(prefix + "v", T.LongType(), True)])
+        cols = {f"{prefix}k{i}": [r[i] for r in rows]
+                for i in range(len(ktypes))}
+        cols[prefix + "v"] = list(range(len(rows)))
+        return HostBatch.from_pydict(cols, schema).to_device(
+            capacity=cap or 2 * round_capacity(len(rows) + 1))
+    return (batch("l", lrows, lcap), batch("r", rrows, rcap),
+            tuple(range(len(ktypes))))
+
+
+def _assert_branches_agree(lb, rb, keys, jt, monkeypatch):
+    """Both branches of probe_fast on one stream batch and one prepared
+    build: equal but for ``start`` where ``cnt`` is 0.  Returns the
+    merge's arrays."""
+    from spark_rapids_tpu.ops import join as OJ
+    if len(keys) > 1:
+        build, packing = OJ.build_prepare_packed(rb, keys)
+        lkey = keys
+    else:
+        build, packing, lkey = OJ.build_prepare_fast(rb, 0), None, 0
+    got = {}
+    for branch, ratio in (("merge", 1 << 40), ("steps", 0)):
+        monkeypatch.setattr(OJ, "MERGE_MAX_BUILD_RATIO", ratio)
+        assert OJ.probe_merges(lb.capacity, rb.capacity) == (branch == "merge")
+        (start, cnt, perm, out_cnt, none), total = OJ.probe_fast(
+            lb, lkey, *build, jt, packing)
+        assert none is None
+        got[branch] = [np.asarray(x) for x in (start, cnt, perm, out_cnt)] \
+            + [int(total)]
+    (s1, c1, p1, o1, t1), (s2, c2, p2, o2, t2) = got["merge"], got["steps"]
+    assert np.array_equal(c1, c2) and np.array_equal(o1, o2) and t1 == t2
+    assert np.array_equal(p1, p2)
+    assert np.array_equal(s1[c1 > 0], s2[c1 > 0])
+    assert s1.dtype == s2.dtype == c1.dtype == c2.dtype == np.int32
+    assert s1.shape == c1.shape == (lb.capacity,)
+    return got["merge"]
+
+
+@pytest.mark.parametrize("jt", ["inner", "left", "semi", "anti", "full"])
+@pytest.mark.parametrize("case", list(_PROBE_CASES))
+def test_merge_and_steps_agree(case, jt, monkeypatch):
+    from spark_rapids_tpu.columnar.batch import round_capacity
+    lkeys, rkeys, ktype, _, keep = _PROBE_CASES[case]
+    # a filtered build keeps its capacity: few rows, many padding slots
+    rcap = None if keep is None else round_capacity(len(rkeys))
+    lb, rb, keys = _probe_batches(
+        (ktype,), [(k,) for k in lkeys],
+        [(k,) for k in (rkeys if keep is None else rkeys[:keep])], rcap=rcap)
+    start, cnt, perm, out_cnt, total = _assert_branches_agree(
+        lb, rb, keys, jt, monkeypatch)
+    # the runs themselves, from the keys: every stream key's matches
+    build = [k for k in (rkeys if keep is None else rkeys[:keep])
+             if k is not None]
+    want = [0 if k is None else build.count(k) for k in lkeys]
+    assert cnt[:len(lkeys)].tolist() == want and not cnt[len(lkeys):].any()
+    skey = sorted(build)
+    for i, (k, n) in enumerate(zip(lkeys, want)):
+        if n:
+            assert skey[start[i]:start[i] + n] == [k] * n
+
+
+@pytest.mark.parametrize("jt", ["inner", "left", "anti"])
+@pytest.mark.parametrize("case", list(_MERGE_CASES))
+def test_merge_and_steps_agree_on_the_edges(case, jt, monkeypatch):
+    ktypes, lrows, rrows = _MERGE_CASES[case]
+    lb, rb, keys = _probe_batches(ktypes, lrows, rrows)
+    assert lb.capacity > len(lrows) and rb.capacity > len(rrows)
+    start, cnt, perm, out_cnt, total = _assert_branches_agree(
+        lb, rb, keys, jt, monkeypatch)
+    whole = [r for r in rrows if None not in r]
+    want = [0 if None in r else whole.count(r) for r in lrows]
+    assert cnt[:len(lrows)].tolist() == want and not cnt[len(lrows):].any()
+    n = len(lrows)
+    assert total == {"inner": sum(want), "left": sum(max(w, 1) for w in want),
+                     "anti": sum(w == 0 for w in want)}[jt]
+    assert not out_cnt[n:].any()
+    # perm leads from a run in the sorted build back to the build's rows
+    for i, (r, w) in enumerate(zip(lrows, want)):
+        if w:
+            rows = sorted(int(perm[start[i] + k]) for k in range(w))
+            assert rows == [j for j, b in enumerate(rrows) if b == r]
+
+
+def test_probe_shape_rule():
+    """The branch is a function of the two capacities alone."""
+    from spark_rapids_tpu.ops import join as OJ
+    assert OJ.probe_merges(1 << 20, 1 << 22)        # q93 at SF10
+    assert OJ.probe_merges(1 << 20, 1 << 19)
+    assert OJ.probe_merges(1 << 20, 1 << 17)
+    assert OJ.probe_merges(1 << 19, 1 << 15)        # q93 at SF0.1
+    assert not OJ.probe_merges(1 << 14, 1 << 22)
+    # a remnant against a fact-sized build must not sort 4M rows
+    assert not OJ.probe_merges(1 << 10, 1 << 22)
+    r = OJ.MERGE_MAX_BUILD_RATIO
+    assert 16 <= r <= 64
+    assert OJ.probe_merges(1 << 10, r << 10)
+    assert not OJ.probe_merges(1 << 10, (r << 10) + 1)
+
+
+@pytest.mark.parametrize("build_rows,merged", [(200, True), (5000, False)])
+def test_executor_counts_the_merged_batches(build_rows, merged, monkeypatch):
+    """``join.probe.search.merged`` moves with ``join.probe.search`` where
+    the shapes take the merge, and not where they take the steps: the
+    executor applies probe_fast's rule to the capacities probe_fast sees."""
+    from spark_rapids_tpu.exec import joins as J
+    from spark_rapids_tpu.obs.registry import get_registry
+    from spark_rapids_tpu.ops import join as OJ
+    schema = lambda p: T.Schema([T.StructField(p + "k", T.LongType(), True),
+                                 T.StructField(p + "v", T.LongType(), True)])
+    rk = [k * 1_000_003 for k in range(build_rows)]     # sparse: searched
+    lk = [rk[7], 5, rk[199], rk[7], None, rk[0], -3, rk[150]]
+    left = LocalScanExec.from_pydict(
+        {"lk": lk, "lv": list(range(len(lk)))}, schema("l"))
+    right = LocalScanExec.from_pydict(
+        {"rk": rk, "rv": list(range(len(rk)))}, schema("r"))
+    plan = JoinExec(left, right, [col("lk")], [col("rk")], "inner")
+    asked = []
+    monkeypatch.setattr(J, "probe_merges", lambda cl, cr: (
+        asked.append((cl, cr)), OJ.probe_merges(cl, cr))[1])
+    before = get_registry().counters()
+    rows = collect_device(plan)
+    moved = get_registry().counters_since(before)
+    assert sorted(r[1] for r in rows) == [0, 2, 3, 5, 7]
+    assert moved.get("join.probe.search") == 1
+    assert moved.get("join.probe.search.merged", 0) == int(merged)
+    (cl, cr), = asked           # the capacities probe_fast sees
+    assert cl == 8 and cr >= build_rows
+    assert (cr <= OJ.MERGE_MAX_BUILD_RATIO * cl) == merged
+
+
+# ------------------------------------------------------------------
 # A join on several integral keys streams: the keys are packed into one
 # mixed-radix int64 key from the build side's own ranges (ops/join.py
 # build_prepare_packed), and the single-key probes do the rest.
