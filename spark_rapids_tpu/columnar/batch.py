@@ -17,6 +17,7 @@ boundaries (coalesce, collect).
 from __future__ import annotations
 
 import functools as _functools
+import time
 from typing import Sequence
 
 import jax
@@ -197,10 +198,16 @@ class _PackBuilder:
         host_bufs = tuple(
             self.groups[k][0] if len(self.groups[k]) == 1
             else np.concatenate(self.groups[k]) for k in gkeys)
+        t0 = time.perf_counter()
         dev_bufs = tuple(jax.device_put(b) for b in host_bufs)
+        put_s = time.perf_counter() - t0
+        # h2d_put_s: the host's seconds inside the puts — near zero where
+        # the put returns before the copy is done (the link's seconds are
+        # then no host span's), the copy itself where it blocks
         get_registry().inc_many((
             ("h2d_calls", len(host_bufs)),
-            ("h2d_bytes", sum(b.nbytes for b in host_bufs))))
+            ("h2d_bytes", sum(b.nbytes for b in host_bufs)),
+            ("h2d_put_s", put_s)))
         spec = (self.capacity, gkeys, tuple(self.leaves),
                 tuple(self.col_specs), nr, ip)
         arrays = _packed_unpack_cached(spec)(dev_bufs)

@@ -409,7 +409,8 @@ class SharedJit:
     ``join_probe_fast``, ``mesh_region_chain`` …): it is stamped on the
     wrapped python function, so XLA names the module ``jit_<name>`` in
     device traces, and it is the key of the program's counters
-    ``program.<name>.launches`` / ``.arg_bytes`` / ``.result_bytes``.
+    ``program.<name>.launches`` / ``.arg_bytes`` / ``.result_bytes`` /
+    ``.dispatch_s``.
     One name per jit site — tests/test_query_record.py holds them unique.
 
     jax compiles one executable per abstract input signature inside the
@@ -420,7 +421,10 @@ class SharedJit:
     signature — the one that traces and compiles — runs under a
     ``program.compile@<name>`` span, moves ``compile_count`` and is
     timed into ``compile_wall_s``; a signature already seen costs one
-    dict lookup and three adds.  ``compile_count`` / ``compile_wall_s``
+    dict lookup, two clock reads and four adds: ``.dispatch_s`` is the
+    host's seconds inside that warm call (the launch's enqueue; where
+    the device's queue is full, its backlog seen from the host), and the
+    compiling call never moves it.  ``compile_count`` / ``compile_wall_s``
     see SharedJit programs only: the compiles of eager ``jnp``
     operations outside any program (PERF.md Findings PR 23) reach only
     a ``jax.monitoring`` listener on
@@ -442,7 +446,8 @@ class SharedJit:
         self._lock = threading.Lock()
         self._keys = (f"program.{name}.launches",
                       f"program.{name}.arg_bytes",
-                      f"program.{name}.result_bytes")
+                      f"program.{name}.result_bytes",
+                      f"program.{name}.dispatch_s")
         _ALL_SHARED.add(self)
 
     def signature_count(self) -> int:
@@ -458,10 +463,13 @@ class SharedJit:
         hash(sig)  # unhashable static leaf -> fall back to uncounted
         return sig, leaves
 
-    def _count(self, facts) -> None:
-        launches, arg_bytes, result_bytes = self._keys
-        get_registry().inc_many(((launches, 1), (arg_bytes, facts[0]),
-                                 (result_bytes, facts[1])))
+    def _count(self, facts, dispatch_s: float | None = None) -> None:
+        launches, arg_bytes, result_bytes, dispatch = self._keys
+        pairs = ((launches, 1), (arg_bytes, facts[0]),
+                 (result_bytes, facts[1]))
+        if dispatch_s is not None:
+            pairs += ((dispatch, dispatch_s),)
+        get_registry().inc_many(pairs)
 
     def __call__(self, *args, **kwargs):
         try:
@@ -480,9 +488,12 @@ class SharedJit:
                     # result bytes are known once the first call returns
                     facts = self._sigs[sig] = [_leaf_bytes(leaves), 0]
         if not new:
-            self._count(facts)
             with dispatch_guard():
-                return self.fn(*args, **kwargs)
+                t0 = time.perf_counter()
+                try:
+                    return self.fn(*args, **kwargs)
+                finally:
+                    self._count(facts, time.perf_counter() - t0)
         reg = get_registry()
         t0 = time.perf_counter()
         try:

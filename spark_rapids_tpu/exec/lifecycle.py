@@ -356,9 +356,9 @@ class QueryLifecycle:
         ``open_record`` and now: the span table
         (``span.<name>.count/seconds``), the transfer and wait counters,
         and the per-program table
-        (``program.<name>.launches/arg_bytes/result_bytes``).  The
-        session calls this once the collect has unwound, so a cancelled
-        query's record holds all it did.  The counters are the
+        (``program.<name>.launches/arg_bytes/result_bytes/dispatch_s``).
+        The session calls this once the collect has unwound, so a
+        cancelled query's record holds all it did.  The counters are the
         PROCESS's movement over the query's interval: exact with one
         running query, shared among queries that overlap.  Idempotent;
         None when no record was opened."""

@@ -402,11 +402,22 @@ class FileScanExec(PlanNode):
         reader's decode-ahead does the same for the host half,
         GpuMultiFileReader.scala).  Window of 2 bounds host+HBM usage.
 
-        The worker has no enclosing operator annotation, so its span
-        says whose work it does: ``stage@<Scan>Exec`` per batch (encode
-        + pack + ``device_put``); the seconds it is blocked on the full
-        queue go to ``scan_backpressure_s``.  What the pulling thread's
-        own ``<Scan>Exec`` annotation covers is ``q.get()``: the wait."""
+        Who owns which span and counter.  The ``scan-prefetch`` worker
+        has no enclosing operator annotation, so its spans say whose work
+        it does, and the three together are its life:
+        ``starved@<Scan>Exec`` around obtaining each record batch (blocked
+        on the reader pool's future, or decoding in-thread where the
+        reader is pulled lazily: ``decode@<Scan>Exec`` then nests inside
+        it), ``stage@<Scan>Exec`` per batch (encode + pack +
+        ``device_put`` + the ``batch_unpack`` launch: of it ``h2d_put_s``
+        is the put, ``program.batch_unpack.dispatch_s`` the launch), and
+        the seconds it is blocked on the full queue, ``scan_backpressure_s``.
+        The pulling thread's own ``<Scan>Exec`` annotation covers
+        ``q.get()``, the wait; counted from inside as ``scan.wait_s``,
+        the first get of a pipeline also as ``scan.first_batch_s`` beside
+        ``scan.pipelines`` (a counter: a child annotation would empty the
+        self time the annotation is read for).  The stage that sets the
+        pace is the one that never waits."""
         import queue
         import threading
         import time
@@ -415,6 +426,7 @@ class FileScanExec(PlanNode):
         stop = threading.Event()
         reg = get_registry()
         stage = f"stage@{type(self).__name__}"
+        starved = f"starved@{type(self).__name__}"
 
         def put(item) -> bool:
             t0 = time.perf_counter()
@@ -431,8 +443,12 @@ class FileScanExec(PlanNode):
 
         def worker():
             try:
-                for rb in rbs:
-                    if stop.is_set():
+                it = iter(rbs)
+                while not stop.is_set():
+                    with reg.span(starved):
+                        rb = next(it, DONE)
+                    if rb is DONE:
+                        put(DONE)
                         return
                     if rb.num_rows == 0:
                         continue
@@ -441,7 +457,6 @@ class FileScanExec(PlanNode):
                             rb, string_widths=self._width_map(rb))
                     if not put(b):
                         return
-                put(DONE)
             # enginelint: disable=RL001 (prefetch thread forwards the exception through the queue; the consumer re-raises it)
             except BaseException as e:  # noqa: BLE001 - re-raised below
                 put(e)
@@ -449,9 +464,18 @@ class FileScanExec(PlanNode):
         t = threading.Thread(target=worker, daemon=True,
                              name="scan-prefetch")
         t.start()
+        first = True
         try:
             while True:
+                t0 = time.perf_counter()
                 item = q.get()
+                waited = time.perf_counter() - t0
+                pairs = (("scan.wait_s", waited),)
+                if first:
+                    pairs += (("scan.first_batch_s", waited),
+                              ("scan.pipelines", 1))
+                    first = False
+                reg.inc_many(pairs)
                 if item is DONE:
                     break
                 if isinstance(item, BaseException):

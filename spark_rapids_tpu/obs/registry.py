@@ -26,14 +26,23 @@ seconds).  Names: ``query`` > ``query.plan`` / ``query.execute`` /
 ``query.fetch`` (session.py), ``program.compile@<program>``
 (exec/compile_cache.py), and ``<phase>@<Operator>Exec`` for work done
 for an operator outside its per-pull annotation — ``decode@`` /
-``stage@ParquetScanExec`` on the scan's worker threads (io/scan.py),
+``stage@ParquetScanExec`` on the scan's worker threads and
+``starved@ParquetScanExec`` around the staging thread's ``next()`` on
+its input (io/scan.py ``_device_batches``: blocked on the reader pool,
+or decoding in-thread with ``decode@`` inside it),
 ``fetch@HashAggregateExec`` … around every blocking device fetch
 (exec/core.py ``fetch_to_host``).  The counters at the same seams:
-``program.<name>.launches`` / ``.arg_bytes`` / ``.result_bytes`` per
-SharedJit program, ``dispatch_wait_s`` (blocked on the DeviceSemaphore),
-``h2d_calls`` / ``h2d_bytes`` (one per ``jax.device_put``), ``d2h_calls``
-/ ``d2h_bytes`` / ``sync_wait_s`` (host blocked inside a fetch), and
-``scan_backpressure_s`` (scan worker blocked on its full queue).  One
+``program.<name>.launches`` / ``.arg_bytes`` / ``.result_bytes`` /
+``.dispatch_s`` per SharedJit program (the last: host seconds inside a
+warm launch, exec/compile_cache.py ``SharedJit.__call__``),
+``dispatch_wait_s`` (blocked on the DeviceSemaphore), ``h2d_calls`` /
+``h2d_bytes`` (one per ``jax.device_put``) / ``h2d_put_s`` (host seconds
+inside the puts of a ``_PackBuilder.build``, columnar/batch.py),
+``d2h_calls`` / ``d2h_bytes`` / ``sync_wait_s`` (host blocked inside a
+fetch), ``scan_backpressure_s`` (scan worker blocked on its full
+queue), and from the other end of that queue ``scan.wait_s`` (the
+pulling thread blocked in ``q.get()``), ``scan.first_batch_s`` (the
+first get of a pipeline) and ``scan.pipelines`` (io/scan.py).  One
 record per finished query — the counter movement over its interval —
 is kept in a ring of ``RECENT_QUERIES`` entries (``recent_queries``);
 exec/lifecycle.py fills it.
@@ -335,7 +344,7 @@ class MetricsRegistry:
 
     def inc_many(self, pairs) -> None:
         """``inc`` for several ``(name, value)`` pairs under one lock
-        acquisition (a span's two counters, a launch's three)."""
+        acquisition (a span's two counters, a launch's four)."""
         with self._lock:
             counters = self._counters
             for name, value in pairs:

@@ -106,10 +106,16 @@ def test_record_holds_the_query_phases(query):
     assert c["span.query.execute.seconds"] >= c["span.query.fetch.seconds"]
     # the seams of this plan: scan workers, both chunked fetches
     for span in ("decode@ParquetScanExec", "stage@ParquetScanExec",
+                 "starved@ParquetScanExec",
                  "fetch@JoinExec", "fetch@HashAggregateExec"):
         assert c[f"span.{span}.count"] >= 1, span
     assert c["queries_executed"] == 1
     assert c["sync_wait_s"] > 0 and c["scan_backpressure_s"] >= 0
+    # the host side of the scan and of every launch, counted from inside
+    assert c["scan.pipelines"] == FACT_FILES + 1    # a partition a file
+    assert 0 < c["scan.first_batch_s"] <= c["scan.wait_s"]
+    assert c["h2d_put_s"] > 0
+    assert c["program.batch_unpack.dispatch_s"] > 0
     assert "compile_count" not in c     # a warm collect compiles nothing
 
 
@@ -138,7 +144,9 @@ def test_counts_repeat_exactly_over_collects(query):
         assert a[key] == b[key] > 0, key
     programs = {k for k in a if k.startswith("program.")}
     assert programs == {k for k in b if k.startswith("program.")}
-    assert all(a[k] == b[k] for k in programs)
+    # (a launch's host seconds are seconds: they repeat in name only)
+    assert all(a[k] == b[k] for k in programs
+               if not k.endswith(".dispatch_s"))
 
 
 @pytest.fixture(scope="module")
@@ -477,6 +485,178 @@ def test_compaction_counters_follow_the_plan(session, tables, shape):
                    or k.startswith("span.fetch@FusedStage") for k in c)
 
 
+# ------------------------------------------------- the scan's pipeline
+
+NAP = 0.2       # seconds the slow side of a pipeline sleeps a batch
+BATCHES = 3 * 4     # FACT_FILES files of 4000 rows, 1000 rows a batch
+
+
+class _Pipeline:
+    """One partition of the fact table through ``partition_iter`` (reader
+    pool or lazy reader -> the ``scan-prefetch`` thread -> this thread),
+    with a reader and a consumer that can be slowed; ``drain`` returns
+    the counters that moved and the staging threads' lives."""
+
+    def __init__(self, tables, monkeypatch, reader_type="MULTITHREADED"):
+        import threading
+        import time
+        from spark_rapids_tpu.conf import TpuConf
+        from spark_rapids_tpu.io.scan import ParquetScanExec
+        self.conf = TpuConf(dict(
+            CONF, **{"spark.rapids.sql.reader.batchRows": "1000",
+                     "spark.rapids.sql.format.parquet.reader.type":
+                         reader_type}))
+        self.scan = ParquetScanExec(os.path.join(tables, "fact"),
+                                    partitions=1)
+        self.reader_nap = 0.0
+        self.lives = lives = []
+        real = ParquetScanExec._read_file
+
+        def slow_read(scan, path, batch_rows=1 << 16):
+            for rb in real(scan, path, batch_rows):
+                time.sleep(self.reader_nap)
+                yield rb
+        monkeypatch.setattr(ParquetScanExec, "_read_file", slow_read)
+
+        class Timed(threading.Thread):
+            def run(self):
+                t0 = time.perf_counter()
+                try:
+                    super().run()
+                finally:
+                    if self.name == "scan-prefetch":
+                        lives.append(time.perf_counter() - t0)
+        monkeypatch.setattr(threading, "Thread", Timed)
+        self.drain()    # batch_unpack compiles for this batch shape
+
+    def drain(self, reader_nap=0.0, consumer_nap=0.0, consumers=1):
+        import time
+        from spark_rapids_tpu.exec.core import ExecCtx
+        self.reader_nap = reader_nap
+        del self.lives[:]
+        before = get_registry().counters()
+        with ExecCtx(backend="device", conf=self.conf) as ctx:
+            for _ in range(consumers):
+                n = 0
+                for _b in self.scan.partition_iter(ctx, 0):
+                    n += 1
+                    time.sleep(consumer_nap)
+                assert n == BATCHES
+        return get_registry().counters_since(before), list(self.lives)
+
+
+@pytest.mark.parametrize("reader_type,naps", [
+    # the pool decodes the three files at once: the staging thread is
+    # blocked on the first future for one file's four naps
+    ("MULTITHREADED", 4),
+    # a lazy reader decodes in the staging thread: every batch's nap
+    ("PERFILE", BATCHES)])
+def test_a_slow_reader_starves_the_staging_thread(tables, monkeypatch,
+                                                  reader_type, naps):
+    c, _ = _Pipeline(tables, monkeypatch, reader_type).drain(reader_nap=NAP)
+    assert c["span.starved@ParquetScanExec.seconds"] >= 0.8 * naps * NAP
+    # one wait a batch and one for the end of the input
+    assert c["span.starved@ParquetScanExec.count"] == BATCHES + 1
+    assert c["scan_backpressure_s"] < NAP
+    # the consumer waits for what the staging thread waits for
+    assert c["scan.wait_s"] >= 0.8 * naps * NAP
+    if reader_type == "PERFILE":    # decode@ nests inside starved@
+        assert c["span.starved@ParquetScanExec.seconds"] >= \
+            c["span.decode@ParquetScanExec.seconds"] >= naps * NAP
+
+
+def test_a_slow_consumer_backs_the_staging_thread_up(tables, monkeypatch):
+    c, _ = _Pipeline(tables, monkeypatch).drain(consumer_nap=NAP)
+    # the queue holds two: all but the first few puts wait a nap each
+    assert c["scan_backpressure_s"] >= 0.8 * (BATCHES - 3) * NAP
+    assert c["span.starved@ParquetScanExec.seconds"] < NAP
+    assert c["scan.wait_s"] < NAP
+    assert c["span.stage@ParquetScanExec.count"] == BATCHES
+
+
+@pytest.mark.parametrize("slow", ["reader", "consumer"])
+def test_the_staging_threads_life_is_its_three_counters(tables, monkeypatch,
+                                                        slow):
+    c, lives = _Pipeline(tables, monkeypatch).drain(**{f"{slow}_nap": NAP})
+    (life,) = lives     # the worker, from its start to DONE handed over
+    told = (c["span.starved@ParquetScanExec.seconds"]
+            + c["span.stage@ParquetScanExec.seconds"]
+            + c["scan_backpressure_s"])
+    assert life > 3 * NAP
+    assert 0.9 * life <= told <= life
+
+
+@pytest.mark.parametrize("shared", [False, True])
+def test_first_batch_counts_once_per_staging(tables, monkeypatch, shared):
+    pipe = _Pipeline(tables, monkeypatch)
+    pipe.scan.share_output, pipe.scan.share_consumers = shared, 2
+    c, lives = pipe.drain(reader_nap=NAP / 4, consumers=2)
+    # a shared scan stages its partition once, for both consumers
+    stagings = 1 if shared else 2
+    assert c["scan.pipelines"] == len(lives) == stagings
+    assert c["span.stage@ParquetScanExec.count"] == stagings * BATCHES
+    # the first get waits for a file's decode; the later gets wait too
+    assert 0.9 * stagings * NAP <= c["scan.first_batch_s"] \
+        < c["scan.wait_s"]
+
+
+def test_h2d_put_seconds_are_the_seconds_inside_the_puts(query, monkeypatch):
+    import time
+    import jax
+    spent = []
+    real = jax.device_put
+
+    def spy(x, *a, **k):
+        t0 = time.perf_counter()
+        try:
+            time.sleep(0.002)
+            return real(x, *a, **k)
+        finally:
+            if isinstance(x, np.ndarray):   # _PackBuilder.build's buffers
+                spent.append(time.perf_counter() - t0)
+    monkeypatch.setattr(jax, "device_put", spy)
+    c = _collect(query)["counters"]
+    assert len(spent) == c["h2d_calls"]
+    assert sum(spent) <= c["h2d_put_s"] <= 1.5 * sum(spent) + 0.05
+    # the put is a share of the staging that holds it
+    assert c["h2d_put_s"] < c["span.stage@ParquetScanExec.seconds"]
+
+
+def test_dispatch_seconds_move_once_per_warm_launch():
+    import time
+    import jax.numpy as jnp
+    prog = cc.shared_jit(cc.fragment_key("test_dispatch", "record"),
+                         lambda x: x + 1, name="test_record_dispatch")
+    key = "program.test_record_dispatch."
+    x = jnp.ones((8,), jnp.float32)
+    before = get_registry().counters()
+    prog(x)     # traces and compiles: program.compile@ holds its seconds
+    moved = get_registry().counters_since(before)
+    assert moved[key + "launches"] == 1 and key + "dispatch_s" not in moved
+    assert moved["span.program.compile@test_record_dispatch.count"] == 1
+    real = prog.fn
+
+    def slow(*a, **k):
+        time.sleep(0.01)
+        return real(*a, **k)
+    prog.fn = slow
+    before = get_registry().counters()
+    for _ in range(3):
+        prog(x)
+    moved = get_registry().counters_since(before)
+    assert moved[key + "launches"] == 3 and "compile_count" not in moved
+    assert 0.03 <= moved[key + "dispatch_s"] < 0.03 + 0.1
+
+    def failing(*a, **k):
+        raise RuntimeError("a launch that raises is still a launch")
+    prog.fn = failing
+    before = get_registry().counters()
+    with pytest.raises(RuntimeError):
+        prog(x)
+    moved = get_registry().counters_since(before)
+    assert moved[key + "launches"] == 1 and moved[key + "dispatch_s"] >= 0
+
+
 # ----------------------------------------------------------------- trace
 
 def test_worker_and_fetch_spans_share_the_collects_clock(query, tmp_path):
@@ -504,16 +684,30 @@ def test_worker_and_fetch_spans_share_the_collects_clock(query, tmp_path):
         by_name.setdefault(name, []).append((start, start + dur))
     counters = rec["counters"]
     for span in ("decode@ParquetScanExec", "stage@ParquetScanExec",
+                 "starved@ParquetScanExec",
                  "fetch@JoinExec", "fetch@HashAggregateExec"):
         assert len(by_name[span]) == counters[f"span.{span}.count"], span
         for start, end in by_name[span]:
             assert c0 <= start <= end <= c0 + cdur, span
     # the worker threads' spans are on other lines than the puller's
-    lines = {i for i, ln in enumerate(planes["host"])
-             if any(e[0] == "stage@ParquetScanExec" for e in ln["events"])}
-    pullers = {i for i, ln in enumerate(planes["host"])
-               if any(e[0] == reduce_trace.COLLECT for e in ln["events"])}
+
+    def lines_of(name):
+        return {i for i, ln in enumerate(planes["host"])
+                if any(e[0] == name for e in ln["events"])}
+    lines = lines_of("stage@ParquetScanExec")
+    pullers = lines_of(reduce_trace.COLLECT)
     assert lines and not lines & pullers
+    # the staging thread's wait for its input opens on that same thread
+    # (a scan-prefetch thread a partition), beside its stage@ spans
+    assert lines_of("starved@ParquetScanExec") == lines
+    # one file a partition: the reader is pulled lazily, so every decode
+    # lies inside a starved@ span of its thread
+    for ln in (planes["host"][i] for i in lines):
+        starved = [(s0, s0 + d) for n, s0, d in ln["events"]
+                   if n == "starved@ParquetScanExec"]
+        for n, s0, d in ln["events"]:
+            if n == "decode@ParquetScanExec":
+                assert any(a <= s0 and s0 + d <= b for a, b in starved)
     # the query's own spans carry its id (the benchmark's load drops
     # them: it keeps bench.collect and …Exec names only)
     data = jax.profiler.ProfileData.from_file(path)
