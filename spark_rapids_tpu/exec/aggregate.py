@@ -5,7 +5,10 @@ Reference: aggregate.scala (GpuHashAggregateExec, ``doExecuteColumnar``
 concatenate with the running result and merge-aggregate; final projection
 over the aggregation buffer.  The device kernel here is sort-based
 (:mod:`spark_rapids_tpu.ops.segmented`, the TPU-idiomatic substitute for
-cuDF's hash groupby — see SURVEY.md §7 hard parts).
+cuDF's hash groupby — see SURVEY.md §7 hard parts): one sort by the keys,
+each aggregate a segmented scan that leaves a group's value on its first
+sorted row, then keys and results gathered together from those rows; no
+row is scattered.
 
 When the update sorts and when it does not: the per-batch update is ONE
 program (``agg_update``) around ``segmented.group_by_update``.  It
@@ -228,7 +231,7 @@ class HashAggregateExec(PlanNode):
     @property
     def children_coalesce_goal(self):
         # batch small scan output up to batchSizeBytes before aggregating
-        # (fewer, larger segment-reduce dispatches; reference: the
+        # (fewer, larger sorted group-by dispatches; reference: the
         # aggregate's TargetSize child goal, GpuExec.scala:71-86).
         # TargetSize(0) resolves to spark.rapids.sql.batchSizeBytes at
         # planning.  Final mode reads shuffle output that the adaptive
@@ -367,7 +370,7 @@ class HashAggregateExec(PlanNode):
 
         # Each incoming batch is reduced to its own group buffer and
         # SHRUNK to its group count; buffers then merge in one n-way
-        # concat + segment-reduce.  The previous pairwise loop re-sorted
+        # concat + sorted group-by.  The previous pairwise loop re-sorted
         # the whole running buffer per batch — k full sorts for k
         # batches — which dominated agg-heavy plans (q65's final
         # aggregates were ~5s each on SF1).  The reference's

@@ -67,7 +67,6 @@ from __future__ import annotations
 
 from typing import NamedTuple, Sequence
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -75,6 +74,7 @@ from jax import lax
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
+from spark_rapids_tpu.ops.kernels import gather_stacked
 from spark_rapids_tpu.ops.segmented import _cols_differ
 from spark_rapids_tpu.ops.sort import encode_key_operands
 
@@ -597,10 +597,10 @@ def gather_join_output(lbatch: ColumnBatch, rbatch: ColumnBatch,
                        stacked: bool = False) -> ColumnBatch:
     """Build the output batch from a join_indices plan; ``stacked``: each
     side's leaves move in one gather of rows a dtype
-    (:func:`_stacked_side`) instead of a gather a leaf."""
+    (``ops/kernels.gather_stacked``) instead of a gather a leaf."""
     def side(columns, idx, take):
         if stacked:
-            return _stacked_side(columns, idx, take)
+            return gather_stacked(columns, idx, take)
         return [_take_side(c, idx, take) for c in columns]
     out_cols = side(lbatch.columns, li, l_take)
     if include_right:
@@ -616,37 +616,3 @@ def _take_side(c: DeviceColumn, idx, take) -> DeviceColumn:
                             jnp.where(validity, c.lengths[idx], 0))
     data = jnp.where(validity, c.data[idx], jnp.zeros((), c.data.dtype))
     return DeviceColumn(data, validity, c.dtype)
-
-
-def _stacked_side(columns: Sequence[DeviceColumn], idx, take):
-    """:func:`_take_side` over all of a side's columns at once.  Leaves of
-    one dtype -- validity flags; each width of number; string lengths
-    with the int32 data; byte matrices side by side -- are stacked
-    ``[capacity, k]`` and read by ONE gather of rows: a gather's cost on
-    the chip is its index count, hardly its row width (the move
-    ``ops/kernels._move_rows`` makes for ``compact``; PERF.md, PRs 32
-    and 33)."""
-    n = idx.shape[0]
-    stacks: dict = {}
-    for c in columns:
-        for leaf in (c.validity, c.data, c.lengths):
-            if leaf is not None:
-                stacks.setdefault(leaf.dtype, []).append(
-                    leaf.reshape(leaf.shape[0], -1))
-    moved = {}
-    for dtype, leaves in stacks.items():
-        rows = jnp.concatenate(leaves, axis=1)[idx]
-        bounds = np.cumsum([x.shape[1] for x in leaves])[:-1]
-        moved[dtype] = iter(jnp.split(rows, bounds, axis=1))
-    out = []
-    for c in columns:
-        validity = next(moved[c.validity.dtype]).reshape(n) & take
-        data = next(moved[c.data.dtype]).reshape((n,) + c.data.shape[1:])
-        data = jnp.where(validity[(...,) + (None,) * (data.ndim - 1)],
-                         data, jnp.zeros((), data.dtype))
-        lengths = None
-        if c.is_var_width:
-            lengths = jnp.where(
-                validity, next(moved[c.lengths.dtype]).reshape(n), 0)
-        out.append(DeviceColumn(data, validity, c.dtype, lengths))
-    return out

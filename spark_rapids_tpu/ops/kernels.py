@@ -29,7 +29,8 @@ from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.obs.registry import get_registry
 
 __all__ = ["compact", "count_compaction", "take", "concat_batches",
-           "slice_batch", "slice_rows", "gather_columns", "shrink_capacity",
+           "slice_batch", "slice_rows", "gather_columns", "gather_stacked",
+           "shrink_capacity",
            "pad_capacity", "device_scalar"]
 
 
@@ -67,6 +68,37 @@ def gather_columns(cols: Sequence[DeviceColumn], perm: jax.Array,
     cap = perm.shape[0]
     out_mask = jnp.arange(cap, dtype=jnp.int32) < new_count
     return [_gather_column(c, perm, out_mask) for c in cols]
+
+
+def gather_stacked(columns: Sequence[DeviceColumn], idx: jax.Array,
+                   take: jax.Array) -> list[DeviceColumn]:
+    """:func:`gather_columns` with ONE gather of rows a dtype: the rows
+    at ``idx`` where ``take`` (bool, ``idx``'s length), zeros and nulls
+    elsewhere.  Leaves of one dtype -- validity flags; each width of
+    number; string lengths with the int32 data; byte matrices side by
+    side -- are stacked ``[capacity, k]`` first: a gather costs the chip
+    10-17 ns an index whatever it reads, so fewer, wider gathers win
+    (PERF.md, PRs 28, 32, 33 and 37)."""
+    leaves, tree = jax.tree.flatten(list(columns))
+    stacks: dict = {}
+    for x in leaves:
+        stacks.setdefault(x.dtype, []).append(x.reshape(x.shape[0], -1))
+    moved = {}
+    for dtype, xs in stacks.items():
+        rows = jnp.concatenate(xs, axis=1)[idx]
+        bounds = np.cumsum([x.shape[1] for x in xs])[:-1]
+        moved[dtype] = iter(jnp.split(rows, bounds, axis=1))
+    out = []
+    for c in jax.tree.unflatten(tree, [
+            next(moved[x.dtype]).reshape(idx.shape + x.shape[1:])
+            for x in leaves]):
+        validity = c.validity & take
+        data = jnp.where(validity[(...,) + (None,) * (c.data.ndim - 1)],
+                         c.data, jnp.zeros((), c.data.dtype))
+        out.append(DeviceColumn(
+            data, validity, c.dtype,
+            None if c.lengths is None else jnp.where(validity, c.lengths, 0)))
+    return out
 
 
 # A compaction moves ``capacity // SMALL_BUCKET_DIVISOR`` slots instead
@@ -129,11 +161,8 @@ def _move_rows(columns: Sequence[DeviceColumn], keep: jax.Array,
     end: unique indices are what lets XLA scatter without sorting
     (``ops/join.build_direct_table``; a bucket of 2^22 slots or more no
     longer fits the chip's fast memory and is scattered behind one
-    two-operand sort all the same).  Leaves of one dtype -- validity
-    flags; each width of number; string lengths with the int32 data;
-    byte matrices side by side -- are stacked ``[capacity, k]`` and read
-    by ONE gather of rows: a gather's cost on the chip is its index
-    count, hardly its row width (PERF.md, PRs 28 and 32)."""
+    two-operand sort all the same); the rows then move by
+    :func:`gather_stacked`."""
     cap = keep.shape[0]
     row = jnp.arange(cap, dtype=jnp.int32)
     src = jnp.zeros(slots, jnp.int32).at[
@@ -141,35 +170,13 @@ def _move_rows(columns: Sequence[DeviceColumn], keep: jax.Array,
             row, unique_indices=True, mode="drop")
     live = jnp.arange(slots, dtype=jnp.int32) < new_count
 
-    stacks: dict = {}
-    for c in columns:
-        for leaf in (c.validity, c.data, c.lengths):
-            if leaf is not None:
-                stacks.setdefault(leaf.dtype, []).append(
-                    leaf.reshape(cap, -1))
-    moved = {}
-    for dtype, leaves in stacks.items():
-        rows = jnp.concatenate(leaves, axis=1)[src]
-        bounds = np.cumsum([x.shape[1] for x in leaves])[:-1]
-        moved[dtype] = iter(jnp.split(rows, bounds, axis=1))
-
     def pad(x):
-        return x if slots == cap else jnp.pad(
+        return x if x is None or slots == cap else jnp.pad(
             x, ((0, cap - slots),) + ((0, 0),) * (x.ndim - 1))
 
-    out = []
-    for c in columns:
-        validity = next(moved[c.validity.dtype]).reshape(slots) & live
-        data = next(moved[c.data.dtype]).reshape(
-            (slots,) + c.data.shape[1:])
-        data = jnp.where(validity[(...,) + (None,) * (data.ndim - 1)],
-                         data, jnp.zeros((), data.dtype))
-        lengths = None
-        if c.is_var_width:
-            lengths = pad(jnp.where(
-                validity, next(moved[c.lengths.dtype]).reshape(slots), 0))
-        out.append(DeviceColumn(pad(data), pad(validity), c.dtype, lengths))
-    return out
+    return [DeviceColumn(pad(c.data), pad(c.validity), c.dtype,
+                         pad(c.lengths))
+            for c in gather_stacked(columns, src, live)]
 
 
 def take(batch: ColumnBatch, indices: jax.Array,

@@ -5,8 +5,11 @@ Reference: GpuHashAggregateExec computes cuDF hash-group-by per batch then
 merges (aggregate.scala:348-560).  XLA has no device hash tables, so the
 TPU-idiomatic design (SURVEY §7 "hard parts") is *sort-based*
 (:func:`sorted_group_by`): sort rows by the grouping keys, mark segment
-boundaries, and reduce with XLA segment ops — fully static shapes, group
-count as a traced scalar.
+boundaries, reduce each now contiguous group by a segmented scan that
+leaves the group's value on its first row, and move keys and results
+from those rows to the front by one gather a dtype (:class:`_Segments`;
+no scatter, whose cost on the chip is a further sort) — fully static
+shapes, group count as a traced scalar.
 
 When the update sorts and when it does not (:func:`group_by_update`, the
 entry point of ``HashAggregateExec``'s per-batch update): the program
@@ -39,9 +42,11 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.ops import cents
-from spark_rapids_tpu.ops.kernels import _pad_jit, gather_columns
+from spark_rapids_tpu.ops.kernels import (_pad_jit, gather_columns,
+                                          gather_stacked)
 from spark_rapids_tpu.ops.sort import (SortOrder, normalize_floats,
-                                       sort_batch, string_key_words)
+                                       sort_batch, sort_permutation,
+                                       string_key_words)
 
 __all__ = ["AggSpec", "sorted_group_by", "group_by_update"]
 
@@ -104,6 +109,13 @@ def sorted_group_by(batch: ColumnBatch, key_indices: list[int],
     are already contiguous (PlanNode.output_ordering) — segment
     detection only needs contiguity, so the O(n log n) sort is skipped
     (the reference's sort-aggregate-over-sorted-input fast path).
+
+    After the sort nothing moves ``capacity`` rows by scatter: each
+    aggregate is a segmented scan that leaves a group's value on its
+    segment's first row (:class:`_Segments`), the result columns are
+    made row-wise there, and ONE gather a dtype at the segment starts
+    (a one-operand sort of the flagged row numbers) brings every group's
+    key and results to the front.
     """
     cap = batch.capacity
     # percentile is order-holistic: rows must ALSO sort by the value
@@ -121,10 +133,14 @@ def sorted_group_by(batch: ColumnBatch, key_indices: list[int],
         else:
             orders = [SortOrder(i, True, True) for i in key_indices]
             orders += [SortOrder(i, True, False) for i in pct_cols]
-            sb = sort_batch(batch, orders)
+            # the rows move in one gather a dtype, not one a leaf
+            sb = ColumnBatch(
+                gather_stacked(batch.columns, sort_permutation(batch, orders),
+                               batch.row_mask()),
+                batch.num_rows, batch.schema)
+    idx = jnp.arange(cap, dtype=jnp.int32)
     if key_indices:
         real = sb.row_mask()
-        idx = jnp.arange(cap, dtype=jnp.int32)
         differ = jnp.zeros(cap, jnp.bool_)
         for ki in key_indices:
             differ = differ | _cols_differ(sb.columns[ki])
@@ -140,34 +156,17 @@ def sorted_group_by(batch: ColumnBatch, key_indices: list[int],
         real = sb.row_mask()
         seg_id = jnp.zeros(cap, jnp.int32)
         num_groups = jnp.asarray(1, jnp.int32)  # grand aggregate: one row
-        flag = jnp.arange(cap, dtype=jnp.int32) == 0
+        flag = idx == 0
 
-    out_mask = jnp.arange(cap, dtype=jnp.int32) < num_groups
-    out_cols: list[DeviceColumn] = []
-
-    # --- key columns: value at each segment start -------------------------
-    for ki in key_indices:
-        col = sb.columns[ki]
-        pos = jnp.where(flag & real, seg_id, cap)  # scatter target (drop pad)
-        validity = jnp.zeros(cap, jnp.bool_).at[pos].set(col.validity, mode="drop")
-        validity = validity & out_mask
-        if col.is_var_width:
-            data = jnp.zeros((cap, col.max_len),
-                             col.data.dtype).at[pos].set(col.data, mode="drop")
-            lengths = jnp.zeros(cap, jnp.int32).at[pos].set(col.lengths, mode="drop")
-            out_cols.append(DeviceColumn(jnp.where(validity[:, None], data, 0),
-                                         validity, col.dtype,
-                                         jnp.where(validity, lengths, 0)))
-        else:
-            data = jnp.zeros(cap, col.data.dtype).at[pos].set(col.data, mode="drop")
-            out_cols.append(DeviceColumn(
-                jnp.where(validity, data, jnp.zeros((), data.dtype)),
-                validity, col.dtype))
-
-    # --- aggregates -------------------------------------------------------
-    out_cols += _agg_columns(sb, aggs, _Segments(seg_id, cap), real, out_mask)
-    return ColumnBatch(out_cols, num_groups,
-                       _output_schema(batch.schema, key_indices, aggs))
+    # every result is made on its segment's first row, beside the key:
+    # one gather a dtype at the starts moves keys and results together
+    rows = [sb.columns[ki] for ki in key_indices] + _agg_columns(
+        sb, aggs, _Segments(jnp.roll(flag, -1), seg_id), real,
+        jnp.ones(cap, jnp.bool_))
+    starts = jnp.sort(jnp.where(flag, idx, cap), stable=False)
+    return ColumnBatch(
+        gather_stacked(rows, jnp.minimum(starts, cap - 1), idx < num_groups),
+        num_groups, _output_schema(batch.schema, key_indices, aggs))
 
 
 def _output_schema(schema: T.Schema, key_indices: list[int],
@@ -184,21 +183,41 @@ def _output_schema(schema: T.Schema, key_indices: list[int],
     return T.Schema(fields)
 
 
-class _Segments:
-    """The sorted path's reductions: slot ``s`` of a result reduces the
-    rows whose ``seg_id`` is ``s`` (``cap`` slots)."""
+def _segmented_scan(op, last: jax.Array, x: jax.Array) -> jax.Array:
+    """Scan ``x`` by ``op`` from each segment's last row (where ``last``
+    is set; the final row's is) back to its first, which ends up holding
+    the whole segment's value: ``log2(capacity)`` passes, each combining
+    a row with the one ``d`` after it unless a segment ends between."""
+    d = 1
+    while d < x.shape[0]:
+        x = jnp.where(last, x, op(x, jnp.roll(x, -d)))
+        last = last | jnp.roll(last, -d)
+        d *= 2
+    return x
 
-    def __init__(self, seg_id: jax.Array, cap: int):
-        self.seg_id, self.cap = seg_id, cap
+
+class _Segments:
+    """The sorted path's reductions.  The rows of a segment are
+    contiguous and ``last`` marks each segment's last row (``seg_id`` is
+    the running count of segments), so a reduction is a segmented scan
+    and nothing scatters: a result has a slot a ROW, and the slot that
+    counts is the segment's first row, which holds the value of the whole
+    segment, made of its own rows only (an integer sum exactly, a
+    floating one without the rounding of any other group).  What
+    ``_compute_agg`` derives from such results holds on those rows too;
+    ``sorted_group_by`` reads them there once everything is made."""
+
+    def __init__(self, last: jax.Array, seg_id: jax.Array):
+        self.last, self.seg_id = last, seg_id
 
     def sum(self, x):
-        return jax.ops.segment_sum(x, self.seg_id, num_segments=self.cap)
+        return _segmented_scan(jnp.add, self.last, x)
 
     def min(self, x):
-        return jax.ops.segment_min(x, self.seg_id, num_segments=self.cap)
+        return _segmented_scan(jnp.minimum, self.last, x)
 
     def max(self, x):
-        return jax.ops.segment_max(x, self.seg_id, num_segments=self.cap)
+        return _segmented_scan(jnp.maximum, self.last, x)
 
 
 class _OneGroup:
@@ -236,9 +255,11 @@ def _sum_doubles(red, x: jax.Array) -> jax.Array:
 def _agg_columns(batch: ColumnBatch, aggs: list[AggSpec], red, real,
                  out_mask) -> list[DeviceColumn]:
     """One result column per spec; ``red`` reduces rows to result slots
-    (:class:`_Segments` or :class:`_OneGroup`), ``real`` marks the rows
+    (:class:`_OneGroup`: one slot; :class:`_Segments`: a slot a row, the
+    one that counts being its segment's first), ``real`` marks the rows
     that count and ``out_mask`` the result slots that hold a group."""
-    real_cnt = red.sum(real.astype(jnp.int64))
+    # a count fits int32 (capacity does); 64-bit words are emulated on the chip
+    real_cnt = red.sum(real.astype(jnp.int32)).astype(jnp.int64)
     return [_compute_agg(
         spec, None if spec.op == "count_star" else
         batch.columns[spec.child_index], red, real, out_mask, real_cnt)
@@ -255,7 +276,7 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, red, real,
                             T.LongType())
 
     contributes = col.validity & real
-    cnt_valid = red.sum(contributes.astype(jnp.int64))
+    cnt_valid = red.sum(contributes.astype(jnp.int32)).astype(jnp.int64)
 
     if op == "count":
         validity = out_mask
@@ -310,23 +331,13 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, red, real,
             words = encode_key_operands(col, ascending=(op == "min"))
             flag = (~contributes).astype(jnp.uint8)
             iota = jnp.arange(cap, dtype=jnp.int32)
-            sorted_ops = lax.sort([seg_id, flag, *words, iota],
-                                  num_keys=2 + len(words),
-                                  is_stable=True)
-            s_seg, s_flag, order = sorted_ops[0], sorted_ops[1], sorted_ops[-1]
-            firsts = jnp.concatenate(
-                [jnp.ones(1, jnp.bool_), s_seg[1:] != s_seg[:-1]])
-            take = firsts & (s_flag == 0)
-            target = jnp.where(take, s_seg, cap)
-            src = col.data[order]
-            data = jnp.zeros((cap, col.max_len), jnp.uint8
-                             ).at[target].set(src, mode="drop")
-            lens = jnp.zeros(cap, jnp.int32
-                             ).at[target].set(col.lengths[order], mode="drop")
+            # a segment still begins on the same row after this sort
+            order = lax.sort([seg_id, flag, *words, iota],
+                             num_keys=2 + len(words), is_stable=True)[-1]
             validity = (cnt_valid > 0) & out_mask
-            return DeviceColumn(jnp.where(validity[:, None], data, 0),
-                                validity, col.dtype,
-                                jnp.where(validity, lens, 0))
+            return DeviceColumn(
+                jnp.where(validity[:, None], col.data[order], 0), validity,
+                col.dtype, jnp.where(validity, col.lengths[order], 0))
         else:
             info = jnp.iinfo(col.data.dtype) if col.data.dtype != jnp.bool_ else None
             if col.data.dtype == jnp.bool_:
@@ -350,13 +361,11 @@ def _compute_agg(spec: AggSpec, col: DeviceColumn | None, red, real,
         # linear interpolation at q*(n-1), Spark Percentile semantics
         q = spec.param
         assert q is not None, "percentile AggSpec needs param=q"
-        idx = jnp.arange(cap, dtype=jnp.int32)
-        starts = red.min(jnp.where(real, idx, cap))
         pos = (cnt_valid - 1).astype(jnp.float64) * q
         lo = jnp.floor(pos).astype(jnp.int32)
         hi = jnp.ceil(pos).astype(jnp.int32)
         frac = pos - lo
-        base = jnp.clip(starts, 0, cap - 1)
+        base = jnp.arange(cap, dtype=jnp.int32)  # a result's own row
         x = col.data.astype(jnp.float64)
         vlo = x[jnp.clip(base + lo, 0, cap - 1)]
         vhi = x[jnp.clip(base + hi, 0, cap - 1)]

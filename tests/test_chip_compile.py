@@ -13,6 +13,8 @@ may load the TPU library, and every xdist worker imports every file.
 Capacity 2^14 keeps each compile to seconds; the 2^20 programs of a real
 q6 are sized by hand before a chip call (PERF.md).
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -64,6 +66,17 @@ def _compile(fn, *args):
     compiled = jitted.lower(*args).compile()
     assert compiled.memory_analysis() is not None
     return compiled
+
+
+def _capacity_row(hlo: str, op: str) -> list[str]:
+    """Names of the compiled module's ``op`` instructions that take or
+    give an array of ``CAP`` rows."""
+    types = dict(re.findall(r"(%[^\s=]+) = (.+?) [\w-]+\(", hlo))
+    big = re.compile(rf"\[{CAP}[\],]")
+    return [name for name, rtype, args in re.findall(
+        rf"(%[^\s=]+) = (.+?) {op}\(([^)]*)\)", hlo)
+        if big.search(rtype) or any(
+            big.search(types.get(a.strip(), "")) for a in args.split(","))]
 
 
 def _sales_batch(n: int = 900, cap: int = CAP) -> ColumnBatch:
@@ -350,6 +363,12 @@ def test_group_by_update_compiles_for_v5e(one_chip):
                         _shapes(b, one_chip))
     hlo = compiled.as_text()
     assert " conditional(" in hlo and " while(" in hlo
+    # the sort branch reads its sorted rows through scans and gathers:
+    # beside the key sort at most one more capacity-row sort (the segment
+    # starts), and at most one scatter of capacity-many updates -- what
+    # the TPU compiler makes of a scatter that large is a sort of its own
+    sorts, scatters = _capacity_row(hlo, "sort"), _capacity_row(hlo, "scatter")
+    assert len(sorts) <= 2 and len(scatters) <= 1, (sorts, scatters)
 
 
 def test_distributed_groupby_compiles_for_2x2_mesh(topo):

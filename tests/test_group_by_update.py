@@ -236,6 +236,109 @@ def test_table_never_wider_than_the_batch():
 
 
 # ---------------------------------------------------------------------------
+# the sort branch's body alone (scans and gathers at the segment
+# boundaries): every op x the shapes a boundary can be wrong at
+# ---------------------------------------------------------------------------
+
+def _body_table(n: int) -> HostBatch:
+    rng = np.random.default_rng(n)
+    schema = T.Schema([
+        T.StructField("k", T.LongType(), True),      # few groups, a null one
+        T.StructField("s", T.StringType(), True),
+        T.StructField("one", T.IntegerType(), True),  # one group of every row
+        T.StructField("u", T.LongType(), True),      # every row its own group
+        T.StructField("x", T.LongType(), True),
+        T.StructField("c", T.DoubleType(), True),    # whole cents
+        T.StructField("y", T.DoubleType(), True),    # thousandths: not whole
+        T.StructField("z", T.DoubleType(), True),
+        T.StructField("b", T.BooleanType(), True),
+        T.StructField("w", T.StringType(), True),
+    ])
+    return HostBatch.from_pydict({
+        "k": _with_nulls(rng, rng.integers(-3, 4, n).tolist()),
+        "s": _with_nulls(rng, rng.choice(["a", "ab", "", "b"], n).tolist()),
+        "one": [7] * n,
+        "u": rng.permutation(n).tolist(),
+        "x": _with_nulls(rng, rng.integers(-10**12, 10**12, n).tolist()),
+        "c": _with_nulls(rng, (rng.integers(-5000, 5000, n) / 100).tolist()),
+        "y": _with_nulls(rng, (rng.integers(1, 99999, n) / 1000).tolist()),
+        "z": _with_nulls(rng, rng.choice(
+            [NAN, -0.0, 0.0, -2.5, 1e300, math.inf, -math.inf], n).tolist()),
+        "b": _with_nulls(rng, (rng.random(n) < 0.5).tolist()),
+        "w": _with_nulls(rng, rng.choice(["p", "q", "rs", ""], n).tolist()),
+    }, schema)
+
+
+BK, BS, ONE, BU, BX, BC, BY, BZ, BB, BW = range(10)
+
+BODY_OPS = {
+    "counts": [AggSpec("count_star", 0), AggSpec("count", BX)],
+    "sum_int": [AggSpec("sum", BX), AggSpec("avg", BX)],
+    "sum_whole_cents": [AggSpec("sum", BC), AggSpec("avg", BC)],
+    "sum_not_whole": [AggSpec("sum", BY), AggSpec("avg", BY)],
+    "min_max_int": [AggSpec("min", BX), AggSpec("max", BX)],
+    "min_max_double": [AggSpec("min", BZ), AggSpec("max", BZ)],
+    "min_max_bool": [AggSpec("min", BB), AggSpec("max", BB)],
+    "min_max_string": [AggSpec("min", BW), AggSpec("max", BW)],
+    "first_last": [AggSpec("first", BZ), AggSpec("last", BX),
+                   AggSpec("first", BW), AggSpec("last", BW),
+                   AggSpec("first_non_null", BZ),
+                   AggSpec("last_non_null", BX),
+                   AggSpec("first_non_null", BW),
+                   AggSpec("last_non_null", BW)],
+    "percentile": [AggSpec("percentile", BY, 0.3), AggSpec("count", BY)],
+}
+
+_BODY_N, _BODY_CAP = 48, 64
+
+
+def _sorted_by_key(t: HostBatch) -> HostBatch:
+    k = t.columns[BK]
+    order = np.argsort(np.where(k.validity, k.data, -99), kind="stable")
+    return HostBatch([c.take(order) for c in t.columns], t.schema)
+
+
+#: name -> (rows, keys, presorted, the device batch's num_rows forced to 0)
+BODY_SHAPES = {
+    "empty": (0, [BK], False, False),
+    "all_padding": (_BODY_N, [BK], False, True),
+    "num_rows_is_capacity": (_BODY_CAP, [BK], False, False),
+    "one_group": (_BODY_N, [ONE], False, False),
+    "every_row_a_group": (_BODY_N, [BU], False, False),
+    "every_row_a_group_full": (_BODY_CAP, [BU], False, False),
+    "null_key_group": (_BODY_N, [BK], False, False),
+    "two_keys_with_string": (_BODY_N, [BS, BK], False, False),
+    "presorted": (_BODY_N, [BK], True, False),
+    "grand": (_BODY_N, [], False, False),
+    "grand_all_padding": (_BODY_N, [], False, True),
+}
+
+
+@pytest.mark.parametrize("ops", sorted(BODY_OPS))
+@pytest.mark.parametrize("shape", sorted(BODY_SHAPES))
+def test_sorted_group_by_matches_host(shape, ops):
+    rows, keys, presorted, hollow = BODY_SHAPES[shape]
+    hb = _body_table(rows)
+    if presorted:
+        hb = _sorted_by_key(hb)
+    db = host_to_device(hb, _BODY_CAP)
+    assert db.capacity == _BODY_CAP
+    if hollow:      # stale rows under num_rows == 0 must never count
+        db = type(db)(db.columns, jax.numpy.asarray(0, jax.numpy.int32),
+                      db.schema)
+        hb = HostBatch([c.take(np.zeros(0, np.int64)) for c in hb.columns],
+                       hb.schema)
+    aggs = BODY_OPS[ops]
+    out = jax.jit(lambda b: sorted_group_by(b, keys, aggs, presorted))(db)
+    assert out.capacity == _BODY_CAP
+    _assert_rows(device_to_host(out).to_rows(),
+                 hk.host_group_by(hb, keys, aggs).to_rows())
+    # canonical: nothing is left in the slots past the groups
+    for leaf in jax.tree.leaves(out.columns):
+        assert not np.asarray(leaf)[int(out.num_rows):].any()
+
+
+# ---------------------------------------------------------------------------
 # through the session: which branch ran is in the query's record
 # ---------------------------------------------------------------------------
 
