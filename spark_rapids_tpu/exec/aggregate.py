@@ -402,6 +402,7 @@ class HashAggregateExec(PlanNode):
             ng = merged.host_num_rows(_FETCH)
             cap = round_capacity(max(int(ng), 1))
             merged = ctx.dispatch(dk.shrink_capacity, merged, cap)
+            merged.known_rows = int(ng)
             parts = [merged]
             total_cap = cap
 
@@ -468,6 +469,7 @@ class HashAggregateExec(PlanNode):
                     continue
                 cap = round_capacity(max(ng, 1))
                 part = ctx.dispatch(dk.shrink_capacity, part, cap)
+                part.known_rows = ng
                 parts.append(part)
                 total_cap += cap
                 if total_cap >= self._MERGE_PENDING_CAP:
@@ -504,7 +506,10 @@ class HashAggregateExec(PlanNode):
         if self.mode == "partial":
             yield running
         else:
-            yield ctx.dispatch(final_jit, running)
+            # the final projection keeps the groups the flushes counted
+            out = ctx.dispatch(final_jit, running)
+            out.known_rows = running.known_rows
+            yield out
 
     # -- host oracle path --------------------------------------------------
     def _run_host(self, child_it, key_idx) -> Iterator[HostBatch]:

@@ -171,7 +171,12 @@ class ProjectExec(PlanNode):
                     offset = offset + b.num_rows
                 # elementwise: splitting on OOM yields identical rows
                 # in order (reference GpuProjectExec withRetry)
-                yield from ctx.dispatch_retry(fn, b, op="project")
+                outs = list(ctx.dispatch_retry(fn, b, op="project"))
+                if len(outs) == 1:
+                    # a projection keeps its rows: the host-side count
+                    # the jit boundary strips stays free downstream
+                    outs[0].known_rows = b.known_rows
+                yield from outs
         else:
             offset = 0
             for b in child_it:

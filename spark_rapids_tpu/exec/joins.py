@@ -459,7 +459,9 @@ class JoinExec(PlanNode):
                 matched = jnp.zeros(rb2.capacity, jnp.bool_)
             dk.count_compaction(rb2.capacity)
             tail = self._unmatched_right_jit()(rb2, matched)
-            if tail.host_num_rows(_FETCH) > 0:
+            unmatched = tail.host_num_rows(_FETCH)
+            get_registry().inc("join.full.unmatched_rows", unmatched)
+            if unmatched > 0:
                 yield tail
 
     def _stream_aug_fields(self):

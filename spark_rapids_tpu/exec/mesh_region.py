@@ -284,7 +284,7 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
         aug, orders, part_idx, order_idx, input_idx, nbase = \
             self._window_args(b)
         return _window_body(aug, orders, part_idx, order_idx, input_idx,
-                            tuple(self._wexprs), nbase, self._schema)
+                            self._fns, nbase, self._schema)
 
     def _local_step(self):
         """Per-device body (local view in, local view out) — the unit a

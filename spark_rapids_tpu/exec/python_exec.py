@@ -661,12 +661,11 @@ class WindowInPandasExec(PlanNode):
             assert isinstance(e, WindowExpression), e
             assert isinstance(e.function, PandasWindowUDF), e.function
             self._wexprs.append(e)
-        spec0 = self._wexprs[0].spec
-        for e in self._wexprs[1:]:
-            if e.spec != spec0:
-                raise ValueError("one WindowInPandasExec handles one "
-                                 "WindowSpec; split plans per spec")
-        self.spec = spec0
+        from spark_rapids_tpu.exec.window import spec_key
+        self.spec = self._wexprs[0].spec
+        if len({spec_key(e.spec) for e in self._wexprs}) > 1:
+            raise ValueError("one WindowInPandasExec handles one "
+                             "WindowSpec; split plans per spec")
         cs = child.output_schema
         self._part_b = [bind(p, cs) for p in self.spec.partition_by]
         self._order_b = [(bind(o[0], cs), o[1] if len(o) > 1 else True,

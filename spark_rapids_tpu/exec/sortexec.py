@@ -113,6 +113,8 @@ class SortExec(PlanNode):
         if ctx.is_device:
             b = batches[0] if len(batches) == 1 \
                 else ctx.dispatch(dk.concat_batches, batches)
+            if self._global:
+                b = _right_sized(ctx, b)
             # withRetryNoSplit (reference GpuSortExec): a sort's output
             # is a TOTAL order over its input — emitting independently
             # sorted halves would break it, so on OOM this scope only
@@ -127,6 +129,37 @@ class SortExec(PlanNode):
 
     def node_desc(self) -> str:
         return f"SortExec[{self._orders}]"
+
+
+#: a total sort right-sizes an input of at least this many slots whose
+#: row count the host does not hold: below it a sort of the empty slots
+#: costs less than the fetch that would tell (PERF.md Findings PR 38)
+_RIGHT_SIZE_MIN_CAPACITY = 1 << 16
+
+
+def _shrunk_to_rows(ctx: ExecCtx, b, op: str):
+    """``b`` in its rows' own capacity bucket where that is at most half
+    of its capacity (one fetch of the count where the host does not hold
+    it, a slice program), else ``b`` as it is."""
+    from spark_rapids_tpu.columnar.batch import round_capacity
+    rows = b.host_num_rows(op)
+    cap = round_capacity(max(rows, 1))
+    if cap > b.capacity // 2:
+        return b
+    b = ctx.dispatch(dk.shrink_capacity, b, cap)
+    b.known_rows = rows
+    return b
+
+
+def _right_sized(ctx: ExecCtx, b):
+    """A total sort, and the fetch of its result, run at batch CAPACITY,
+    and the sort's one input may be what a filter left of a far larger
+    batch (TPC-DS q51: 92k rows in 2^23 slots): sort it in its rows' own
+    bucket.  The count is the host's where the batch carries it
+    (``known_rows``); else one fetch, for large batches only."""
+    if b.known_rows is None and b.capacity < _RIGHT_SIZE_MIN_CAPACITY:
+        return b
+    return _shrunk_to_rows(ctx, b, "fetch@SortExec")
 
 
 class CoalesceBatchesExec(PlanNode):
@@ -216,12 +249,7 @@ class CoalesceBatchesExec(PlanNode):
         leave batches sparse."""
         if not ctx.is_device or not self._upstream_can_shrink():
             return b
-        from spark_rapids_tpu.columnar.batch import round_capacity
-        n = b.host_num_rows("fetch@CoalesceBatchesExec")
-        cap = round_capacity(max(n, 1))
-        if cap > b.capacity // 2:
-            return b
-        return ctx.dispatch(dk.shrink_capacity, b, cap)
+        return _shrunk_to_rows(ctx, b, "fetch@CoalesceBatchesExec")
 
     def _flush(self, ctx: ExecCtx, batches: list):
         if len(batches) == 1:

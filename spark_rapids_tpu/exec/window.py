@@ -25,21 +25,57 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, RequireSingleBatch
-from spark_rapids_tpu.exec.compile_cache import guarded_jit
-from spark_rapids_tpu.expr.core import (Expression, bind, eval_device,
-                                        eval_host, output_name)
+from spark_rapids_tpu.exec.compile_cache import fingerprint, guarded_jit
+from spark_rapids_tpu.expr.core import (BoundReference, Expression, Literal,
+                                        bind, eval_device, eval_host,
+                                        output_name)
 from spark_rapids_tpu.expr import aggregates as A
 from spark_rapids_tpu.expr.window import (DenseRank, Lag, Lead, Rank,
                                           RowNumber, WindowExpression,
                                           window_agg_op)
 from spark_rapids_tpu.host.batch import HostBatch, HostColumn
 from spark_rapids_tpu.obs.registry import get_registry
+from spark_rapids_tpu.ops import cents
 from spark_rapids_tpu.ops import host_kernels as hk
 from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops import window as W
-from spark_rapids_tpu.ops.sort import SortOrder, sort_batch
+from spark_rapids_tpu.ops.sort import SortOrder, sort_permutation
 
-__all__ = ["WindowExec"]
+__all__ = ["WindowExec", "spec_key"]
+
+
+def spec_key(spec) -> str:
+    """A ``WindowSpec``'s content: the canonical fingerprint of its
+    partition-by, order-by (direction and null order resolved) and frame.
+    Window expressions are grouped into one ``WindowExec`` by this key,
+    never by ``==`` or ``hash`` of the spec: an ``Expression`` hashes by
+    identity, and its ``==`` builds an ``EqualTo`` node, which is
+    truthy whatever it compares."""
+    orders = tuple((o[0], o[1] if len(o) > 1 else True,
+                    o[2] if len(o) > 2 else None) for o in spec.order_by)
+    return fingerprint(tuple(spec.partition_by), orders,
+                       spec.resolved_frame())
+
+
+def _fn_desc(w: WindowExpression) -> tuple:
+    """What ``_window_body`` needs of a window expression, as plain
+    hashable data (a static argument of the jitted program):
+    ``(kind,)`` for the ranking functions, ``("offset", rows, default)``
+    for lead / lag, ``("agg", op, frame)`` for an aggregate."""
+    f = w.function
+    for cls, kind in ((RowNumber, "row_number"), (Rank, "rank"),
+                      (DenseRank, "dense_rank")):
+        if isinstance(f, cls):
+            return (kind,)
+    if isinstance(f, (Lead, Lag)):
+        # Lag subclasses Lead: a lag reads backwards
+        default = None
+        if f.default is not None:
+            assert isinstance(f.default, Literal)
+            default = f.default.value
+        return ("offset", -f.offset if isinstance(f, Lag) else f.offset,
+                default)
+    return ("agg", window_agg_op(f), w.spec.resolved_frame())
 
 
 def _wexpr_dtype(w: WindowExpression, bound_input) -> T.DataType:
@@ -82,12 +118,11 @@ class WindowExec(PlanNode):
             assert isinstance(e, WindowExpression), e
             self._wexprs.append(e)
         assert self._wexprs, "need at least one window expression"
-        spec0 = self._wexprs[0].spec
-        for e in self._wexprs[1:]:
-            if e.spec != spec0:
-                raise ValueError("one WindowExec handles one WindowSpec; "
-                                 "split plans per spec as Spark does")
-        self.spec = spec0
+        self.spec = self._wexprs[0].spec
+        if len({spec_key(e.spec) for e in self._wexprs}) > 1:
+            raise ValueError("one WindowExec handles one WindowSpec; "
+                             "split plans per spec as Spark does")
+        self._fns = tuple(_fn_desc(w) for w in self._wexprs)
         cs = child.output_schema
         # bind partition/order/function-input expressions against the child
         self._part_b = [bind(p, cs) for p in self.spec.partition_by]
@@ -109,6 +144,29 @@ class WindowExec(PlanNode):
             list(cs.fields)
             + [T.StructField(n, dt, True)
                for n, dt in zip(self._names, self._out_dtypes)])
+
+        # one launch's counters, all from what the host holds: the
+        # aggregate frames answered by a scan; the sum / avg frames over
+        # doubles, which the program takes over int64 cents (ops/cents.py;
+        # a frame holding an addend that is not whole cents falls back,
+        # inside the program, to adding doubles); and, where the batch
+        # carries its row count (known_rows: an exchange's piece, an
+        # aggregate's or a join's output, a projection of such), its rows
+        # by total and by the operator's schema, window.rows@<input
+        # types>><appended types>: what a roofline over rows and schema
+        # needs
+        aggs = [(fn, inp) for fn, inp in zip(self._fns, self._fn_inputs)
+                if fn[0] == "agg"]
+        self._launch_counts = [
+            ("window.launches", 1),
+            ("window.frames.scanned",
+             sum(1 for fn, _ in aggs if W.frame_scans(fn[2]))),
+            ("window.sum.cents",
+             sum(1 for fn, inp in aggs if fn[1] in ("sum", "avg")
+                 and inp.dtype.fractional))]
+        self._rows_by_schema = "window.rows@{}>{}".format(
+            ",".join(f.data_type.name for f in cs.fields),
+            ",".join(dt.name for dt in self._out_dtypes))
 
     @property
     def output_schema(self) -> T.Schema:
@@ -209,17 +267,20 @@ class WindowExec(PlanNode):
         inputs = self._fn_inputs
 
         def update(b: ColumnBatch):
-            """Per-wexpr state (sum, count, min, max, rows).  Integral
-            inputs accumulate in int64 (an f64 fold would round sums and
-            extremes past 2^53 — the single-batch segment kernels are
-            exact there, and the two paths must agree)."""
+            """Per-wexpr state (sum, count, min, max, rows, cents, odd).
+            Integral inputs accumulate in int64 (an f64 fold would round
+            sums and extremes past 2^53 — the single-batch segment
+            kernels are exact there, and the two paths must agree), and
+            doubles that are whole cents also as ``cents``, with ``odd``
+            counting the addends that are not (ops/cents.py): the sum is
+            then the same double whatever batches the rows came in."""
             out = []
             real = b.row_mask()
             rows = jnp.sum(real, dtype=jnp.int64)
+            z = jnp.zeros((), jnp.int64)
             for e in inputs:
                 if e is None:
-                    z = jnp.zeros((), jnp.int64)
-                    out.append((z, rows, z, z, rows))
+                    out.append((z, rows, z, z, rows, z, z))
                     continue
                 c = eval_device(e, b)
                 valid = c.validity & real
@@ -233,14 +294,27 @@ class WindowExec(PlanNode):
                 xd = c.data.astype(acc)
                 mn = jnp.min(jnp.where(valid, xd, big))
                 mx = jnp.max(jnp.where(valid, xd, small))
-                out.append((jnp.sum(x), cnt, mn, mx, rows))
+                money, odd = z, z
+                if acc == jnp.float64:
+                    in_cents, whole = cents.as_cents(
+                        jnp, x, min(cents.ROW_LIMIT,
+                                    (1 << 62) // max(b.capacity, 1)))
+                    money = jnp.sum(in_cents)
+                    odd = jnp.sum(~whole, dtype=jnp.int64)
+                out.append((jnp.sum(x), cnt, mn, mx, rows, money, odd))
             return tuple(out)
 
         def merge(a, b):
+            def money(ma, mb, oa, ob):
+                # a total past SUM_LIMIT is no longer given as cents, and
+                # must not grow on towards int64's end
+                m = ma + mb
+                return m, oa + ob + (jnp.abs(m) >= cents.SUM_LIMIT)
             return tuple((sa + sb, ca + cb, jnp.minimum(mna, mnb),
-                          jnp.maximum(mxa, mxb), ra + rb)
-                         for (sa, ca, mna, mxa, ra),
-                             (sb, cb, mnb, mxb, rb) in zip(a, b))
+                          jnp.maximum(mxa, mxb), ra + rb,
+                          *money(ma, mb, oa, ob))
+                         for (sa, ca, mna, mxa, ra, ma, oa),
+                             (sb, cb, mnb, mxb, rb, mb, ob) in zip(a, b))
 
         if not hasattr(self, "_gs_jits"):
             from spark_rapids_tpu.exec import compile_cache as cc
@@ -272,9 +346,13 @@ class WindowExec(PlanNode):
         def append(b: ColumnBatch, st):
             cols = list(b.columns)
             real = b.row_mask()
-            for (s, cnt, mn, mx, rows), w, dt in zip(
+            for (s, cnt, mn, mx, rows, money, odd), w, dt in zip(
                     st, self._wexprs, self._out_dtypes):
                 op = window_agg_op(w.function)
+                if s.dtype == jnp.float64:
+                    s = jnp.where((odd == 0)
+                                  & (jnp.abs(money) < cents.SUM_LIMIT),
+                                  cents.from_cents(jnp, money), s)
                 if op == "count_star":
                     val, ok = rows, jnp.bool_(True)
                 elif op == "count":
@@ -282,7 +360,8 @@ class WindowExec(PlanNode):
                 elif op == "sum":
                     val, ok = s, cnt > 0
                 elif op == "avg":
-                    val = s.astype(jnp.float64) / jnp.maximum(cnt, 1)
+                    val = cents.mean(jnp, s.astype(jnp.float64),
+                                     jnp.maximum(cnt, 1))
                     ok = cnt > 0
                 elif op == "min":
                     val, ok = mn, cnt > 0
@@ -324,22 +403,19 @@ class WindowExec(PlanNode):
         nbase = big.num_columns
         cols = list(big.columns)
         fields = list(big.schema.fields)
-        part_idx, order_idx, input_idx = [], [], []
-        for e in self._part_b:
+
+        def column(e, name: str) -> int:
+            # a plain column is sorted where it stands: appended again
+            # it would be moved twice by the row sort
+            if isinstance(e, BoundReference):
+                return e.index
             cols.append(eval_device(e, big))
-            fields.append(T.StructField(f"_wp{len(part_idx)}", e.dtype, True))
-            part_idx.append(len(cols) - 1)
-        for e, asc, nf in self._order_b:
-            cols.append(eval_device(e, big))
-            fields.append(T.StructField(f"_wo{len(order_idx)}", e.dtype, True))
-            order_idx.append(len(cols) - 1)
-        for e in self._fn_inputs:
-            if e is None:
-                input_idx.append(None)
-            else:
-                cols.append(eval_device(e, big))
-                fields.append(T.StructField(f"_wi{len(cols)}", e.dtype, True))
-                input_idx.append(len(cols) - 1)
+            fields.append(T.StructField(f"{name}{len(cols)}", e.dtype, True))
+            return len(cols) - 1
+        part_idx = [column(e, "_wp") for e in self._part_b]
+        order_idx = [column(e, "_wo") for e, _, _ in self._order_b]
+        input_idx = [None if e is None else column(e, "_wi")
+                     for e in self._fn_inputs]
         aug = ColumnBatch(cols, big.num_rows, T.Schema(fields))
         orders = [SortOrder(i, True, True) for i in part_idx] + \
             [SortOrder(i, asc, nf)
@@ -350,9 +426,13 @@ class WindowExec(PlanNode):
     def _run_device(self, big: ColumnBatch) -> ColumnBatch:
         aug, orders, part_idx, order_idx, input_idx, nbase = \
             self._window_args(big)
-        out = _jit_window(aug, orders, part_idx, order_idx, input_idx,
-                          tuple(self._wexprs), nbase, self._schema)
-        return out
+        rows = big.known_rows
+        get_registry().inc_many(self._launch_counts if rows is None else
+                                self._launch_counts + [
+                                    ("window.rows", rows),
+                                    (self._rows_by_schema, rows)])
+        return _jit_window(aug, orders, part_idx, order_idx, input_idx,
+                           self._fns, nbase, self._schema)
 
     def _run_host(self, big: HostBatch) -> HostBatch:
         n = big.num_rows
@@ -490,13 +570,13 @@ def _host_agg(op, vals, cnt_rows, dtype):
         return len(vals), True
     if not vals:
         return None, False
-    fvals = [float(v) for v in vals]
     if op == "sum":
         if isinstance(dtype, T.LongType):
             return int(sum(int(v) for v in vals)), True
-        return float(sum(fvals)), True
+        return _host_sum(vals), True
     if op == "avg":
-        return float(sum(fvals) / len(vals)), True
+        return float(cents.mean(np, np.float64(_host_sum(vals)),
+                                np.int64(len(vals)))), True
     has_nan = any(isinstance(v, float) and math.isnan(v) for v in vals)
     if op == "min":
         nn = [v for v in vals
@@ -511,6 +591,18 @@ def _host_agg(op, vals, cnt_rows, dtype):
     raise ValueError(op)
 
 
+def _host_sum(vals) -> float:
+    """A frame's sum of doubles as the device takes it (ops/cents.py):
+    over the cents as integers, rounded once, where every addend is
+    whole cents; one addend after another elsewhere."""
+    x = np.asarray([float(v) for v in vals], np.float64)
+    c, whole = cents.as_cents(np, x)
+    total = int(c.sum())
+    if whole.all() and abs(total) < cents.SUM_LIMIT:
+        return float(cents.from_cents(np, np.int64(total)))
+    return float(sum(x.tolist()))
+
+
 def _objs_to_host(data, validity, dtype) -> HostColumn:
     if isinstance(dtype, T.StringType):
         return HostColumn(data, validity, dtype)
@@ -523,68 +615,50 @@ def _objs_to_host(data, validity, dtype) -> HostColumn:
 
 
 def _window_body(aug: ColumnBatch, orders, part_idx, order_idx, input_idx,
-                 wexprs, nbase: int, schema: T.Schema) -> ColumnBatch:
+                 fns, nbase: int, schema: T.Schema) -> ColumnBatch:
     """The traceable window kernel: sort by (partition, order), derive
-    the shared segment arrays, evaluate every expression.  ``_jit_window``
-    is its eager jitted wrapper; MeshWindowExec calls the body directly
-    inside its per-device program."""
-    sb = sort_batch(aug, list(orders))
+    the shared segment arrays, evaluate every expression (``fns``:
+    ``_fn_desc`` of each).  ``_jit_window`` is its eager jitted wrapper;
+    MeshWindowExec calls the body directly inside its per-device
+    program."""
+    # the rows move in one gather a dtype, not one a leaf
+    sb = ColumnBatch(
+        dk.gather_stacked(aug.columns, sort_permutation(aug, list(orders)),
+                          aug.row_mask()), aug.num_rows, aug.schema)
     seg = W.sorted_segments(sb, part_idx, order_idx)
     out_cols = list(sb.columns[:nbase])
-    for w, ii in zip(wexprs, input_idx):
-        f = w.function
-        if isinstance(f, RowNumber):
-            data = W.row_number(seg).astype(jnp.int32)
+    for fn, ii in zip(fns, input_idx):
+        kind = fn[0]
+        if kind in ("row_number", "rank", "dense_rank"):
+            data = getattr(W, kind)(seg).astype(jnp.int32)
             out_cols.append(DeviceColumn(
                 jnp.where(seg.real, data, 0), seg.real, T.IntegerType()))
-        elif isinstance(f, Rank):
-            data = W.rank(seg).astype(jnp.int32)
-            out_cols.append(DeviceColumn(
-                jnp.where(seg.real, data, 0), seg.real, T.IntegerType()))
-        elif isinstance(f, DenseRank):
-            data = W.dense_rank(seg).astype(jnp.int32)
-            out_cols.append(DeviceColumn(
-                jnp.where(seg.real, data, 0), seg.real, T.IntegerType()))
-        elif isinstance(f, (Lead, Lag)):
-            # NOTE: Lag subclasses Lead — test Lag FIRST (isinstance of
-            # Lead is true for both; the old order made lag read forward)
-            off = -f.offset if isinstance(f, Lag) else f.offset
+        elif kind == "offset":
+            _, off, default = fn
             col = sb.columns[ii]
             dd = dv = dl = None
-            if f.default is not None:
-                from spark_rapids_tpu.expr.core import Literal
-                assert isinstance(f.default, Literal)
-                if f.default.value is not None:
-                    if col.is_string:
-                        import numpy as _np
-                        from spark_rapids_tpu.columnar.column import \
-                            round_string_width
-                        bs = str(f.default.value).encode("utf-8")
-                        w = max(col.max_len,
+            if default is not None:
+                if col.is_string:
+                    from spark_rapids_tpu.columnar.column import \
+                        round_string_width
+                    bs = str(default).encode("utf-8")
+                    width = max(col.max_len,
                                 round_string_width(max(len(bs), 1)))
-                        row = _np.zeros(w, _np.uint8)
-                        row[:len(bs)] = _np.frombuffer(bs, _np.uint8)
-                        dd = jnp.broadcast_to(jnp.asarray(row),
-                                              (sb.capacity, w))
-                        dl = jnp.full(sb.capacity, len(bs), jnp.int32)
-                    else:
-                        dd = jnp.full(sb.capacity, f.default.value,
-                                      col.data.dtype)
-                    dv = jnp.ones(sb.capacity, jnp.bool_)
+                    row = np.zeros(width, np.uint8)
+                    row[:len(bs)] = np.frombuffer(bs, np.uint8)
+                    dd = jnp.broadcast_to(jnp.asarray(row),
+                                          (sb.capacity, width))
+                    dl = jnp.full(sb.capacity, len(bs), jnp.int32)
+                else:
+                    dd = jnp.full(sb.capacity, default, col.data.dtype)
+                dv = jnp.ones(sb.capacity, jnp.bool_)
             data, validity, lengths = W.lead_lag(col, seg, off, dd, dv, dl)
             out_cols.append(DeviceColumn(data, validity, col.dtype, lengths))
         else:
-            op = window_agg_op(f)
-            frame = w.spec.resolved_frame()
-            if op == "count_star":
-                col = DeviceColumn(jnp.zeros(sb.capacity, jnp.int64),
-                                   seg.real, T.LongType())
-                data, validity, rtype = W.running_or_bounded_agg(
-                    "count", col, seg, frame)
-            else:
-                col = sb.columns[ii]
-                data, validity, rtype = W.running_or_bounded_agg(
-                    op, col, seg, frame)
+            _, op, frame = fn
+            data, validity, rtype = W.running_or_bounded_agg(
+                op, None if op == "count_star" else sb.columns[ii], seg,
+                frame)
             zero = jnp.zeros((), data.dtype)
             out_cols.append(DeviceColumn(jnp.where(validity, data, zero),
                                          validity, rtype))
@@ -593,4 +667,4 @@ def _window_body(aug: ColumnBatch, orders, part_idx, order_idx, input_idx,
 
 _jit_window = guarded_jit(
     "window_frame", static_argnames=("orders", "part_idx", "order_idx", "input_idx",
-                     "wexprs", "nbase", "schema"))(_window_body)
+                     "fns", "nbase", "schema"))(_window_body)

@@ -183,15 +183,20 @@ def _output_schema(schema: T.Schema, key_indices: list[int],
     return T.Schema(fields)
 
 
-def _segmented_scan(op, last: jax.Array, x: jax.Array) -> jax.Array:
+def _segmented_scan(op, last: jax.Array, x: jax.Array,
+                    forward: bool = False) -> jax.Array:
     """Scan ``x`` by ``op`` from each segment's last row (where ``last``
     is set; the final row's is) back to its first, which ends up holding
     the whole segment's value: ``log2(capacity)`` passes, each combining
-    a row with the one ``d`` after it unless a segment ends between."""
+    a row with the one ``d`` after it unless a segment ends between.
+    ``forward`` scans the other way: ``last`` then marks each segment's
+    FIRST row (row 0's is set), and every row ends up holding the value
+    of its segment's rows up to itself (a running total)."""
     d = 1
     while d < x.shape[0]:
-        x = jnp.where(last, x, op(x, jnp.roll(x, -d)))
-        last = last | jnp.roll(last, -d)
+        shift = d if forward else -d
+        x = jnp.where(last, x, op(x, jnp.roll(x, shift)))
+        last = last | jnp.roll(last, shift)
         d *= 2
     return x
 

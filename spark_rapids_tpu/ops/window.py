@@ -7,10 +7,13 @@ keys), every window shape becomes static-shape index arithmetic:
 
 * partition extents ``seg_start/seg_end`` via boundary-flag cummax,
 * running (UNBOUNDED PRECEDING..CURRENT ROW) and whole-partition frames
-  via prefix sums / segment reductions,
-* bounded ROWS frames via **sparse tables** (log2(cap) levels of
-  power-of-two-span min/max, XLA-friendly static depth) for min/max and
-  clamped prefix-sum differences for sum/count/avg,
+  via one **segmented scan** of the sorted column (log2(cap) shifted
+  passes; no gather, and no prefix that carries other partitions' rows),
+* frames bounded by row offsets via **sparse tables** (log2(cap) levels
+  of power-of-two-span min/max, XLA-friendly static depth) for min/max
+  and clamped prefix-sum differences for sum/count/avg,
+* sums and averages of doubles that are whole cents (money) over
+  ``int64`` cents, rounded once (``ops/cents.py``),
 * RANGE frames differ from ROWS only in using peer-group edges
   (first/last row with equal order keys) as the effective row,
 * row_number/rank/dense_rank/lead/lag from the same segment arrays.
@@ -27,14 +30,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
-from spark_rapids_tpu.ops.segmented import _cols_differ
-from spark_rapids_tpu.ops.sort import SortOrder, normalize_floats, sort_batch
+from spark_rapids_tpu.ops.segmented import (AggSpec, _cols_differ,
+                                            _compute_agg, _segmented_scan)
 
 __all__ = ["WindowFrame", "UNBOUNDED", "CURRENT_ROW", "SegmentInfo",
-           "sorted_segments", "running_or_bounded_agg", "row_number", "rank",
+           "sorted_segments", "frame_scans", "running_or_bounded_agg",
+           "row_number", "rank",
            "dense_rank", "lead_lag"]
 
 UNBOUNDED = None
@@ -120,8 +123,84 @@ def _frame_edges(seg: SegmentInfo, frame: WindowFrame):
 
 
 # ---------------------------------------------------------------------------
-# sparse-table range min/max (static log depth)
+# reductions over every row's frame
 # ---------------------------------------------------------------------------
+
+def frame_scans(frame: WindowFrame) -> bool:
+    """True for a frame that starts at its partition's first row and
+    ends at the current row, its last peer (range mode) or the
+    partition's last row: running totals and whole-partition frames."""
+    return frame.lower is UNBOUNDED and frame.upper in (UNBOUNDED,
+                                                        CURRENT_ROW)
+
+
+class _ScanFrames:
+    """Reductions over frames that start at the partition's first row
+    (``frame_scans``): one forward segmented scan of the sorted column
+    (``ops/segmented._segmented_scan``: log2(capacity) shifted passes,
+    no gather, no prefix over other partitions' rows), and where the
+    frame ends past the current row one more scan that carries the value
+    on the frame's last row back over its rows.  A row's result is made
+    of its own partition's rows only: an integer sum exactly."""
+
+    def __init__(self, seg: SegmentInfo, frame: WindowFrame):
+        cap = seg.seg_start.shape[0]
+        idx = jnp.arange(cap, dtype=jnp.int32)
+        self.first = idx == seg.seg_start
+        end = seg.seg_end if frame.upper is UNBOUNDED else \
+            seg.peer_end if frame.mode == "range" else None
+        self.last = None if end is None else \
+            (idx == end) | (idx == cap - 1)
+
+    def _reduce(self, op, x):
+        x = _segmented_scan(op, self.first, x, forward=True)
+        if self.last is not None:
+            x = _segmented_scan(lambda row, later: later, self.last, x)
+        return x
+
+    def sum(self, x):
+        return self._reduce(jnp.add, x)
+
+    def min(self, x):
+        return self._reduce(jnp.minimum, x)
+
+    def max(self, x):
+        return self._reduce(jnp.maximum, x)
+
+
+class _TableFrames:
+    """Reductions over frames bounded by row offsets: a sum is the
+    difference of two prefix sums over the whole sorted batch (exact for
+    integers; a floating sum carries the rounding of the rows before its
+    partition), ``min`` / ``max`` two overlapping power-of-two spans of a
+    sparse table (log2(capacity) levels of the whole column)."""
+
+    def __init__(self, seg: SegmentInfo, frame: WindowFrame):
+        cap = seg.seg_start.shape[0]
+        self.lo, self.hi = _frame_edges(seg, frame)
+        # empty frames (lo > hi, e.g. ROWS 2 FOLLOWING..5 FOLLOWING at the
+        # partition tail) must yield 0, not a negative cross-partition diff
+        self._to = jnp.clip(jnp.maximum(self.hi + 1, self.lo), 0, cap)
+        self._from = jnp.clip(self.lo, 0, cap)
+
+    def sum(self, x):
+        ps = jnp.concatenate([jnp.zeros(1, x.dtype), jnp.cumsum(x)])
+        return ps[self._to] - ps[self._from]
+
+    def _extreme(self, op, x):
+        ident = jnp.inf if x.dtype.kind == "f" else jnp.iinfo(x.dtype).max
+        if op is jnp.maximum:
+            ident = -jnp.inf if x.dtype.kind == "f" else \
+                jnp.iinfo(x.dtype).min
+        return _range_query(_sparse_table(x, op), self.lo, self.hi, op,
+                            ident)
+
+    def min(self, x):
+        return self._extreme(jnp.minimum, x)
+
+    def max(self, x):
+        return self._extreme(jnp.maximum, x)
+
 
 def _sparse_table(x: jax.Array, op) -> list[jax.Array]:
     """st[k][i] = op over x[i : i+2^k), clamped at the end."""
@@ -161,87 +240,26 @@ def _range_query(levels: list[jax.Array], lo, hi, op, identity):
 # aggregates over frames
 # ---------------------------------------------------------------------------
 
-def running_or_bounded_agg(op: str, col: DeviceColumn, seg: SegmentInfo,
-                           frame: WindowFrame):
-    """sum|count|avg|min|max over the frame. Returns (data, validity,
-    result_type)."""
-    cap = col.capacity
-    contributes = col.validity & seg.real
-    lo, hi = _frame_edges(seg, frame)
+def running_or_bounded_agg(op: str, col: DeviceColumn | None,
+                           seg: SegmentInfo, frame: WindowFrame):
+    """count_star|count|sum|avg|min|max over every row's frame.  Returns
+    (data, validity, result_type).
 
-    if op in ("sum", "count", "avg"):
-        if op == "count":
-            x = contributes.astype(jnp.int64)
-            acc_dt = jnp.int64
-        else:
-            acc_dt = jnp.int64 if col.dtype.integral else jnp.float64
-            x = jnp.where(contributes, col.data.astype(acc_dt),
-                          jnp.zeros((), acc_dt))
-        # empty frames (lo > hi, e.g. ROWS 2 FOLLOWING..5 FOLLOWING at the
-        # partition tail) must yield 0, not a negative cross-partition diff
-        hi1 = jnp.maximum(hi + 1, lo)
-        ps = jnp.concatenate([jnp.zeros(1, acc_dt), jnp.cumsum(x)])
-        total = ps[jnp.clip(hi1, 0, cap)] - ps[jnp.clip(lo, 0, cap)]
-        cnt_x = contributes.astype(jnp.int64)
-        pc = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(cnt_x)])
-        cnt = pc[jnp.clip(hi1, 0, cap)] - pc[jnp.clip(lo, 0, cap)]
-        if op == "count":
-            return cnt, seg.real, T.LongType()
-        if op == "avg":
-            data = total.astype(jnp.float64) / jnp.maximum(cnt, 1)
-            return data, seg.real & (cnt > 0), T.DoubleType()
-        if col.dtype.integral:
-            return total, seg.real & (cnt > 0), T.LongType()
-        return total.astype(jnp.float64), seg.real & (cnt > 0), \
-            T.DoubleType()
-
-    if op in ("min", "max"):
-        if col.dtype.fractional:
-            x = normalize_floats(col.data)
-            # NaN largest: min ignores NaN unless all-NaN; max returns NaN
-            # if any NaN (Spark float ordering)
-            isnan = jnp.isnan(x)
-            base = jnp.where(contributes & ~isnan, x,
-                             jnp.full((), jnp.inf if op == "min" else -jnp.inf,
-                                      x.dtype))
-            ident = jnp.inf if op == "min" else -jnp.inf
-            fop = jnp.minimum if op == "min" else jnp.maximum
-            levels = _sparse_table(base, fop)
-            res = _range_query(levels, lo, hi, fop, ident)
-            hi1 = jnp.maximum(hi + 1, lo)
-            nan_x = (contributes & isnan).astype(jnp.int64)
-            pn = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(nan_x)])
-            nan_cnt = pn[jnp.clip(hi1, 0, cap)] - pn[jnp.clip(lo, 0, cap)]
-            cnt_x = contributes.astype(jnp.int64)
-            pc = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(cnt_x)])
-            cnt = pc[jnp.clip(hi1, 0, cap)] - pc[jnp.clip(lo, 0, cap)]
-            if op == "min":
-                data = jnp.where((cnt > 0) & (cnt == nan_cnt),
-                                 jnp.full((), jnp.nan, x.dtype), res)
-            else:
-                data = jnp.where(nan_cnt > 0, jnp.full((), jnp.nan, x.dtype),
-                                 res)
-            return data, seg.real & (cnt > 0), col.dtype
-        if col.is_var_width:
-            raise NotImplementedError(
-                "windowed min/max over strings/arrays")
-        d = col.data.astype(jnp.int64) if col.data.dtype == jnp.bool_ \
-            else col.data
-        info = jnp.iinfo(d.dtype)
-        ident = info.max if op == "min" else info.min
-        base = jnp.where(contributes, d, ident)
-        fop = jnp.minimum if op == "min" else jnp.maximum
-        levels = _sparse_table(base, fop)
-        res = _range_query(levels, lo, hi, fop, ident)
-        hi1 = jnp.maximum(hi + 1, lo)
-        cnt_x = contributes.astype(jnp.int64)
-        pc = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(cnt_x)])
-        cnt = pc[jnp.clip(hi1, 0, cap)] - pc[jnp.clip(lo, 0, cap)]
-        if col.data.dtype == jnp.bool_:
-            res = res.astype(jnp.bool_)
-        return res, seg.real & (cnt > 0), col.dtype
-
-    raise ValueError(f"window agg op {op}")
+    The aggregate is the group-by's (``ops/segmented._compute_agg``:
+    NULLs skipped, NaN the largest double, a ``sum`` / ``avg`` of doubles
+    that are whole cents taken over ``int64`` cents and rounded once,
+    ``ops/cents.py``, so equal totals compare equal whatever batch,
+    partition or sort order produced them); what differs is how rows
+    reduce to a result: over each row's frame, by a scan where the frame
+    starts at its partition's first row (``frame_scans``) and by prefix
+    differences and a sparse table where row offsets bound it."""
+    if col is not None and col.is_var_width and op in ("min", "max"):
+        raise NotImplementedError("windowed min/max over strings/arrays")
+    red = (_ScanFrames if frame_scans(frame) else _TableFrames)(seg, frame)
+    rows = red.sum(seg.real.astype(jnp.int32)).astype(jnp.int64) \
+        if op == "count_star" else None
+    out = _compute_agg(AggSpec(op, 0), col, red, seg.real, seg.real, rows)
+    return out.data, out.validity, out.dtype
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,7 @@ from spark_rapids_tpu.exec import (CrossJoinExec, FilterExec,
                                    ShuffleExchangeExec, SortExec, UnionExec,
                                    WindowExec)
 from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
+from spark_rapids_tpu.exec.window import spec_key
 from spark_rapids_tpu.exec.transitions import BackendSwitchExec
 from spark_rapids_tpu.expr.core import (Alias, Expression, col, output_name)
 from spark_rapids_tpu.expr.window import WindowExpression
@@ -440,7 +441,9 @@ def _ensure_window_distribution(cur: PlannedNode, spec,
     exchange for ClusteredDistribution; reference GpuWindowExec.scala:92
     needs one batch per partition GROUP only).  Skips the exchange when
     the child is already hash-partitioned on a subset of the window keys
-    — rows equal on the window keys are then already co-located."""
+    — rows equal on the window keys are then already co-located — and
+    where the child is a final aggregate grouped by the window's keys
+    and more, whose own exchange is then made on the window's keys."""
     if not spec.partition_by:
         return cur, False
     if cur.exec_node.num_partitions(ExecCtx(backend="host")) <= 1:
@@ -457,10 +460,39 @@ def _ensure_window_distribution(cur: PlannedNode, spec,
             have = _window_key_names(node.partitioning._keys)
             if have and set(have) <= set(want):
                 return cur, True
+        # a final aggregate grouped by the window's keys and more: the
+        # exchange it reads clusters its groups just as well on the
+        # window's keys alone (rows equal on all the group keys are equal
+        # on some of them), and the window needs no exchange of its own
+        exch = _aggregate_exchange(node)
+        if exch is not None:
+            have = _window_key_names(exch.partitioning._keys)
+            if have and set(have) <= set(want):
+                return cur, True    # the aggregate keeps its distribution
+            if have and set(want) <= set(have):
+                exch.partitioning = HashPartitioning(
+                    [col(n) for n in want], exch.partitioning.num_partitions)
+                exch.partitioning.bind(exch.children[0].output_schema)
+                return cur, True
     part = HashPartitioning(list(spec.partition_by),
                             conf.shuffle_partitions)
     exch = ShuffleExchangeExec(part, cur.exec_node)
     return PlannedNode(exch, list(spec.partition_by), [cur]), True
+
+
+def _aggregate_exchange(node: PlanNode) -> ShuffleExchangeExec | None:
+    """The hash exchange a final ``HashAggregateExec`` reads its partial
+    buffers from (through the adaptive reader), or None."""
+    from spark_rapids_tpu.exec.exchange import AdaptiveShuffleReaderExec
+    if not (isinstance(node, HashAggregateExec) and node.mode == "final"):
+        return None
+    child = node.children[0]
+    if isinstance(child, AdaptiveShuffleReaderExec):
+        child = child.children[0]
+    if isinstance(child, ShuffleExchangeExec) \
+            and isinstance(child.partitioning, HashPartitioning):
+        return child
+    return None
 
 
 def _lower_project(node: L.Project, conf: TpuConf) -> PlannedNode:
@@ -477,13 +509,15 @@ def _lower_project(node: L.Project, conf: TpuConf) -> PlannedNode:
         ex = ProjectExec(exprs, c.exec_node)
         return PlannedNode(ex, list(exprs), [c])
     # one WindowExec per distinct spec (Spark's planner does the same),
-    # then the final projection over the appended columns
+    # told apart by the specs' content (spec_key), then the final
+    # projection over the appended columns
     by_spec: dict = {}
     for w in windows:
         inner = w.children[0] if isinstance(w, Alias) else w
-        by_spec.setdefault(inner.spec, []).append(w)
+        by_spec.setdefault(spec_key(inner.spec), (inner.spec, []))[1] \
+            .append(w)
     cur = c
-    for spec, spec_windows in by_spec.items():
+    for spec, spec_windows in by_spec.values():
         if _mesh_window_ok(cur.exec_node, spec, conf, spec_windows):
             cur = _stack_window_execs(cur, spec_windows, False,
                                       conf=conf, mesh=True)

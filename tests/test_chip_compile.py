@@ -371,6 +371,65 @@ def test_group_by_update_compiles_for_v5e(one_chip):
     assert len(sorts) <= 2 and len(scatters) <= 1, (sorts, scatters)
 
 
+def _q51_window(which: str):
+    """``(exec, batch)``: q51's running sum over the daily sums, or its
+    two running maxes over the full join's rows, at ``CAP``."""
+    from spark_rapids_tpu.exec import LocalScanExec
+    from spark_rapids_tpu.exec.window import WindowExec
+    from spark_rapids_tpu.expr.aggregates import Max, Sum
+    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.expr.window import (CURRENT_ROW, UNBOUNDED,
+                                              WindowExpression, WindowFrame,
+                                              WindowSpec)
+    from spark_rapids_tpu.host.batch import HostBatch
+    money = ["day_sales"] if which == "sum" else ["web_sales", "store_sales"]
+    schema = T.Schema([T.StructField("item_sk", T.IntegerType(), True),
+                       T.StructField("d_date", T.DateType(), True)]
+                      + [T.StructField(m, T.DoubleType(), True)
+                         for m in money])
+    rng = np.random.default_rng(51)
+    n = 900
+    data = {"item_sk": rng.integers(1, 40, n).astype(np.int32),
+            "d_date": rng.integers(10957, 11323, n).astype(np.int32)}
+    data.update({m: np.round(rng.uniform(0, 300, n), 2) for m in money})
+    hb = HostBatch.from_pydict(data, schema)
+
+    def over(fn):       # a spec built anew for every expression, as q51's
+        return WindowExpression(fn, WindowSpec(
+            partition_by=(col("item_sk"),),
+            order_by=((col("d_date"), True),),
+            frame=WindowFrame("rows", UNBOUNDED, CURRENT_ROW)))
+    agg = Sum if which == "sum" else Max
+    ex = WindowExec([over(agg(col(m))).alias("c_" + m) for m in money],
+                    LocalScanExec.from_pydict(data, schema))
+    return ex, hb.to_device(capacity=CAP)
+
+
+@pytest.mark.parametrize("which", ["sum", "two_max"])
+def test_q51_window_body_compiles_for_v5e(one_chip, which):
+    """The window program with q51's columns: the sort by (item, day),
+    one row gather a dtype, the segment arrays and the frames.  A
+    running frame is a scan of shifted passes: the program holds the key
+    sort (five operands: three stable passes of two, the later two
+    gathering their keys: 3 sorts, 4 gathers), the row gather (bool,
+    int32, float64 leaves, stacked) and no other capacity-row sort,
+    scatter or gather (the parent's frames took 23 levels of a sparse
+    table and two gathers a level, its row sort one gather a leaf: 16
+    and 22 gathers before a single frame)."""
+    from spark_rapids_tpu.exec.window import _window_body
+    ex, batch = _q51_window(which)
+    aug, orders, part_idx, order_idx, input_idx, nbase = \
+        ex._window_args(batch)
+    assert len(ex._fns) == (1 if which == "sum" else 2)     # one exec
+    assert aug.num_columns == nbase     # plain columns are not appended
+    hlo = _compile(lambda b: _window_body(
+        b, orders, part_idx, order_idx, input_idx, ex._fns, nbase,
+        ex.output_schema), _shapes(aug, one_chip)).as_text()
+    assert not _capacity_row(hlo, "scatter")
+    assert len(_capacity_row(hlo, "sort")) <= 3
+    assert len(_capacity_row(hlo, "gather")) <= 8
+
+
 def test_distributed_groupby_compiles_for_2x2_mesh(topo):
     """partial group-by -> all-to-all -> merge as ONE shard_map program
     over the four described devices."""

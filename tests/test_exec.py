@@ -158,6 +158,37 @@ def test_sort(rng):
     assert_tpu_and_cpu_equal(plan, ignore_order=False)
 
 
+@pytest.mark.parametrize("slots,kept,fetches,shrinks", [
+    (1 << 17, 90, 1, 1),        # what a filter left of a large batch
+    (1 << 17, (1 << 16) + 1, 1, 0),     # over half full: sorted as it is
+    (1 << 12, 90, 0, 0)])       # small: not worth the fetch
+def test_total_sort_right_sizes_a_sparse_input(slots, kept, fetches,
+                                               shrinks):
+    """A total sort, and the fetch of its rows, run at batch capacity:
+    one whose input is what a filter left of a far larger batch (q51:
+    92k rows in 2^23 slots) sorts it in its rows' own bucket."""
+    from spark_rapids_tpu.exec.core import ExecCtx, device_to_host
+    from spark_rapids_tpu.obs.registry import get_registry
+    schema = T.Schema([T.StructField("k", T.IntegerType(), True),
+                       T.StructField("v", T.LongType(), True)])
+    n = slots - 5
+    k = np.random.default_rng(5).permutation(n).astype(np.int32)
+    scan = LocalScanExec.from_pydict({"k": k, "v": k.astype(np.int64) * 3},
+                                     schema, rows_per_batch=slots)
+    plan = SortExec([("k", False)],
+                    FilterExec(col("k") < lit(kept), scan), global_sort=True)
+    before = get_registry().counters()
+    with ExecCtx(backend="device") as ctx:
+        out, = list(plan.partition_iter(ctx, 0))
+        rows = device_to_host(out).to_rows()
+    moved = get_registry().counters_since(before)
+    assert rows == [(i, 3 * i) for i in reversed(range(kept))]
+    assert moved.get("span.fetch@SortExec.count", 0) == fetches
+    assert moved.get("program.batch_shrink.launches", 0) == shrinks
+    from spark_rapids_tpu.columnar.batch import round_capacity
+    assert out.capacity == (round_capacity(kept) if shrinks else slots)
+
+
 def test_sort_nulls_and_nans(rng):
     schema = T.Schema([T.StructField("x", T.DoubleType())])
     vals = [1.0, None, float("nan"), -0.0, 0.0, float("inf"),

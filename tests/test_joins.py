@@ -721,6 +721,7 @@ def test_packed_multi_key_join(case, jt, monkeypatch):
     tail = sum(1 for a, _ in _pandas_pairs(lrows, rrows, "full")
                if a is None) if jt == "full" else 0
     assert moved.get("join.probe.rows_out", 0) == len(rows) - tail
+    assert moved.get("join.full.unmatched_rows", 0) == tail
 
     nk = len(lk)
     lv = nk
@@ -745,6 +746,50 @@ def test_packed_multi_key_join(case, jt, monkeypatch):
     assert sorted(collect_device(plan), key=repr) == sorted(rows, key=repr)
     moved = get_registry().counters_since(before)
     assert moved.get("join.probe.sorted") and "join.keys.packed" not in moved
+
+
+@pytest.mark.parametrize("stream_batch", [7, 16, 64])
+def test_two_key_full_outer_join_over_stream_batches(stream_batch):
+    """q51's join: both sides unique on (item, day), some pairs on both
+    sides, some on one, NULL keys on both; the stream comes in several
+    batches, so a build row is matched by whichever batch holds its
+    pair and the tail is what no batch matched."""
+    from spark_rapids_tpu.obs.registry import get_registry
+    rng = np.random.default_rng(51)
+    pairs = [(int(i), int(d)) for i in range(1, 9) for d in range(10957, 10963)]
+    pick = rng.permutation(len(pairs))
+    lrows = [pairs[i] for i in pick[:30]] + [(None, 10957), (3, None)]
+    rrows = [pairs[i] for i in pick[18:]] + [(None, 10957), (None, None)]
+    schema = lambda p: T.Schema([  # noqa: E731
+        T.StructField(p + "item", T.IntegerType(), True),
+        T.StructField(p + "day", T.DateType(), True),
+        T.StructField(p + "v", T.LongType(), True)])
+
+    def side(p, rows, **kw):
+        return LocalScanExec.from_pydict(
+            {p + "item": [r[0] for r in rows], p + "day": [r[1] for r in rows],
+             p + "v": list(range(len(rows)))}, schema(p), **kw)
+    plan = JoinExec(side("l", lrows, rows_per_batch=stream_batch),
+                    side("r", rrows), [col("litem"), col("lday")],
+                    [col("ritem"), col("rday")], "full")
+    before = get_registry().counters()
+    rows = collect_device(plan)
+    moved = get_registry().counters_since(before)
+    want = _pandas_pairs(lrows, rrows, "full")
+    assert sorted(((r[2], r[5]) for r in rows), key=repr) \
+        == sorted(want, key=repr)
+    matched = sum(1 for a, b in want if a is not None and b is not None)
+    left_only = sum(1 for a, b in want if b is None)
+    right_only = sum(1 for a, b in want if a is None)
+    assert (matched, left_only, right_only) == (12, 20, 20)
+    assert moved["join.keys.packed"] == 1
+    assert moved["join.full.unmatched_rows"] == right_only
+    assert moved["join.probe.rows_out"] == matched + left_only
+    assert moved["join.probe.direct"] == -(-len(lrows) // stream_batch)
+    # NULL-extended on the side that has no partner
+    for r in rows:
+        assert (r[0] is None and r[1] is None) or r[2] is not None
+    assert sorted(collect_host(plan), key=repr) == sorted(rows, key=repr)
 
 
 @pytest.mark.parametrize("why", ["product_past_int64", "string_key",
