@@ -73,6 +73,7 @@ class _PackBuilder:
         self.leaves: list[tuple] = []       # ("g"|"w", ...) — see _add_leaf
         self.i64_params: list[int] = []
         self.col_specs: list[tuple] = []
+        self.dict_gathers = 0               # gathers the unpack will hold
 
     def _add_leaf(self, arr: np.ndarray) -> int:
         """Register one host buffer.
@@ -162,7 +163,9 @@ class _PackBuilder:
                         dict_matrix: np.ndarray, dict_lengths: np.ndarray,
                         validity: np.ndarray | None):
         """Dictionary-encoded string column: bit-packed int32 indices +
-        a pow2-row-padded dictionary byte matrix; decode is one gather."""
+        a pow2-row-padded dictionary byte matrix; decode selects among
+        a small dictionary's rows and gathers from a larger one
+        (wirecodec.decode_dict)."""
         cap = self.capacity
         k, w = dict_matrix.shape
         kp = round_capacity(max(k, 1))
@@ -172,6 +175,8 @@ class _PackBuilder:
         lfull[:k] = dict_lengths
         if validity is not None and not validity.all():
             indices = np.where(validity, indices, 0)
+        if not wc.dict_selects(kp):
+            self.dict_gathers += 2
         idesc = wc.encode_fixed(indices, validity, cap, self._add_leaf,
                                 self._add_i64)
         self.col_specs.append(("dict", idesc,
@@ -184,16 +189,15 @@ class _PackBuilder:
         """One device_put per dtype group — with the u32 word routing in
         :meth:`_add_leaf`, typically ONE transfer total — plus one jitted
         unpack+decode.  The i64 decode params (FOR bases) ship as u32
-        word pairs and are rebuilt arithmetically on device (64-bit
+        words, the low halves then the high halves (a strided read would
+        lower to a gather), and are rebuilt arithmetically on device (64-bit
         bitcasts don't lower on TPU; shifts do)."""
         nr = self._add_leaf(np.asarray([num_rows], dtype=np.int32))
         ip = -1
         if self.i64_params:
             p = np.asarray(self.i64_params, np.int64)
-            pairs = np.empty(2 * p.size, np.uint32)
-            pairs[0::2] = (p & 0xFFFFFFFF).astype(np.uint32)
-            pairs[1::2] = ((p >> 32) & 0xFFFFFFFF).astype(np.uint32)
-            ip = self._add_leaf(pairs)
+            ip = self._add_leaf(np.concatenate(
+                [p & 0xFFFFFFFF, (p >> 32) & 0xFFFFFFFF]).astype(np.uint32))
         gkeys = tuple(sorted(self.groups))
         host_bufs = tuple(
             self.groups[k][0] if len(self.groups[k]) == 1
@@ -204,10 +208,15 @@ class _PackBuilder:
         # h2d_put_s: the host's seconds inside the puts — near zero where
         # the put returns before the copy is done (the link's seconds are
         # then no host span's), the copy itself where it blocks
+        # unpack.leaves.*: how the program about to run decodes what was
+        # shipped — leaves it reads by static slices and shifts, and the
+        # gathers left (two a dictionary too large to select from)
         get_registry().inc_many((
             ("h2d_calls", len(host_bufs)),
             ("h2d_bytes", sum(b.nbytes for b in host_bufs)),
-            ("h2d_put_s", put_s)))
+            ("h2d_put_s", put_s),
+            ("unpack.leaves.static", len(self.leaves) - self.dict_gathers),
+            ("unpack.leaves.gather", self.dict_gathers)))
         spec = (self.capacity, gkeys, tuple(self.leaves),
                 tuple(self.col_specs), nr, ip)
         arrays = _packed_unpack_cached(spec)(dev_bufs)
@@ -251,8 +260,8 @@ def _packed_unpack_cached(spec):
         i64p = None
         if ip_idx >= 0:
             pw = leaf(ip_idx)
-            i64p = ((pw[1::2].astype(jnp.int64) << 32)
-                    | pw[0::2].astype(jnp.int64))
+            k = pw.shape[0] // 2
+            i64p = (pw[k:].astype(jnp.int64) << 32) | pw[:k].astype(jnp.int64)
         out_cols = []
         for cspec in col_specs:
             kind = cspec[0]
@@ -271,10 +280,11 @@ def _packed_unpack_cached(spec):
                 out_cols.append((data, validity, lens))
             else:  # dict string
                 idx = wc.decode_data(cspec[1], leaf, i64p, cap)
-                mat, dlens = leaf(cspec[3]), leaf(cspec[4])
-                data = jnp.where(validity[:, None], mat[idx],
-                                 jnp.zeros((), mat.dtype))
-                lens = jnp.where(validity, dlens[idx], 0)
+                data, lens = wc.decode_dict(leaf(cspec[3]), leaf(cspec[4]),
+                                            idx)
+                data = jnp.where(validity[:, None], data,
+                                 jnp.zeros((), data.dtype))
+                lens = jnp.where(validity, lens, 0)
                 out_cols.append((data, validity, lens))
         return tuple(out_cols), nr
 

@@ -13,29 +13,44 @@ slice/reshape pass and costs no extra dispatch or host round trip.
 Encodings (chosen per column per batch, host-side, O(n) numpy passes):
 
 * ints / dates / timestamps / bools — frame-of-reference + bit-packing:
-  ship ``ceil(n*b/32)`` uint32 words where ``b = bit_length(max-min)``,
-  decode ``(bits + min) * div``; an optional integral divisor (1e3/1e6)
+  ship ``capacity * b / 32`` uint32 words where ``b`` is the rung of
+  ``_BIT_BUCKETS`` that holds ``bit_length(max - min)``, decode
+  ``(bits + min) * div``; an optional integral divisor (1e3/1e6)
   catches second-aligned timestamps.
 * float64 — when exactly representable as scaled integers (money is
   cents: ``rint(v/s)*s == v`` bitwise for s in {1, 0.01}), ship the
   FOR/bit-packed integers and decode ``(bits + base) * s``.
 * strings — pyarrow dictionary encoding when it pays: ship the (small)
-  dictionary byte-matrix plus bit-packed indices; decode is one gather.
+  dictionary byte-matrix plus bit-packed indices; decode selects among a
+  dictionary of at most ``_DICT_SELECT_MAX_ROWS`` rows and gathers from
+  a larger one (the one place the data decides an index).
 * validity — all-valid columns ship NOTHING (decode compares against
   num_rows); others ship 1 bit/row.
 
-Bit widths are arbitrary (1..32, values may straddle word boundaries),
-not power-of-two buckets: a 17-bit key column ships 17 bits, not 32.
+Bit widths are the rungs of ``_BIT_BUCKETS`` (1, 2, 4, 8, 12, ... 32):
+a 17-bit key column ships 20 bits, so that a batch whose value range
+crosses a bit boundary does not compile a fresh unpack program.
+
+The bit layout is PLANAR, and host packer and device decode are one
+format (:func:`pack_bits_host`, :func:`_unpack_bits_device`): values are
+grouped in blocks of ``lcm(b, 32)`` bits that no value straddles, the
+words of all blocks are shipped plane by plane (word 0 of every block,
+then word 1, ...), and block ``k`` holds slots ``k, k + nblocks,
+k + 2 * nblocks, ...``.  Slot ``j * nblocks + k`` is then the same shift
+of the same one or two planes for every ``k``: the decode is static
+slices, shifts, masks and one concatenate — no index computed from an
+``arange``, so no gather (10 ns a row a column on the chip, PERF.md
+Findings PR 39).
 """
 from __future__ import annotations
+
+from math import gcd
 
 import numpy as np
 
 __all__ = ["encode_fixed", "encode_lengths", "maybe_dict_arrow",
-           "pack_bits_host", "decode_data", "decode_validity",
-           "bits_needed"]
-
-_FAST_BITS = {8: np.uint8, 16: np.uint16, 32: np.uint32}
+           "pack_bits_host", "decode_data", "decode_dict",
+           "decode_validity", "dict_selects", "bits_needed"]
 
 #: integral divisors probed for int64 columns (timestamp micros that are
 #: second- or milli-aligned shrink below the 32-bit FOR window)
@@ -51,6 +66,10 @@ _FLOAT_SCALES = (0.01, 1.0)
 #: variant count bounded while staying within ~15% of minimal bits
 _BIT_BUCKETS = (1, 2, 4, 8, 12, 16, 20, 24, 28, 32)
 
+#: a dictionary of at most this many (padded) rows is decoded by a chain
+#: of selects, a larger one by a gather (measured, PERF.md Findings PR 39)
+_DICT_SELECT_MAX_ROWS = 64
+
 
 def bits_needed(rng: int) -> int:
     """Bucketed bits to hold values in [0, rng]."""
@@ -61,68 +80,63 @@ def bits_needed(rng: int) -> int:
     return raw
 
 
-def pack_bits_host(vals: np.ndarray, bits: int, cap: int) -> np.ndarray:
-    """Pack ``vals`` (non-negative, < 2**bits, any int dtype) into a
-    little-endian bit stream of ``cap`` slots, returned as uint32 words.
-    Slots beyond ``len(vals)`` are zero bits."""
-    n = vals.shape[0]
-    nwords = (cap * bits + 31) // 32
-    if bits in _FAST_BITS:
-        per = 32 // bits
-        buf = np.zeros(nwords * per, dtype=_FAST_BITS[bits])
-        buf[:n] = vals.astype(_FAST_BITS[bits])
-        return buf.view(np.uint32)
-    # Word-level shift/or accumulation.  The previous formulation built
-    # an n x bits uint8 bit-matrix plus a 32-aligned bit stream (~n*bits
-    # bytes each — ~120 MB of host staging per 4M-row 24-bit column
-    # before the arrays even reached packbits).  Values are laid out in
-    # BLOCKS of lcm(bits, 32): g = lcm/bits values fill exactly
-    # wpb = lcm/32 words, value j of a block starting at bit j*bits —
-    # and because g*bits == wpb*32, no value ever spills across a block
-    # boundary, so each of the g column passes is a pure vectorized
-    # shift/or over the block rows with no scatter and no carries.
-    # Peak temporaries are O(n) bytes (padded input + one uint64 column
-    # + the uint64 accumulator), independent of the bit width.
-    from math import gcd
+def _layout(cap: int, bits: int) -> tuple[int, int, int]:
+    """``(g, wpb, nblocks)`` of a ``cap``-slot stream of ``bits``-bit
+    values: a block is ``lcm(bits, 32)`` bits = ``g`` values = ``wpb``
+    words, so no value straddles a block; ``nblocks`` blocks hold the
+    slots.  For a power-of-two ``cap`` >= 8 and a width of
+    ``_BIT_BUCKETS`` that is ``cap * bits / 32`` words, no padding."""
     lcm = bits * 32 // gcd(bits, 32)
-    g = lcm // bits             # values per block
-    wpb = lcm // 32             # words per block
-    nblocks = (nwords + wpb - 1) // wpb
-    padded = np.zeros(nblocks * g, dtype=vals.dtype)
-    padded[:n] = vals
-    blocks = padded.reshape(nblocks, g)
-    acc = np.zeros((nblocks, wpb + 1), np.uint64)
+    g = lcm // bits
+    return g, lcm // 32, -(-cap // g)
+
+
+def pack_bits_host(vals: np.ndarray, bits: int, cap: int) -> np.ndarray:
+    """Pack ``vals`` (non-negative, < 2**bits, any int dtype) into
+    ``cap`` slots of ``bits`` bits, returned as uint32 words; slots
+    beyond ``len(vals)`` are zero bits.
+
+    The layout is PLANAR, because the device decodes it by static
+    slices and shifts (:func:`_unpack_bits_device`): the stream is
+    ``wpb`` word planes of ``nblocks`` words, block ``k`` is word ``k``
+    of every plane, and it holds the values ``k, k + nblocks,
+    k + 2 * nblocks, ...`` little-endian at bit ``j * bits``.  So value
+    ``j * nblocks + k`` is a fixed shift of one or two planes whatever
+    ``k`` is, and each of the ``g`` passes here is a shift/or over one
+    contiguous row: O(n) temporaries whatever the width."""
+    g, wpb, nblocks = _layout(cap, bits)
+    padded = np.zeros(g * nblocks, np.uint32)
+    padded[:vals.shape[0]] = vals
+    rows = padded.reshape(g, nblocks)
+    acc = np.zeros((wpb, nblocks), np.uint32)
     for j in range(g):
-        off = j * bits
-        wi, sh = off // 32, np.uint64(off % 32)
-        contrib = blocks[:, j].astype(np.uint64) << sh
-        acc[:, wi] |= contrib & np.uint64(0xFFFFFFFF)
-        acc[:, wi + 1] |= contrib >> np.uint64(32)
-    return acc[:, :wpb].reshape(-1)[:nwords].astype(np.uint32)
+        wi, sh = divmod(j * bits, 32)
+        acc[wi] |= rows[j] << np.uint32(sh)
+        if sh + bits > 32:
+            acc[wi + 1] |= rows[j] >> np.uint32(32 - sh)
+    return acc.reshape(-1)
 
 
 def _unpack_bits_device(words, cap: int, bits: int):
-    """uint32[cap] of ``bits``-bit values from the packed word stream
-    (traced; runs inside the batch unpack program)."""
+    """uint32[cap] of ``bits``-bit values from :func:`pack_bits_host`'s
+    words (traced; runs inside the batch unpack program).  Every index
+    is static: plane ``p`` is a slice, value row ``j`` a shift of one or
+    two planes, the result their concatenation — no gather."""
+    import jax
     import jax.numpy as jnp
-    mask = jnp.uint32((1 << bits) - 1) if bits < 32 else jnp.uint32(0xFFFFFFFF)
-    i = jnp.arange(cap, dtype=jnp.uint32)
-    if bits in _FAST_BITS:
-        per = 32 // bits
-        w = words[(i // per).astype(jnp.int32)]
-        sh = (i % per) * jnp.uint32(bits)
-        return (w >> sh) & mask
-    nwords = words.shape[0]
-    o = i * jnp.uint32(bits)
-    wi = (o >> 5).astype(jnp.int32)
-    sh = o & jnp.uint32(31)
-    lo = words[wi] >> sh
-    hi = words[jnp.minimum(wi + 1, nwords - 1)]
-    # (32 - sh) & 31 keeps the shift defined when sh == 0; the where
-    # discards that lane anyway
-    spill = jnp.where(sh > 0, hi << ((jnp.uint32(32) - sh) & jnp.uint32(31)),
-                      jnp.uint32(0))
-    return (lo | spill) & mask
+    g, wpb, nblocks = _layout(cap, bits)
+    planes = [jax.lax.slice(words, (p * nblocks,), ((p + 1) * nblocks,))
+              for p in range(wpb)]
+    rows = []
+    for j in range(g):
+        wi, sh = divmod(j * bits, 32)
+        v = planes[wi] >> jnp.uint32(sh) if sh else planes[wi]
+        if sh + bits > 32:
+            v = v | (planes[wi + 1] << jnp.uint32(32 - sh))
+        if sh + bits != 32:
+            v = v & jnp.uint32((1 << bits) - 1)
+        rows.append(v)
+    return jnp.concatenate(rows)[:cap]
 
 
 # ---------------------------------------------------------------------------
@@ -264,6 +278,31 @@ def decode_validity(desc, leaf, cap: int, nr):
     if desc[0] == "av":
         return jnp.arange(cap, dtype=jnp.int32) < nr
     return _unpack_bits_device(leaf(desc[1]), cap, 1) != 0
+
+
+def dict_selects(rows: int) -> bool:
+    """Whether a dictionary of ``rows`` (padded) rows is decoded by
+    compare-and-select rather than by a gather: static, read from the
+    dictionary leaf's own shape."""
+    return rows <= _DICT_SELECT_MAX_ROWS
+
+
+def decode_dict(mat, dlens, idx):
+    """(bytes[cap, w], lengths[cap]) of a dictionary string column from
+    its padded dictionary and decoded indices.  A gather costs about
+    10 ns an index on the chip whatever the dictionary's size, a select
+    pass over the rows far less for a few of them (PERF.md Findings
+    PR 39), so a small dictionary is a chain of selects."""
+    import jax.numpy as jnp
+    if not dict_selects(mat.shape[0]):
+        return mat[idx], dlens[idx]
+    data = jnp.zeros((idx.shape[0],) + mat.shape[1:], mat.dtype)
+    lens = jnp.zeros(idx.shape, dlens.dtype)
+    for r in range(mat.shape[0]):
+        hit = idx == r
+        data = jnp.where(hit[:, None], mat[r][None, :], data)
+        lens = jnp.where(hit, dlens[r], lens)
+    return data, lens
 
 
 def decode_data(desc, leaf, i64p, cap: int):

@@ -37,7 +37,9 @@ or decoding in-thread with ``decode@`` inside it),
 warm launch, exec/compile_cache.py ``SharedJit.__call__``),
 ``dispatch_wait_s`` (blocked on the DeviceSemaphore), ``h2d_calls`` /
 ``h2d_bytes`` (one per ``jax.device_put``) / ``h2d_put_s`` (host seconds
-inside the puts of a ``_PackBuilder.build``, columnar/batch.py),
+inside the puts of a ``_PackBuilder.build``, columnar/batch.py) and
+``unpack.leaves.static`` / ``unpack.leaves.gather`` (that build's
+leaves decoded with no gather, and the dictionary gathers left),
 ``d2h_calls`` / ``d2h_bytes`` / ``sync_wait_s`` (host blocked inside a
 fetch), ``scan_backpressure_s`` (scan worker blocked on its full
 queue), and from the other end of that queue ``scan.wait_s`` (the

@@ -37,12 +37,16 @@ def test_pack_bits_host_all_widths():
         words = wc.pack_bits_host(vals, bits, 1024)
         assert words.dtype == np.uint32
         assert words.size == (1024 * bits + 31) // 32
-        # decode on host via the same bit math the device uses
-        stream = np.unpackbits(words.view(np.uint8), bitorder="little")
-        got = np.zeros(n, np.uint32)
+        # decode on host via the same bit math the device uses: block k
+        # is word k of each plane, value j of it sits at bit j * bits
+        g, wpb, nblocks = wc._layout(1024, bits)
+        blocks = np.ascontiguousarray(words.reshape(wpb, nblocks).T)
+        stream = np.unpackbits(blocks.view(np.uint8), axis=1,
+                               bitorder="little")
+        got = np.zeros((nblocks, g), np.uint32)
         for b in range(bits):
-            got |= stream[b::bits][:n].astype(np.uint32) << np.uint32(b)
-        np.testing.assert_array_equal(got, vals)
+            got |= stream[:, b::bits].astype(np.uint32) << np.uint32(b)
+        np.testing.assert_array_equal(got.T.reshape(-1)[:n], vals)
 
 
 @pytest.mark.parametrize("dtype,lo,hi", [
@@ -149,28 +153,36 @@ def test_mixed_schema_roundtrip():
 
 
 def _pack_bits_reference(vals, bits, cap):
-    """The pre-optimization n x bits bit-matrix formulation, kept here
-    as the oracle for the word-level accumulation rewrite."""
+    """The n x bits bit-matrix formulation of the planar layout, kept
+    here as the oracle for the word-level shift/or packer: slot
+    ``j * nblocks + k`` is value ``j`` of block ``k``, a block's bits are
+    its values' bits little-endian back to back, and word ``p`` of block
+    ``k`` is word ``k`` of plane ``p``."""
     n = vals.shape[0]
-    nwords = (cap * bits + 31) // 32
-    u = vals.astype(np.uint32)
+    g, wpb, nblocks = wc._layout(cap, bits)
+    u = np.zeros(g * nblocks, np.uint32)
+    u[:n] = vals
     bm = ((u[:, None] >> np.arange(bits, dtype=np.uint32)[None, :]) & 1) \
         .astype(np.uint8)
-    stream = np.zeros(nwords * 32, np.uint8)
-    stream[:n * bits] = bm.reshape(-1)
-    return np.packbits(stream, bitorder="little").view(np.uint32)
+    blocks = bm.reshape(g, nblocks, bits).transpose(1, 0, 2) \
+        .reshape(nblocks, g * bits)
+    words = np.ascontiguousarray(
+        np.packbits(blocks, axis=1, bitorder="little")).view(np.uint32)
+    return np.ascontiguousarray(words.T).reshape(-1)
 
 
 @pytest.mark.parametrize("bits", [1, 2, 3, 5, 7, 11, 12, 13, 17, 20, 24, 31])
 def test_pack_bits_word_accumulation_matches_bit_matrix(rng, bits):
-    """The word-level shift/or rewrite is bit-for-bit identical to the
-    old bit-matrix packer for every width and ragged length."""
+    """The word-level shift/or packer is bit-for-bit identical to the
+    bit-matrix formulation for every width and ragged length."""
     for n in (0, 1, 7, 31, 32, 33, 1000, 4097):
         cap = max(n, 1)
         vals = rng.integers(0, 1 << bits, n, dtype=np.uint64)
         got = wc.pack_bits_host(vals, bits, cap)
         want = _pack_bits_reference(vals, bits, cap)
         assert got.dtype == np.uint32
+        _, wpb, nblocks = wc._layout(cap, bits)
+        assert got.size == wpb * nblocks
         assert np.array_equal(got, want), (bits, n)
 
 
@@ -187,9 +199,194 @@ def test_pack_bits_peak_memory_is_linear():
     out = wc.pack_bits_host(vals, bits, n)
     _, peak = tracemalloc.get_traced_memory()
     tracemalloc.stop()
-    # old matrix formulation alone: n*bits ~ 25 MB of uint8 plus the
-    # 32-aligned stream copy; the rewrite's budget is a few n*8-byte
-    # temporaries.  40 MB bounds the new path with slack while failing
-    # the old one (~50+ MB).
+    # a matrix formulation alone: n*bits ~ 25 MB of uint8 plus the
+    # 32-aligned stream copy; the packer's budget is a few n*4-byte
+    # temporaries.  40 MB bounds it with slack while failing the
+    # matrix (~50+ MB).
     assert peak < 40 << 20, f"peak {peak >> 20} MB"
     assert out.nbytes == ((n * bits + 31) // 32) * 4
+
+
+# ---------------------------------------------------------------------------
+# The whole format, through _PackBuilder.build: host layout and device
+# decode are one format, so they are held to the host arrays together.
+# ---------------------------------------------------------------------------
+
+def _build(cap, n, cols, schema):
+    """``cols``: ("fixed", data, validity) / ("var", matrix, lengths,
+    validity, width) / ("dict", indices, matrix, lengths, validity)."""
+    from spark_rapids_tpu.columnar.batch import _PackBuilder
+    pack = _PackBuilder(cap, True)
+    for c in cols:
+        getattr(pack, {"fixed": "add_fixed", "var": "add_var",
+                       "dict": "add_dict_string"}[c[0]])(*c[1:])
+    return pack, pack.build(n, schema)
+
+
+def _padded(a, cap, validity):
+    """What the device must hold: ``a`` with null slots zeroed, then
+    zero padding up to ``cap``."""
+    if validity is not None:
+        a = np.where(validity.reshape((-1,) + (1,) * (a.ndim - 1)), a,
+                     a.dtype.type(0))
+    out = np.zeros((cap,) + a.shape[1:], a.dtype)
+    out[:a.shape[0]] = a
+    return out
+
+
+@pytest.mark.parametrize("nulls", [False, True], ids=["valid", "nulls"])
+@pytest.mark.parametrize("rows", ["0", "1", "cap-1", "cap"])
+@pytest.mark.parametrize("cap", [2048, 1 << 16, 1 << 20])
+@pytest.mark.parametrize("bits", wc._BIT_BUCKETS)
+def test_packed_batch_round_trip(bits, cap, rows, nulls):
+    """Every width of the format x capacity x row count x {no nulls,
+    nulls}: data, validity and lengths on the device equal the host
+    arrays bit for bit, null and padding slots zero."""
+    from spark_rapids_tpu import types as T
+    n = {"0": 0, "1": 1, "cap-1": cap - 1, "cap": cap}[rows]
+    rng = np.random.default_rng(bits * 7919 + cap + n + nulls)
+    top = (1 << bits) - 1
+
+    def ranged(base, scale, dtype):
+        """Values whose frame of reference needs exactly ``bits`` bits
+        (both ends present where there are two rows to hold them)."""
+        r = rng.integers(0, top + 1, n, dtype=np.uint64).astype(np.int64)
+        r[:1], r[-1:] = 0, top if n > 1 else 0
+        return ((r + base) * scale).astype(dtype)
+
+    def validity():
+        if not nulls or n == 0:
+            return None
+        v = rng.random(n) < 0.8
+        v[:1] = v[-1:] = True      # the ends keep the range exact
+        return v
+
+    width = 8
+    lens = rng.integers(0, width + 1, n).astype(np.int32)
+    chars = rng.integers(97, 123, (n, width), dtype=np.uint8)
+    chars[np.arange(width)[None, :] >= lens[:, None]] = 0
+    host = [
+        ("fixed", ranged(-1000, 1, np.int32 if bits < 32 else np.int64),
+         validity()),
+        ("fixed", ranged(1_600_000_000, 1_000_000, np.int64), validity()),
+        ("fixed", ranged(-50, 1, np.int64) * 0.01, validity()),
+        ("fixed", rng.random(n) < 0.5, validity()),
+        ("fixed", ranged(10_000, 1, np.int32) if bits < 32
+         else rng.integers(-2**31, 2**31, n).astype(np.int32), validity()),
+        ("var", chars, lens, validity(), width),
+    ]
+    schema = T.Schema([
+        T.StructField("i", T.IntegerType() if bits < 32 else T.LongType(),
+                      True),
+        T.StructField("ts", T.LongType(), True),
+        T.StructField("cents", T.DoubleType(), True),
+        T.StructField("b", T.BooleanType(), True),
+        T.StructField("d", T.DateType(), True),
+        T.StructField("s", T.StringType(), True)])
+    pack, batch = _build(cap, n, host, schema)
+    assert batch.known_rows == n and int(batch.num_rows) == n
+    assert pack.dict_gathers == 0
+    if n > 1 and bits < 32:
+        # the widths under test are the ones that shipped
+        assert pack.col_specs[0][1][:3:2] == ("bits", bits)
+        assert pack.col_specs[2][1][:3:2] == ("fbits", bits)
+    for c, col in zip(host, batch.columns):
+        v = c[-2] if c[0] == "var" else c[-1]
+        want_v = np.zeros(cap, np.bool_)
+        want_v[:n] = True if v is None else v
+        np.testing.assert_array_equal(np.asarray(col.validity), want_v)
+        got = np.asarray(col.data)
+        want = _padded(c[1], cap, v)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+        if c[0] == "var":
+            got_l = np.asarray(col.lengths)
+            assert got_l.dtype == np.int32
+            np.testing.assert_array_equal(got_l, _padded(c[2], cap, v))
+
+
+def _unpack_gathers(monkeypatch, cap, n, cols, schema):
+    """(gathers in the unpack program's jaxpr, the registry's
+    unpack.leaves.* movement, leaves shipped) for one built batch."""
+    import jax
+    from spark_rapids_tpu.columnar import batch as B
+    from spark_rapids_tpu.obs.registry import get_registry
+    seen = {}
+    real = B._packed_unpack_cached
+
+    def spy(spec):
+        program = real(spec)
+
+        def call(bufs):
+            seen["program"], seen["bufs"] = program, bufs
+            return program(bufs)
+        return call
+    monkeypatch.setattr(B, "_packed_unpack_cached", spy)
+    before = get_registry().counters()
+    pack, batch = _build(cap, n, cols, schema)
+    moved = get_registry().counters_since(before)
+    text = str(jax.make_jaxpr(seen["program"].fn)(seen["bufs"]))
+    lowered = seen["program"].fn.lower(seen["bufs"]).as_text()
+    assert ("gather" in lowered) == ("gather" in text)
+    return (text.count(" gather["), moved.get("unpack.leaves.static", 0),
+            moved.get("unpack.leaves.gather", 0), len(pack.leaves), batch)
+
+
+def _dict_col(rng, n, k, width=8):
+    lens = rng.integers(1, width + 1, k).astype(np.int32)
+    mat = rng.integers(97, 123, (k, width), dtype=np.uint8)
+    mat[np.arange(width)[None, :] >= lens[:, None]] = 0
+    idx = rng.integers(0, k, n).astype(np.int32)
+    valid = rng.random(n) < 0.9
+    return ("dict", idx, mat, lens, valid)
+
+
+@pytest.mark.parametrize("case,want_gathers", [
+    ("fixed+validity+lengths", 0), ("small dictionary", 0),
+    ("large dictionary", 2), ("one of each", 2)])
+def test_unpack_program_gathers_only_from_large_dictionaries(
+        monkeypatch, rng, case, want_gathers):
+    """The unpack program computes no index from ``arange``: it holds a
+    gather only where the data decides the index (a dictionary too large
+    to select from: its bytes and its lengths), and the registry counts
+    the same leaves."""
+    from spark_rapids_tpu import types as T
+    cap, n = 4096, 4000
+    valid = rng.random(n) < 0.9
+    fixed = [
+        ("fixed", rng.integers(0, 3000, n).astype(np.int32), valid),  # 12
+        ("fixed", rng.integers(0, 10**6, n).astype(np.int64), None),  # 20
+        ("fixed", rng.integers(0, 10**7, n) * 0.01, valid),           # 24
+        ("fixed", rng.integers(0, 2**27, n).astype(np.int64), valid),  # 28
+        ("fixed", rng.random(n) < 0.5, valid),
+        ("var", np.zeros((n, 8), np.uint8),
+         rng.integers(0, 9, n).astype(np.int32), valid, 8)]
+    fields = [("a", T.IntegerType()), ("b", T.LongType()),
+              ("c", T.DoubleType()), ("d", T.LongType()),
+              ("e", T.BooleanType()), ("f", T.StringType())]
+    small = wc._DICT_SELECT_MAX_ROWS
+    cols = {"fixed+validity+lengths": fixed,
+            "small dictionary": [_dict_col(rng, n, 3),
+                                 _dict_col(rng, n, small)],
+            "large dictionary": [_dict_col(rng, n, small + 1)],
+            "one of each": fixed + [_dict_col(rng, n, 2),
+                                    _dict_col(rng, n, 1000)]}[case]
+    fields = {"fixed+validity+lengths": fields,
+              "small dictionary": [("s", T.StringType())] * 2,
+              "large dictionary": [("s", T.StringType())],
+              "one of each": fields + [("s", T.StringType())] * 2}[case]
+    schema = T.Schema([T.StructField(f"{nm}{i}", t, True)
+                       for i, (nm, t) in enumerate(fields)])
+    gathers, static, counted, shipped, batch = _unpack_gathers(
+        monkeypatch, cap, n, cols, schema)
+    assert gathers == counted == want_gathers
+    assert static == shipped - counted and static > 0
+    # and what a dictionary decodes to, either way, is its rows
+    for c, col in zip(cols, batch.columns):
+        if c[0] != "dict":
+            continue
+        _, idx, mat, lens, v = c
+        assert np.asarray(col.data).tobytes() == \
+            _padded(mat[idx], cap, v).tobytes()
+        np.testing.assert_array_equal(np.asarray(col.lengths),
+                                      _padded(lens[idx], cap, v))
