@@ -35,7 +35,7 @@ TABLES = ("date_dim", "time_dim", "item", "customer", "customer_address",
           "catalog_sales", "catalog_returns", "web_sales", "web_returns")
 
 #: bump when generated schemas change; tables regenerate on mismatch
-_SCHEMA_VERSION = "v6"
+_SCHEMA_VERSION = "v7"
 
 #: returns tables are sampled FROM their parent's rows so that joins on
 #: (item_sk, ticket/order number) actually match (dsdgen links them the
@@ -515,7 +515,41 @@ def _sales_common(rng, n, counts, prefix):
     return qty, price, wholesale, ext
 
 
+def _unique_tickets(item: np.ndarray, ticket: np.ndarray,
+                    first_free: int) -> np.ndarray:
+    """``ticket`` with every (item, ticket) pair made unique, as
+    store_sales' primary key has it in dsdgen: the later rows of a pair
+    drawn twice get the tickets ``first_free``, ``first_free + 1``, …
+    in row order.  Nothing is drawn, so the random stream, every other
+    column and every other row stay as they were (about 26 rows a
+    million at any scale: n / 2 pairs of n × n / 3 keys × 57k items)."""
+    key = (item.astype(np.int64) << 32) | ticket
+    # the keys drawn more than once (a plain sort: an argsort of every
+    # row costs 10 s at SF10), then the few rows that hold them
+    ordered = np.sort(key)
+    twice = np.unique(ordered[1:][ordered[1:] == ordered[:-1]])
+    if not len(twice):
+        return ticket
+    at = np.minimum(np.searchsorted(twice, key), len(twice) - 1)
+    rows = np.flatnonzero(twice[at] == key)         # in row order
+    order = np.argsort(key[rows], kind="stable")
+    held = key[rows][order]
+    again = np.sort(rows[order][1:][held[1:] == held[:-1]])
+    out = ticket.copy()
+    out[again] = first_free + np.arange(len(again), dtype=ticket.dtype)
+    return out
+
+
 def _gen_store_sales(rng, n: int, counts) -> dict[str, np.ndarray]:
+    data = _draw_store_sales(rng, n, counts)
+    data["ss_ticket_number"] = _unique_tickets(
+        data["ss_item_sk"], data["ss_ticket_number"], max(n // 3, 2))
+    return data
+
+
+def _draw_store_sales(rng, n: int, counts) -> dict[str, np.ndarray]:
+    """store_sales as the repo's generator draws it (tickets at random,
+    so a few (item, ticket) pairs come twice)."""
     qty, price, wholesale, ext = _sales_common(rng, n, counts, "ss")
     return {
         "ss_sold_date_sk": _with_nulls(

@@ -14,13 +14,11 @@ import time
 from dataclasses import dataclass
 
 from . import bytes as scanned_bytes
-from . import reduce_trace
+from . import host, reduce_trace
 from .cell import ROOT, Cell, load_cell, load_module
 from .compare import rows_match
 from .compiles import watch_compiles
 
-#: seeds of one dataset kept on disk (SF10 is 1.8 GB a seed)
-KEEP_SEEDS = 2
 #: a cell whose collect takes longer traces one collect, else up to three
 LONG_COLLECT_S = 5.0
 
@@ -60,15 +58,15 @@ def _peak_bytes(devices) -> list:
 
 
 def _make_data(cell: Cell, seed: int, root: str, queries) -> str:
-    """Generate (or find) this seed's tables; keep KEEP_SEEDS seeds."""
+    """Generate this seed's tables in place of whatever an earlier run
+    left of the cell's dataset.  Every run makes its data, as every run
+    of a check does: a run that found its tables on disk collected
+    slower and less steadily than one that had just made them (PERF.md,
+    Findings PR 41), and two runs are compared like with like."""
     base = os.path.join(root, ".bench_data", cell.dataset)
+    shutil.rmtree(base, ignore_errors=True)
     data_dir = os.path.join(base, f"seed{seed}")
-    os.makedirs(data_dir, exist_ok=True)
-    os.utime(data_dir)
-    others = sorted((d for d in os.listdir(base) if d != f"seed{seed}"),
-                    key=lambda d: os.path.getmtime(os.path.join(base, d)))
-    for old in others[:max(0, len(others) - (KEEP_SEEDS - 1))]:
-        shutil.rmtree(os.path.join(base, old))
+    os.makedirs(data_dir)
     tables = sorted({t for q in queries for t in q.mod.TABLES})
     gen = load_module(root, "datagen", cell.config["datagen"])
     gen.generate(data_dir, cell.config["scale_factor"], seed, tables)
@@ -76,16 +74,11 @@ def _make_data(cell: Cell, seed: int, root: str, queries) -> str:
 
 
 def _reference_rows(root: str, data_dir: str, qfile: str) -> list:
-    """The plain reference's rows, computed once per (query, data) and
-    kept beside the data."""
-    path = os.path.join(data_dir, f"reference_{qfile}.json")
-    if os.path.exists(path):
-        with open(path) as f:
-            return [tuple(r) for r in json.load(f)]
+    """The plain reference's rows, left beside the data they were
+    computed from for a look by hand."""
     rows = load_module(root, "reference", qfile).rows(data_dir)
-    with open(path + ".tmp", "w") as f:
+    with open(os.path.join(data_dir, f"reference_{qfile}.json"), "w") as f:
         json.dump(rows, f)
-    os.replace(path + ".tmp", path)
     return [tuple(r) for r in rows]
 
 
@@ -187,8 +180,10 @@ def _measure(cell, devices, queries, seconds, trace, root, t_start, watch,
 
     # ---- the window: closed loop, one client, queries in turn
     results, trace_dir = [], None
+    load = host.load_per_core()
     t_window = time.perf_counter()
     setup_s = t_window - t_start
+    at_start = host.snapshot()
     if trace:
         n = 1 if max(warm_s.values()) > LONG_COLLECT_S else 3
         trace_dir = os.path.join(root, ".bench_data", "traces", cell.name)
@@ -198,6 +193,7 @@ def _measure(cell, devices, queries, seconds, trace, root, t_start, watch,
     while time.perf_counter() - t_window < seconds:
         results.append(_timed_collect(queries[len(results) % len(queries)]))
     t_last_end = time.perf_counter()
+    window = host.moved(at_start, host.snapshot())
     window_compiles = len(compiles) - len(setup_compiles)
 
     # ---- the check, outside the window
@@ -213,6 +209,10 @@ def _measure(cell, devices, queries, seconds, trace, root, t_start, watch,
     plain = [secs for _, secs, rows in results[n_traced:] if rows is not None]
     done = [query for query, _, rows in results if rows is not None]
     counters.update(
+        host_load=load,
+        host_cpu_s=window["cpu_s"] / len(results),
+        host_disk_read_bytes=None if window["disk_read_bytes"] is None
+        else window["disk_read_bytes"] / len(results),
         window_compiles=window_compiles,
         collect_seconds=plain,
         traced_collect_seconds=[s for _, s, _ in results[:n_traced]],
@@ -227,7 +227,16 @@ def _measure(cell, devices, queries, seconds, trace, root, t_start, watch,
     }
     say(phase="window", collects=len(results), traced=n_traced,
         failed=failed, window_compiles=window_compiles,
-        seconds=[round(s, 4) for _, s, _ in results], **e2e)
+        seconds=[round(s, 4) for _, s, _ in results], **e2e,
+        # what the host was doing (layer_metrics/host_*.py read the
+        # same counters on a traced run), so that a window that reads
+        # apart from its neighbours says why
+        collect_iqr_s=load_module(
+            root, "layer_metrics", "collect_iqr_s").quartile_distance(plain),
+        host_cpu_s=counters["host_cpu_s"],
+        host_disk_read_bytes=counters["host_disk_read_bytes"],
+        host_load=load, cores=host.cores(),
+        memory_pool=host.memory_pool())
 
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),

@@ -55,14 +55,13 @@ def test_reader_means_its_counter_over_the_windows_collects(metric):
     assert read(_facts(0, 0)) is None
 
 
-def test_entries_are_appended_under_the_layers_perf_md_names():
+def test_entries_are_under_the_layers_perf_md_names():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    assert [m["name"] for m in per_layer[-6:]] == [
-        "scan_starved_s", "scan_backpressure_s", "scan_wait_s",
-        "scan_first_batch_s", "h2d_put_s", "engine_dispatch_s"]
     layers = {m["name"]: m["layer"] for m in per_layer}
-    for m in per_layer[-6:]:
+    # found by name: later PRs append their own entries behind these
+    assert set(READS) <= set(layers)
+    for m in (m for m in per_layer if m["name"] in READS):
         assert m["moves"] == "query_s" and "workloads" not in m
         assert m["unit"] == "s" and m["better"] == "lower"
         twin = "engine_launches" if m["name"] == "engine_dispatch_s" \
