@@ -60,6 +60,48 @@ _MIN_CAPACITY = 8
 # ---------------------------------------------------------------------------
 
 
+#: a raw string matrix larger than this is staged and put in row chunks
+#: of this size (``_strings_to_row_chunks``), each a put of its own:
+#: above every dictionary a batch ships (at most capacity / 8 rows)
+_CHUNK_BYTES = 16 << 20
+#: idle chunk buffers kept for the next batch, at most (1 GiB: eight
+#: staged 2^20 x 128 matrices, more than a scan's window holds at once)
+_CHUNKS_KEPT = 64
+_idle_chunks: list = []
+_idle_lock = __import__("threading").Lock()
+
+
+def _chunk_buffer(rows: int, w: int) -> np.ndarray:
+    """uint8[rows, w] (uninitialized, at most ``_CHUNK_BYTES``) over
+    memory of the engine's own, used again batch after batch: a mapping
+    goes back to ``_idle_chunks`` when the last array over it is gone —
+    ours, the views made of it and the reference jax keeps until the
+    transfer is done (on XLA:CPU, where the device array may alias the
+    host's bytes, when that array is).  A buffer from ``np.empty`` comes
+    from the heap of whichever thread stages, a new thread every scan:
+    whether its pages were already there differed collect by collect,
+    and the chip machine's kernel makes a fresh page dear (PERF.md
+    Findings PR 42, refusal round)."""
+    import mmap
+    import weakref
+    with _idle_lock:
+        owner = _idle_chunks.pop() if _idle_chunks else None
+    if owner is None:
+        owner = mmap.mmap(-1, _CHUNK_BYTES)
+    # the base of every view made of ``flat`` is ``flat`` itself (numpy
+    # collapses a chain of views to the first array over a foreign
+    # buffer), so it lives as long as any of them does
+    flat = np.frombuffer(owner, dtype=np.uint8)
+    weakref.finalize(flat, _chunk_idle, owner).atexit = False
+    return flat[:rows * w].reshape(rows, w)
+
+
+def _chunk_idle(owner) -> None:
+    with _idle_lock:
+        if len(_idle_chunks) < _CHUNKS_KEPT:
+            _idle_chunks.append(owner)
+
+
 class _PackBuilder:
     """Accumulates per-column host leaves — raw or wire-codec encoded
     (columnar/wirecodec.py) — and materializes them on device with one
@@ -74,8 +116,9 @@ class _PackBuilder:
         self.i64_params: list[int] = []
         self.col_specs: list[tuple] = []
         self.dict_gathers = 0               # gathers the unpack will hold
+        self.string_bytes = 0               # raw string matrices, bytes
 
-    def _add_leaf(self, arr: np.ndarray) -> int:
+    def _add_leaf(self, arr: np.ndarray, own_put: bool = False) -> int:
         """Register one host buffer.
 
         Every dtype of width <= 4 bytes rides ONE shared uint32 word
@@ -86,9 +129,20 @@ class _PackBuilder:
         one transfer per dtype.  Leaf records:
           ("g", gkey, elem_off, elem_size, shape)     — plain group
           ("w", word_off, word_size, dtype, shape, n) — u32-view leaf
+
+        ``own_put``: a buffer so large that copying it into the shared
+        word buffer would cost more than a transfer of its own (a row
+        chunk of a raw string matrix: 150 ms of memcpy a 128 MB matrix
+        against a put's fixed cost, PERF.md Findings PR 42) is a group
+        by itself — shipped as it lies, decoded by a reshape.
         """
         dt = arr.dtype
         flat = np.ravel(arr)
+        if own_put:
+            gkey = f"{dt.str}@{len(self.leaves)}"
+            self.groups[gkey] = [flat]
+            self.leaves.append(("g", gkey, 0, flat.size, arr.shape))
+            return len(self.leaves) - 1
         if dt.itemsize <= 4 and dt.kind in "uifb":
             by = flat.view(np.uint8)
             pad = (-by.size) % 4
@@ -136,25 +190,35 @@ class _PackBuilder:
             desc = ("raw", self._add_leaf(full))
         self.col_specs.append(("fixed", desc, self._val_desc(validity)))
 
-    def add_var(self, matrix: np.ndarray, lengths: np.ndarray,
+    def add_var(self, matrix, lengths: np.ndarray,
                 validity: np.ndarray | None, width: int):
-        """Var-width (string/array) column from an UNPADDED [n, w]
-        matrix + lengths."""
-        n = matrix.shape[0]
+        """Var-width (string/array) column from an [n, w] matrix + n
+        lengths.  A matrix already at [capacity, w], its tail zero,
+        ships as it is, and so does a list of row chunks that make one
+        up (``_strings_to_row_chunks``): each chunk a put of its own,
+        joined by the unpack program.  Null rows' bytes are zeroed by
+        that program, not here."""
         cap = self.capacity
+        chunks = matrix if isinstance(matrix, list) else [matrix]
+        if sum(c.shape[0] for c in chunks) != cap:
+            (short,) = chunks
+            chunks = [np.zeros((cap, width), dtype=short.dtype)]
+            chunks[0][:short.shape[0]] = short
         if validity is not None and not validity.all():
-            matrix = np.where(validity[:, None], matrix,
-                              matrix.dtype.type(0))
             lengths = np.where(validity, lengths, 0)
-        mfull = np.zeros((cap, width), dtype=matrix.dtype)
-        mfull[:n] = matrix
-        mdesc = ("raw", self._add_leaf(mfull))
+        if chunks[0].dtype == np.uint8:
+            self.string_bytes += sum(c.nbytes for c in chunks)
+        if len(chunks) == 1:
+            mdesc = ("raw", self._add_leaf(chunks[0]))
+        else:
+            mdesc = ("rows", tuple(self._add_leaf(c, own_put=True)
+                                   for c in chunks))
         if self.codec:
             ldesc = wc.encode_lengths(lengths, cap, width, self._add_leaf,
                                       self._add_i64)
         else:
             lfull = np.zeros(cap, dtype=np.int32)
-            lfull[:n] = lengths
+            lfull[:lengths.shape[0]] = lengths
             ldesc = ("raw", self._add_leaf(lfull))
         self.col_specs.append(("var", mdesc, self._val_desc(validity),
                                ldesc))
@@ -211,12 +275,16 @@ class _PackBuilder:
         # unpack.leaves.*: how the program about to run decodes what was
         # shipped — leaves it reads by static slices and shifts, and the
         # gathers left (two a dictionary too large to select from)
+        # scan.stage.string_bytes: bytes of the raw (not dictionary)
+        # string matrices among them, at their staged width
         get_registry().inc_many((
             ("h2d_calls", len(host_bufs)),
             ("h2d_bytes", sum(b.nbytes for b in host_bufs)),
             ("h2d_put_s", put_s),
             ("unpack.leaves.static", len(self.leaves) - self.dict_gathers),
-            ("unpack.leaves.gather", self.dict_gathers)))
+            ("unpack.leaves.gather", self.dict_gathers))
+            + ((("scan.stage.string_bytes", self.string_bytes),)
+               if self.string_bytes else ()))
         spec = (self.capacity, gkeys, tuple(self.leaves),
                 tuple(self.col_specs), nr, ip)
         arrays = _packed_unpack_cached(spec)(dev_bufs)
@@ -418,9 +486,9 @@ class ColumnBatch:
                     dm, dlens = _strings_to_matrix(dictionary, w)
                     pack.add_dict_string(idx, dm, dlens, validity)
                 else:
-                    bm, lens = _strings_to_matrix(arr, w)
-                    pack.add_var(bm, lens, validity,
-                                 bm.shape[1] if bm.ndim == 2 else 4)
+                    chunks, lens = _strings_to_row_chunks(arr, w, cap)
+                    pack.add_var(chunks, lens, validity,
+                                 chunks[0].shape[1])
             elif isinstance(field.data_type, T.ArrayType):
                 m, lens = _lists_to_matrix(arr, field.data_type)
                 pack.add_var(m, lens, validity,
@@ -529,17 +597,23 @@ def _lists_to_matrix(arr, dtype):
     return out, lens
 
 
-def _strings_to_matrix(arr, width: int | None = None):
-    """Arrow string array -> (uint8[n, w] padded bytes, int32[n] lengths)."""
+def _string_rows(arr, width: int | None):
+    """``(data, starts, lens, w)`` of an Arrow string array: its flat
+    bytes (None where it has none), where each row starts in them, the
+    rows' byte lengths (a null's is 0) and the width bucket."""
     import pyarrow as pa
-    arr = arr.cast(pa.large_string())
+    if not (pa.types.is_string(arr.type)
+            or pa.types.is_large_string(arr.type)):
+        arr = arr.cast(pa.large_string())
     n = len(arr)
+    odt = np.int64 if pa.types.is_large_string(arr.type) else np.int32
     buffers = arr.buffers()
-    # large_string: [validity, offsets(int64), data]
-    offsets = np.frombuffer(buffers[1], dtype=np.int64, count=n + 1,
-                            offset=arr.offset * 8)
-    databuf = np.frombuffer(buffers[2], dtype=np.uint8) if buffers[2] is not None \
-        else np.zeros(0, np.uint8)
+    # [validity, offsets, data]
+    if n and buffers[1] is not None:
+        offsets = np.frombuffer(buffers[1], dtype=odt, count=n + 1,
+                                offset=arr.offset * odt().itemsize)
+    else:
+        offsets = np.zeros(n + 1, odt)
     lens = (offsets[1:] - offsets[:-1]).astype(np.int32)
     # nulls contribute zero-length
     if arr.null_count:
@@ -549,10 +623,46 @@ def _strings_to_matrix(arr, width: int | None = None):
     w = width or round_string_width(max(maxw, 1))
     if maxw > w:
         raise ValueError(f"string width {maxw} exceeds bucket {w}")
-    out = np.zeros((n, w), dtype=np.uint8)
-    if n and databuf.size:
-        # vectorized gather: out[i, j] = databuf[offsets[i] + j] for j < lens[i]
-        pos = offsets[:-1, None] + np.arange(w, dtype=np.int64)[None, :]
-        mask = np.arange(w, dtype=np.int32)[None, :] < lens[:, None]
-        out[mask] = databuf[pos[mask]]
-    return out, lens
+    data = np.frombuffer(buffers[2], dtype=np.uint8) \
+        if maxw and buffers[2] is not None else None
+    return data, offsets[:-1], lens, w
+
+
+def _fill_rows(out: np.ndarray, data, starts, lens) -> np.ndarray:
+    """Fill uint8[rows, w] ``out`` (uninitialized): one memcpy and one
+    memset a row (native ``pad_rows``), every byte written once; rows
+    past ``len(lens)`` are zeros."""
+    if data is None:
+        out[...] = 0
+    else:
+        from spark_rapids_tpu.native import pad_rows
+        pad_rows(data, starts, lens, out)
+    return out
+
+
+def _strings_to_matrix(arr, width: int | None = None):
+    """Arrow string array -> (uint8[n, w] padded bytes, int32[n]
+    lengths), by a row-wise copy from the Arrow offsets: no index
+    matrix, no mask."""
+    data, starts, lens, w = _string_rows(arr, width)
+    return _fill_rows(np.empty((len(lens), w), np.uint8),
+                      data, starts, lens), lens
+
+
+def _strings_to_row_chunks(arr, width: int | None, rows: int):
+    """``_strings_to_matrix`` at ``rows`` >= n rows (a batch's capacity,
+    the tail zero), the matrix in row chunks of at most
+    ``_CHUNK_BYTES``: ``(chunks, lengths)``.  A fact-sized
+    matrix in one piece is 128 MB of fresh pages a batch (an allocation
+    that large is mapped anew every time, and the chip machine's kernel
+    makes a fresh page dear: PERF.md Findings PRs 41, 42); its chunks
+    lie in buffers that are used again (``_chunk_buffer``), and each
+    ships by a put of its own, with no copy into a shared buffer.  A
+    matrix of one chunk is copied into that buffer, and is the heap's."""
+    data, starts, lens, w = _string_rows(arr, width)
+    step = max(_CHUNK_BYTES // w, 1)
+    make = _chunk_buffer if rows > step \
+        else lambda r, w: np.empty((r, w), np.uint8)
+    return [_fill_rows(make(min(step, rows - lo), w), data,
+                       starts[lo:lo + step], lens[lo:lo + step])
+            for lo in range(0, rows, step)], lens

@@ -252,6 +252,13 @@ def maybe_dict_arrow(arr, n: int):
     if n < 4096:
         return None
     import pyarrow.compute as pc
+    # a column of all but distinct values (comments, names) is told from
+    # its first n/64 rows: encoding every row only to throw the
+    # dictionary away hashes each byte of a fact-sized batch.  A column
+    # uniform over k <= n/8 values shows at most 88 % distinct there.
+    if n >= 1 << 16 and \
+            pc.count_distinct(arr.slice(0, n // 64)).as_py() > 0.95 * (n // 64):
+        return None
     try:
         enc = arr.dictionary_encode()
     # enginelint: disable=RL001 (dictionary codec is best-effort; un-encodable arrays ship raw)
@@ -314,6 +321,8 @@ def decode_data(desc, leaf, i64p, cap: int):
     kind = desc[0]
     if kind == "raw":
         return leaf(desc[1])
+    if kind == "rows":  # a matrix shipped in row chunks
+        return jnp.concatenate([leaf(i) for i in desc[1]], axis=0)
     _, li, bits, out_dtype, pbase, factor = desc
     raw = _unpack_bits_device(leaf(li), cap, bits)
     dt = np.dtype(out_dtype)

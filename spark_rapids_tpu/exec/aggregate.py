@@ -362,6 +362,13 @@ class HashAggregateExec(PlanNode):
     _MERGE_PENDING_CAP = 1 << 23
     #: batches whose group counts sync to host in one stacked device_get
     _SYNC_CHUNK = 8
+    #: an input batch of more slots than this is updated half by half
+    #: (``ops/kernels.halve_capacity``: static slices), the halves'
+    #: buffers merged like any two batches': on the chip ONE sort-branch
+    #: update at 2^24 slots took 4.55 s or 5.71 s, collect by collect,
+    #: where two at 2^23 and their merge take 1.27 s and repeat to 0.1 s
+    #: (PERF.md Findings PR 42)
+    _UPDATE_MAX_CAP = 1 << 23
 
     def _run_device(self, ctx: ExecCtx, child_it, key_idx) \
             -> Iterator[ColumnBatch]:
@@ -483,9 +490,10 @@ class HashAggregateExec(PlanNode):
             else:
                 if b.known_rows is not None:
                     get_registry().inc("agg.update.rows", b.known_rows)
-                src = SpillableColumnarBatch(b, ctx.catalog,
-                                             SpillPriority.READ_SHUFFLE)
-                chunk.extend(update_entries(src))
+                for piece in self._update_pieces(b):
+                    src = SpillableColumnarBatch(piece, ctx.catalog,
+                                                 SpillPriority.READ_SHUFFLE)
+                    chunk.extend(update_entries(src))
             if len(chunk) >= self._SYNC_CHUNK:
                 flush_chunk(chunk)
                 chunk = []
@@ -510,6 +518,15 @@ class HashAggregateExec(PlanNode):
             out = ctx.dispatch(final_jit, running)
             out.known_rows = running.known_rows
             yield out
+
+    def _update_pieces(self, b: ColumnBatch) -> list[ColumnBatch]:
+        """``b``, or its halves by slots until none has more than
+        ``_UPDATE_MAX_CAP``; a half known to be empty is left out.  A
+        holistic aggregate has no merge and takes its one batch whole."""
+        if self._holistic or b.capacity <= self._UPDATE_MAX_CAP:
+            return [b]
+        return [p for half in dk.halve_capacity(b) if half.known_rows != 0
+                for p in self._update_pieces(half)]
 
     # -- host oracle path --------------------------------------------------
     def _run_host(self, child_it, key_idx) -> Iterator[HostBatch]:

@@ -253,6 +253,38 @@ class ProjectExec(PlanNode):
         return f"ProjectExec[{self._schema.names}]"
 
 
+def matched_columns(ops) -> tuple:
+    """``(matches, ordinals)`` of a filter / project chain
+    (innermost-first): how many device string matches its expressions
+    hold (expr/strings.py ``string_matches``) and, for those whose
+    matched child is a column of the chain's input, that column's
+    ordinal."""
+    from spark_rapids_tpu.expr.core import BoundReference
+    from spark_rapids_tpu.expr.strings import string_matches
+    matches, ordinals, projected = 0, [], False
+    for op in ops:
+        for e in op.bound_exprs:
+            for child in string_matches(e):
+                matches += 1
+                if not projected and isinstance(child, BoundReference):
+                    ordinals.append(child.index)
+        projected = projected or type(op) is not FilterExec
+    return matches, tuple(ordinals)
+
+
+def count_string_matches(b, matches: int, ordinals: tuple) -> None:
+    """One dispatch of a program that matches strings on ``b``:
+    ``like.device.rows`` (the batch's rows where it carries its count,
+    else its slots, a match) and ``like.device.bytes`` (rows x the
+    matched column's staged width: what the match reads at least once)."""
+    if matches:
+        rows = b.capacity if b.known_rows is None else b.known_rows
+        get_registry().inc_many((
+            ("like.device.rows", rows * matches),
+            ("like.device.bytes", rows * sum(
+                b.columns[i].data.shape[1] for i in ordinals))))
+
+
 class FilterExec(PlanNode):
     """Boolean condition -> compact kept rows (GpuFilterExec:
     Table.filter via front-packing permutation on device)."""
@@ -296,10 +328,14 @@ class FilterExec(PlanNode):
                 keep = c.data & c.validity  # null -> drop (SQL WHERE)
                 return dk.compact(b, keep)
 
+            # a condition that matches strings runs under a name of its
+            # own, so a trace tells its seconds from other filters'
+            self._matched = matched_columns([self])
             self._filter_jit = cc.shared_jit(
                 cc.fragment_key("filter", self._cond,
                                 self.children[0].output_schema),
-                filt, name="filter_batch")
+                filt, name="string_match_filter" if self._matched[0]
+                else "filter_batch")
         return self._filter_jit
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
@@ -310,6 +346,7 @@ class FilterExec(PlanNode):
                 # row-wise predicate: split pieces filter to the same
                 # surviving rows in order (GpuFilterExec withRetry)
                 dk.count_compaction(b.capacity)
+                count_string_matches(b, *self._matched)
                 yield from ctx.dispatch_retry(fn, b, op="filter")
         else:
             for b in child_it:

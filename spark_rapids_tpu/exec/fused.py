@@ -42,7 +42,9 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
-from spark_rapids_tpu.exec.basic import FilterExec, ProjectExec
+from spark_rapids_tpu.exec.basic import (FilterExec, ProjectExec,
+                                          count_string_matches,
+                                          matched_columns)
 from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
 from spark_rapids_tpu.expr.core import eval_device, eval_host
 from spark_rapids_tpu.host.batch import HostBatch
@@ -137,6 +139,7 @@ class FusedStageExec(PlanNode):
         self._ops = tuple(ops)
         self._merged = filters_merged(self._ops)
         self._compacts = has_filter(self._ops)
+        self._matched = matched_columns(self._ops)
         # cleared by the fusion pass when the stage input is shared by
         # another consumer: donating a shared batch deletes the buffers
         # under the sibling (e.g. a CTE scanned once, consumed twice)
@@ -177,7 +180,8 @@ class FusedStageExec(PlanNode):
             kw = {"donate_argnums": 0} if donate else {}
             self._fused_jits[donate] = cc.shared_jit(
                 self._stage_key(donate), stage_body(self._ops),
-                name="fused_stage_body", **kw)
+                name="string_match_stage" if self._matched[0]
+                else "fused_stage_body", **kw)
         return self._fused_jits[donate]
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
@@ -211,6 +215,7 @@ class FusedStageExec(PlanNode):
                 get_registry().inc("fused.filters_merged", self._merged)
             if self._compacts:
                 dk.count_compaction(cap)
+            count_string_matches(b, *self._matched)
             try:
                 yield from ctx.dispatch_retry(fn, b, op="fused_stage")
             except Exception as e:

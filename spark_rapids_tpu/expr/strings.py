@@ -7,9 +7,14 @@ columnar/column.py).  Kernels are dense VPU-friendly ops:
   masks + cumulative character counts) matching Spark;
 * Upper/Lower are ASCII-only on device (flagged incompat in the planner,
   like the reference's incompat string ops);
-* Like supports the prefix/suffix/contains patterns on device; general
-  patterns are host-only (the reference likewise gates regex behind shims,
-  Spark300Shims.scala:235).
+* Like evaluates every pattern made of literal segments and ``%`` on
+  device (any number of segments, anchored or not at either end); ``_``
+  and escapes are host-only (the reference likewise gates regex behind
+  shims, Spark300Shims.scala:235);
+* a literal needle (Like's segments, and StartsWith / EndsWith / Contains
+  with a literal right side) is matched by static slices of the byte
+  matrix against constants, never by a gather: an index an ``arange``
+  decides is still a gather on the chip (PERF.md Findings PR 39).
 """
 from __future__ import annotations
 
@@ -31,6 +36,86 @@ def _char_starts(data, lengths, xp):
     w = data.shape[1]
     in_range = xp.arange(w, dtype=np.int32)[None, :] < lengths[:, None]
     return ((data & 0xC0) != 0x80) & in_range
+
+
+def literal_needle(expr) -> bytes | None:
+    """The UTF-8 bytes of a non-null string literal, else None."""
+    if isinstance(expr, Literal) and isinstance(expr.value, str):
+        return expr.value.encode("utf-8")
+    return None
+
+
+def _needle_at(data, needle: bytes):
+    """bool[n, w - L + 1]: the needle's L bytes stand at offset p of the
+    row — L static slices compared against L constants and and-ed, every
+    offset at once.  The caller bounds ``p + L`` by the row's length
+    (padding is zeros, and a needle may hold one).  L <= w, L >= 1."""
+    span = data.shape[1] - len(needle) + 1
+    hit = None
+    for i, byte in enumerate(needle):
+        eq = data[:, i:i + span] == np.uint8(byte)
+        hit = eq if hit is None else hit & eq
+    return hit
+
+
+def _starts_with(data, lengths, needle: bytes, xp):
+    if len(needle) > data.shape[1]:
+        return xp.zeros(data.shape[0], dtype=bool)
+    if not needle:
+        return xp.ones(data.shape[0], dtype=bool)
+    return _needle_at(data[:, :len(needle)], needle)[:, 0] \
+        & (lengths >= len(needle))
+
+
+def _ends_with(data, lengths, needle: bytes, xp, after=None):
+    """The needle ends the row (and starts at or after ``after``)."""
+    start = lengths - len(needle)
+    ok = start >= (0 if after is None else after)
+    if len(needle) > data.shape[1]:
+        return xp.zeros(data.shape[0], dtype=bool)
+    if not needle:
+        return ok
+    hit = _needle_at(data, needle)
+    p = xp.arange(hit.shape[1], dtype=np.int32)[None, :]
+    return ok & xp.any(hit & (p == start[:, None]), axis=1)
+
+
+def _first_after(data, lengths, needle: bytes, after, xp):
+    """``(found, end)``: the leftmost occurrence of the needle that
+    starts at or after ``after`` (int32[n], or 0) and lies inside the
+    row; ``end`` is one past it (past the width where none is found, so
+    nothing matches after it either)."""
+    n, w = data.shape
+    if len(needle) > w:
+        return xp.zeros(n, dtype=bool), xp.full(n, w + 1, dtype=np.int32)
+    hit = _needle_at(data, needle)
+    p = xp.arange(hit.shape[1], dtype=np.int32)[None, :]
+    hit = hit & (p + len(needle) <= lengths[:, None])
+    if after is not None:
+        hit = hit & (p >= after[:, None])
+    first = xp.min(xp.where(hit, p, np.int32(w)), axis=1)
+    return first < w, first + np.int32(len(needle))
+
+
+def string_matches(expr) -> list:
+    """The device string matches of a bound expression tree, as their
+    matched children: one entry for each ``Like`` and each
+    ``StartsWith`` / ``EndsWith`` / ``Contains`` with a literal needle.
+    An operator whose condition holds one launches under a program name
+    of its own and counts ``like.device.rows`` / ``like.device.bytes``
+    (exec/basic.py ``count_string_matches``)."""
+    found = []
+
+    def walk(e):
+        if isinstance(e, Like) or (
+                isinstance(e, _StringPredicate)
+                and literal_needle(e.children[1]) is not None):
+            found.append(e.children[0])
+        for c in e.children:
+            walk(c)
+    walk(expr)
+    return found
+
 
 
 class _StringUnary(Expression):
@@ -281,8 +366,12 @@ class _StringPredicate(Expression):
                              for x, y, va, vb in
                              zip(a.data, b.data, a.validity, b.validity)], bool)
             return ctx.canonical(data, validity, T.BooleanType())
-        return ctx.canonical(self._device(a, b, ctx), validity,
-                             T.BooleanType())
+        needle = literal_needle(self.children[1])
+        if needle is not None:
+            data = self._literal(a.data, a.lengths, needle, ctx.xp)
+        else:  # a needle that is a column: its bytes differ row by row
+            data = self._device(a, b, ctx)
+        return ctx.canonical(data, validity, T.BooleanType())
 
 
 class StartsWith(_StringPredicate):
@@ -290,6 +379,8 @@ class StartsWith(_StringPredicate):
 
     def _host_one(self, x, y):
         return x.startswith(y)
+
+    _literal = staticmethod(_starts_with)
 
     def _device(self, a, b, ctx):
         xp = ctx.xp
@@ -306,6 +397,8 @@ class EndsWith(_StringPredicate):
 
     def _host_one(self, x, y):
         return x.endswith(y)
+
+    _literal = staticmethod(_ends_with)
 
     def _device(self, a, b, ctx):
         xp = ctx.xp
@@ -326,6 +419,12 @@ class Contains(_StringPredicate):
     def _host_one(self, x, y):
         return y in x
 
+    @staticmethod
+    def _literal(data, lengths, needle, xp):
+        if not needle:
+            return xp.ones(data.shape[0], dtype=bool)
+        return _first_after(data, lengths, needle, None, xp)[0]
+
     def _device(self, a, b, ctx):
         xp = ctx.xp
         da, db = _string_pair_device(a, b, ctx)
@@ -345,8 +444,14 @@ class Contains(_StringPredicate):
 
 
 class Like(Expression):
-    """SQL LIKE. Device path handles the common shapes
-    (%x, x%, %x%, exact); general patterns are host-only."""
+    """SQL LIKE.  The device evaluates every pattern made of literal
+    segments and ``%`` — any number of segments, anchored or not at
+    either end (``x``, ``x%``, ``%x``, ``%x%y%``, ``a%b%c`` …): the
+    leftmost match of each segment at or after the end of the one
+    before, over the padded byte matrix and the lengths, by static
+    slices (bytes, which for literal segments is characters: UTF-8
+    resynchronizes).  NULL in, NULL out.  A pattern with ``_`` or an
+    escape character is host-only."""
     sql_name = "Like"
 
     def __init__(self, child: Expression, pattern: str, escape: str = "\\"):
@@ -363,23 +468,21 @@ class Like(Expression):
 
     @property
     def device_supported(self):
-        return self._simple_shape() is not None
+        return self._segments() is not None
 
-    def _simple_shape(self):
-        """(kind, needle) for %-only patterns without _ or escapes."""
+    def _segments(self):
+        """``(head, middle, tail)`` of a pattern of literals and ``%``:
+        the bytes the string must start with (b"" where the pattern
+        starts with ``%``), the segments between two ``%`` in order, the
+        bytes it must end with; ``(whole, None, None)`` for a pattern
+        without ``%``; None where the pattern holds ``_`` or an escape."""
         p = self.pattern
-        if "_" in p or self.escape in p:
+        if "_" in p or (self.escape and self.escape in p):
             return None
-        body = p.strip("%")
-        if "%" in body:
-            return None
-        if p.startswith("%") and p.endswith("%") and len(p) >= 2:
-            return ("contains", body)
-        if p.endswith("%"):
-            return ("prefix", body)
-        if p.startswith("%"):
-            return ("suffix", body)
-        return ("equals", body)
+        parts = [s.encode("utf-8") for s in p.split("%")]
+        if len(parts) == 1:
+            return parts[0], None, None
+        return parts[0], [s for s in parts[1:-1] if s], parts[-1]
 
     def _regex(self):
         import re
@@ -408,18 +511,23 @@ class Like(Expression):
             data = np.array([bool(rx.match(s)) if v else False
                              for s, v in zip(a.data, a.validity)], bool)
             return ctx.canonical(data, a.validity, T.BooleanType())
-        shape = self._simple_shape()
-        if shape is None:
-            raise NotImplementedError("general LIKE is host-only")
-        kind, needle = shape
-        nv = ctx.const(needle, T.StringType())
-        cls = {"contains": Contains, "prefix": StartsWith,
-               "suffix": EndsWith}.get(kind)
-        if cls is None:  # equals
-            from spark_rapids_tpu.expr.predicates import _string_eq
-            data = _string_eq(a, nv, ctx)
-        else:
-            data = cls(None, None)._device(a, nv, ctx)
+        segments = self._segments()
+        if segments is None:
+            raise NotImplementedError(
+                "LIKE with _ or an escape character is host-only")
+        xp = ctx.xp
+        head, middle, tail = segments
+        if middle is None:  # no %: the whole string
+            data = _starts_with(a.data, a.lengths, head, xp) \
+                & (a.lengths == len(head))
+            return ctx.canonical(data, a.validity, T.BooleanType())
+        data = _starts_with(a.data, a.lengths, head, xp)
+        end = xp.full(a.data.shape[0], len(head), dtype=np.int32) \
+            if head else None
+        for seg in middle:
+            found, end = _first_after(a.data, a.lengths, seg, end, xp)
+            data = data & found
+        data = data & _ends_with(a.data, a.lengths, tail, xp, after=end)
         return ctx.canonical(data, a.validity, T.BooleanType())
 
 

@@ -16,7 +16,8 @@ import tempfile
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SRCS = [os.path.join(_DIR, "arena.cpp"), os.path.join(_DIR, "lz4.cpp")]
+_SRCS = [os.path.join(_DIR, "arena.cpp"), os.path.join(_DIR, "lz4.cpp"),
+         os.path.join(_DIR, "rows.cpp")]
 
 
 def _so_path() -> str:
@@ -102,7 +103,29 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
                                    ctypes.c_size_t,
                                    ctypes.POINTER(ctypes.c_uint8),
                                    ctypes.c_size_t]
+    for fn, offset in ((lib.pad_rows32, ctypes.c_int32),
+                       (lib.pad_rows64, ctypes.c_int64)):
+        fn.restype = None
+        fn.argtypes = [ctypes.c_void_p, ctypes.POINTER(offset),
+                       ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+                       ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64]
     return lib
+
+
+def pad_rows(data, starts, lens, out) -> None:
+    """Fill the C-contiguous uint8 matrix ``out`` (``[>= len(lens),
+    width]``, uninitialized): row i gets its ``lens[i]`` bytes at
+    ``data[starts[i]:]`` and zeros to the width, the rows past
+    ``len(lens)`` zeros.  ``starts`` int32 or int64."""
+    import numpy as np
+    lib = load()
+    fn, ctype = (lib.pad_rows32, ctypes.c_int32) \
+        if starts.dtype == np.int32 else (lib.pad_rows64, ctypes.c_int64)
+    starts = np.ascontiguousarray(starts)
+    lens = np.ascontiguousarray(lens, dtype=np.int32)
+    fn(data.ctypes.data, starts.ctypes.data_as(ctypes.POINTER(ctype)),
+       lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+       len(lens), out.shape[1], out.ctypes.data, out.shape[0])
 
 
 def lz4_compress(data) -> bytes:

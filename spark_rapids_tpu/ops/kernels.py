@@ -263,6 +263,39 @@ def _shrink_jit(batch: ColumnBatch, cap: int) -> ColumnBatch:
     return ColumnBatch(cols, batch.num_rows, batch.schema)
 
 
+def halve_capacity(batch: ColumnBatch) -> tuple[ColumnBatch, ColumnBatch]:
+    """The lower and the upper half of a front-packed batch's slots,
+    each a batch at half the capacity: static slices, the rows counted
+    on the device (the upper half holds what lies past the middle, maybe
+    nothing).  ``memory.retry.split_half`` cuts at the middle ROW, which
+    it has to fetch and then moves rows from by a dynamic start — 2 s
+    for a 2^24-slot batch of two columns on the chip, a gather's price,
+    where this is a copy (PERF.md Findings PR 42)."""
+    run = _SHARED_JITS.get("batch_halves") \
+        or _shared("batch_halves", jax.jit(_halves))
+    lo, hi = run(batch)
+    if batch.known_rows is not None:
+        half = batch.capacity // 2
+        lo.known_rows = min(batch.known_rows, half)
+        hi.known_rows = max(batch.known_rows - half, 0)
+    return lo, hi
+
+
+def _halves(batch: ColumnBatch):
+    half = batch.capacity // 2
+
+    def part(at: int, rows) -> ColumnBatch:
+        cols = [DeviceColumn(c.data[at:at + half], c.validity[at:at + half],
+                             c.dtype,
+                             c.lengths[at:at + half] if c.is_var_width
+                             else None)
+                for c in batch.columns]
+        return ColumnBatch(cols, rows, batch.schema)
+    n = batch.num_rows
+    return (part(0, jnp.minimum(n, half)),
+            part(half, jnp.maximum(n - half, 0)))
+
+
 def pad_capacity(batch: ColumnBatch, cap: int) -> ColumnBatch:
     """Grow a batch's storage to ``cap`` rows with trailing padding
     (cheap realloc; keeps compilation buckets canonical)."""

@@ -388,3 +388,61 @@ def test_unique_key_group_by_takes_the_sort(session, tpch_dir):
     assert len(rows) > segmented._DENSE_MAX_GROUPS
     assert counters["agg.update.sorted"] >= 1
     assert counters.get("agg.update.dense", 0) == 0
+
+
+@pytest.mark.parametrize("max_cap,updates", [(1 << 30, 1), (1 << 14, 4),
+                                             (1 << 12, 15)])
+def test_an_oversized_batch_is_updated_half_by_half(session, tpch_dir,
+                                                    monkeypatch, max_cap,
+                                                    updates):
+    """A batch of more slots than ``_UPDATE_MAX_CAP`` is halved by slots
+    (static slices, ``kernels.halve_capacity``) until no piece has more,
+    one update a piece that is not known to be empty, and the pieces'
+    buffers merge like any batches': the same groups, a key that
+    straddles a cut among them, and no fetch added to learn a count."""
+    from spark_rapids_tpu.exec.aggregate import HashAggregateExec
+    from spark_rapids_tpu.expr.aggregates import Count, Sum
+    from spark_rapids_tpu.expr.core import col
+    li = session.read_parquet(os.path.join(tpch_dir, "lineitem"),
+                              columns=["l_orderkey", "l_quantity"])
+    df = li.group_by("l_orderkey").agg(Sum(col("l_quantity")).alias("q"),
+                                       Count(col("l_quantity")).alias("n"))
+    if "want" not in _SPLIT:
+        _SPLIT["want"], whole = _counters_of(df)
+        _SPLIT["d2h"] = whole["d2h_calls"]
+        _SPLIT["rows"] = whole["agg.update.rows"]
+        assert whole["agg.update.sorted"] == 1
+    # one scan batch of 2^16 slots, its last 4096 holding no row
+    assert 14 << 12 < _SPLIT["rows"] <= 15 << 12
+    monkeypatch.setattr(HashAggregateExec, "_UPDATE_MAX_CAP", max_cap)
+    rows, counters = _counters_of(df)
+    assert sorted(rows) == sorted(_SPLIT["want"])
+    assert counters["agg.update.sorted"] == updates
+    assert counters["agg.update.rows"] == _SPLIT["rows"]
+    # the pieces' counts ride in the chunked fetches that were there;
+    # past eight pieces a second chunk and its merge fetch their own
+    assert counters["d2h_calls"] <= _SPLIT["d2h"] + (updates > 1) + updates // 8
+
+
+_SPLIT: dict = {}
+
+
+def test_halves_of_a_batch_are_its_slots_and_count_their_rows():
+    from spark_rapids_tpu.ops import kernels as dk
+    hb = HostBatch.from_pydict(
+        {"k": [1, 2, None, 4, 5], "s": ["a", None, "ccc", "dd", "e"]},
+        T.Schema([T.StructField("k", T.IntegerType()),
+                  T.StructField("s", T.StringType())]))
+    b = host_to_device(hb)
+    assert b.capacity == 8
+    lo, hi = dk.halve_capacity(b)
+    assert (lo.capacity, hi.capacity) == (4, 4)
+    assert (lo.known_rows, hi.known_rows) == (4, 1) \
+        if b.known_rows is not None else (None, None)
+    assert device_to_host(lo).to_rows() == hb.to_rows()[:4]
+    assert device_to_host(hi).to_rows() == hb.to_rows()[4:]
+    # all rows in the lower half: the upper half is empty, not negative
+    lo2, hi2 = dk.halve_capacity(lo)
+    assert device_to_host(lo2).to_rows() == hb.to_rows()[:2]
+    lo3, hi3 = dk.halve_capacity(dk.pad_capacity(hi, 8))
+    assert int(lo3.num_rows) == 1 and int(hi3.num_rows) == 0
