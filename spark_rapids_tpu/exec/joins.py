@@ -40,7 +40,8 @@ from spark_rapids_tpu.ops.join import (JOIN_TYPES, DirectBuild, PackedBuild,
                                        direct_table_size, gather_join_output,
                                        join_indices_from_probe, join_probe,
                                        matched_build_rows, packed_key_span,
-                                       probe_direct, probe_fast, probe_merges)
+                                       probe_counts, probe_direct, probe_fast,
+                                       probe_merges)
 
 __all__ = ["JoinExec", "CrossJoinExec", "BroadcastHashJoinExec"]
 
@@ -48,14 +49,20 @@ __all__ = ["JoinExec", "CrossJoinExec", "BroadcastHashJoinExec"]
 _FETCH = "fetch@JoinExec"
 
 
+def _probed(lb, probe_arrays, total):
+    """What each probe program returns: the probe's arrays without the
+    None placeholder of a join that is not full outer (pytree-stable
+    output) and ``[total, aligned]`` (ops/join.probe_counts), the one
+    array a flush fetches of each batch."""
+    if probe_arrays[-1] is None:
+        probe_arrays = probe_arrays[:-1]
+    return probe_arrays, probe_counts(probe_arrays[3], lb.row_mask(), total)
+
+
 @guarded_jit("join_probe", static_argnames=("lkeys", "rkeys", "join_type"))
 def _jit_probe(lb, rb, lkeys, rkeys, join_type):
     """Heavy rank-path phase (all sorts): compiled once per capacity pair."""
-    probe_arrays, total = join_probe(lb, rb, lkeys, rkeys, join_type)
-    # drop the None placeholder for non-full joins (pytree-stable output)
-    if probe_arrays[-1] is None:
-        probe_arrays = probe_arrays[:-1]
-    return probe_arrays, total
+    return _probed(lb, *join_probe(lb, rb, lkeys, rkeys, join_type))
 
 
 @guarded_jit("join_build_prep", static_argnames=("rkey",))
@@ -83,15 +90,13 @@ def _unpacked(prep) -> tuple:
 @guarded_jit("join_probe_fast", static_argnames=("lkey", "join_type"))
 def _jit_probe_fast(lb, prep, lkey, join_type):
     build, packing = _unpacked(prep)
-    probe_arrays, total = probe_fast(lb, lkey, *build, join_type, packing)
-    return probe_arrays[:-1], total  # drop the None placeholder
+    return _probed(lb, *probe_fast(lb, lkey, *build, join_type, packing))
 
 
 @guarded_jit("join_probe_direct", static_argnames=("lkey", "join_type"))
 def _jit_probe_direct(lb, prep, lkey, join_type):
     build, packing = _unpacked(prep)
-    probe_arrays, total = probe_direct(lb, lkey, build, join_type, packing)
-    return probe_arrays[:-1], total  # drop the None placeholder
+    return _probed(lb, *probe_direct(lb, lkey, build, join_type, packing))
 
 
 def prepare_fast_build(rb, rkeys: tuple):
@@ -130,14 +135,21 @@ def prepare_fast_build(rb, rkeys: tuple):
 
 @guarded_jit("join_gather",
              static_argnames=("cl", "join_type", "out_cap", "include_right",
-                              "schema", "track_matched"))
+                              "schema", "track_matched", "aligned"))
 def _jit_gather(lb, rb, probe_arrays, cl, join_type, out_cap, include_right,
-                schema, track_matched=False):
-    """Light phase (gathers only): re-specialized per output capacity."""
+                schema, track_matched=False, aligned=False):
+    """Light phase (gathers only): re-specialized per output capacity and
+    per plan (ops/join.join_indices_from_probe).  ``aligned``: the batch's
+    fetched flag said every live stream row comes out exactly once, so the
+    stream's columns are sliced, not gathered, and only ``perm[start]`` and
+    the build's stacks move: 34 ms a launch on the chip where the
+    expanding plan takes 99 at 2^20 slots x 9 columns (37 against 101 in
+    q93's collects; PERF.md section 6 PR 43).  Any other batch takes the expanding plan; the output is
+    the same batch array for array whichever ran."""
     if len(probe_arrays) == 4:
         probe_arrays = probe_arrays + (None,)
     plan = join_indices_from_probe(cl, probe_arrays, join_type, out_cap,
-                                   stacked=True)
+                                   stacked=True, aligned=aligned)
     out = gather_join_output(lb, rb, *plan, schema, include_right,
                              stacked=True)
     if track_matched:
@@ -356,8 +368,10 @@ class JoinExec(PlanNode):
         kf_schema = T.Schema(kf)
         matched = None
 
-        # Probe totals sync in CHUNKS: each stream batch's match count
-        # must reach the host to pick the static gather capacity, but a
+        # Probe counts sync in CHUNKS: each stream batch's match count
+        # (and beside it whether every row came out once: the aligned
+        # gather plan) must reach the host to pick the static gather
+        # capacity, but a
         # host round trip is pure latency with the device idle behind
         # it — so up to _SYNC_CHUNK probes are dispatched
         # asynchronously and their totals fetched in ONE device_get of
@@ -376,7 +390,7 @@ class JoinExec(PlanNode):
             if prep is None:
                 if jt != "cross":
                     get_registry().inc("join.probe.sorted")
-                probe_arrays, total_dev = _jit_probe(
+                probe_arrays, counts_dev = _jit_probe(
                     lb2, rb2, lkeys, rkeys, stream_jt)
             else:
                 # a packed build takes all the key columns, any other its one
@@ -389,11 +403,11 @@ class JoinExec(PlanNode):
                                                build[0].shape[0]):
                     get_registry().inc("join.probe.search.merged")
                 run = _jit_probe_direct if direct else _jit_probe_fast
-                probe_arrays, total_dev = run(lb2, prep, lkey, stream_jt)
-            return lb2, total_dev, probe_arrays
+                probe_arrays, counts_dev = run(lb2, prep, lkey, stream_jt)
+            return lb2, counts_dev, probe_arrays
 
         def probe_entries(lb) -> list:
-            return [(piece, l2, td, pa) for piece, (l2, td, pa)
+            return [(piece, l2, cd, pa) for piece, (l2, cd, pa)
                     in ctx.dispatch_retry(probe, lb, op="join_probe",
                                           pairs=True)]
 
@@ -406,33 +420,36 @@ class JoinExec(PlanNode):
                 pending[:] = [e for p in pending
                               for e in probe_entries(p[0])]
 
-            def sync_totals():
+            def sync_counts():
+                """``[total, aligned]`` of every pending probe."""
                 if len(pending) == 1:
-                    # enginelint: disable=RL003 (single-entry fast path; one scalar sync)
-                    return [int(fetch_to_host(pending[0][2], _FETCH))]
+                    # enginelint: disable=RL003 (single-entry fast path; one two-number sync)
+                    return fetch_to_host(pending[0][2], _FETCH)[None]
                 # enginelint: disable=RL003 (stacked transfer for all pending probes; this IS the batched sync)
-                return [int(t) for t in fetch_to_host(ctx.dispatch(
-                    jnp.stack, [p[2] for p in pending]), _FETCH)]
+                return fetch_to_host(ctx.dispatch(
+                    jnp.stack, [p[2] for p in pending]), _FETCH)
 
-            totals = ctx.retry_sync(sync_totals, redo=redo,
-                                    op="join_flush")
-            get_registry().inc("join.probe.rows_out", sum(totals))
-            for (lb, lb2, _td, probe_arrays), total in zip(pending, totals):
+            counts = [(int(t), bool(a)) for t, a in ctx.retry_sync(
+                sync_counts, redo=redo, op="join_flush")]
+            get_registry().inc_many((
+                ("join.probe.rows_out", sum(t for t, _ in counts)),
+                ("join.gather.aligned", sum(a for t, a in counts if t))))
+            for (lb, lb2, _cd, probe_arrays), (total, aligned) in zip(
+                    pending, counts):
                 if total == 0:
                     if jt == "full" and matched is None:
                         matched = jnp.zeros(rb2.capacity, jnp.bool_)
                     continue
                 out_cap = round_capacity(max(total, 1))
+                gather = partial(
+                    ctx.dispatch, _jit_gather, lb2, rb2, probe_arrays,
+                    lb2.capacity, stream_jt, out_cap, self.include_right,
+                    kf_schema, aligned=aligned)
                 if jt == "full":
-                    out, bm = ctx.dispatch(
-                        _jit_gather, lb2, rb2, probe_arrays, lb2.capacity,
-                        stream_jt, out_cap, self.include_right, kf_schema,
-                        track_matched=True)
+                    out, bm = gather(track_matched=True)
                     matched = bm if matched is None else matched | bm
                 else:
-                    out = ctx.dispatch(
-                        _jit_gather, lb2, rb2, probe_arrays, lb2.capacity,
-                        stream_jt, out_cap, self.include_right, kf_schema)
+                    out = gather()
                 out = self._project_out(
                     out, lb.num_columns, lb2.num_columns, n_right_raw,
                     device=True)

@@ -19,7 +19,10 @@ sort-based (SURVEY.md §7 "hard parts"):
    cumsum + searchsorted (``stacked``, the one-chip executor's plan: one
    scatter of the rows' offsets and a running maximum); full-outer
    appends unmatched right rows by scatter.  Gathers build the output
-   columns (``stacked``: one gather a dtype over stacked leaves).
+   columns (``stacked``: one gather a dtype over stacked leaves).  A
+   stream batch whose every live row comes out exactly once takes the
+   **aligned** plan instead (:func:`probe_counts` says so beside the
+   total): its own columns stay in their slots, only the build's move.
 
 Right outer join is the exec layer's job (swap sides, reorder columns,
 exec/joins.py), matching the reference's build-side flip.
@@ -74,14 +77,15 @@ from jax import lax
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
-from spark_rapids_tpu.ops.kernels import gather_stacked
+from spark_rapids_tpu.ops.kernels import (front_rows, front_stacked,
+                                          gather_stacked)
 from spark_rapids_tpu.ops.segmented import _cols_differ
 from spark_rapids_tpu.ops.sort import encode_key_operands
 
 __all__ = ["join_probe", "join_total", "join_indices_from_probe",
            "gather_join_output", "JOIN_TYPES", "DirectBuild", "KeyPacking",
            "PackedBuild", "direct_table_size", "packed_key_span",
-           "probe_merges"]
+           "probe_merges", "probe_counts"]
 
 JOIN_TYPES = ("inner", "left", "semi", "anti", "full", "cross")
 
@@ -519,8 +523,21 @@ def join_total(lbatch: ColumnBatch, rbatch: ColumnBatch,
     return join_probe(lbatch, rbatch, lkeys, rkeys, join_type)[1]
 
 
+def probe_counts(out_cnt, real_l, total) -> jax.Array:
+    """int64[2] ``[total, aligned]``: what the host fetches of a stream
+    batch's probe, in one array so it is one transfer.  ``aligned`` is 1
+    where every live stream row (``real_l``) comes out exactly once and
+    no other row does (for a left join: no key matched twice; for an inner
+    join also none went unmatched; a cross join: the build holds one row):
+    the batch's output is then its own rows in their own slots, and
+    :func:`join_indices_from_probe` need not place them."""
+    aligned = jnp.all(out_cnt == real_l.astype(out_cnt.dtype))
+    return jnp.stack([total.astype(jnp.int64), aligned.astype(jnp.int64)])
+
+
 def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
-                            out_cap: int, stacked: bool = False):
+                            out_cap: int, stacked: bool = False,
+                            aligned: bool = False):
     """Phase 2: gather plan into a static ``out_cap`` output from
     precomputed probe arrays (no sorts here).
 
@@ -529,15 +546,45 @@ def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
       l_take/r_take: bool[out_cap] — False means that side is all-null for
       the slot (outer non-matches) or the slot is padding.
 
-    ``stacked`` (here and in :func:`gather_join_output`) picks the plan
-    the one-chip executor runs since PR 33: the left row of each slot by
-    one scatter and a running maximum, per-row numbers and column leaves
-    moved in stacked gathers (501.7 -> 99.5 ms at 2^20 slots x 9 columns,
-    PERF.md PR 33).  A mesh region's join body keeps the plan it was
-    measured with (a search of the offsets, a gather a leaf) until a PR
-    measures the four-chip cell with the other one and deletes this one.
+    Three plans, the same arrays out of each:
+
+    * **expanding, searched** (``stacked=False``): a search of the offsets
+      for each slot's left row, a gather a leaf.  A mesh region's join
+      body keeps it, the plan it was measured with, until a PR measures
+      the four-chip cell with another and deletes this one.
+    * **expanding, stacked** (``stacked=True``; the one-chip executor's
+      since PR 33): the left row of each slot by one scatter and a running
+      maximum, per-row numbers and column leaves moved in stacked gathers:
+      99 ms at 2^20 slots x 9 columns on the chip (PERF.md PR 43; 501.7 ms
+      searched, PR 33).  Any batch may take it: a stream row matched
+      twice, an inner join that drops rows.
+    * **aligned** (``aligned=True``, since PR 43): only for a batch whose
+      probe said every live stream row comes out exactly once
+      (:func:`probe_counts`; the executor picks it batch by batch from
+      that fetched flag).  **Precondition, not checked here:** the caller
+      has fetched :func:`probe_counts`' flag for these very arrays and it
+      read 1, and the stream's live rows are front-packed
+      (``row_mask`` = ``arange < num_rows``, as every ColumnBatch's are);
+      on any other batch the result is silently wrong rows.  Slot ``j``
+      is then stream row ``j``: ``li`` is
+      None (:func:`gather_join_output` takes the stream's first
+      ``out_cap`` slots as they lie), no scatter, no running maximum, no
+      gather of per-row numbers; ``ri = perm[start]`` is the one index
+      pass left here (9.9 ms at 2^20 slots on the chip; the whole aligned
+      body 34 ms where the expanding one takes 99: the build's stacks are
+      25.7 of them, its one 64-bit leaf 17.3; PERF.md section 6 PR 43).
     """
     start, cnt, rsort_perm, out_cnt, unmatched_r = probe_arrays
+    if aligned:
+        assert unmatched_r is None, "the full-outer tail expands"
+        total = jnp.sum(out_cnt, dtype=jnp.int32)
+        l_take = jnp.arange(out_cap, dtype=jnp.int32) < total
+        first, n = front_rows(start, out_cap), front_rows(cnt, out_cap)
+        ri = rsort_perm[jnp.clip(first, 0, rsort_perm.shape[0] - 1)]
+        r_take = l_take & (n > 0)
+        if join_type in ("semi", "anti"):
+            r_take = jnp.zeros_like(r_take)
+        return None, ri, l_take, r_take, total
     offsets = jnp.concatenate(
         [jnp.zeros(1, jnp.int32),
          jnp.cumsum(out_cnt)[:-1].astype(jnp.int32)])
@@ -597,8 +644,14 @@ def gather_join_output(lbatch: ColumnBatch, rbatch: ColumnBatch,
                        stacked: bool = False) -> ColumnBatch:
     """Build the output batch from a join_indices plan; ``stacked``: each
     side's leaves move in one gather of rows a dtype
-    (``ops/kernels.gather_stacked``) instead of a gather a leaf."""
+    (``ops/kernels.gather_stacked``) instead of a gather a leaf.  With
+    ``li`` None (the aligned plan of :func:`join_indices_from_probe`) the
+    stream's columns do not move at all: their first ``out_cap`` slots,
+    sliced and masked (``ops/kernels.front_stacked``), and only the
+    build's columns are gathered."""
     def side(columns, idx, take):
+        if idx is None:
+            return front_stacked(columns, take)
         if stacked:
             return gather_stacked(columns, idx, take)
         return [_take_side(c, idx, take) for c in columns]

@@ -30,7 +30,7 @@ from spark_rapids_tpu.obs.registry import get_registry
 
 __all__ = ["compact", "count_compaction", "take", "concat_batches",
            "slice_batch", "slice_rows", "gather_columns", "gather_stacked",
-           "shrink_capacity",
+           "front_rows", "front_stacked", "shrink_capacity",
            "pad_capacity", "device_scalar"]
 
 
@@ -88,17 +88,41 @@ def gather_stacked(columns: Sequence[DeviceColumn], idx: jax.Array,
         rows = jnp.concatenate(xs, axis=1)[idx]
         bounds = np.cumsum([x.shape[1] for x in xs])[:-1]
         moved[dtype] = iter(jnp.split(rows, bounds, axis=1))
-    out = []
-    for c in jax.tree.unflatten(tree, [
-            next(moved[x.dtype]).reshape(idx.shape + x.shape[1:])
-            for x in leaves]):
-        validity = c.validity & take
-        data = jnp.where(validity[(...,) + (None,) * (c.data.ndim - 1)],
-                         c.data, jnp.zeros((), c.data.dtype))
-        out.append(DeviceColumn(
-            data, validity, c.dtype,
-            None if c.lengths is None else jnp.where(validity, c.lengths, 0)))
-    return out
+    return [_keep_rows(c, take) for c in jax.tree.unflatten(tree, [
+        next(moved[x.dtype]).reshape(idx.shape + x.shape[1:])
+        for x in leaves])]
+
+
+def _keep_rows(c: DeviceColumn, take: jax.Array) -> DeviceColumn:
+    """``c`` where ``take`` and valid, zeros and nulls elsewhere."""
+    validity = c.validity & take
+    data = jnp.where(validity[(...,) + (None,) * (c.data.ndim - 1)],
+                     c.data, jnp.zeros((), c.data.dtype))
+    return DeviceColumn(
+        data, validity, c.dtype,
+        None if c.lengths is None else jnp.where(validity, c.lengths, 0))
+
+
+def front_rows(x: jax.Array, slots: int) -> jax.Array:
+    """The first ``slots`` rows of ``x``: a static slice, or zeros after
+    its last row where it has fewer."""
+    n = x.shape[0]
+    if n >= slots:
+        return x if n == slots else x[:slots]
+    return jnp.pad(x, ((0, slots - n),) + ((0, 0),) * (x.ndim - 1))
+
+
+def front_stacked(columns: Sequence[DeviceColumn],
+                  take: jax.Array) -> list[DeviceColumn]:
+    """What :func:`gather_stacked` gives for ``idx = arange(len(take))``,
+    with no gather: the columns' first ``len(take)`` slots where ``take``,
+    zeros and nulls elsewhere (static slices and selects: a launch's
+    floor where a gather costs 10-17 ns an index)."""
+    slots = take.shape[0]
+    return [_keep_rows(DeviceColumn(
+        front_rows(c.data, slots), front_rows(c.validity, slots), c.dtype,
+        None if c.lengths is None else front_rows(c.lengths, slots)), take)
+        for c in columns]
 
 
 # A compaction moves ``capacity // SMALL_BUCKET_DIVISOR`` slots instead

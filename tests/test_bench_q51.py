@@ -90,6 +90,10 @@ def test_q51_record_follows_the_plan(session, generated):
     # the store side is the build: its rows no web row matched
     assert c["join.full.unmatched_rows"] == len(y) - web
     assert c["join.keys.packed"] == 1 and "join.probe.sorted" not in c
+    # (item, day) is unique on both sides: every web row comes out of the
+    # full join's stream phase once, so each of its batches keeps its own
+    # columns in place (PR 43; the joins to date_dim drop rows and expand)
+    assert c["join.gather.aligned"] == 1
 
 
 def _write(path, web, store):

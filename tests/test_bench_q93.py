@@ -115,6 +115,10 @@ def test_q93_record_follows_the_plan(session, data):
     # the returned sales
     left_out = c["join.probe.rows_out"] - c["agg.update.rows"]
     assert n_ss <= left_out <= n_ss + 10
+    # (item, ticket) is unique: every sale comes out once and the left
+    # join's batch keeps its own columns in place (PR 43); the
+    # semi-join's batch drops rows and takes the expanding plan
+    assert left_out == n_ss and c["join.gather.aligned"] == 1
     assert 0 < c["agg.update.rows"] < len(sr) / 20
     assert c["limit.rows_out"] == 100
     # one fetch a build, one a flush of each join

@@ -152,6 +152,45 @@ def test_join_build_and_probe_compiles_for_v5e(one_chip):
     _compile(join_step, _shapes(lb, one_chip), _shapes(rb, one_chip))
 
 
+@pytest.mark.parametrize("out_cap", [CAP, CAP >> 1])
+def test_aligned_join_gather_compiles_for_v5e(one_chip, out_cap):
+    """``join_gather``'s two plans (exec/joins._jit_gather's body) over a
+    stream batch with an s64, an f64 and a string and such a build: the
+    aligned plan holds no scatter, no running maximum's scan over the
+    slots and only the build's gathers -- ``perm[start]`` and one a dtype
+    of the build's leaves -- where the expanding plan holds the stream's
+    as well."""
+    from spark_rapids_tpu.ops.join import (build_prepare_fast,
+                                           gather_join_output,
+                                           join_indices_from_probe,
+                                           probe_fast)
+    lb, rb = _keyed_batch(900, CAP, 1), _keyed_batch(300, CAP >> 2, 2)
+    schema = T.Schema(list(lb.schema) + list(rb.schema))
+    prep = build_prepare_fast(rb, 0)
+    probe, _total = probe_fast(lb, 0, *prep, "left")
+
+    def gathers(aligned):
+        def gather(left, right, probe):
+            plan = join_indices_from_probe(
+                left.capacity, probe, "left", out_cap, stacked=True,
+                aligned=aligned)
+            return gather_join_output(left, right, *plan, schema, True,
+                                      stacked=True)
+        hlo = _compile(gather, *(_shapes(x, one_chip)
+                                 for x in (lb, rb, probe))).as_text()
+        big = re.compile(rf"\[{out_cap}[\],]")
+        found = re.findall(r"= (.+?) (gather|scatter)\(", hlo)
+        return [op for rtype, op in found if big.search(rtype)]
+    expanding, aligned = gathers(False), gathers(True)
+    assert "scatter" in expanding and "scatter" not in aligned
+    # perm[start] and the build's stacks: flags, string lengths, bytes,
+    # and two 32-bit halves each for the s64 and the f64 (64-bit words
+    # are emulated); the expanding plan moves the stream's seven as well,
+    # behind a gather of per-row numbers
+    assert 1 <= aligned.count("gather") <= 8
+    assert expanding.count("gather") >= aligned.count("gather") + 7
+
+
 @pytest.mark.parametrize("table", [32, 1 << 19])
 def test_direct_probe_compiles_for_v5e(one_chip, table):
     """The probe by address at q6's sizes: a 2^20-row stream batch against

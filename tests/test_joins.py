@@ -313,16 +313,17 @@ def test_direct_and_search_probe_agree(case, jt, monkeypatch):
         return real_table(prep, size)
 
     def checked_direct(lb, build, lkey, join_type):
-        (start, cnt, perm, out_cnt), total = real_direct(
+        # ``counts``: [total, aligned] (ops/join.probe_counts)
+        (start, cnt, perm, out_cnt), counts = real_direct(
             lb, build, lkey, join_type)
-        (s2, c2, p2, o2), t2 = J._jit_probe_fast(
+        (s2, c2, p2, o2), counts2 = J._jit_probe_fast(
             lb, seen["prep"], lkey, join_type)
         hit = np.asarray(c2) > 0   # a run's start means nothing at cnt 0
         assert np.array_equal(cnt, c2) and np.array_equal(out_cnt, o2)
         assert np.array_equal(np.asarray(start)[hit], np.asarray(s2)[hit])
-        assert np.array_equal(perm, p2) and int(total) == int(t2)
-        probes.append(int(total))
-        return (start, cnt, perm, out_cnt), total
+        assert np.array_equal(perm, p2) and np.array_equal(counts, counts2)
+        probes.append(int(counts[0]))
+        return (start, cnt, perm, out_cnt), counts
     monkeypatch.setattr(J, "_jit_build_table", spy_table)
     monkeypatch.setattr(J, "_jit_probe_direct", checked_direct)
 
@@ -385,6 +386,190 @@ def test_the_two_gather_plans_agree_leaf_for_leaf(rng, jt, room):
     assert len(a) == len(b)
     for x, y in zip(a, b):
         assert x.dtype == y.dtype and np.array_equal(x, y, equal_nan=True)
+
+
+# ------------------------------------------------------------------
+# The aligned gather plan (ops/join.join_indices_from_probe, PR 43): a
+# stream batch whose every live row comes out exactly once keeps its own
+# columns in their slots and gathers only the build's.  The executor
+# picks it batch by batch from the flag each probe returns beside its
+# total; the batch it hands on is the expanding plan's, array for array.
+
+#: probe kind -> (key fields, key tuple of build row ``i``, the counter
+#: that says the kind ran)
+_ALIGNED_PROBES = {
+    "direct": ([T.IntegerType()], lambda i: (100 + 2 * i,),
+               "join.probe.direct"),
+    "search": ([T.LongType()], lambda i: (7 + 1_000_003 * i,),
+               "join.probe.search"),
+    "packed": ([T.IntegerType(), T.LongType()],
+               lambda i: (i % 10, 5_000_000_000 + i // 10),
+               "join.keys.packed"),
+    "sort": ([T.StringType()], lambda i: (f"k{i}",), "join.probe.sorted"),
+}
+_N_BUILD, _BATCH = 150, 64
+
+
+def _aligned_plan(probe, jt, fill, stream_ids, build_ids=None):
+    """Two stream batches of 64 slots (``fill`` "sparse": a filter keeps
+    the first 20 rows of each, so the gather's capacity is under the
+    batch's) joined to one build batch; ``stream_ids`` / ``build_ids``
+    name build rows (None: a NULL key; >= ``_N_BUILD``: in no build)."""
+    from spark_rapids_tpu.exec.basic import FilterExec
+    ktypes, key_of, _ = _ALIGNED_PROBES[probe]
+    build_ids = list(range(_N_BUILD)) if build_ids is None else build_ids
+
+    def side(p, ids, extra):
+        keys = [key_of(i) if i is not None else (None,) * len(ktypes)
+                for i in ids]
+        fields = [T.StructField(f"{p}k{j}", t, True)
+                  for j, t in enumerate(ktypes)]
+        data = {f.name: [k[j] for k in keys] for j, f in enumerate(fields)}
+        for name, (t, values) in extra.items():
+            fields.append(T.StructField(p + name, t, True))
+            data[p + name] = values
+        return data, T.Schema(fields), [col(f"{p}k{j}")
+                                        for j in range(len(ktypes))]
+    n = len(stream_ids)
+    ldata, lschema, lkeys = side("l", stream_ids, {
+        "v": (T.LongType(), [None if i % 9 == 4 else i * 7 - 300
+                             for i in range(n)]),
+        "s": (T.StringType(), [None if i % 5 == 2 else f"s{i % 13}" * 2
+                               for i in range(n)]),
+        "pos": (T.IntegerType(), [i % _BATCH for i in range(n)])})
+    rdata, rschema, rkeys = side("r", build_ids, {
+        "v": (T.DoubleType(), [None if i % 6 == 1 else i / 4
+                               for i in range(len(build_ids))]),
+        "s": (T.StringType(), [None if i % 7 == 3 else f"reason {i}"
+                               for i in range(len(build_ids))])})
+    left = LocalScanExec.from_pydict(ldata, lschema, rows_per_batch=_BATCH)
+    if fill == "sparse":
+        left = FilterExec(col("lpos") < lit(20), left)
+    right = LocalScanExec.from_pydict(rdata, rschema)
+    return JoinExec(left, right, lkeys, rkeys, jt)
+
+
+def _run_checking_gathers(plan, monkeypatch):
+    """Collect ``plan`` on the device with every aligned ``join_gather``
+    launch checked against the expanding plan of the same arguments.
+    Returns (rows, [(aligned, cl, out_cap) a launch], counters moved)."""
+    import jax
+    from spark_rapids_tpu.exec import joins as J
+    from spark_rapids_tpu.obs.registry import get_registry
+    real, launches = J._jit_gather, []
+
+    def checked(lb, rb, probe_arrays, cl, join_type, out_cap, *rest,
+                aligned=False, **kw):
+        run = lambda a: real(lb, rb, probe_arrays, cl, join_type, out_cap,
+                             *rest, aligned=a, **kw)
+        out = run(aligned)
+        launches.append((aligned, cl, out_cap))
+        if aligned:
+            a, b = (jax.tree_util.tree_leaves(o) for o in (out, run(False)))
+            assert len(a) == len(b)
+            for x, y in zip(a, b):
+                assert x.dtype == y.dtype and x.shape == y.shape
+                assert np.array_equal(x, y, equal_nan=True)
+        return out
+    monkeypatch.setattr(J, "_jit_gather", checked)
+    before = get_registry().counters()
+    rows = collect_device(plan)
+    return rows, launches, get_registry().counters_since(before)
+
+
+@pytest.mark.parametrize("fill", ["full", "sparse"])
+@pytest.mark.parametrize("probe", list(_ALIGNED_PROBES))
+@pytest.mark.parametrize("jt", ["left", "inner", "full", "semi"])
+def test_aligned_gather_is_the_expanding_one_array_for_array(
+        jt, probe, fill, monkeypatch):
+    rng = np.random.default_rng(43)
+    ids = [int(x) for x in rng.integers(0, _N_BUILD, 2 * _BATCH)]
+    if jt in ("left", "full"):      # NULL keys and keys in no build
+        ids = [None if i % 10 == 3 else _N_BUILD + i if i % 10 == 7 else x
+               for i, x in enumerate(ids)]
+    plan = _aligned_plan(probe, jt, fill, ids)
+    rows, launches, moved = _run_checking_gathers(plan, monkeypatch)
+
+    live = 20 if fill == "sparse" else _BATCH
+    assert launches == [(True, _BATCH, 32 if fill == "sparse" else _BATCH)] * 2
+    assert moved["join.gather.aligned"] == 2
+    assert moved["join.probe.rows_out"] == 2 * live
+    assert moved.get(_ALIGNED_PROBES[probe][2]) in (1, 2)
+    # one fetch a build that streams, ONE a flush (the flag rides with the
+    # totals), one for the full join's tail
+    assert moved["span.fetch@JoinExec.count"] == \
+        (probe != "sort") + 1 + (jt == "full")
+    assert len(rows) == 2 * live + (
+        _N_BUILD - len({i for b in (ids[:live], ids[_BATCH:_BATCH + live])
+                        for i in b if i is not None and i < _N_BUILD})
+        if jt == "full" else 0)
+    assert sorted(collect_host(plan), key=repr) == sorted(rows, key=repr)
+
+
+@pytest.mark.parametrize("probe", list(_ALIGNED_PROBES))
+@pytest.mark.parametrize("case,jt,aligned", [
+    ("duplicated_build_key", "left", 0),   # one stream row a batch expands
+    ("inner_drops_a_row", "inner", 0),     # one stream row a batch unmatched
+    ("one_batch_of_two", "left", 1)])      # the flag is a batch's own
+def test_a_batch_that_expands_or_drops_keeps_the_expanding_plan(
+        case, jt, aligned, probe, monkeypatch):
+    rng = np.random.default_rng(7)
+    ids = [int(x) for x in rng.integers(1, _N_BUILD, 2 * _BATCH)]
+    build_ids = list(range(_N_BUILD))
+    hit = (5,) if case == "one_batch_of_two" else (5, _BATCH + 5)
+    for at in hit:
+        ids[at] = _N_BUILD + 1 if case == "inner_drops_a_row" else 0
+    if case != "inner_drops_a_row":
+        build_ids.append(0)         # build row 0's key, a second time
+    plan = _aligned_plan(probe, jt, "full", ids, build_ids)
+    rows, launches, moved = _run_checking_gathers(plan, monkeypatch)
+    assert moved.get("join.gather.aligned", 0) == aligned
+    assert [a for a, _cl, _cap in launches] == [False, bool(aligned)]
+    dups, drops = (0, 2) if case == "inner_drops_a_row" else (len(hit), 0)
+    assert len(rows) == moved["join.probe.rows_out"] == \
+        2 * _BATCH + dups - drops
+    assert sorted(collect_host(plan), key=repr) == sorted(rows, key=repr)
+
+
+def test_aligned_gather_cleans_what_lies_under_a_null():
+    """A stream column may hold anything under a NULL or past its rows
+    (an expression's result does): the aligned plan zeroes it as the
+    expanding plan's gather does, and pads where the output's capacity
+    is over the batch's."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.batch import ColumnBatch
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    from spark_rapids_tpu.host.batch import HostBatch
+    from spark_rapids_tpu.ops.join import (gather_join_output,
+                                           join_indices_from_probe,
+                                           join_probe, probe_counts)
+    clean = HostBatch.from_pydict({
+        "lk": [3, None, 5, 1, 9, 3], "lv": [10, 11, None, 13, 14, 15],
+        "ls": ["a", None, "ccc", "dd", None, "f"]}, L_SCHEMA).to_device()
+    dirty = ColumnBatch([
+        DeviceColumn(jnp.where(c.validity[(...,) + (None,) * (c.data.ndim - 1)],
+                               c.data, 77).astype(c.data.dtype),
+                     c.validity, c.dtype,
+                     None if c.lengths is None
+                     else jnp.where(c.validity, c.lengths, 1))
+        for c in clean.columns], clean.num_rows, clean.schema)
+    rb = HostBatch.from_pydict({"rk": [1, 3, 4, 5], "rv": [.5, None, 2., 3.]},
+                               R_SCHEMA).to_device()
+    probe, total = join_probe(dirty, rb, (0,), (0,), "left")
+    assert list(np.asarray(probe_counts(probe[3], dirty.row_mask(), total))) \
+        == [6, 1]
+    for out_cap in (dirty.capacity, 4 * dirty.capacity):
+        outs = [gather_join_output(
+            dirty, rb, *join_indices_from_probe(
+                dirty.capacity, probe, "left", out_cap, stacked=True,
+                aligned=aligned), None, True, stacked=True)
+            for aligned in (False, True)]
+        a, b = (jax.tree_util.tree_leaves(o) for o in outs)
+        assert len(a) == len(b)
+        for x, y in zip(a, b):
+            assert x.dtype == y.dtype and np.array_equal(x, y, equal_nan=True)
+        assert not np.asarray(outs[1].columns[0].data)[1]   # the 77 is gone
 
 
 def test_direct_table_rule():
