@@ -59,10 +59,42 @@ def _probed(lb, probe_arrays, total):
     return probe_arrays, probe_counts(probe_arrays[3], lb.row_mask(), total)
 
 
+def probe_selected(prep, lkeys: tuple) -> tuple:
+    """``(kind, lkey)``: THE probe selection, from what the prepared build
+    side IS (:func:`prepare_fast_build`) and nothing else: ``sorted`` for
+    None (the sort path over all the keys: a string, fractional or
+    boolean key, keys that cannot be packed), ``direct`` for a
+    :class:`DirectBuild` (read by address), ``search`` for sorted keys
+    (merged or stepped through); a :class:`PackedBuild` takes all the key
+    columns, any other prepared build its one.  The one-chip executor
+    picks its probe program by it and a mesh region's join body its probe
+    (exec/mesh_exec.py), so the two cannot drift apart."""
+    if prep is None:
+        return "sorted", lkeys
+    build, packing = _unpacked(prep)
+    kind = "direct" if isinstance(build, DirectBuild) else "search"
+    return kind, (lkeys[0] if packing is None else lkeys)
+
+
+def probe_traced(kind: str, lb, rb, prep, lkey, rkeys, join_type):
+    """The probe ``kind`` names (:func:`probe_selected`), traceable:
+    ``(probe_arrays, total)``.  The three one-chip probe programs are this
+    under their names, each with its kind fixed; a mesh region's join
+    body traces it inside its ``shard_map`` program.  ``rb`` and
+    ``rkeys`` serve the sort path alone, ``prep`` the other two."""
+    if kind == "sorted":
+        return join_probe(lb, rb, lkey, rkeys, join_type)
+    build, packing = _unpacked(prep)
+    if kind == "direct":
+        return probe_direct(lb, lkey, build, join_type, packing)
+    return probe_fast(lb, lkey, *build, join_type, packing)
+
+
 @guarded_jit("join_probe", static_argnames=("lkeys", "rkeys", "join_type"))
 def _jit_probe(lb, rb, lkeys, rkeys, join_type):
     """Heavy rank-path phase (all sorts): compiled once per capacity pair."""
-    return _probed(lb, *join_probe(lb, rb, lkeys, rkeys, join_type))
+    return _probed(lb, *probe_traced("sorted", lb, rb, None, lkeys, rkeys,
+                                     join_type))
 
 
 @guarded_jit("join_build_prep", static_argnames=("rkey",))
@@ -89,14 +121,14 @@ def _unpacked(prep) -> tuple:
 
 @guarded_jit("join_probe_fast", static_argnames=("lkey", "join_type"))
 def _jit_probe_fast(lb, prep, lkey, join_type):
-    build, packing = _unpacked(prep)
-    return _probed(lb, *probe_fast(lb, lkey, *build, join_type, packing))
+    return _probed(lb, *probe_traced("search", lb, None, prep, lkey, None,
+                                     join_type))
 
 
 @guarded_jit("join_probe_direct", static_argnames=("lkey", "join_type"))
 def _jit_probe_direct(lb, prep, lkey, join_type):
-    build, packing = _unpacked(prep)
-    return _probed(lb, *probe_direct(lb, lkey, build, join_type, packing))
+    return _probed(lb, *probe_traced("direct", lb, None, prep, lkey, None,
+                                     join_type))
 
 
 def prepare_fast_build(rb, rkeys: tuple):
@@ -149,9 +181,8 @@ def _jit_gather(lb, rb, probe_arrays, cl, join_type, out_cap, include_right,
     if len(probe_arrays) == 4:
         probe_arrays = probe_arrays + (None,)
     plan = join_indices_from_probe(cl, probe_arrays, join_type, out_cap,
-                                   stacked=True, aligned=aligned)
-    out = gather_join_output(lb, rb, *plan, schema, include_right,
-                             stacked=True)
+                                   aligned=aligned)
+    out = gather_join_output(lb, rb, *plan, schema, include_right)
     if track_matched:
         li, ri, l_take, r_take, total = plan
         return out, matched_build_rows(ri, r_take, rb.capacity)
@@ -387,22 +418,19 @@ class JoinExec(PlanNode):
                 get_registry().inc("join.cross.launches")
             elif jt in ("semi", "anti"):
                 get_registry().inc("join.semi.batches")
-            if prep is None:
-                if jt != "cross":
-                    get_registry().inc("join.probe.sorted")
+            kind, lkey = probe_selected(prep, lkeys)
+            if jt != "cross":
+                get_registry().inc(f"join.probe.{kind}")
+            if kind == "sorted":
                 probe_arrays, counts_dev = _jit_probe(
                     lb2, rb2, lkeys, rkeys, stream_jt)
             else:
-                # a packed build takes all the key columns, any other its one
-                build, packing = _unpacked(prep)
-                lkey = lkeys[0] if packing is None else lkeys
-                direct = isinstance(build, DirectBuild)
-                get_registry().inc(
-                    "join.probe.direct" if direct else "join.probe.search")
-                if not direct and probe_merges(lb2.capacity,
-                                               build[0].shape[0]):
+                sorted_key = _unpacked(prep)[0][0]
+                if kind == "search" and probe_merges(lb2.capacity,
+                                                     sorted_key.shape[0]):
                     get_registry().inc("join.probe.search.merged")
-                run = _jit_probe_direct if direct else _jit_probe_fast
+                run = _jit_probe_direct if kind == "direct" \
+                    else _jit_probe_fast
                 probe_arrays, counts_dev = run(lb2, prep, lkey, stream_jt)
             return lb2, counts_dev, probe_arrays
 

@@ -32,9 +32,12 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.exec.aggregate import HashAggregateExec, _relabel_d
 from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
-from spark_rapids_tpu.exec.joins import JoinExec
+from spark_rapids_tpu.exec.joins import (JoinExec, probe_selected,
+                                         probe_traced)
 from spark_rapids_tpu.expr.core import Expression, bind, eval_device
 from spark_rapids_tpu.ops import kernels as dk
+from spark_rapids_tpu.ops.join import (PackedBuild, gather_join_output,
+                                       join_indices_from_probe)
 from spark_rapids_tpu.ops.segmented import sorted_group_by
 from spark_rapids_tpu.obs.registry import get_registry
 from spark_rapids_tpu.parallel.mesh import (local_view, make_mesh, restack,
@@ -167,8 +170,9 @@ def all_gather_batch(b: ColumnBatch, p: int, axis: str) -> ColumnBatch:
     segment-aware real mask (gathered rows are packed per shard segment,
     not globally — the MeshSortExec gather), then one compaction to
     restore the front-packed num_rows/row_mask contract downstream
-    traced bodies rely on.  This is the replicated mesh join's build
-    broadcast and the global window's input gather."""
+    traced bodies rely on.  This is the global window's input gather
+    (a replicated mesh join's build is prepared outside its program and
+    handed in replicated: MeshJoinExec._region_build)."""
     from spark_rapids_tpu.columnar.column import DeviceColumn
     cap = b.capacity
     counts = jax.lax.all_gather(b.num_rows, axis)  # int32[P]
@@ -736,7 +740,9 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
       to every mesh device (torrent-broadcast analog — small table
       resident per chip); the stream side is placed as per-device
       shards (place_shards, no central gather) and each device probes
-      its own shard.  No collectives at all.
+      its own shard.  No collectives at all.  Absorbed into a region,
+      the same prepared build is an argument of the region program
+      (:meth:`_region_build`), not a collective inside it.
     - **partitioned** (the GpuShuffledHashJoinExec.scala:162 analog):
       BOTH sides hash-exchange on the join keys over the mesh
       (:class:`MeshExchangeExec` — exchange_local all-to-all inside
@@ -759,7 +765,7 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
         self.mesh_size = mesh_size
         # the island path never names the mesh axis (its collectives run
         # through MeshExchangeExec), but the in-region body issues its
-        # own all_gather/all-to-all under the region's axis
+        # own all-to-alls (partitioned mode) under the region's axis
         self.axis_name = "data"
         self.build_threshold_bytes = build_threshold_bytes
         # unbound key exprs in POST-swap orientation (children[0] =
@@ -854,17 +860,47 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
         return self._exchanges
 
     # -- region interior -----------------------------------------------
+    def _region_build(self, ctx: ExecCtx, mesh):
+        """A replicated-mode region's build side: the ctx-cached
+        ``(rb2, rkeys, prep)`` of :meth:`JoinExec._build_device` — prepared
+        ONCE, outside the program, by the code the one-chip executor
+        uses, and read again by the lost-slice fallback's island path —
+        with ``(rb2, prep)`` placed replicated over ``mesh`` (the
+        torrent-broadcast analog: a device-to-device copy per chip, no
+        collective in the program).  Returns ``((rb2, prep), probe)``,
+        ``probe`` the static ``(kind, packed, rkeys)`` the body is built
+        and keyed by."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        rb2, rkeys, prep = self._build_device(ctx)
+        kind, _ = probe_selected(prep, rkeys)
+
+        def rep():
+            return jax.device_put((rb2, prep), NamedSharding(mesh, P()))
+        placed = ctx.cached((id(self), "mesh_region_build", id(mesh)), rep)
+        return placed, (kind, isinstance(prep, PackedBuild), rkeys)
+
     def _region_step(self, mode: str, out_cap: int,
-                     send_capacity: int | None = None):
+                     send_capacity: int | None = None, probe=None):
         """Per-device traceable join body for MeshRegionExec interiors:
-        ``(stream_local, build_local) -> (joined, (total, flags))``.
+        ``(stream_local, build) -> (joined, (total, flags))``.
 
         ``mode`` is the host-side replicated/partitioned pick
-        (_use_partitioned): replicated runs the build-side broadcast as
-        an in-program all_gather; partitioned runs BOTH key exchanges as
-        in-program all-to-alls (reusing the eagerly-built
-        MeshExchangeExec steps, so partition ids are Spark-bit-exact and
-        co-partitioning is guaranteed by construction).
+        (_use_partitioned).  **Replicated:** ``build`` is the replicated
+        ``(rb2, prep)`` of :meth:`_region_build` and ``probe`` its static
+        ``(kind, packed, rkeys)``; the body augments the stream shard and
+        runs the probe ``kind`` names — the one-chip executor's own
+        selection and probes (exec/joins.probe_selected / probe_traced):
+        a table read by address for dense keys, a merge into the sorted
+        keys otherwise, the sort path for keys that cannot be prepared.
+        No collective, no sort of the build in the program.
+        **Partitioned:** ``build`` is this device's raw build shard; BOTH
+        key exchanges run as in-program all-to-alls (reusing the
+        eagerly-built MeshExchangeExec steps, so partition ids are
+        Spark-bit-exact and co-partitioning is guaranteed by
+        construction), and the co-partitioned shards join by the sort
+        path: a build shard that exists only inside the program cannot
+        be prepared outside it.  Either way the output is gathered by the
+        expanding plan of ops/join.py, the one-chip executor's.
 
         ``out_cap`` is the STATIC join output capacity — a host sync of
         the probe total is impossible inside shard_map, so the region
@@ -873,24 +909,25 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
         the guess was short (the output is discarded, never truncated
         silently).  ``flags`` carries the bounded-send-buffer overflow
         bits of the partitioned exchanges (empty when replicated)."""
-        from spark_rapids_tpu.ops.join import (gather_join_output,
-                                               join_indices_from_probe,
-                                               join_probe)
         jt = self.join_type
         n_right_raw = len(self.children[1].output_schema.fields)
 
-        def step(sb: ColumnBatch, bb: ColumnBatch):
+        def step(sb: ColumnBatch, build):
             flags = ()
+            prep = None
             if mode == "partitioned":
                 sb, s_ovf = self._exchanges[0]._local_step(send_capacity)(sb)
-                bb, b_ovf = self._exchanges[1]._local_step(send_capacity)(bb)
+                bb, b_ovf = self._exchanges[1]._local_step(send_capacity)(
+                    build)
                 flags = (s_ovf, b_ovf)
+                rb2, rkeys = self._augment_device(bb, self._rkeys_b)
             else:
-                bb = all_gather_batch(bb, self.mesh_size, self.axis_name)
+                rb2, prep = build
+                rkeys = probe[2]
             lb2, lkeys = self._augment_device(sb, self._lkeys_b)
-            rb2, rkeys = self._augment_device(bb, self._rkeys_b)
-            probe_arrays, total = join_probe(lb2, rb2, list(lkeys),
-                                             list(rkeys), jt)
+            kind, lkey = probe_selected(prep, lkeys)
+            probe_arrays, total = probe_traced(kind, lb2, rb2, prep, lkey,
+                                               rkeys, jt)
             plan = join_indices_from_probe(lb2.capacity, probe_arrays, jt,
                                            out_cap)
             kf = T.Schema(list(lb2.schema.fields)
@@ -911,10 +948,13 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
         return step
 
     def _region_step_key_parts(self, mode: str, out_cap: int,
-                               send_capacity: int | None = None) -> tuple:
+                               send_capacity: int | None = None,
+                               probe=None) -> tuple:
         """Fragment-key material for the in-region join body (the region
-        key composes these per member; mesh part added by the builder)."""
-        parts = ("mesh_join", mode, out_cap, self.join_type, self._swapped,
+        key composes these per member; mesh part added by the builder):
+        a replicated body is keyed by the probe it was built for."""
+        parts = ("mesh_join", mode, probe, out_cap, self.join_type,
+                 self._swapped,
                  tuple(self._lkeys_b), tuple(self._rkeys_b),
                  self.children[0].output_schema,
                  self.children[1].output_schema,

@@ -416,9 +416,11 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
     input); ``terminal`` is a MeshAggregateExec, MeshExchangeExec,
     MeshSortExec, or MeshWindowExec whose child is members[-1].
     Members are elementwise ops (filter / project / fused stage) plus
-    the collective interiors: a :class:`MeshJoinExec` (its build-side
-    broadcast runs as an in-program all_gather in replicated mode, both
-    key exchanges as in-program all-to-alls in partitioned mode) and a
+    the collective interiors: a :class:`MeshJoinExec` (in replicated mode
+    its body probes a build prepared once outside the program and handed
+    in replicated, by the one-chip executor's probe selection and gather
+    plan; in partitioned mode both key exchanges run as in-program
+    all-to-alls) and a
     :class:`MeshWindowExec` (in-program hash exchange or gather+slice),
     so a region can hold scan→filter→join→project→agg as one program
     per mesh shape.  Like FusedStageExec, every member and the terminal
@@ -431,8 +433,10 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
     The region's children are the pipeline leaf plus one build-side
     subtree per absorbed join — those stay real plan edges: they are
     drained on the host side (the replicated/partitioned mode pick
-    needs the materialized size) and their batches are stacked onto the
-    mesh as extra program inputs.
+    needs the materialized size) and handed to the program as extra
+    inputs: a replicated join's prepared build whole on every device
+    (``MeshJoinExec._region_build``), a partitioned join's raw batches
+    stacked onto the mesh.
 
     Execution primes the terminal's per-execution output cache and then
     delegates ``partition_iter`` to the terminal, so its partition
@@ -538,7 +542,7 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
         return tuple(caps)
 
     def _body_key_parts(self, modes: tuple, caps: tuple,
-                        send_capacity: int | None) -> tuple:
+                        send_capacity: int | None, probes: tuple) -> tuple:
         parts = []
         ji = 0
         for kind, seg in self._segs:
@@ -546,15 +550,18 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
                 parts.append(("stage", stage_key_parts(seg)))
             elif kind == "join":
                 parts.append(seg._region_step_key_parts(
-                    modes[ji], caps[ji], send_capacity))
+                    modes[ji], caps[ji], send_capacity, probes[ji]))
                 ji += 1
             else:
                 parts.append(seg._step_key_parts())
         return tuple(parts)
 
     def _program(self, mesh, send_capacity: int | None = None,
-                 modes: tuple = (), caps: tuple = ()):
-        memo = (id(mesh), send_capacity, modes, caps)
+                 modes: tuple = (), caps: tuple = (), probes: tuple = ()):
+        """``probes``: per absorbed join, the static ``(kind, packed,
+        rkeys)`` of its prepared build (MeshJoinExec._region_build) in
+        replicated mode, None in partitioned."""
+        memo = (id(mesh), send_capacity, modes, caps, probes)
         if memo in self._jitted:
             return self._jitted[memo]
         from jax.sharding import PartitionSpec as P
@@ -568,7 +575,7 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
                 steps.append(("stage", stage_body(seg)))
             elif kind == "join":
                 steps.append(("join", seg._region_step(
-                    modes[ji], caps[ji], send_capacity)))
+                    modes[ji], caps[ji], send_capacity, probes[ji])))
                 ji += 1
             else:
                 steps.append(("window", seg._local_step()))
@@ -580,7 +587,8 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
             tstep = self._terminal._local_step()
             tparts = self._terminal._step_key_parts()
         key = cc.fragment_key(
-            "mesh_region", self._body_key_parts(modes, caps, send_capacity),
+            "mesh_region",
+            self._body_key_parts(modes, caps, send_capacity, probes),
             *tparts, tuple(c.output_schema for c in self.children),
             cc.mesh_key_part(mesh, axis))
         n_builds = len(self._joins)
@@ -591,12 +599,26 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
         def build():
             def prog(stacked, *builds):
                 b = local_view(stacked)
-                blocal = [local_view(x) for x in builds]
+                # a partitioned join's build is this device's shard; a
+                # replicated one's is the whole prepared build as it is
+                blocal = [local_view(x) if m == "partitioned" else x
+                          for m, x in zip(modes, builds)]
                 totals, flags = [], []
                 bi = 0
                 for kind, step in steps:
                     if kind == "join":
-                        b, (total, fl) = step(b, blocal[bi])
+                        # named in the ops' metadata, so a compiled module
+                        # or a trace can tell one join's work from the
+                        # next's and from the terminal's
+                        with jax.named_scope(f"join{bi}"):
+                            b, (total, fl) = step(b, blocal[bi])
+                        # one join's work ends before the next's begins:
+                        # its row stacks ([capacity, k] with k small pad
+                        # to 512 bytes a row in HBM) are then dead, and
+                        # the program's temporaries are the widest
+                        # join's, not the sum over the joins (described
+                        # v5e, three joins at 2^20 slots: 4.19 GB -> 2.17)
+                        b, total = jax.lax.optimization_barrier((b, total))
                         totals.append(total)
                         flags.extend(fl)
                         bi += 1
@@ -610,7 +632,8 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
                 aux = tuple(restack(t) for t in totals) \
                     + tuple(restack(f) for f in flags)
                 return restack(out), aux
-            in_specs = (P(axis),) * (1 + n_builds)
+            in_specs = (P(axis),) + tuple(
+                P(axis) if m == "partitioned" else P() for m in modes)
             out_specs = (P(axis), (P(axis),) * n_aux)
             # a region with a join in it is another program to tune
             # than a chain of per-shard steps: the name says which
@@ -622,7 +645,8 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
         self._jitted[memo] = fn
         return fn
 
-    def _launch(self, ctx: ExecCtx, mesh, stacked, builds, leaf_cap: int):
+    def _launch(self, ctx: ExecCtx, mesh, stacked, builds, leaf_cap: int,
+                modes: tuple, probes: tuple):
         """Run the region program, re-running on the two loud
         under-capacity signals (never truncating): a join whose probe
         total exceeded its static output capacity recompiles at the
@@ -634,9 +658,13 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
 
         from spark_rapids_tpu.conf import MESH_SEND_CAPACITY
         send_cap = ctx.conf.get(MESH_SEND_CAPACITY) or None
-        modes = tuple("partitioned" if j._use_partitioned(ctx)
-                      else "replicated" for j in self._joins)
         nj = len(self._joins)
+        for probe in probes:
+            if probe is not None:
+                # which probe this join's body runs against its prepared
+                # build, beside mesh_join_replicated (a retry at a larger
+                # capacity runs the same probe and is not counted again)
+                get_registry().inc(f"mesh_join.probe.{probe[0]}")
         floors = [0] * nj
         result = None
         for _ in range(nj + 2):
@@ -646,7 +674,7 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
             if self._compacts:
                 # the slots the region was handed, over all its devices
                 dk.count_compaction(leaf_cap * self.mesh_size)
-            result, aux = self._program(mesh, send_cap, modes, caps)(
+            result, aux = self._program(mesh, send_cap, modes, caps, probes)(
                 stacked, *builds)
             if not aux or (nj == 0 and send_cap is None):
                 return result
@@ -719,15 +747,23 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
                 stacked = shard_batches(shards, mesh, self.axis_name)
                 if chained is None:
                     _note_a2a_bytes(stacked)
-                builds = []
-                for j in self._joins:
-                    bl = drain_cached(ctx, j.children[1]) or \
-                        [concat_or_empty([], j.children[1].output_schema)]
-                    bshards = place_shards(bl, self.mesh_size)
-                    bstacked = shard_batches(bshards, mesh, self.axis_name)
-                    _note_a2a_bytes(bstacked)
-                    builds.append(bstacked)
-                result = self._launch(ctx, mesh, stacked, builds, leaf_cap)
+                modes = tuple("partitioned" if j._use_partitioned(ctx)
+                              else "replicated" for j in self._joins)
+                builds, probes = [], []
+                for j, mode in zip(self._joins, modes):
+                    if mode == "replicated":
+                        build, probe = j._region_build(ctx, mesh)
+                    else:
+                        bl = drain_cached(ctx, j.children[1]) or \
+                            [concat_or_empty([], j.children[1].output_schema)]
+                        bshards = place_shards(bl, self.mesh_size)
+                        build = shard_batches(bshards, mesh, self.axis_name)
+                        _note_a2a_bytes(build)
+                        probe = None
+                    builds.append(build)
+                    probes.append(probe)
+                result = self._launch(ctx, mesh, stacked, builds, leaf_cap,
+                                      modes, tuple(probes))
                 if self._is_exchange():
                     ctx.cache[tkey] = ("mesh", split_shards(result))
                 else:

@@ -15,14 +15,15 @@ sort-based (SURVEY.md §7 "hard parts"):
 3. **count** (phase 1): per-left-row output counts by join type; total
    is materialized to host ONCE at the batch boundary to pick a static
    pow2 output capacity (XLA static-shape discipline, columnar/batch.py).
-4. **gather** (phase 2): output slot j -> (left row, right row) via
-   cumsum + searchsorted (``stacked``, the one-chip executor's plan: one
-   scatter of the rows' offsets and a running maximum); full-outer
-   appends unmatched right rows by scatter.  Gathers build the output
-   columns (``stacked``: one gather a dtype over stacked leaves).  A
-   stream batch whose every live row comes out exactly once takes the
-   **aligned** plan instead (:func:`probe_counts` says so beside the
-   total): its own columns stay in their slots, only the build's move.
+4. **gather** (phase 2): output slot j -> (left row, right row) by a
+   cumsum of the rows' output counts, one scatter of their offsets and a
+   running maximum (the **expanding** plan, the one-chip executor's and
+   a mesh region's alike); full-outer appends unmatched right rows by
+   scatter.  Gathers build the output columns, one gather a dtype over
+   stacked leaves.  A stream batch whose every live row comes out exactly
+   once takes the **aligned** plan instead (:func:`probe_counts` says so
+   beside the total): its own columns stay in their slots, only the
+   build's move.
 
 Right outer join is the exec layer's job (swap sides, reorder columns,
 exec/joins.py), matching the reference's build-side flip.
@@ -536,8 +537,7 @@ def probe_counts(out_cnt, real_l, total) -> jax.Array:
 
 
 def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
-                            out_cap: int, stacked: bool = False,
-                            aligned: bool = False):
+                            out_cap: int, aligned: bool = False):
     """Phase 2: gather plan into a static ``out_cap`` output from
     precomputed probe arrays (no sorts here).
 
@@ -546,18 +546,16 @@ def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
       l_take/r_take: bool[out_cap] — False means that side is all-null for
       the slot (outer non-matches) or the slot is padding.
 
-    Three plans, the same arrays out of each:
+    Two plans, the same arrays out of each:
 
-    * **expanding, searched** (``stacked=False``): a search of the offsets
-      for each slot's left row, a gather a leaf.  A mesh region's join
-      body keeps it, the plan it was measured with, until a PR measures
-      the four-chip cell with another and deletes this one.
-    * **expanding, stacked** (``stacked=True``; the one-chip executor's
-      since PR 33): the left row of each slot by one scatter and a running
-      maximum, per-row numbers and column leaves moved in stacked gathers:
-      99 ms at 2^20 slots x 9 columns on the chip (PERF.md PR 43; 501.7 ms
-      searched, PR 33).  Any batch may take it: a stream row matched
-      twice, an inner join that drops rows.
+    * **expanding** (the one-chip executor's since PR 33, a mesh region's
+      join body's since PR 44): the left row of each slot by one scatter
+      and a running maximum, per-row numbers and column leaves moved in
+      stacked gathers: 99 ms at 2^20 slots x 9 columns on the chip
+      (PERF.md PR 43; the plan it replaced searched the offsets for each
+      slot and gathered a leaf at a time: 501.7 ms, PR 33).  Any batch
+      may take it: a stream row matched twice, an inner join that drops
+      rows.
     * **aligned** (``aligned=True``, since PR 43): only for a batch whose
       probe said every live stream row comes out exactly once
       (:func:`probe_counts`; the executor picks it batch by batch from
@@ -592,31 +590,22 @@ def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
 
     j = jnp.arange(out_cap, dtype=jnp.int32)
     in_left = j < total_left
-    if stacked:
-        # left row for slot j: the last row with output whose offset is
-        # <= j.  Those offsets rise strictly, so each such row writes its
-        # index at its offset (unique indices: no sort; the others go
-        # past the end, each to an index of its own) and a running
-        # maximum fills the slots between.  A search of the offsets is
-        # log2(cl) dependent gathers a slot.
-        row = jnp.arange(cl, dtype=jnp.int32)
-        at = jnp.where((out_cnt > 0) & (offsets < out_cap), offsets,
-                       out_cap + row)
-        li = lax.cummax(jnp.zeros(out_cap, jnp.int32).at[at].set(
-            row, unique_indices=True, mode="drop"))
-        # one gather of rows for the three per-row numbers a slot needs
-        off, n, first = jnp.stack([offsets, cnt, start], axis=1)[li].T
-        k = j - off
-        matched = in_left & (k < n)
-        pos = jnp.clip(first + k, 0, rsort_perm.shape[0] - 1)
-    else:
-        # left row for slot j: last offset <= j. offsets is non-decreasing.
-        li = (jnp.searchsorted(offsets, j, side="right") - 1).astype(
-            jnp.int32)
-        li = jnp.clip(li, 0, cl - 1)
-        k = j - offsets[li]
-        matched = in_left & (k < cnt[li])
-        pos = jnp.clip(start[li] + k, 0, rsort_perm.shape[0] - 1)
+    # left row for slot j: the last row with output whose offset is
+    # <= j.  Those offsets rise strictly, so each such row writes its
+    # index at its offset (unique indices: no sort; the others go
+    # past the end, each to an index of its own) and a running
+    # maximum fills the slots between.  A search of the offsets is
+    # log2(cl) dependent gathers a slot.
+    row = jnp.arange(cl, dtype=jnp.int32)
+    at = jnp.where((out_cnt > 0) & (offsets < out_cap), offsets,
+                   out_cap + row)
+    li = lax.cummax(jnp.zeros(out_cap, jnp.int32).at[at].set(
+        row, unique_indices=True, mode="drop"))
+    # one gather of rows for the three per-row numbers a slot needs
+    off, n, first = jnp.stack([offsets, cnt, start], axis=1)[li].T
+    k = j - off
+    matched = in_left & (k < n)
+    pos = jnp.clip(first + k, 0, rsort_perm.shape[0] - 1)
     ri = rsort_perm[pos]
     l_take = in_left
     r_take = matched
@@ -640,32 +629,16 @@ def join_indices_from_probe(cl: int, probe_arrays, join_type: str,
 
 def gather_join_output(lbatch: ColumnBatch, rbatch: ColumnBatch,
                        li, ri, l_take, r_take, total,
-                       schema: T.Schema, include_right: bool,
-                       stacked: bool = False) -> ColumnBatch:
-    """Build the output batch from a join_indices plan; ``stacked``: each
-    side's leaves move in one gather of rows a dtype
-    (``ops/kernels.gather_stacked``) instead of a gather a leaf.  With
-    ``li`` None (the aligned plan of :func:`join_indices_from_probe`) the
-    stream's columns do not move at all: their first ``out_cap`` slots,
-    sliced and masked (``ops/kernels.front_stacked``), and only the
-    build's columns are gathered."""
-    def side(columns, idx, take):
-        if idx is None:
-            return front_stacked(columns, take)
-        if stacked:
-            return gather_stacked(columns, idx, take)
-        return [_take_side(c, idx, take) for c in columns]
-    out_cols = side(lbatch.columns, li, l_take)
+                       schema: T.Schema, include_right: bool) -> ColumnBatch:
+    """Build the output batch from a join_indices plan: each side's
+    leaves move in one gather of rows a dtype
+    (``ops/kernels.gather_stacked``).  With ``li`` None (the aligned plan
+    of :func:`join_indices_from_probe`) the stream's columns do not move
+    at all: their first ``out_cap`` slots, sliced and masked
+    (``ops/kernels.front_stacked``), and only the build's columns are
+    gathered."""
+    out_cols = front_stacked(lbatch.columns, l_take) if li is None \
+        else gather_stacked(lbatch.columns, li, l_take)
     if include_right:
-        out_cols += side(rbatch.columns, ri, r_take)
+        out_cols += gather_stacked(rbatch.columns, ri, r_take)
     return ColumnBatch(out_cols, total.astype(jnp.int32), schema)
-
-
-def _take_side(c: DeviceColumn, idx, take) -> DeviceColumn:
-    validity = c.validity[idx] & take
-    if c.is_var_width:
-        data = jnp.where(validity[:, None], c.data[idx], 0)
-        return DeviceColumn(data, validity, c.dtype,
-                            jnp.where(validity, c.lengths[idx], 0))
-    data = jnp.where(validity, c.data[idx], jnp.zeros((), c.data.dtype))
-    return DeviceColumn(data, validity, c.dtype)

@@ -172,10 +172,8 @@ def test_aligned_join_gather_compiles_for_v5e(one_chip, out_cap):
     def gathers(aligned):
         def gather(left, right, probe):
             plan = join_indices_from_probe(
-                left.capacity, probe, "left", out_cap, stacked=True,
-                aligned=aligned)
-            return gather_join_output(left, right, *plan, schema, True,
-                                      stacked=True)
+                left.capacity, probe, "left", out_cap, aligned=aligned)
+            return gather_join_output(left, right, *plan, schema, True)
         hlo = _compile(gather, *(_shapes(x, one_chip)
                                  for x in (lb, rb, probe))).as_text()
         big = re.compile(rf"\[{out_cap}[\],]")
@@ -485,3 +483,90 @@ def test_distributed_groupby_compiles_for_2x2_mesh(topo):
             sharding=NamedSharding(mesh, P("data"))), local)
     compiled = _compile(step.fn, stacked)
     assert "all-to-all" in compiled.as_text()
+
+
+def test_region_join_body_compiles_for_2x2_mesh(topo, monkeypatch):
+    """The region program of a two-join pipeline with dense ``int32`` keys
+    (fact x a month of days x a filtered dimension, then an exchange),
+    lowered against the four described devices with each prepared build
+    replicated: a join's body -- the ops under its ``join<i>`` scope --
+    holds NO sort and NO collective (the build arrives prepared: its
+    all-gather and its sorts left the program), ONE scatter (the
+    expanding plan's offsets) and a handful of gathers (the table read,
+    the per-row numbers, ``perm[pos]``, one a dtype of each side's
+    stacked leaves).  The parent's body at this shape (its module scoped
+    the same way, PR 44): 2 sorts (stream + build ranked together, the
+    build's ranks again), 2 scatters, 20-22 gathers of which 6 sit in
+    ``while`` loops (three ``searchsorted`` of 12 steps each here, 20 at
+    2^20 slots) and the builds' all-gathers; at 2^20 slots and four joins
+    the new bodies hold 0 / 1 / 3-6 each."""
+    from jax.sharding import Mesh
+    from spark_rapids_tpu.exec import compile_cache as cc
+    from spark_rapids_tpu.exec.mesh_region import MeshRegionExec
+    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.session import TpuSession
+    rng = np.random.default_rng(3)
+    n, cap = 3000, CAP >> 2
+    ints = lambda *names: T.Schema(
+        [T.StructField(c, T.IntegerType(), True) for c in names])
+    s = TpuSession({"spark.rapids.tpu.mesh.deviceCount": 4,
+                    "spark.rapids.sql.resultCache.enabled": False})
+    fact = s.from_pydict({
+        "d": rng.integers(2451000, 2451100, n).astype(np.int32),
+        "i": rng.integers(1, 18000, n).astype(np.int32),
+        "c": rng.integers(1, 100000, n).astype(np.int32)},
+        ints("d", "i", "c"), partitions=3)
+    days = s.from_pydict(
+        {"dk": np.arange(2451020, 2451051, dtype=np.int32)}, ints("dk"))
+    items = s.from_pydict({"ik": np.sort(rng.choice(
+        np.arange(1, 18000, dtype=np.int32), 3000, replace=False))},
+        ints("ik"))
+    df = fact.join(days, [("d", "dk")]).join(items, [("i", "ik")]) \
+        .repartition(4, col("c"))
+
+    # one collect on the virtual CPU mesh hands over what the region
+    # launched with; the same body is then lowered for the described chips
+    seen = {}
+    real = MeshRegionExec._launch
+
+    def spy(self, ctx, mesh, stacked, builds, leaf_cap, modes, probes):
+        if self._joins:
+            seen.update(region=self, stacked=stacked, builds=builds,
+                        leaf_cap=leaf_cap, modes=modes, probes=probes)
+        return real(self, ctx, mesh, stacked, builds, leaf_cap, modes,
+                    probes)
+    monkeypatch.setattr(MeshRegionExec, "_launch", spy)
+    assert df.collect()
+    region = seen["region"]
+    assert [p[0] for p in seen["probes"]] == ["direct", "direct"]
+    assert seen["modes"] == ("replicated", "replicated")
+
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+
+    def described(spec, rows=None):
+        def shape(a):
+            dims = a.shape if rows is None or a.ndim == 1 \
+                else a.shape[:1] + (rows,) + a.shape[2:]
+            return jax.ShapeDtypeStruct(
+                dims, a.dtype, sharding=NamedSharding(mesh, spec))
+        return shape
+    stacked = jax.tree.map(described(P("data"), cap), seen["stacked"])
+    builds = [jax.tree.map(described(P()), b) for b in seen["builds"]]
+    # the virtual CPU devices and the described ones share their ids, so
+    # the process-wide cache would hand back the CPU mesh's program
+    cc.reset_cache()
+    try:
+        caps = region._caps(cap, seen["modes"], None)
+        program = region._program(mesh, None, seen["modes"], caps,
+                                  seen["probes"])
+        hlo = _compile(program.fn, stacked, *builds).as_text()
+    finally:
+        cc.reset_cache()
+    assert "all-gather" not in hlo
+    for j in range(2):
+        ops = re.findall(rf"= .+? ([\w-]+)\([^\n]*/join{j}/", hlo)
+        assert ops, "the join's scope is in the ops' metadata"
+        assert "sort" not in ops and "while" not in ops
+        assert not [op for op in ops if op.startswith("all-")]
+        assert ops.count("scatter") == 1
+        assert 1 <= ops.count("gather") <= 8

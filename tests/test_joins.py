@@ -347,47 +347,6 @@ def test_direct_and_search_probe_agree(case, jt, monkeypatch):
     assert sorted(collect_host(plan), key=repr) == sorted(rows, key=repr)
 
 
-@pytest.mark.parametrize("room", ["tight", "padded"])
-@pytest.mark.parametrize("jt", ["inner", "left", "semi", "anti", "full"])
-def test_the_two_gather_plans_agree_leaf_for_leaf(rng, jt, room):
-    """The one-chip executor's plan (a scatter and a running maximum for
-    each slot's left row, stacked gathers) against the one a mesh
-    region's join body keeps (a search of the offsets, a gather a leaf):
-    the same batch, leaf for leaf, padding included."""
-    import jax
-    from spark_rapids_tpu.columnar.batch import round_capacity
-    from spark_rapids_tpu.host.batch import HostBatch
-    from spark_rapids_tpu.ops.join import (gather_join_output,
-                                           join_indices_from_probe,
-                                           join_probe)
-    nl, nr = 70, 50
-    lb = HostBatch.from_pydict({
-        "lk": [None if i % 11 == 0 else int(x)
-               for i, x in enumerate(rng.integers(0, 9, nl))],
-        "lv": [int(x) for x in rng.integers(-50, 50, nl)],
-        "ls": [f"s{x}" if x % 4 else None for x in rng.integers(0, 30, nl)],
-    }, L_SCHEMA).to_device()
-    rb = HostBatch.from_pydict({
-        "rk": [None if i % 7 == 0 else int(x)
-               for i, x in enumerate(rng.integers(0, 12, nr))],
-        "rv": [None if i % 5 == 0 else float(i) for i in range(nr)],
-    }, R_SCHEMA).to_device()
-    probe, total = join_probe(lb, rb, (0,), (0,), jt)
-    out_cap = round_capacity(int(total)) * (1 if room == "tight" else 4)
-    include_right = jt not in ("semi", "anti")
-    outs = []
-    for stacked in (False, True):
-        plan = join_indices_from_probe(lb.capacity, probe, jt, out_cap,
-                                       stacked=stacked)
-        outs.append(gather_join_output(lb, rb, *plan, None, include_right,
-                                       stacked=stacked))
-    assert int(outs[0].num_rows) == int(total) > 0
-    a, b = (jax.tree_util.tree_leaves(o) for o in outs)
-    assert len(a) == len(b)
-    for x, y in zip(a, b):
-        assert x.dtype == y.dtype and np.array_equal(x, y, equal_nan=True)
-
-
 # ------------------------------------------------------------------
 # The aligned gather plan (ops/join.join_indices_from_probe, PR 43): a
 # stream batch whose every live row comes out exactly once keeps its own
@@ -562,8 +521,8 @@ def test_aligned_gather_cleans_what_lies_under_a_null():
     for out_cap in (dirty.capacity, 4 * dirty.capacity):
         outs = [gather_join_output(
             dirty, rb, *join_indices_from_probe(
-                dirty.capacity, probe, "left", out_cap, stacked=True,
-                aligned=aligned), None, True, stacked=True)
+                dirty.capacity, probe, "left", out_cap, aligned=aligned),
+            None, True)
             for aligned in (False, True)]
         a, b = (jax.tree_util.tree_leaves(o) for o in outs)
         assert len(a) == len(b)

@@ -550,6 +550,157 @@ def test_join_region_slice_lost_recovers_exact_once(tpch_dir,
 
 
 # ---------------------------------------------------------------------------
+# a region's join probes a prepared build (ISSUE 44): the body is the
+# one-chip executor's probe selection and gather plan, under shard_map
+# ---------------------------------------------------------------------------
+
+MESH4 = {"spark.rapids.tpu.mesh.deviceCount": 4,
+         "spark.rapids.sql.resultCache.enabled": False}
+PROBES = ("direct", "search", "packed", "sorted")
+_JL = T.Schema([T.StructField("a", T.IntegerType(), True),
+                T.StructField("b", T.IntegerType(), True),
+                T.StructField("s", T.StringType(), True),
+                T.StructField("lv", T.LongType(), True)])
+_JR = T.Schema([T.StructField("ra", T.IntegerType(), True),
+                T.StructField("rb", T.IntegerType(), True),
+                T.StructField("rs", T.StringType(), True),
+                T.StructField("rv", T.LongType(), True)])
+
+
+def _join_sides(probe: str, n: int = 330, fan: int = 1):
+    """Stream and build of one case: NULL keys on both sides, stream keys
+    with no match, build keys held twice (``fan`` times more), and keys
+    ``direct_table_size`` calls dense or not.  ``sorted`` joins on the
+    strings, ``packed`` on both integers, the others on ``a``."""
+    rng = np.random.default_rng(7)
+    step = 1_000_003 if probe == "search" else 1   # 40M wide: no table
+    a = rng.integers(0, 40, n)
+    b = rng.integers(0, 6, n)
+    def nullable(x, every):
+        return [None if i % every == 0 else int(v) for i, v in enumerate(x)]
+    left = {"a": nullable(a * step, 13), "b": nullable(b, 17),
+            "s": [None if i % 19 == 0 else f"k{v:02d}"
+                  for i, v in enumerate(a)],
+            "lv": list(range(n))}
+    ra = np.concatenate([np.arange(30)] + [np.array([5, 5, 11])] * fan)
+    rb = np.arange(len(ra)) % 6
+    right = {"ra": nullable(ra * step, 9), "rb": nullable(rb, 8),
+             "rs": [None if i % 7 == 0 else f"k{v:02d}"
+                    for i, v in enumerate(ra)],
+             "rv": [10 * i for i in range(len(ra))]}
+    on = {"sorted": [("s", "rs")],
+          "packed": [("a", "ra"), ("b", "rb")]}.get(probe, [("a", "ra")])
+    return left, right, on
+
+
+def _region_join(conf, probe, jt, **sides):
+    left, right, on = _join_sides(probe, **sides)
+    s = TpuSession(conf)
+    # three stream batches over four devices: one shard is empty
+    return s.from_pydict(left, _JL, partitions=3) \
+        .join(s.from_pydict(right, _JR), on, jt).repartition(4, col("lv"))
+
+
+def _join_region_of(df):
+    regions = [n for n in _walk(_executed_plan(df))
+               if type(n).__name__ == "MeshRegionExec"
+               and "MeshJoinExec" in n.node_desc()]
+    assert len(regions) == 1, _executed_plan(df).node_desc()
+    return regions[0]
+
+
+def _moved_by(df):
+    b0 = get_registry().snapshot()
+    rows = df.collect()
+    return rows, get_registry().delta(b0)["counters"]
+
+
+@pytest.mark.parametrize("probe", PROBES)
+@pytest.mark.parametrize("jt", ["inner", "left", "semi", "anti"])
+def test_region_join_matches_one_chip_join(jt, probe):
+    """A replicated region join on 4 devices hands on the single-device
+    plan's rows, and the counter names the probe its body ran against
+    the build prepared outside the program: by address for dense keys
+    (one key or two packed), searched for sparse ones, the sort path for
+    a string."""
+    df = _region_join(MESH4, probe, jt)
+    _join_region_of(df)
+    rows, moved = _moved_by(df)
+    want = _region_join({}, probe, jt).collect()
+    assert len(rows) == len(want) > 0
+    assert sorted(rows, key=repr) == sorted(want, key=repr)
+    ran = {"packed": "direct"}.get(probe, probe)
+    assert {k: v for k, v in moved.items()
+            if k.startswith("mesh_join.probe.")} == \
+        {f"mesh_join.probe.{ran}": 1}, moved
+    assert moved.get("mesh_join_replicated") == 1
+    assert "mesh_join_partitioned" not in moved
+    assert moved.get("join.keys.packed", 0) == (probe == "packed")
+    # prepared once, by the one-chip executor's code; nothing fell back
+    assert moved.get("program.join_build_prep.launches", 0) == \
+        (probe != "sorted")
+    assert moved.get("mesh_gather_fallbacks", 0) == 0
+
+
+@pytest.mark.parametrize("jt", ["inner", "left", "semi", "anti"])
+def test_partitioned_region_join_matches_one_chip_join(jt):
+    """``buildThresholdBytes=0``: both sides exchange inside the program
+    and the co-partitioned shards join by the sort path; no build is
+    prepared outside it, so no probe kind is counted."""
+    conf = {**MESH4, "spark.rapids.tpu.mesh.join.buildThresholdBytes": 0}
+    df = _region_join(conf, "direct", jt)
+    _join_region_of(df)
+    rows, moved = _moved_by(df)
+    want = _region_join({}, "direct", jt).collect()
+    assert sorted(rows, key=repr) == sorted(want, key=repr) and rows
+    assert moved.get("mesh_join_partitioned") == 1
+    assert not [k for k in moved if k.startswith("mesh_join.probe.")]
+    assert "program.join_build_prep.launches" not in moved
+
+
+def test_region_join_past_its_capacity_retries_once_and_truncates_nothing():
+    """Every matched stream row comes out many times over, so a shard's
+    total passes the static ``out_cap`` the region guessed: ONE retry at
+    the measured capacity, every row there, the probe counted once."""
+    df = _region_join(MESH4, "direct", "inner", fan=60)
+    rows, moved = _moved_by(df)
+    want = _region_join({}, "direct", "inner", fan=60).collect()
+    assert len(rows) == len(want) > 4 * 128     # more than the shards hold
+    assert sorted(rows, key=repr) == sorted(want, key=repr)
+    assert moved.get("mesh_join_capacity_retries") == 1, moved
+    assert moved.get("program.mesh_region_join.launches") == 2
+    assert moved.get("mesh_join.probe.direct") == 1
+
+
+def test_tpcds_q6_region_probes_four_prepared_builds(tmp_path):
+    """The benchmark's q6 (benchmark/queries/tpcds_q6.py) at SF0.1 (at
+    SF0.01 no state reaches its ten customers) on 4 virtual devices: ONE
+    region holds its four surrogate-key joins, each probes a
+    direct-address table made outside the program, none takes the sort
+    path, and the rows are the single-device plan's."""
+    from benchmark.harness.cell import ROOT, load_module
+    q6 = load_module(ROOT, "queries", "tpcds_q6")
+    data_dir = str(tmp_path / "sf01")
+    load_module(ROOT, "datagen", "tpcds").generate(
+        data_dir, 0.1, 2**31 + 44, sorted(q6.TABLES))
+    df = q6.build(TpuSession(MESH4), data_dir)
+    region = _join_region_of(df)
+    assert region.node_desc().count("MeshJoinExec[inner") == 4
+    rows, moved = _moved_by(df)
+    assert moved.get("mesh_join.probe.direct") == 4, moved
+    assert "mesh_join.probe.sorted" not in moved
+    assert "mesh_join.probe.search" not in moved
+    # the fifth is the island join of item to its category averages (on
+    # a string, outside the region: its stream is no fact table)
+    assert moved.get("mesh_join_replicated") == 5
+    assert moved.get("program.mesh_region_join.launches") == 1
+    want = q6.build(TpuSession(
+        {"spark.rapids.sql.resultCache.enabled": False}), data_dir).collect()
+    # states with equal counts come out in either order
+    assert sorted(rows, key=repr) == sorted(want, key=repr) and rows
+
+
+# ---------------------------------------------------------------------------
 # windows under the mesh (MeshWindowExec)
 # ---------------------------------------------------------------------------
 
