@@ -254,15 +254,6 @@ MESH_WINDOW_ENABLED = bool_conf(
     "global-order machinery). Disable to gather window inputs to a "
     "single device (the pre-mesh WindowExec path).")
 
-MESH_REGION_CHAINING = bool_conf(
-    "spark.rapids.tpu.mesh.regions.chain.enabled", True,
-    "Chain consecutive mesh regions: when a region's exchange terminal "
-    "feeds another region's leaf, the producing region's output shards "
-    "stay committed one-per-device (parallel/mesh.split_shards) and the "
-    "downstream region shards them in place — no gather to device 0, no "
-    "host hop, no re-partitioning round trip between regions. Disable "
-    "to route chained regions through the per-partition island path.")
-
 UDF_COMPILER_ENABLED = bool_conf(
     "spark.rapids.sql.udfCompiler.enabled", False,
     "Compile Python UDF bytecode to native expressions when possible. "

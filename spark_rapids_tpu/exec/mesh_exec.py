@@ -22,7 +22,6 @@ aggregation-buffer contract the reference's partial/final modes use
 from __future__ import annotations
 
 import time
-from functools import partial
 from typing import Iterator, Sequence
 
 import jax
@@ -31,7 +30,10 @@ import jax.numpy as jnp
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
 from spark_rapids_tpu.exec.aggregate import HashAggregateExec, _relabel_d
-from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
+from spark_rapids_tpu.exec.core import (ExecCtx, PlanNode, drain_partitions,
+                                        fetch_to_host)
+from spark_rapids_tpu.exec.fused import (filters_merged, has_filter,
+                                         stage_body, stage_key_parts)
 from spark_rapids_tpu.exec.joins import (JoinExec, probe_selected,
                                          probe_traced)
 from spark_rapids_tpu.expr.core import Expression, bind, eval_device
@@ -48,9 +50,10 @@ from spark_rapids_tpu.parallel.mesh_shuffle import (canonicalize,
                                                     exchange_local_checked,
                                                     partition_ids_for_keys)
 
-__all__ = ["DeviceSliceLost", "MeshSendOverflow", "MeshAggregateExec",
-           "MeshExchangeExec", "MeshJoinExec", "all_gather_batch",
-           "mesh_for"]
+__all__ = ["DeviceSliceLost", "MeshSendOverflow", "MeshLauncher",
+           "MeshAggregateExec", "MeshExchangeExec", "MeshJoinExec",
+           "all_gather_batch", "all_gather_rows", "mesh_for", "order_slice",
+           "with_key_columns"]
 
 
 def _committed_device(b: ColumnBatch):
@@ -164,15 +167,13 @@ def _note_slice_recovery(ctx: ExecCtx, wall_s: float) -> None:
     m["recovery_wall_s"] = m.get("recovery_wall_s", 0.0) + wall_s
 
 
-def all_gather_batch(b: ColumnBatch, p: int, axis: str) -> ColumnBatch:
-    """In-program replication: every device ends up with ALL rows of the
-    sharded batch, front-packed.  Per-column tiled ``all_gather`` plus a
-    segment-aware real mask (gathered rows are packed per shard segment,
-    not globally — the MeshSortExec gather), then one compaction to
-    restore the front-packed num_rows/row_mask contract downstream
-    traced bodies rely on.  This is the global window's input gather
-    (a replicated mesh join's build is prepared outside its program and
-    handed in replicated: MeshJoinExec._region_build)."""
+def all_gather_rows(b: ColumnBatch, p: int, axis: str):
+    """In-program replication of a sharded batch's storage: per-column
+    tiled ``all_gather`` of every shard's ``cap`` slots, the shards' row
+    counts, and the segment-aware real mask (gathered rows are packed per
+    shard segment, not globally).  Returns ``(cols, counts, real)`` over
+    ``p * cap`` slots — the MeshSortExec gather, which orders by the mask
+    instead of compacting."""
     from spark_rapids_tpu.columnar.column import DeviceColumn
     cap = b.capacity
     counts = jax.lax.all_gather(b.num_rows, axis)  # int32[P]
@@ -185,13 +186,46 @@ def all_gather_batch(b: ColumnBatch, p: int, axis: str) -> ColumnBatch:
             cols.append(DeviceColumn(data, val, c.dtype, ln))
         else:
             cols.append(DeviceColumn(data, val, c.dtype))
-    gcap = p * cap
-    idx = jnp.arange(gcap, dtype=jnp.int32)
-    real = (idx % cap) < counts[idx // cap]
+    idx = jnp.arange(p * cap, dtype=jnp.int32)
+    return cols, counts, (idx % cap) < counts[idx // cap]
+
+
+def all_gather_batch(b: ColumnBatch, p: int, axis: str) -> ColumnBatch:
+    """Every device ends up with ALL rows of the sharded batch,
+    front-packed: :func:`all_gather_rows`, then one compaction to
+    restore the front-packed num_rows/row_mask contract downstream
+    traced bodies rely on.  This is the global window's input gather
+    (a replicated mesh join's build is prepared outside its program and
+    handed in replicated: MeshJoinExec._region_build)."""
+    cols, _, real = all_gather_rows(b, p, axis)
     # num_rows = gcap so compact's row_mask covers every gathered slot;
     # compact itself front-packs and sets the true count
-    gb = ColumnBatch(cols, jnp.asarray(gcap, jnp.int32), b.schema)
+    gb = ColumnBatch(cols, jnp.asarray(p * b.capacity, jnp.int32), b.schema)
     return dk.compact(gb, real)
+
+
+def with_key_columns(b: ColumnBatch, bound: Sequence[Expression]):
+    """``b`` with the evaluated ``bound`` key expressions appended, and
+    their column indices: what a hash exchange computes its partition
+    ids over (the keys never travel — the raw batch is what is sent)."""
+    cols = list(b.columns)
+    fields = list(b.schema.fields)
+    for i, k in enumerate(bound):
+        cols.append(eval_device(k, b))
+        fields.append(T.StructField(f"_pk{i}", k.dtype, True))
+    return (ColumnBatch(cols, b.num_rows, T.Schema(fields)),
+            list(range(b.num_columns, len(cols))))
+
+
+def order_slice(total, p: int, axis: str):
+    """``(start, count)`` of this device's contiguous slice of a total
+    order of ``total`` rows held whole on each of ``p`` devices: device i
+    keeps rows [i*base + min(i, rem), ...), so partition order IS global
+    order."""
+    i = jax.lax.axis_index(axis)
+    base = total // p
+    rem = total % p
+    return i * base + jnp.minimum(i, rem), base + (i < rem).astype(jnp.int32)
 
 
 def _mesh_devices(size: int):
@@ -303,7 +337,6 @@ def drain_cached(ctx: ExecCtx, node: PlanNode) -> list:
     size probe, an exchange, and a build can share one materialization
     (review finding: the partitioned-join size check must not drain the
     build side twice)."""
-    from spark_rapids_tpu.exec.core import drain_partitions
     return ctx.cached(("drained", id(node), ctx.backend),
                       lambda: list(drain_partitions(ctx, node)))
 
@@ -362,7 +395,287 @@ def _pad_widths(b: ColumnBatch, widths) -> ColumnBatch:
     return ColumnBatch(cols, b.num_rows, b.schema) if changed else b
 
 
-class MeshAggregateExec(_MeshOutputMixin, PlanNode):
+class MeshLauncher:
+    """The one definition of *build, launch, retry, recover* for a mesh
+    program: a ``shard_map`` executable whose per-device body is a
+    region's segments (elementwise stages, absorbed joins and windows)
+    followed by its terminal's ``_local_step`` — or, for a bare
+    terminal, that step alone (zero segments).
+
+    ``terminal`` supplies what is its own: ``_local_step``,
+    ``_step_key_parts``, ``_fallback_outputs`` and the two names a bare
+    launch goes by (``_program_name``, ``_fault_op``).  ``region`` is the
+    MeshRegionExec holding the launcher, or None for a bare terminal: the
+    plan node whose children feed the program, the fault op
+    (``meshregion``), the program name (``mesh_region_join`` with joins,
+    ``mesh_region_chain`` without), the fetch span and the fragment key
+    follow it.  ``segs`` is the region's ``(kind, op)`` segmentation.
+    ``drain`` materializes the leaf: a bare exchange passes
+    ``drain_cached`` (MeshJoinExec._use_partitioned has already drained
+    that subtree for its size probe); everything else drains its
+    partitions and holds nothing for the rest of the query."""
+
+    def __init__(self, terminal: PlanNode, region: PlanNode | None = None,
+                 segs: Sequence[tuple] = (), drain=None):
+        self._terminal = terminal
+        self._node = terminal if region is None else region
+        self._segs = tuple(segs)
+        self._joins = tuple(op for kind, op in self._segs if kind == "join")
+        stages = [seg for kind, seg in self._segs if kind == "stage"]
+        self._merged = sum(filters_merged(seg) for seg in stages)
+        self._compacts = any(has_filter(seg) for seg in stages)
+        self._fault_op = terminal._fault_op if region is None \
+            else "meshregion"
+        self._drain = drain or (
+            lambda ctx, node: list(drain_partitions(ctx, node)))
+        self._is_ex = isinstance(terminal, MeshExchangeExec)
+        self._jitted = {}
+
+    def _caps(self, leaf_cap: int, modes: tuple, send_cap: int | None,
+              floors=None) -> tuple:
+        """Symbolic per-device capacity walk over the segments, yielding
+        the STATIC output capacity of each join (shard_map bodies cannot
+        sync the probe total).  Elementwise stages and the global-window
+        slice preserve capacity; a partitioned exchange's worst case is
+        P*C; a join's output capacity starts as its post-exchange stream
+        capacity and is floored by the measured total on a retry."""
+        p = self._terminal.mesh_size
+        cap = leaf_cap
+        caps = []
+        ji = 0
+        for kind, seg in self._segs:
+            if kind == "join":
+                if modes[ji] == "partitioned":
+                    c = cap if send_cap is None else min(send_cap, cap)
+                    cap = p * c
+                guess = round_capacity(max(cap, 8))
+                if floors is not None and floors[ji]:
+                    guess = max(guess, floors[ji])
+                caps.append(guess)
+                cap = guess
+                ji += 1
+            elif kind == "window" and seg._part_b:
+                cap = p * cap
+        return tuple(caps)
+
+    def _program(self, mesh, send_capacity: int | None = None,
+                 modes: tuple = (), caps: tuple = (), probes: tuple = ()):
+        """``probes``: per absorbed join, the static ``(kind, packed,
+        rkeys)`` of its prepared build (MeshJoinExec._region_build) in
+        replicated mode, None in partitioned."""
+        memo = (id(mesh), send_capacity, modes, caps, probes)
+        if memo in self._jitted:
+            return self._jitted[memo]
+        from jax.sharding import PartitionSpec as P
+
+        from spark_rapids_tpu.exec import compile_cache as cc
+        axis = self._terminal.axis_name
+        steps, body_parts = [], []
+        ji = 0
+        for kind, seg in self._segs:
+            if kind == "stage":
+                steps.append((kind, stage_body(seg)))
+                body_parts.append(("stage", stage_key_parts(seg)))
+            elif kind == "join":
+                jargs = (modes[ji], caps[ji], send_capacity, probes[ji])
+                steps.append((kind, seg._region_step(*jargs)))
+                body_parts.append(seg._region_step_key_parts(*jargs))
+                ji += 1
+            else:
+                steps.append((kind, seg._local_step()))
+                body_parts.append(seg._step_key_parts())
+        is_ex = self._is_ex
+        # only the exchange's step has a send buffer to bound
+        targs = (send_capacity,) if is_ex else ()
+        tstep = self._terminal._local_step(*targs)
+        tparts = self._terminal._step_key_parts(*targs)
+        if self._node is self._terminal:
+            key = cc.fragment_key(*tparts, cc.mesh_key_part(mesh, axis))
+        else:
+            key = cc.fragment_key(
+                "mesh_region", tuple(body_parts), *tparts,
+                tuple(c.output_schema for c in self._node.children),
+                cc.mesh_key_part(mesh, axis))
+        n_flags = 2 * sum(m == "partitioned" for m in modes) \
+            + (1 if is_ex else 0)
+        n_aux = len(self._joins) + n_flags
+
+        def build():
+            def prog(stacked, *builds):
+                b = local_view(stacked)
+                # a partitioned join's build is this device's shard; a
+                # replicated one's is the whole prepared build as it is
+                blocal = [local_view(x) if m == "partitioned" else x
+                          for m, x in zip(modes, builds)]
+                totals, flags = [], []
+                bi = 0
+                for kind, step in steps:
+                    if kind == "join":
+                        # named in the ops' metadata, so a compiled module
+                        # or a trace can tell one join's work from the
+                        # next's and from the terminal's
+                        with jax.named_scope(f"join{bi}"):
+                            b, (total, fl) = step(b, blocal[bi])
+                        # one join's work ends before the next's begins:
+                        # its row stacks ([capacity, k] with k small pad
+                        # to 512 bytes a row in HBM) are then dead, and
+                        # the program's temporaries are the widest
+                        # join's, not the sum over the joins (described
+                        # v5e, three joins at 2^20 slots: 4.19 GB -> 2.17)
+                        b, total = jax.lax.optimization_barrier((b, total))
+                        totals.append(total)
+                        flags.extend(fl)
+                        bi += 1
+                    else:
+                        b = step(b)
+                if is_ex:
+                    out, ovf = tstep(b)
+                    flags.append(ovf)
+                else:
+                    out = tstep(b)
+                aux = tuple(restack(t) for t in totals) \
+                    + tuple(restack(f) for f in flags)
+                return restack(out), aux
+            in_specs = (P(axis),) + tuple(
+                P(axis) if m == "partitioned" else P() for m in modes)
+            out_specs = (P(axis), (P(axis),) * n_aux)
+            # a region with a join in it is another program to tune
+            # than a chain of per-shard steps: the name says which
+            return cc.instrument(jax.jit(shard_map(
+                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs)),
+                self._terminal._program_name if self._node is self._terminal
+                else "mesh_region_join" if self._joins
+                else "mesh_region_chain")
+
+        fn = cc.get_or_build(key, build)
+        self._jitted[memo] = fn
+        return fn
+
+    def _launch(self, ctx: ExecCtx, mesh, stacked, builds, leaf_cap: int,
+                modes: tuple, probes: tuple):
+        """Run the program, re-running on the two loud under-capacity
+        signals (never truncating): a join whose probe total exceeded
+        its static output capacity recompiles at the rounded-up measured
+        size; an overflowed bounded send buffer falls back to worst-case
+        capacity (the mesh analog of the OOM split-and-retry ladder).
+        All join totals and overflow flags are read back in ONE stacked
+        device fetch per attempt."""
+        import numpy as np
+
+        from spark_rapids_tpu.conf import MESH_SEND_CAPACITY
+        send_cap = ctx.conf.get(MESH_SEND_CAPACITY) or None
+        nj = len(self._joins)
+        for probe in probes:
+            if probe is not None:
+                # which probe this join's body runs against its prepared
+                # build, beside mesh_join_replicated (a retry at a larger
+                # capacity runs the same probe and is not counted again)
+                get_registry().inc(f"mesh_join.probe.{probe[0]}")
+        floors = [0] * nj
+        result = None
+        for _ in range(nj + 2):
+            caps = self._caps(leaf_cap, modes, send_cap, floors)
+            if self._merged:
+                get_registry().inc("fused.filters_merged", self._merged)
+            if self._compacts:
+                # the slots the region was handed, over all its devices
+                dk.count_compaction(leaf_cap * self._terminal.mesh_size)
+            result, aux = self._program(mesh, send_cap, modes, caps, probes)(
+                stacked, *builds)
+            if not aux or (nj == 0 and send_cap is None):
+                return result
+            vals = [np.asarray(v) for v in
+                    # enginelint: disable=RL003 (join totals + overflow flags; one stacked sync gates the retry)
+                    fetch_to_host(aux,
+                                  f"fetch@{type(self._node).__name__}")]
+            retry = False
+            for i in range(nj):
+                total = int(vals[i].max())
+                if total > caps[i]:
+                    get_registry().inc("mesh_join_capacity_retries")
+                    floors[i] = max(floors[i],
+                                    round_capacity(max(total, 1)))
+                    retry = True
+            if send_cap is not None and any(v.any() for v in vals[nj:]):
+                get_registry().inc("mesh_send_overflows")
+                send_cap = None
+                retry = True
+            if not retry:
+                return result
+        return result
+
+    def run(self, ctx: ExecCtx, chained=None):
+        """One execution's outputs in the shape the terminal caches them
+        in (``_outputs_cache_key``): the exchange's ``("mesh", shards)``,
+        else one list per device.  ``chained`` are an upstream exchange's
+        committed shards, stacked in place instead of a drained leaf
+        (MeshRegionExec._chained_shards).  A lost slice or an empty input
+        is answered by the terminal's own single-device fallback, which
+        recomputes through the intact member chain — a join member's
+        island path re-materializes BOTH its sides, so a whole region's
+        lineage (build subtrees included) replays."""
+        t = self._terminal
+        p, axis = t.mesh_size, t.axis_name
+        mesh = mesh_for(ctx, p, axis)
+        batches = chained if chained is not None \
+            else self._drain(ctx, self._node.children[0])
+        t0 = None
+        if batches:
+            try:
+                _check_slice_fault(ctx, self._fault_op, mesh)
+                shards = chained if chained is not None \
+                    else place_shards(batches, p)
+                stacked = shard_batches(shards, mesh, axis)
+                if chained is None:
+                    _note_a2a_bytes(stacked)
+                modes = tuple("partitioned" if j._use_partitioned(ctx)
+                              else "replicated" for j in self._joins)
+                builds, probes = [], []
+                for j, mode in zip(self._joins, modes):
+                    if mode == "replicated":
+                        build, probe = j._region_build(ctx, mesh)
+                    else:
+                        bl = drain_cached(ctx, j.children[1]) or \
+                            [concat_or_empty([], j.children[1].output_schema)]
+                        build = shard_batches(place_shards(bl, p), mesh, axis)
+                        _note_a2a_bytes(build)
+                        probe = None
+                    builds.append(build)
+                    probes.append(probe)
+                out = split_shards(self._launch(
+                    ctx, mesh, stacked, builds, shards[0].capacity, modes,
+                    tuple(probes)))
+                return ("mesh", out) if self._is_ex else [[b] for b in out]
+            except Exception as err:
+                _reraise_unless_slice_lost(err)
+                t0 = time.perf_counter()
+        out = t._fallback_outputs(ctx)
+        if t0 is not None:
+            _note_slice_recovery(ctx, time.perf_counter() - t0)
+        return out
+
+
+class _MeshTerminal(_MeshOutputMixin):
+    """A collective operator a :class:`MeshLauncher` can end a program
+    with.  Its own: ``_local_step``, ``_step_key_parts``,
+    ``_fallback_outputs``, ``_outputs_cache_key`` and two names — the
+    program a bare launch is counted under and the op its injected slice
+    loss is checked under."""
+
+    _program_name: str
+    _fault_op: str
+
+    def _outputs(self, ctx: ExecCtx):
+        """Per-execution outputs: primed by the region that absorbed this
+        terminal, else launched bare (on the host backend only the
+        exchange serves partitions from here: its in-process path)."""
+        return ctx.cached(
+            self._outputs_cache_key(ctx),
+            lambda: self._launcher.run(ctx) if ctx.is_device
+            else self._fallback_outputs(ctx))
+
+
+class MeshAggregateExec(_MeshTerminal, PlanNode):
     """Grouped aggregation as ONE distributed program over the mesh.
 
     Device plan per shard: pre-project -> partial sorted group-by ->
@@ -370,6 +683,9 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
     final projection.  Falls back to a complete-mode
     :class:`HashAggregateExec` on the host backend or on empty input.
     """
+
+    _program_name = "mesh_aggregate"
+    _fault_op = "meshagg"
 
     def __init__(self, group_exprs: Sequence[Expression],
                  result_exprs: Sequence[Expression], child: PlanNode,
@@ -387,7 +703,7 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
             [T.StructField(f.name, f.data_type, True)
              for f in HashAggregateExec.final_from_partial(
                  self._layout, child).output_schema])
-        self._jitted = {}
+        self._launcher = MeshLauncher(self)
 
     @property
     def output_schema(self) -> T.Schema:
@@ -453,35 +769,8 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
                 tuple(L._final_exprs), self._output_schema,
                 len(L._group_bound), self.mesh_size)
 
-    def _program(self, mesh):
-        memo = id(mesh)
-        if memo in self._jitted:
-            return self._jitted[memo]
-        from jax.sharding import PartitionSpec as P
-
-        from spark_rapids_tpu.exec import compile_cache as cc
-        axis = self.axis_name
-        step = self._local_step()
-        key = cc.fragment_key(*self._step_key_parts(),
-                              cc.mesh_key_part(mesh, axis))
-
-        def build():
-            def prog(stacked: ColumnBatch) -> ColumnBatch:
-                return restack(step(local_view(stacked)))
-            return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))),
-                "mesh_aggregate")
-
-        fn = cc.get_or_build(key, build)
-        self._jitted[memo] = fn
-        return fn
-
     def _outputs_cache_key(self, ctx: ExecCtx) -> tuple:
         return ("meshagg", id(self), ctx.backend)
-
-    def _outputs(self, ctx: ExecCtx):
-        return ctx.cached(self._outputs_cache_key(ctx),
-                          lambda: self._compute_outputs(ctx))
 
     def _fallback_outputs(self, ctx: ExecCtx):
         """Single-device recompute: the complete-mode aggregation is the
@@ -490,27 +779,6 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
         existed or the child produced nothing."""
         out = [list(self._complete_exec().partition_iter(ctx, 0))]
         out += [[] for _ in range(self.mesh_size - 1)]
-        return out
-
-    def _compute_outputs(self, ctx: ExecCtx):
-        from spark_rapids_tpu.exec.core import drain_partitions
-        batches = list(drain_partitions(ctx, self.children[0]))
-        mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
-        t0 = None
-        if batches:
-            try:
-                _check_slice_fault(ctx, "meshagg", mesh)
-                shards = place_shards(batches, self.mesh_size)
-                stacked = shard_batches(shards, mesh, self.axis_name)
-                _note_a2a_bytes(stacked)
-                result = self._program(mesh)(stacked)
-                return [[b] for b in split_shards(result)]
-            except Exception as err:
-                _reraise_unless_slice_lost(err)
-                t0 = time.perf_counter()
-        out = self._fallback_outputs(ctx)
-        if t0 is not None:
-            _note_slice_recovery(ctx, time.perf_counter() - t0)
         return out
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
@@ -525,7 +793,7 @@ class MeshAggregateExec(_MeshOutputMixin, PlanNode):
                 f"out={self._output_schema.names}]")
 
 
-class MeshExchangeExec(_MeshOutputMixin, PlanNode):
+class MeshExchangeExec(_MeshTerminal, PlanNode):
     """Hash repartition as an all-to-all collective over the mesh.
 
     Device path: pack child output into per-device shards, then ONE
@@ -535,6 +803,9 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
     sides are the same collective).  Host backend delegates to the
     in-process ShuffleExchangeExec.
     """
+
+    _program_name = "mesh_exchange"
+    _fault_op = "meshex"
 
     def __init__(self, keys: Sequence[Expression], child: PlanNode,
                  mesh_size: int, axis_name: str = "data",
@@ -550,7 +821,10 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
         self._num_parts = num_partitions or mesh_size
         self._keys = list(keys)
         self._bound = [bind(k, child.output_schema) for k in self._keys]
-        self._jitted = {}
+        # drain_cached, not drain_partitions: in partitioned mesh-join
+        # mode _use_partitioned already drained this subtree for its size
+        # probe — share that materialization instead of executing twice
+        self._launcher = MeshLauncher(self, drain=drain_cached)
 
     @property
     def output_schema(self) -> T.Schema:
@@ -565,16 +839,6 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
         return ShuffleExchangeExec(
             HashPartitioning(self._keys, self._num_parts), self.children[0])
 
-    def _augment(self, b: ColumnBatch):
-        cols = list(b.columns)
-        fields = list(self.output_schema.fields)
-        kidx = []
-        for i, k in enumerate(self._bound):
-            cols.append(eval_device(k, b))
-            fields.append(T.StructField(f"_pk{i}", k.dtype, True))
-            kidx.append(len(cols) - 1)
-        return ColumnBatch(cols, b.num_rows, T.Schema(fields)), kidx
-
     def _local_step(self, send_capacity: int | None = None):
         """Per-device body returning ``(batch, overflow)`` — the region
         splices this into its own shard_map program; overflow is
@@ -584,7 +848,7 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
         axis = self.axis_name
 
         def step(b: ColumnBatch):
-            aug, kidx = self._augment(b)
+            aug, kidx = with_key_columns(b, self._bound)
             pid = partition_ids_for_keys(aug, kidx, n)
             dev = jnp.where(pid < n, pid % p, p)  # padding -> p (dropped)
             return exchange_local_checked(b, dev, p, axis,
@@ -597,30 +861,6 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
                 self.children[0].output_schema, self._num_parts,
                 send_capacity, self.mesh_size)
 
-    def _program(self, mesh, send_capacity: int | None = None):
-        memo = (id(mesh), send_capacity)
-        if memo in self._jitted:
-            return self._jitted[memo]
-        from jax.sharding import PartitionSpec as P
-
-        from spark_rapids_tpu.exec import compile_cache as cc
-        axis = self.axis_name
-        step = self._local_step(send_capacity)
-        key = cc.fragment_key(*self._step_key_parts(send_capacity),
-                              cc.mesh_key_part(mesh, axis))
-
-        def build():
-            def prog(stacked: ColumnBatch):
-                out, overflow = step(local_view(stacked))
-                return restack(out), restack(overflow)
-            return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=P(axis),
-                out_specs=(P(axis), P(axis)))), "mesh_exchange")
-
-        fn = cc.get_or_build(key, build)
-        self._jitted[memo] = fn
-        return fn
-
     def _pick_jit(self):
         # per output partition: keep rows of the device shard whose
         # recomputed partition id matches (device-local slice of the N
@@ -629,7 +869,7 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
             n = self._num_parts
 
             def pick(b, pid):
-                aug, kidx = self._augment(b)
+                aug, kidx = with_key_columns(b, self._bound)
                 ids = partition_ids_for_keys(aug, kidx, n)
                 return dk.compact(b, ids == pid)
 
@@ -640,10 +880,6 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
     def _outputs_cache_key(self, ctx: ExecCtx) -> tuple:
         return ("meshex", id(self), ctx.backend)
 
-    def _outputs(self, ctx: ExecCtx):
-        return ctx.cached(self._outputs_cache_key(ctx),
-                          lambda: self._compute_outputs(ctx))
-
     def _fallback_outputs(self, ctx: ExecCtx):
         """Single-device recompute from lineage: the in-process exchange
         over the same child and keys — also the degenerate path when
@@ -651,48 +887,6 @@ class MeshExchangeExec(_MeshOutputMixin, PlanNode):
         he = self._host_exchange()
         return ("host", [list(he.partition_iter(ctx, pid))
                          for pid in range(self._num_parts)])
-
-    def _run_exchange(self, ctx: ExecCtx, mesh, stacked):
-        """Launch the exchange program; a bounded send buffer that
-        overflowed under key skew retries ONCE at worst-case capacity
-        (counted, never truncated — the mesh analog of split-and-retry)."""
-        import numpy as np
-
-        from spark_rapids_tpu.conf import MESH_SEND_CAPACITY
-        send_cap = ctx.conf.get(MESH_SEND_CAPACITY) or None
-        result, flags = self._program(mesh, send_cap)(stacked)
-        if send_cap is not None and bool(
-                # enginelint: disable=RL003 (overflow-flag check; one scalar sync gates the recompile fallback)
-                np.asarray(fetch_to_host(
-                    flags, "fetch@MeshExchangeExec")).any()):
-            get_registry().inc("mesh_send_overflows")
-            result, _ = self._program(mesh, None)(stacked)
-        return result
-
-    def _compute_outputs(self, ctx: ExecCtx):
-        if not ctx.is_device:
-            return self._fallback_outputs(ctx)
-        # drain_cached, not drain_partitions: in partitioned mesh-join
-        # mode _use_partitioned already drained this subtree for its size
-        # probe — share that materialization instead of executing twice
-        batches = drain_cached(ctx, self.children[0])
-        mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
-        t0 = None
-        if batches:
-            try:
-                _check_slice_fault(ctx, "meshex", mesh)
-                shards = place_shards(batches, self.mesh_size)
-                stacked = shard_batches(shards, mesh, self.axis_name)
-                _note_a2a_bytes(stacked)
-                result = self._run_exchange(ctx, mesh, stacked)
-                return ("mesh", split_shards(result))
-            except Exception as err:
-                _reraise_unless_slice_lost(err)
-                t0 = time.perf_counter()
-        out = self._fallback_outputs(ctx)
-        if t0 is not None:
-            _note_slice_recovery(ctx, time.perf_counter() - t0)
-        return out
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
         yield from self._aligned(self._partition_iter_mesh(ctx, pid))
@@ -803,8 +997,7 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
 
     def _stream_batches(self, ctx: ExecCtx, pid: int):
         if self._use_partitioned(ctx):
-            lex, _ = self._partitioned_exchanges()
-            yield from lex.partition_iter(ctx, pid)
+            yield from self._exchanges[0].partition_iter(ctx, pid)
             return
         shards = self._mesh_shards(ctx)
         if pid < len(shards):
@@ -855,9 +1048,6 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
                 decision="partitioned" if partitioned else "replicated")
             return partitioned
         return ctx.cached((id(self), "mesh_join_partitioned"), decide)
-
-    def _partitioned_exchanges(self):
-        return self._exchanges
 
     # -- region interior -----------------------------------------------
     def _region_build(self, ctx: ExecCtx, mesh):
@@ -979,8 +1169,8 @@ class MeshJoinExec(_MeshOutputMixin, JoinExec):
             return MeshJoinExec._device_build_replicated(self, ctx, pid)
 
         def build():
-            _, rex = self._partitioned_exchanges()
-            rb = concat_or_empty(list(rex.partition_iter(ctx, pid)),
+            rb = concat_or_empty(
+                list(self._exchanges[1].partition_iter(ctx, pid)),
                                  self.children[1].output_schema)
             rb2, rkeys = self._augment_device(rb, self._rkeys_b)
             return rb2, rkeys, self._prepare_build(rb2, rkeys)

@@ -1,11 +1,10 @@
 """Mesh regions: whole pipelines as ONE per-device program, plus the
-mesh-distributed sort.
+mesh-distributed sort and window.
 
 A mesh *island* (exec/mesh_exec.py) runs one collective operator per
 ``shard_map`` program: the planner shards the operator's input, runs the
-program, and splits the output back into per-device batches.  Between
-two islands every batch used to take a host/device-0 round trip — the
-exact gather the pod-scale plan shape must avoid.
+program, and splits the output back into per-device batches; what sits
+between two islands runs per batch, per device.
 
 A mesh *region* extends the island downward: the contiguous elementwise
 pipeline feeding a collective operator (filter / project / fused stage —
@@ -13,7 +12,17 @@ the same absorbable set as whole-stage fusion, exec/fused.py) is spliced
 INTO the per-device program, so batches are sharded once at the region's
 leaves, flow shard-resident through the member pipeline and the
 collective, and cross the device boundary only at the region's output —
-one compiled executable per (pipeline, collective, mesh shape).
+one compiled executable per (pipeline, collective, mesh shape).  Island
+and region launch through the same :class:`MeshLauncher`
+(exec/mesh_exec.py): a bare terminal is a program of zero segments.
+
+What the chip said of the two (``tpcds-sf1-mesh4.q6``, four v5e chips):
+regions on, the default, 2.47 s a collect (ledger, PR 44); regions off,
+every join an island, 0.64 s (PERF.md §6, PR 44).  An island join is the
+one-chip JoinExec per device and gathers at the size it found; an
+absorbed join runs at the static capacity of its input.  The hop a
+region removes costs less than the padding it carries wherever joins
+shrink their input (ROADMAP D14).
 
 :class:`MeshSortExec` completes the operator set: a global sort (or
 TopN) as a broadcast sort inside ``shard_map`` — all-gather the shard
@@ -28,7 +37,6 @@ reads partitions in order with zero cross-device traffic; with
 """
 from __future__ import annotations
 
-import time
 from typing import Iterator, Sequence
 
 import jax
@@ -36,39 +44,26 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch, round_capacity
-from spark_rapids_tpu.columnar.column import DeviceColumn
-from spark_rapids_tpu.exec.core import ExecCtx, PlanNode, fetch_to_host
-from spark_rapids_tpu.exec.fused import (FusedStageExec, filters_merged,
-                                         has_filter, stage_body,
-                                         stage_key_parts)
-from spark_rapids_tpu.exec.mesh_exec import (MeshAggregateExec,
-                                             MeshExchangeExec,
-                                             MeshJoinExec,
-                                             _MeshOutputMixin,
-                                             _check_slice_fault,
-                                             _note_a2a_bytes,
-                                             _note_slice_recovery,
-                                             _reraise_unless_slice_lost,
+from spark_rapids_tpu.exec.core import ExecCtx, PlanNode
+from spark_rapids_tpu.exec.fused import FusedStageExec
+from spark_rapids_tpu.exec.mesh_exec import (MeshExchangeExec, MeshJoinExec,
+                                             MeshLauncher, _MeshOutputMixin,
+                                             _MeshTerminal,
                                              all_gather_batch,
-                                             concat_or_empty, drain_cached,
-                                             mesh_for, place_shards)
+                                             all_gather_rows, order_slice,
+                                             with_key_columns)
 from spark_rapids_tpu.exec.sortexec import SortExec
 from spark_rapids_tpu.exec.window import WindowExec, _window_body
-from spark_rapids_tpu.expr.core import eval_device
 from spark_rapids_tpu.obs.registry import get_registry
-from spark_rapids_tpu.ops import kernels as dk
 from spark_rapids_tpu.ops.kernels import gather_columns
 from spark_rapids_tpu.ops.sort import sort_permutation
-from spark_rapids_tpu.parallel.mesh import (local_view, restack,
-                                            shard_batches, shard_map,
-                                            split_shards)
 from spark_rapids_tpu.parallel.mesh_shuffle import (exchange_local,
                                                     partition_ids_for_keys)
 
 __all__ = ["MeshSortExec", "MeshWindowExec", "MeshRegionExec"]
 
 
-class MeshSortExec(_MeshOutputMixin, PlanNode):
+class MeshSortExec(_MeshTerminal, PlanNode):
     """Global sort / TopN over the mesh as one broadcast-sort program.
 
     Per-device body: all-gather every shard's rows and counts, build the
@@ -86,6 +81,9 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
     wants the range-exchange plan the in-process path already has.
     """
 
+    _program_name = "mesh_sort"
+    _fault_op = "meshsort"
+
     def __init__(self, orders: Sequence, child: PlanNode, mesh_size: int,
                  limit: int | None = None, axis_name: str = "data"):
         from spark_rapids_tpu.exec.sortexec import resolve_orders
@@ -94,7 +92,7 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
         self.mesh_size = mesh_size
         self.limit = limit
         self.axis_name = axis_name
-        self._jitted = {}
+        self._launcher = MeshLauncher(self)
 
     @property
     def output_schema(self) -> T.Schema:
@@ -128,37 +126,20 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
 
         def step(b: ColumnBatch) -> ColumnBatch:
             cap = b.capacity
-            counts = jax.lax.all_gather(b.num_rows, axis)  # int32[P]
-            cols = []
-            for c in b.columns:
-                data = jax.lax.all_gather(c.data, axis, tiled=True)
-                val = jax.lax.all_gather(c.validity, axis, tiled=True)
-                if c.is_string:
-                    ln = jax.lax.all_gather(c.lengths, axis, tiled=True)
-                    cols.append(DeviceColumn(data, val, c.dtype, ln))
-                else:
-                    cols.append(DeviceColumn(data, val, c.dtype))
             gcap = p * cap
-            idx = jnp.arange(gcap, dtype=jnp.int32)
-            # segment-aware real mask: rows are packed per gathered
-            # shard segment, not globally
-            real = (idx % cap) < counts[idx // cap]
+            cols, counts, real = all_gather_rows(b, p, axis)
             total = jnp.sum(counts, dtype=jnp.int32)
             gb = ColumnBatch(cols, total, schema)
             perm = sort_permutation(gb, orders, real=real)
-            i = jax.lax.axis_index(axis)
             if limit is None:
                 # contiguous slice of the total order per device; each
                 # count is <= cap because total <= p*cap
-                base = total // p
-                rem = total % p
-                start = i * base + jnp.minimum(i, rem)
-                cnt = base + (i < rem).astype(jnp.int32)
+                start, cnt = order_slice(total, p, axis)
                 out_cap = cap
             else:
                 out_cap = round_capacity(max(1, min(limit, gcap)))
                 start = jnp.int32(0)
-                cnt = jnp.where(i == 0,
+                cnt = jnp.where(jax.lax.axis_index(axis) == 0,
                                 jnp.minimum(jnp.int32(limit), total),
                                 jnp.int32(0))
             pick = jnp.clip(start + jnp.arange(out_cap, dtype=jnp.int32),
@@ -172,35 +153,8 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
         return ("mesh_sort", tuple(self._orders),
                 self.children[0].output_schema, self.limit, self.mesh_size)
 
-    def _program(self, mesh):
-        memo = id(mesh)
-        if memo in self._jitted:
-            return self._jitted[memo]
-        from jax.sharding import PartitionSpec as P
-
-        from spark_rapids_tpu.exec import compile_cache as cc
-        axis = self.axis_name
-        step = self._local_step()
-        key = cc.fragment_key(*self._step_key_parts(),
-                              cc.mesh_key_part(mesh, axis))
-
-        def build():
-            def prog(stacked: ColumnBatch) -> ColumnBatch:
-                return restack(step(local_view(stacked)))
-            return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))),
-                "mesh_sort")
-
-        fn = cc.get_or_build(key, build)
-        self._jitted[memo] = fn
-        return fn
-
     def _outputs_cache_key(self, ctx: ExecCtx) -> tuple:
         return ("meshsort", id(self), ctx.backend)
-
-    def _outputs(self, ctx: ExecCtx):
-        return ctx.cached(self._outputs_cache_key(ctx),
-                          lambda: self._compute_outputs(ctx))
 
     def _fallback_outputs(self, ctx: ExecCtx):
         """Single-device recompute from lineage: the in-process global
@@ -208,27 +162,6 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
         child produced nothing."""
         out = [list(self._single_exec().partition_iter(ctx, 0))]
         out += [[] for _ in range(self.mesh_size - 1)]
-        return out
-
-    def _compute_outputs(self, ctx: ExecCtx):
-        from spark_rapids_tpu.exec.core import drain_partitions
-        batches = list(drain_partitions(ctx, self.children[0]))
-        mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
-        t0 = None
-        if batches:
-            try:
-                _check_slice_fault(ctx, "meshsort", mesh)
-                shards = place_shards(batches, self.mesh_size)
-                stacked = shard_batches(shards, mesh, self.axis_name)
-                _note_a2a_bytes(stacked)
-                result = self._program(mesh)(stacked)
-                return [[b] for b in split_shards(result)]
-            except Exception as err:
-                _reraise_unless_slice_lost(err)
-                t0 = time.perf_counter()
-        out = self._fallback_outputs(ctx)
-        if t0 is not None:
-            _note_slice_recovery(ctx, time.perf_counter() - t0)
         return out
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
@@ -242,7 +175,7 @@ class MeshSortExec(_MeshOutputMixin, PlanNode):
         return f"MeshSortExec[mesh={self.mesh_size}, {self._orders}{lim}]"
 
 
-class MeshWindowExec(_MeshOutputMixin, WindowExec):
+class MeshWindowExec(_MeshTerminal, WindowExec):
     """Window functions distributed over the mesh, by spec shape:
 
     - **partitioned** (PARTITION BY present): rows hash-exchange on the
@@ -262,13 +195,16 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
     bounded-memory `_stream_global` two-pass stream beats gathering).
     """
 
+    _program_name = "mesh_window"
+    _fault_op = "meshwindow"
+
     def __init__(self, window_exprs: Sequence, child: PlanNode,
                  mesh_size: int, axis_name: str = "data"):
         WindowExec.__init__(self, window_exprs, child,
                             keys_partitioned=False)
         self.mesh_size = mesh_size
         self.axis_name = axis_name
-        self._jitted = {}
+        self._launcher = MeshLauncher(self)
 
     @property
     def output_batching(self):
@@ -299,14 +235,7 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
                 # recomputed from the shipped raw columns after the
                 # exchange (_window_args), so only the input schema
                 # travels — no augmented columns on the wire
-                cols = list(b.columns)
-                fields = list(b.schema.fields)
-                kidx = []
-                for i, e in enumerate(part_b):
-                    cols.append(eval_device(e, b))
-                    fields.append(T.StructField(f"_wk{i}", e.dtype, True))
-                    kidx.append(len(cols) - 1)
-                aug = ColumnBatch(cols, b.num_rows, T.Schema(fields))
+                aug, kidx = with_key_columns(b, part_b)
                 pid = partition_ids_for_keys(aug, kidx, p)
                 ex = exchange_local(b, pid, p, axis)
                 return self._window_local(ex)
@@ -318,12 +247,7 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
             cap = b.capacity
             gb = all_gather_batch(b, p, axis)
             out = self._window_local(gb)
-            total = out.num_rows
-            i = jax.lax.axis_index(axis)
-            base = total // p
-            rem = total % p
-            start = i * base + jnp.minimum(i, rem)
-            cnt = base + (i < rem).astype(jnp.int32)
+            start, cnt = order_slice(out.num_rows, p, axis)
             pick = jnp.clip(start + jnp.arange(cap, dtype=jnp.int32),
                             0, p * cap - 1)
             out_cols = gather_columns(out.columns, pick, cnt)
@@ -337,35 +261,8 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
                 self.children[0].output_schema, self._schema,
                 self.mesh_size)
 
-    def _program(self, mesh):
-        memo = id(mesh)
-        if memo in self._jitted:
-            return self._jitted[memo]
-        from jax.sharding import PartitionSpec as P
-
-        from spark_rapids_tpu.exec import compile_cache as cc
-        axis = self.axis_name
-        step = self._local_step()
-        key = cc.fragment_key(*self._step_key_parts(),
-                              cc.mesh_key_part(mesh, axis))
-
-        def build():
-            def prog(stacked: ColumnBatch) -> ColumnBatch:
-                return restack(step(local_view(stacked)))
-            return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=P(axis), out_specs=P(axis))),
-                "mesh_window")
-
-        fn = cc.get_or_build(key, build)
-        self._jitted[memo] = fn
-        return fn
-
     def _outputs_cache_key(self, ctx: ExecCtx) -> tuple:
         return ("meshwin", id(self), ctx.backend)
-
-    def _outputs(self, ctx: ExecCtx):
-        return ctx.cached(self._outputs_cache_key(ctx),
-                          lambda: self._compute_outputs(ctx))
 
     def _fallback_outputs(self, ctx: ExecCtx):
         """Single-device recompute from lineage: the in-process window
@@ -373,27 +270,6 @@ class MeshWindowExec(_MeshOutputMixin, WindowExec):
         child produced nothing."""
         out = [list(WindowExec.partition_iter(self, ctx, 0))]
         out += [[] for _ in range(self.mesh_size - 1)]
-        return out
-
-    def _compute_outputs(self, ctx: ExecCtx):
-        from spark_rapids_tpu.exec.core import drain_partitions
-        batches = list(drain_partitions(ctx, self.children[0]))
-        mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
-        t0 = None
-        if batches:
-            try:
-                _check_slice_fault(ctx, "meshwindow", mesh)
-                shards = place_shards(batches, self.mesh_size)
-                stacked = shard_batches(shards, mesh, self.axis_name)
-                _note_a2a_bytes(stacked)
-                result = self._program(mesh)(stacked)
-                return [[b] for b in split_shards(result)]
-            except Exception as err:
-                _reraise_unless_slice_lost(err)
-                t0 = time.perf_counter()
-        out = self._fallback_outputs(ctx)
-        if t0 is not None:
-            _note_slice_recovery(ctx, time.perf_counter() - t0)
         return out
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
@@ -438,13 +314,15 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
     (``MeshJoinExec._region_build``), a partitioned join's raw batches
     stacked onto the mesh.
 
-    Execution primes the terminal's per-execution output cache and then
-    delegates ``partition_iter`` to the terminal, so its partition
-    serving (exchange partition slicing, alignment, shrink) is reused
-    unchanged.  When the leaf is itself a mesh exchange — bare or a
-    chained region's exchange terminal — the upstream output shards
-    stay committed one-per-device and are stacked in place
-    (``_chained_shards``): no gather, no host hop between regions.
+    Execution primes the terminal's per-execution output cache — by the
+    :class:`MeshLauncher` a bare terminal launches through, given the
+    region's segments — and then delegates ``partition_iter`` to the
+    terminal, so its partition serving (exchange partition slicing,
+    alignment, shrink) is reused unchanged.  When the leaf is itself a
+    mesh exchange — bare or a chained region's exchange terminal — the
+    upstream output shards stay committed one-per-device and are stacked
+    in place (``_chained_shards``): no gather, no host hop between
+    regions.
     """
 
     combines_batches = True
@@ -477,17 +355,13 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
                 run.append(op)
         if run:
             segs.append(("stage", tuple(run)))
-        self._segs = tuple(segs)
-        self._merged = sum(filters_merged(seg) for kind, seg in segs
-                           if kind == "stage")
-        self._compacts = any(has_filter(seg) for kind, seg in segs
-                             if kind == "stage")
-        self._joins = tuple(op for k, op in segs if k == "join")
+        # builds, launches, retries and recovers the region's program:
+        # the launcher a bare terminal holds, given segments
+        self._launcher = MeshLauncher(terminal, self, segs)
         super().__init__([members[0].children[0]]
-                         + [j.children[1] for j in self._joins])
+                         + [j.children[1] for j in self._launcher._joins])
         self.mesh_size = terminal.mesh_size
         self.axis_name = terminal.axis_name
-        self._jitted = {}
         # the member chain is the terminal's recovery lineage: after a
         # lost slice the fallback replays it per batch, so a fused
         # member must not have donated (deleted) its input buffers
@@ -510,197 +384,12 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
     def region_ops(self) -> tuple:
         return self._flat + (self._terminal,)
 
-    # -- program -------------------------------------------------------
-    def _is_exchange(self) -> bool:
-        return isinstance(self._terminal, MeshExchangeExec)
-
-    def _caps(self, leaf_cap: int, modes: tuple, send_cap: int | None,
-              floors=None) -> tuple:
-        """Symbolic per-device capacity walk over the segments, yielding
-        the STATIC output capacity of each join (shard_map bodies cannot
-        sync the probe total).  Elementwise stages and the global-window
-        slice preserve capacity; a partitioned exchange's worst case is
-        P*C; a join's output capacity starts as its post-exchange stream
-        capacity and is floored by the measured total on a retry."""
-        p = self.mesh_size
-        cap = leaf_cap
-        caps = []
-        ji = 0
-        for kind, seg in self._segs:
-            if kind == "join":
-                if modes[ji] == "partitioned":
-                    c = cap if send_cap is None else min(send_cap, cap)
-                    cap = p * c
-                guess = round_capacity(max(cap, 8))
-                if floors is not None and floors[ji]:
-                    guess = max(guess, floors[ji])
-                caps.append(guess)
-                cap = guess
-                ji += 1
-            elif kind == "window" and seg._part_b:
-                cap = p * cap
-        return tuple(caps)
-
-    def _body_key_parts(self, modes: tuple, caps: tuple,
-                        send_capacity: int | None, probes: tuple) -> tuple:
-        parts = []
-        ji = 0
-        for kind, seg in self._segs:
-            if kind == "stage":
-                parts.append(("stage", stage_key_parts(seg)))
-            elif kind == "join":
-                parts.append(seg._region_step_key_parts(
-                    modes[ji], caps[ji], send_capacity, probes[ji]))
-                ji += 1
-            else:
-                parts.append(seg._step_key_parts())
-        return tuple(parts)
-
-    def _program(self, mesh, send_capacity: int | None = None,
-                 modes: tuple = (), caps: tuple = (), probes: tuple = ()):
-        """``probes``: per absorbed join, the static ``(kind, packed,
-        rkeys)`` of its prepared build (MeshJoinExec._region_build) in
-        replicated mode, None in partitioned."""
-        memo = (id(mesh), send_capacity, modes, caps, probes)
-        if memo in self._jitted:
-            return self._jitted[memo]
-        from jax.sharding import PartitionSpec as P
-
-        from spark_rapids_tpu.exec import compile_cache as cc
-        axis = self.axis_name
-        steps = []
-        ji = 0
-        for kind, seg in self._segs:
-            if kind == "stage":
-                steps.append(("stage", stage_body(seg)))
-            elif kind == "join":
-                steps.append(("join", seg._region_step(
-                    modes[ji], caps[ji], send_capacity, probes[ji])))
-                ji += 1
-            else:
-                steps.append(("window", seg._local_step()))
-        is_ex = self._is_exchange()
-        if is_ex:
-            tstep = self._terminal._local_step(send_capacity)
-            tparts = self._terminal._step_key_parts(send_capacity)
-        else:
-            tstep = self._terminal._local_step()
-            tparts = self._terminal._step_key_parts()
-        key = cc.fragment_key(
-            "mesh_region",
-            self._body_key_parts(modes, caps, send_capacity, probes),
-            *tparts, tuple(c.output_schema for c in self.children),
-            cc.mesh_key_part(mesh, axis))
-        n_builds = len(self._joins)
-        n_flags = 2 * sum(m == "partitioned" for m in modes) \
-            + (1 if is_ex else 0)
-        n_aux = n_builds + n_flags
-
-        def build():
-            def prog(stacked, *builds):
-                b = local_view(stacked)
-                # a partitioned join's build is this device's shard; a
-                # replicated one's is the whole prepared build as it is
-                blocal = [local_view(x) if m == "partitioned" else x
-                          for m, x in zip(modes, builds)]
-                totals, flags = [], []
-                bi = 0
-                for kind, step in steps:
-                    if kind == "join":
-                        # named in the ops' metadata, so a compiled module
-                        # or a trace can tell one join's work from the
-                        # next's and from the terminal's
-                        with jax.named_scope(f"join{bi}"):
-                            b, (total, fl) = step(b, blocal[bi])
-                        # one join's work ends before the next's begins:
-                        # its row stacks ([capacity, k] with k small pad
-                        # to 512 bytes a row in HBM) are then dead, and
-                        # the program's temporaries are the widest
-                        # join's, not the sum over the joins (described
-                        # v5e, three joins at 2^20 slots: 4.19 GB -> 2.17)
-                        b, total = jax.lax.optimization_barrier((b, total))
-                        totals.append(total)
-                        flags.extend(fl)
-                        bi += 1
-                    else:
-                        b = step(b)
-                if is_ex:
-                    out, ovf = tstep(b)
-                    flags.append(ovf)
-                else:
-                    out = tstep(b)
-                aux = tuple(restack(t) for t in totals) \
-                    + tuple(restack(f) for f in flags)
-                return restack(out), aux
-            in_specs = (P(axis),) + tuple(
-                P(axis) if m == "partitioned" else P() for m in modes)
-            out_specs = (P(axis), (P(axis),) * n_aux)
-            # a region with a join in it is another program to tune
-            # than a chain of per-shard steps: the name says which
-            return cc.instrument(jax.jit(shard_map(
-                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs)),
-                "mesh_region_join" if n_builds else "mesh_region_chain")
-
-        fn = cc.get_or_build(key, build)
-        self._jitted[memo] = fn
-        return fn
-
-    def _launch(self, ctx: ExecCtx, mesh, stacked, builds, leaf_cap: int,
-                modes: tuple, probes: tuple):
-        """Run the region program, re-running on the two loud
-        under-capacity signals (never truncating): a join whose probe
-        total exceeded its static output capacity recompiles at the
-        rounded-up measured size; an overflowed bounded send buffer
-        falls back to worst-case capacity (the mesh analog of the OOM
-        split-and-retry ladder).  All join totals and overflow flags
-        are read back in ONE stacked device fetch per attempt."""
-        import numpy as np
-
-        from spark_rapids_tpu.conf import MESH_SEND_CAPACITY
-        send_cap = ctx.conf.get(MESH_SEND_CAPACITY) or None
-        nj = len(self._joins)
-        for probe in probes:
-            if probe is not None:
-                # which probe this join's body runs against its prepared
-                # build, beside mesh_join_replicated (a retry at a larger
-                # capacity runs the same probe and is not counted again)
-                get_registry().inc(f"mesh_join.probe.{probe[0]}")
-        floors = [0] * nj
-        result = None
-        for _ in range(nj + 2):
-            caps = self._caps(leaf_cap, modes, send_cap, floors)
-            if self._merged:
-                get_registry().inc("fused.filters_merged", self._merged)
-            if self._compacts:
-                # the slots the region was handed, over all its devices
-                dk.count_compaction(leaf_cap * self.mesh_size)
-            result, aux = self._program(mesh, send_cap, modes, caps, probes)(
-                stacked, *builds)
-            if not aux or (nj == 0 and send_cap is None):
-                return result
-            vals = [np.asarray(v) for v in
-                    # enginelint: disable=RL003 (join totals + overflow flags; one stacked sync gates the retry)
-                    fetch_to_host(aux, "fetch@MeshRegionExec")]
-            retry = False
-            for i in range(nj):
-                total = int(vals[i].max())
-                if total > caps[i]:
-                    get_registry().inc("mesh_join_capacity_retries")
-                    floors[i] = max(floors[i],
-                                    round_capacity(max(total, 1)))
-                    retry = True
-            if send_cap is not None and any(v.any() for v in vals[nj:]):
-                get_registry().inc("mesh_send_overflows")
-                send_cap = None
-                retry = True
-            if not retry:
-                return result
-        return result
-
     # -- execution -----------------------------------------------------
     def _ensure(self, ctx: ExecCtx) -> None:
-        ctx.cached(("mesh_region", id(self), ctx.backend),
-                   lambda: self._execute(ctx))
+        """Prime the terminal's per-execution outputs with the region
+        program's, once."""
+        ctx.cached(self._terminal._outputs_cache_key(ctx),
+                   lambda: self._launcher.run(ctx, self._chained_shards(ctx)))
 
     def _chained_shards(self, ctx: ExecCtx):
         """Region chaining: when the leaf IS a mesh exchange — bare, or
@@ -713,7 +402,8 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
         leaf = self.children[0]
         if isinstance(leaf, MeshExchangeExec):
             up = leaf
-        elif isinstance(leaf, MeshRegionExec) and leaf._is_exchange():
+        elif isinstance(leaf, MeshRegionExec) \
+                and isinstance(leaf._terminal, MeshExchangeExec):
             leaf._ensure(ctx)
             up = leaf._terminal
         else:
@@ -726,60 +416,6 @@ class MeshRegionExec(_MeshOutputMixin, PlanNode):
             return None
         get_registry().inc("mesh_region_chains")
         return list(out)
-
-    def _execute(self, ctx: ExecCtx) -> bool:
-        tkey = self._terminal._outputs_cache_key(ctx)
-        from spark_rapids_tpu.conf import MESH_REGION_CHAINING
-        from spark_rapids_tpu.exec.core import drain_partitions
-        mesh = mesh_for(ctx, self.mesh_size, self.axis_name)
-        chained = None
-        if ctx.conf.get(MESH_REGION_CHAINING):
-            chained = self._chained_shards(ctx)
-        batches = chained if chained is not None \
-            else list(drain_partitions(ctx, self.children[0]))
-        t0 = None
-        if batches:
-            try:
-                _check_slice_fault(ctx, "meshregion", mesh)
-                shards = chained if chained is not None \
-                    else place_shards(batches, self.mesh_size)
-                leaf_cap = shards[0].capacity
-                stacked = shard_batches(shards, mesh, self.axis_name)
-                if chained is None:
-                    _note_a2a_bytes(stacked)
-                modes = tuple("partitioned" if j._use_partitioned(ctx)
-                              else "replicated" for j in self._joins)
-                builds, probes = [], []
-                for j, mode in zip(self._joins, modes):
-                    if mode == "replicated":
-                        build, probe = j._region_build(ctx, mesh)
-                    else:
-                        bl = drain_cached(ctx, j.children[1]) or \
-                            [concat_or_empty([], j.children[1].output_schema)]
-                        bshards = place_shards(bl, self.mesh_size)
-                        build = shard_batches(bshards, mesh, self.axis_name)
-                        _note_a2a_bytes(build)
-                        probe = None
-                    builds.append(build)
-                    probes.append(probe)
-                result = self._launch(ctx, mesh, stacked, builds, leaf_cap,
-                                      modes, tuple(probes))
-                if self._is_exchange():
-                    ctx.cache[tkey] = ("mesh", split_shards(result))
-                else:
-                    ctx.cache[tkey] = [[b] for b in split_shards(result)]
-                return True
-            except Exception as err:
-                _reraise_unless_slice_lost(err)
-                t0 = time.perf_counter()
-        # lost slice / empty input: the terminal's own
-        # fallback recomputes through the intact member chain — a join
-        # member's island path re-materializes BOTH its sides, so the
-        # whole region lineage (build subtrees included) replays
-        ctx.cache[tkey] = self._terminal._fallback_outputs(ctx)
-        if t0 is not None:
-            _note_slice_recovery(ctx, time.perf_counter() - t0)
-        return True
 
     def partition_iter(self, ctx: ExecCtx, pid: int) -> Iterator:
         if not ctx.is_device:

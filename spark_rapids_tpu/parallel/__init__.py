@@ -7,15 +7,7 @@ data-parallel shards over a `jax.sharding.Mesh` with the exchange lowered
 to XLA `all_to_all` collectives riding ICI (DCN across slices, handled by
 the same collective via the mesh topology).
 """
-from spark_rapids_tpu.parallel.mesh import make_mesh, shard_batches, unshard_batch
-from spark_rapids_tpu.parallel.mesh_shuffle import (
-    partition_ids_for_keys,
-    make_hash_exchange,
-    make_distributed_groupby,
-)
+from spark_rapids_tpu.parallel.mesh import make_mesh, shard_batches
+from spark_rapids_tpu.parallel.mesh_shuffle import partition_ids_for_keys
 
-__all__ = [
-    "make_mesh", "shard_batches", "unshard_batch",
-    "partition_ids_for_keys", "make_hash_exchange",
-    "make_distributed_groupby",
-]
+__all__ = ["make_mesh", "shard_batches", "partition_ids_for_keys"]

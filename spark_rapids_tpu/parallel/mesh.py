@@ -16,17 +16,14 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
-from spark_rapids_tpu.columnar.column import DeviceColumn
 
 shard_map = jax.shard_map
 
-__all__ = ["make_mesh", "shard_batches", "unshard_batch", "split_shards",
+__all__ = ["make_mesh", "shard_batches", "split_shards",
            "local_view", "stacked_spec", "shard_map"]
 
 
@@ -92,9 +89,9 @@ def split_shards(stacked: ColumnBatch) -> list[ColumnBatch]:
     """Split a sharded batch into P per-device ColumnBatches WITHOUT a
     host round trip: each shard's arrays stay committed to the mesh
     device that produced them.  This is the region-boundary exit path —
-    ``unshard_batch`` (device_get + re-upload) implicitly funneled every
-    mesh output through the default device, re-serializing the
-    distributed pipeline at each island boundary.  Downstream per-batch
+    a device_get + re-upload would funnel every mesh output through the
+    default device, re-serializing the distributed pipeline at each
+    island boundary.  Downstream per-batch
     operators dispatch on the shard's own device; ``place_shards``
     device affinity keeps re-sharded batches where they already live."""
     leaves, treedef = jax.tree_util.tree_flatten(stacked)
@@ -109,16 +106,3 @@ def split_shards(stacked: ColumnBatch) -> list[ColumnBatch]:
     return [jax.tree_util.tree_unflatten(treedef,
                                          [col[i] for col in per_dev])
             for i in range(p)]
-
-
-def unshard_batch(stacked: ColumnBatch) -> list[ColumnBatch]:
-    """Pull a sharded batch back to P host-side ColumnBatch shards."""
-    leaves, treedef = jax.tree_util.tree_flatten(stacked)
-    from spark_rapids_tpu.exec.core import fetch_to_host
-    host = fetch_to_host(leaves, "fetch@unshard_batch")
-    p = host[-1].shape[0] if host else 1  # num_rows is int32[P]
-    out = []
-    for i in range(p):
-        out.append(jax.tree_util.tree_unflatten(
-            treedef, [jnp.asarray(leaf[i]) for leaf in host]))
-    return out

@@ -1,4 +1,4 @@
-"""All-to-all hash exchange + distributed aggregation over a device mesh.
+"""All-to-all hash exchange over a device mesh.
 
 TPU-native shuffle data plane (SURVEY.md §5.8).  The reference moves map
 output peer-to-peer over UCX tag matching (shuffle-plugin/.../UCX.scala,
@@ -28,8 +28,6 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnBatch
@@ -37,12 +35,9 @@ from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.expr.core import EvalCtx, Val
 from spark_rapids_tpu.expr.hashing import murmur3_val, DEFAULT_SEED
 from spark_rapids_tpu.ops import kernels as dk
-from spark_rapids_tpu.ops.segmented import AggSpec, sorted_group_by
-from spark_rapids_tpu.parallel.mesh import local_view, restack, shard_map
 
 __all__ = [
-    "partition_ids_for_keys", "make_hash_exchange",
-    "make_distributed_groupby", "MERGE_OPS",
+    "partition_ids_for_keys",
     "exchange_local", "exchange_local_checked", "canonicalize",
 ]
 
@@ -184,82 +179,3 @@ def canonicalize(batch: ColumnBatch) -> ColumnBatch:
             cols.append(DeviceColumn(
                 jnp.where(v, c.data, jnp.zeros((), c.data.dtype)), v, c.dtype))
     return ColumnBatch(cols, batch.num_rows, batch.schema)
-
-
-def make_hash_exchange(mesh: Mesh, schema: T.Schema,
-                       key_indices: Sequence[int],
-                       axis_name: str = "data"):
-    """Jitted sharded-batch -> sharded-batch all-to-all hash exchange."""
-    num_parts = mesh.shape[axis_name]
-
-    def step(stacked: ColumnBatch) -> ColumnBatch:
-        b = local_view(stacked)
-        part = partition_ids_for_keys(b, key_indices, num_parts)
-        return restack(exchange_local(b, part, num_parts, axis_name))
-
-    mapped = shard_map(step, mesh=mesh, in_specs=P(axis_name),
-                           out_specs=P(axis_name))
-    from spark_rapids_tpu.exec.compile_cache import instrument
-    return instrument(jax.jit(mapped), "mesh_hash_exchange")
-
-
-# Merge-side op per update op (reference: CudfAggregate mergeAggregate,
-# AggregateFunctions.scala:531 — count merges as sum, etc.).  `avg` is not
-# single-column-mergeable: the exec layer decomposes it to sum+count before
-# reaching this kernel (HashAggregateExec buffer layout).
-MERGE_OPS = {
-    "sum": "sum", "count": "sum", "count_star": "sum",
-    "min": "min", "max": "max",
-    "first": "first", "last": "last",
-    "first_non_null": "first_non_null", "last_non_null": "last_non_null",
-}
-
-
-def make_distributed_groupby(mesh: Mesh, schema: T.Schema,
-                             key_indices: Sequence[int],
-                             specs: Sequence[AggSpec],
-                             axis_name: str = "data"):
-    """Jitted full distributed aggregation step over the mesh.
-
-    partial local group-by -> all-to-all exchange of partial rows by key
-    hash -> final merge group-by.  This is the TPU-shaped version of the
-    reference's partial agg / GpuShuffleExchangeExec / final agg plan
-    (aggregate.scala modes + GpuHashPartitioning), fused into ONE compiled
-    program per device so XLA overlaps the collective with compute.
-    """
-    num_parts = mesh.shape[axis_name]
-    key_indices = list(key_indices)
-    for s in specs:
-        if s.op not in MERGE_OPS:
-            raise ValueError(f"op {s.op} is not mergeable here; decompose "
-                             "at the exec layer (e.g. avg -> sum+count)")
-    nkeys = len(key_indices)
-    partial_keys = list(range(nkeys))
-    merge_specs = [AggSpec(MERGE_OPS[s.op], nkeys + i)
-                   for i, s in enumerate(specs)]
-
-    def step(stacked: ColumnBatch) -> ColumnBatch:
-        b = local_view(stacked)
-        part_out = sorted_group_by(b, key_indices, list(specs))
-        if nkeys:
-            part = partition_ids_for_keys(part_out, partial_keys, num_parts)
-        else:
-            # grand aggregate: merge on device 0
-            part = jnp.where(part_out.row_mask(), 0, num_parts)
-        ex = exchange_local(part_out, part, num_parts, axis_name)
-        merged = sorted_group_by(ex, partial_keys, merge_specs)
-        # merge output columns carry nested names (e.g. sum(sum(x))) but
-        # identical types; relabel to the partial (user-facing) schema.
-        out = ColumnBatch(merged.columns, merged.num_rows, part_out.schema)
-        if not nkeys:
-            # only device 0 received rows; suppress identity rows elsewhere
-            on0 = jax.lax.axis_index(axis_name) == 0
-            out = ColumnBatch(out.columns,
-                              jnp.where(on0, out.num_rows, 0), out.schema)
-            out = canonicalize(out)
-        return restack(out)
-
-    mapped = shard_map(step, mesh=mesh, in_specs=P(axis_name),
-                           out_specs=P(axis_name))
-    from spark_rapids_tpu.exec.compile_cache import instrument
-    return instrument(jax.jit(mapped), "mesh_distributed_group_by")

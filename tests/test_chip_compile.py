@@ -469,19 +469,36 @@ def test_q51_window_body_compiles_for_v5e(one_chip, which):
 
 def test_distributed_groupby_compiles_for_2x2_mesh(topo):
     """partial group-by -> all-to-all -> merge as ONE shard_map program
-    over the four described devices."""
+    over the four described devices: the engine's own, a bare
+    ``MeshAggregateExec`` built by the launcher every mesh program is."""
     import __graft_entry__ as g
     from jax.sharding import Mesh
-    from spark_rapids_tpu.parallel.mesh_shuffle import \
-        make_distributed_groupby
+    from spark_rapids_tpu.exec import compile_cache as cc
+    from spark_rapids_tpu.exec.basic import LocalScanExec
+    from spark_rapids_tpu.exec.mesh_exec import MeshAggregateExec
+    from spark_rapids_tpu.expr.aggregates import (Count, CountStar, Max,
+                                                  Min, Sum)
+    from spark_rapids_tpu.expr.core import col
     mesh = Mesh(np.asarray(topo.devices), ("data",))
-    step = make_distributed_groupby(mesh, g.SCHEMA, [0], g._SPECS)
+    price, qty = col("ss_sales_price"), col("ss_quantity")
+    agg = MeshAggregateExec(
+        [col("ss_customer_sk")],
+        [col("ss_customer_sk"), Sum(price).alias("s"),
+         Count(price).alias("c"), Min(qty).alias("lo"),
+         Max(qty).alias("hi"), CountStar().alias("n")],
+        LocalScanExec([], g.SCHEMA), 4)
     local = _sales_batch(cap=CAP >> 2)   # x4 shards: the same rows in all
     stacked = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(
             (4,) + a.shape, a.dtype,
             sharding=NamedSharding(mesh, P("data"))), local)
-    compiled = _compile(step.fn, stacked)
+    # the virtual CPU devices and the described ones share their ids, so
+    # the process-wide cache could hand back a CPU mesh's program
+    cc.reset_cache()
+    try:
+        compiled = _compile(agg._launcher._program(mesh).fn, stacked)
+    finally:
+        cc.reset_cache()
     assert "all-to-all" in compiled.as_text()
 
 
@@ -502,7 +519,7 @@ def test_region_join_body_compiles_for_2x2_mesh(topo, monkeypatch):
     the new bodies hold 0 / 1 / 3-6 each."""
     from jax.sharding import Mesh
     from spark_rapids_tpu.exec import compile_cache as cc
-    from spark_rapids_tpu.exec.mesh_region import MeshRegionExec
+    from spark_rapids_tpu.exec.mesh_exec import MeshLauncher
     from spark_rapids_tpu.expr.core import col
     from spark_rapids_tpu.session import TpuSession
     rng = np.random.default_rng(3)
@@ -527,17 +544,17 @@ def test_region_join_body_compiles_for_2x2_mesh(topo, monkeypatch):
     # one collect on the virtual CPU mesh hands over what the region
     # launched with; the same body is then lowered for the described chips
     seen = {}
-    real = MeshRegionExec._launch
+    real = MeshLauncher._launch
 
     def spy(self, ctx, mesh, stacked, builds, leaf_cap, modes, probes):
         if self._joins:
-            seen.update(region=self, stacked=stacked, builds=builds,
+            seen.update(launcher=self, stacked=stacked, builds=builds,
                         leaf_cap=leaf_cap, modes=modes, probes=probes)
         return real(self, ctx, mesh, stacked, builds, leaf_cap, modes,
                     probes)
-    monkeypatch.setattr(MeshRegionExec, "_launch", spy)
+    monkeypatch.setattr(MeshLauncher, "_launch", spy)
     assert df.collect()
-    region = seen["region"]
+    launcher = seen["launcher"]
     assert [p[0] for p in seen["probes"]] == ["direct", "direct"]
     assert seen["modes"] == ("replicated", "replicated")
 
@@ -556,9 +573,9 @@ def test_region_join_body_compiles_for_2x2_mesh(topo, monkeypatch):
     # the process-wide cache would hand back the CPU mesh's program
     cc.reset_cache()
     try:
-        caps = region._caps(cap, seen["modes"], None)
-        program = region._program(mesh, None, seen["modes"], caps,
-                                  seen["probes"])
+        caps = launcher._caps(cap, seen["modes"], None)
+        program = launcher._program(mesh, None, seen["modes"], caps,
+                                    seen["probes"])
         hlo = _compile(program.fn, stacked, *builds).as_text()
     finally:
         cc.reset_cache()

@@ -29,8 +29,12 @@ devices), a slice lost inside a join- or window-bearing region still
 recovers to exact rows with one recompute, warm reruns of the new
 node kinds compile nothing, and exchange-fed regions chain —
 downstream regions consume upstream shards in place
-(``mesh_region_chains``), reversible via
-``spark.rapids.tpu.mesh.regions.chain.enabled``.
+(``mesh_region_chains``); an upstream that degraded to host partitions
+is drained by partition instead.
+
+ISSUE 45: a bare terminal and a region launch through ONE
+``MeshLauncher`` (exec/mesh_exec.py) — each bare terminal recovers a
+lost slice of its own, and the six program names stay apart.
 """
 import numpy as np
 import pytest
@@ -764,19 +768,6 @@ def _windowed_filter(s, data):
 
 
 @pytest.mark.slow
-def test_standalone_mesh_window_slice_lost_recovers(rng):
-    """No region around it: a bare MeshWindowExec's own fallback path
-    recovers a lost slice on host with exact rows."""
-    data = _data(rng)
-    s = TpuSession({**MESH8,
-                    "spark.rapids.test.faults":
-                    "mesh.slice.lost:lost,op=meshwindow,times=1"})
-    got = sorted(_window_df(s, data).collect())
-    want = sorted(_window_df(TpuSession({}), data).collect())
-    assert got == want
-
-
-@pytest.mark.slow
 def test_join_and_window_regions_warm_rerun_compile_nothing(rng, tpch_dir):
     """Second run of a join-bearing region program and a mesh window at
     the SAME mesh shape compiles nothing: the new node kinds key into
@@ -803,10 +794,11 @@ def test_join_and_window_regions_warm_rerun_compile_nothing(rng, tpch_dir):
 # region chaining: exchange-fed regions consume shards in place
 # ---------------------------------------------------------------------------
 
-def _chained_q(s, data):
-    return s.from_pydict(data, SCHEMA, partitions=4) \
-        .where(col("v") != 0) \
-        .repartition(8, col("k")) \
+def _chained_q(s, data, lead_filter=True):
+    t = s.from_pydict(data, SCHEMA, partitions=4)
+    if lead_filter:         # absorbed: the exchange is a region's terminal
+        t = t.where(col("v") != 0)
+    return t.repartition(8, col("k")) \
         .where(col("v") > 0) \
         .group_by("k").agg(Sum(col("v")).alias("sv"))
 
@@ -828,13 +820,84 @@ def test_region_chaining_consumes_shards_in_place(rng):
     assert sorted(rows) == sorted(want)
 
 
-def test_region_chaining_disabled_same_rows_no_chain(rng):
+# ---------------------------------------------------------------------------
+# one launcher (ISSUE 45): bare terminals and regions, each under its name
+# ---------------------------------------------------------------------------
+
+# fault op -> (plan node, program name) of each bare terminal
+_BARE = {"meshagg": ("MeshAggregateExec", "mesh_aggregate"),
+         "meshex": ("MeshExchangeExec", "mesh_exchange"),
+         "meshsort": ("MeshSortExec", "mesh_sort"),
+         "meshwindow": ("MeshWindowExec", "mesh_window")}
+
+
+def _bare_q(conf, data, op):
+    """A query whose plan holds the terminal ``op`` names BARE: nothing
+    absorbed under it, so it launches (and is lost) under its own name.
+    Shapes this file has compiled by now: ``meshagg`` is the island plan
+    of ``test_regions_disabled_keeps_island_shape_and_rows``; ``meshex``
+    is the chained query without its leading filter, a bare exchange
+    feeding the region of the filter and the aggregate."""
+    if op == "meshagg" and conf:
+        conf = {**conf, "spark.rapids.tpu.mesh.regions.enabled": "false"}
+    s = TpuSession(conf)
+    t = s.from_pydict(data, SCHEMA, partitions=4)
+    if op == "meshagg":
+        return t.where(col("v") > 0).group_by("k") \
+            .agg(Sum(col("v")).alias("sv"))
+    if op == "meshex":
+        return _chained_q(s, data, lead_filter=False)
+    if op == "meshsort":
+        return t.order_by("v", ("k", False), "g")
+    return _window_df(s, data)
+
+
+@pytest.mark.parametrize("op", list(_BARE))
+def test_standalone_mesh_terminal_slice_lost_recovers(rng, op):
+    """No region around it: a bare terminal's own fallback path recovers
+    one injected loss of its slice with exact rows, its mesh program
+    never launched.  The lost bare exchange degrades to host partitions,
+    which the region above it drains instead of chaining."""
     data = _data(rng)
-    s = TpuSession({**MESH8,
-                    "spark.rapids.tpu.mesh.regions.chain.enabled": "false"})
+    df = _bare_q({**MESH8, "spark.rapids.test.faults":
+                  f"mesh.slice.lost:lost,op={op},times=1"}, data, op)
+    node, program = _BARE[op]
+    assert node in _classes(_executed_plan(df))
     b0 = get_registry().snapshot()
-    rows = _chained_q(s, data).collect()
+    got = df.collect()
     delta = get_registry().delta(b0)["counters"]
-    assert delta.get("mesh_region_chains", 0) == 0, delta
-    want = _chained_q(TpuSession({}), data).collect()
-    assert sorted(rows) == sorted(want)
+    want = _bare_q({}, data, op).collect()
+    if op != "meshsort":            # a sort's order is part of its answer
+        got, want = sorted(got), sorted(want)
+    assert got == want
+    assert delta.get(f"program.{program}.launches", 0) == 0, delta
+    if op == "meshex":
+        assert delta.get("mesh_region_chains", 0) == 0, delta
+        assert delta.get("program.mesh_region_chain.launches", 0) == 1
+
+
+def test_one_launcher_keeps_the_six_program_names_apart(rng):
+    """A bare terminal of each kind, a region without a join and a region
+    with one all launch through the one MeshLauncher, each counted under
+    its own program: ``program.<name>.launches`` moves under the six
+    names and under no other ``mesh_*`` one (no plan here serves an
+    exchange's partitions, which ``mesh_exchange_pick`` would count)."""
+    data = _data(rng)
+    left, right, on = _join_sides("direct")
+    s4 = TpuSession(MESH4)
+    joined = s4.from_pydict(left, _JL, partitions=3) \
+        .join(s4.from_pydict(right, _JR), on, "inner") \
+        .group_by("b").agg(Sum(col("rv")).alias("srv"))
+    assert "MeshJoinExec" in _join_region_of(joined).node_desc()
+    dfs = [_bare_q(MESH8, data, op) for op in _BARE] + [joined]
+    b0 = get_registry().snapshot()
+    for df in dfs:
+        assert df.collect()
+    delta = get_registry().delta(b0)["counters"]
+    launched = {k.split(".")[1]: v for k, v in delta.items()
+                if k.startswith("program.mesh_") and k.endswith(".launches")}
+    assert launched == {
+        "mesh_aggregate": 1, "mesh_exchange": 1, "mesh_sort": 1,
+        "mesh_window": 1, "mesh_region_chain": 1, "mesh_region_join": 1}, \
+        launched
+    assert delta.get("mesh_region_chains", 0) == 1, delta

@@ -3,7 +3,9 @@
 Mirrors the reference's transport-mock strategy (RapidsShuffleClientSuite:
 protocol correctness without a network): here the 8-device CPU mesh stands
 in for a TPU slice and results are checked against the single-threaded
-host oracle.
+host oracle.  The programs under test are the engine's own: a bare
+``MeshExchangeExec`` / ``MeshAggregateExec`` over the shards, launched
+through the one ``MeshLauncher`` (exec/mesh_exec.py).
 """
 import jax
 import jax.numpy as jnp
@@ -11,13 +13,13 @@ import numpy as np
 import pytest
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnBatch
+from spark_rapids_tpu.exec.basic import LocalScanExec
+from spark_rapids_tpu.exec.core import ExecCtx
+from spark_rapids_tpu.exec.mesh_exec import (MeshAggregateExec,
+                                             MeshExchangeExec)
+from spark_rapids_tpu.expr.aggregates import Count, CountStar, Max, Min, Sum
+from spark_rapids_tpu.expr.core import col
 from spark_rapids_tpu.host.batch import HostBatch
-from spark_rapids_tpu.ops.segmented import AggSpec
-from spark_rapids_tpu.parallel import (
-    make_mesh, shard_batches, unshard_batch,
-    make_hash_exchange, make_distributed_groupby,
-)
 from spark_rapids_tpu.parallel.mesh_shuffle import partition_ids_for_keys
 
 SCHEMA = T.Schema([
@@ -27,8 +29,8 @@ SCHEMA = T.Schema([
 ])
 
 
-def _make_shards(rng, p=8, n_per=50, cap=64, nkeys=13):
-    shards_h, shards_d = [], []
+def _make_shards(rng, p=8, n_per=50, nkeys=13):
+    shards_h = []
     for _ in range(p):
         k = rng.integers(0, nkeys, n_per).astype(np.int32)
         v = rng.integers(-100, 100, n_per).astype(np.int64)
@@ -39,18 +41,28 @@ def _make_shards(rng, p=8, n_per=50, cap=64, nkeys=13):
             {"k": np.where(kv, k, 0), "v": v, "f": f}, SCHEMA)
         hb.columns[0].validity[:] = kv
         shards_h.append(hb)
-        shards_d.append(hb.to_device(capacity=cap))
-    return shards_h, shards_d
+    return shards_h
+
+
+def _launched(node, ctx):
+    """One batch per device: the outputs a bare terminal's launcher
+    leaves under the terminal's cache key (the exchange's are tagged
+    ``"mesh"``: no slice was lost, nothing degraded to host)."""
+    out = node._outputs(ctx)
+    if isinstance(node, MeshExchangeExec):
+        kind, shards = out
+        assert kind == "mesh"
+        return list(shards)
+    return [b for per_dev in out for b in per_dev]
 
 
 def test_hash_exchange_routes_all_rows(rng):
     p = 8
-    mesh = make_mesh(p)
-    shards_h, shards_d = _make_shards(rng, p=p)
-    stacked = shard_batches(shards_d, mesh)
-    ex = make_hash_exchange(mesh, SCHEMA, [0])
-    out = ex(stacked)
-    outs = [b for b in unshard_batch(out)]
+    shards_h = _make_shards(rng, p=p)
+    ex = MeshExchangeExec([col("k")], LocalScanExec(shards_h, SCHEMA, p), p)
+    with ExecCtx(backend="device") as ctx:
+        outs = _launched(ex, ctx)
+    assert len(outs) == p
     total_in = sum(b.num_rows for b in shards_h)
     total_out = sum(b.host_num_rows() for b in outs)
     assert total_out == total_in
@@ -75,17 +87,17 @@ def test_hash_exchange_routes_all_rows(rng):
 
 def test_distributed_groupby_matches_oracle(rng):
     p = 8
-    mesh = make_mesh(p)
-    shards_h, shards_d = _make_shards(rng, p=p)
-    stacked = shard_batches(shards_d, mesh)
-    specs = [AggSpec("sum", 1), AggSpec("count", 2), AggSpec("min", 1),
-             AggSpec("max", 2)]
-    gb = make_distributed_groupby(mesh, SCHEMA, [0], specs)
-    out = gb(stacked)
-    got = sorted(
-        (r for b in unshard_batch(out)
-         for r in HostBatch.from_device(b).to_rows()),
-        key=lambda r: (r[0] is None, r[0]))
+    shards_h = _make_shards(rng, p=p)
+    gb = MeshAggregateExec(
+        [col("k")],
+        [col("k"), Sum(col("v")).alias("sv"), Count(col("f")).alias("cf"),
+         Min(col("v")).alias("mv"), Max(col("f")).alias("xf")],
+        LocalScanExec(shards_h, SCHEMA, p), p)
+    with ExecCtx(backend="device") as ctx:
+        got = sorted(
+            (r for b in _launched(gb, ctx)
+             for r in HostBatch.from_device(b).to_rows()),
+            key=lambda r: (r[0] is None, r[0]))
 
     # oracle: single-host groupby over the concatenated shards
     big = HostBatch.concat(shards_h)
@@ -114,14 +126,13 @@ def test_distributed_groupby_matches_oracle(rng):
 
 def test_distributed_grand_aggregate(rng):
     p = 8
-    mesh = make_mesh(p)
-    shards_h, shards_d = _make_shards(rng, p=p)
-    stacked = shard_batches(shards_d, mesh)
-    specs = [AggSpec("sum", 1), AggSpec("count_star", 0)]
-    gb = make_distributed_groupby(mesh, SCHEMA, [], specs)
-    out = gb(stacked)
-    rows = [r for b in unshard_batch(out)
-            for r in HostBatch.from_device(b).to_rows()]
+    shards_h = _make_shards(rng, p=p)
+    gb = MeshAggregateExec(
+        [], [Sum(col("v")).alias("sv"), CountStar().alias("n")],
+        LocalScanExec(shards_h, SCHEMA, p), p)
+    with ExecCtx(backend="device") as ctx:
+        rows = [r for b in _launched(gb, ctx)
+                for r in HostBatch.from_device(b).to_rows()]
     assert len(rows) == 1
     big = HostBatch.concat(shards_h)
     vs = big.columns[1]
