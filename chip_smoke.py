@@ -9,6 +9,12 @@ scale of BASELINE.json configs[0] (SF10, single local executor):
     python chip_smoke.py                  # one chip, SF10
     python chip_smoke.py --chips 4        # the mesh path only
 
+On one chip TPC-H Q6 follows at a hundredth of the scale (SF0.1), with
+the rows it keeps counted by discount: its predicates compare float64
+columns with the fractional literals 0.05 and 0.07, which the chip's
+f32-pair arithmetic answered wrongly until PR 46 (every row at exactly
+0.05 dropped); the counts are integers, held to the host oracle's.
+
 The platform is checked FIRST, before any data is generated, so a
 machine whose chip did not come up fails in seconds.  Every earlier
 output line is one JSON fact; the last line is the result
@@ -189,6 +195,9 @@ def _smoke(session, sf, seed, chips, expect_platform, data_dir, t_start,
     _require(_rows_match(rows, want, strict=False),
              "device rows differ from the host oracle")
 
+    if chips == 1:
+        _tpch_q6(session, sf / 100, seed, os.path.join(data_dir, "tpch"))
+
     cache_dir = runtime._enabled_dir
     entries = 0
     if cache_dir and os.path.isdir(cache_dir):
@@ -207,6 +216,49 @@ def _smoke(session, sf, seed, chips, expect_platform, data_dir, t_start,
             "device": {"platform": info["platform"],
                        "kind": info["device_kind"],
                        "count": info["device_count"]}}
+
+
+def _tpch_q6(session, sf: float, seed: int, data_dir: str) -> None:
+    """TPC-H Q6, and the rows its ``where`` clause keeps counted by
+    discount, against the host oracle: the comparisons of a float64
+    column with 0.05 and 0.07 are made on the device."""
+    import datetime
+
+    from spark_rapids_tpu.bench.runner import _collect_rows, _rows_match
+    from spark_rapids_tpu.bench.tpch_gen import generate_tpch
+    from spark_rapids_tpu.bench.tpch_queries import q6
+    from spark_rapids_tpu.expr.aggregates import CountStar
+    from spark_rapids_tpu.expr.core import col, lit
+
+    t0 = time.perf_counter()
+    generate_tpch(data_dir, sf=sf, seed=seed)
+    gen_s = time.perf_counter() - t0
+    revenue = q6(session, data_dir)
+    by_discount = session.read_parquet(
+        os.path.join(data_dir, "lineitem"),
+        columns=["l_discount", "l_shipdate", "l_quantity"]) \
+        .where((col("l_shipdate") >= lit(datetime.date(1994, 1, 1)))
+               & (col("l_shipdate") < lit(datetime.date(1995, 1, 1)))
+               & (col("l_discount") >= lit(0.05))
+               & (col("l_discount") <= lit(0.07))
+               & (col("l_quantity") < lit(24.0))) \
+        .group_by("l_discount").agg(CountStar().alias("lines")) \
+        .order_by(("l_discount", True))
+    for name, df in (("q6", revenue), ("q6 by discount", by_discount)):
+        _require(all(ln.lstrip().startswith("*")
+                     for ln in df.explain().splitlines()),
+                 f"TPC-H {name}: plan holds a host-fallback node")
+        t0 = time.perf_counter()
+        rows = df.collect()
+        wall = time.perf_counter() - t0
+        want = _collect_rows(df, "host")
+        _say(phase="tpch_q6", query=name, sf=sf, gen_s=gen_s, seconds=wall,
+             rows=[list(r) for r in rows], oracle=[list(r) for r in want])
+        _require(_rows_match(rows, want, strict=False),
+                 f"TPC-H {name}: device rows differ from the host oracle")
+    _require([r[0] for r in rows] == [0.05, 0.06, 0.07]
+             and [r[1] for r in rows] == [w[1] for w in want],
+             "TPC-H q6: a discount bound lost or split its rows")
 
 
 def main() -> None:

@@ -117,6 +117,8 @@ class _PackBuilder:
         self.col_specs: list[tuple] = []
         self.dict_gathers = 0               # gathers the unpack will hold
         self.string_bytes = 0               # raw string matrices, bytes
+        # float64 columns, by how they travel (wire.double.*)
+        self.doubles = {"scaled": 0, "raw": 0, "bytes": 0}
 
     def _add_leaf(self, arr: np.ndarray, own_put: bool = False) -> int:
         """Register one host buffer.
@@ -188,6 +190,11 @@ class _PackBuilder:
                             dtype=data.dtype)
             full[:n] = data
             desc = ("raw", self._add_leaf(full))
+        if data.dtype == np.float64:
+            scaled = desc[0] == "fbits"
+            self.doubles["scaled" if scaled else "raw"] += 1
+            self.doubles["bytes"] += \
+                self.capacity * (desc[2] if scaled else 64) // 8
         self.col_specs.append(("fixed", desc, self._val_desc(validity)))
 
     def add_var(self, matrix, lengths: np.ndarray,
@@ -277,6 +284,10 @@ class _PackBuilder:
         # gathers left (two a dictionary too large to select from)
         # scan.stage.string_bytes: bytes of the raw (not dictionary)
         # string matrices among them, at their staged width
+        # wire.double.*: how the float64 columns travel — as scaled
+        # integers the unpack rebuilds into the host's doubles
+        # (wirecodec.rebuild_double), as 8-byte doubles, and the bytes
+        # of both
         get_registry().inc_many((
             ("h2d_calls", len(host_bufs)),
             ("h2d_bytes", sum(b.nbytes for b in host_bufs)),
@@ -284,7 +295,9 @@ class _PackBuilder:
             ("unpack.leaves.static", len(self.leaves) - self.dict_gathers),
             ("unpack.leaves.gather", self.dict_gathers))
             + ((("scan.stage.string_bytes", self.string_bytes),)
-               if self.string_bytes else ()))
+               if self.string_bytes else ())
+            + tuple((f"wire.double.{how}", moved)
+                    for how, moved in self.doubles.items() if moved))
         spec = (self.capacity, gkeys, tuple(self.leaves),
                 tuple(self.col_specs), nr, ip)
         arrays = _packed_unpack_cached(spec)(dev_bufs)

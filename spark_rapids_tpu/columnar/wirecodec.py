@@ -17,9 +17,17 @@ Encodings (chosen per column per batch, host-side, O(n) numpy passes):
   ``_BIT_BUCKETS`` that holds ``bit_length(max - min)``, decode
   ``(bits + min) * div``; an optional integral divisor (1e3/1e6)
   catches second-aligned timestamps.
-* float64 — when exactly representable as scaled integers (money is
-  cents: ``rint(v/s)*s == v`` bitwise for s in {1, 0.01}), ship the
-  FOR/bit-packed integers and decode ``(bits + base) * s``.
+* float64 — when every value is a whole number, or the double nearest
+  a whole number of hundredths (money is cents: ``n / 100.0 == v``
+  bitwise), ship the FOR/bit-packed integers ``n``.  The decode
+  (:func:`rebuild_double`) gives back THE double the host held, in the
+  device's own arithmetic: the chip computes float64 on f32 pairs of
+  about 48 bits, where ``n * 0.01`` is not the pair ``n / 100.0`` turns
+  into for half of all ``n`` (``l_discount >= 0.05`` lost every row at
+  0.05 until PR 46), so hundredths are rebuilt from integers by
+  ``ops/cents.from_cents`` and whole numbers by one conversion.  A
+  column's doubles are the same doubles whichever way a batch of them
+  travelled.
 * strings — pyarrow dictionary encoding when it pays: ship the (small)
   dictionary byte-matrix plus bit-packed indices; decode selects among a
   dictionary of at most ``_DICT_SELECT_MAX_ROWS`` rows and gathers from
@@ -50,13 +58,24 @@ import numpy as np
 
 __all__ = ["encode_fixed", "encode_lengths", "maybe_dict_arrow",
            "pack_bits_host", "decode_data", "decode_dict",
-           "decode_validity", "dict_selects", "bits_needed"]
+           "decode_validity", "dict_selects", "bits_needed",
+           "rebuild_double"]
 
 #: integral divisors probed for int64 columns (timestamp micros that are
 #: second- or milli-aligned shrink below the 32-bit FOR window)
 _INT_DIVISORS = (1_000_000, 1_000)
-#: scales probed for float64 columns (money = cents first, then whole)
-_FLOAT_SCALES = (0.01, 1.0)
+#: what a float64 column may be whole numbers of: ``(per unit, limit)``
+#: — units first (a quantity: fewer bits, one conversion to decode),
+#: then hundredths (money is cents).  A value ``v`` is shipped as the
+#: integer ``n`` only where ``n / per_unit == v`` bit for bit and
+#: ``|n| < limit``, the domain over which :func:`rebuild_double` gives
+#: ``v`` back exactly on the chip (an int64 converts to an f32 pair
+#: exactly below 2^48; ``ops/cents.SUM_LIMIT``)
+_FLOAT_UNITS = ((1, 1 << 48), (100, 1 << 44))
+
+
+#: rows of a float64 column a unit is first tried on
+_PROBE_ROWS = 256
 
 
 #: bit widths are BUCKETED: the unpack program's structure (and the
@@ -211,27 +230,28 @@ def encode_fixed(data: np.ndarray, validity: np.ndarray | None, cap: int,
         zeros = v == 0
         if zeros.any() and np.signbit(v[zeros]).any():
             return raw()
-        for scale in _FLOAT_SCALES:
-            with np.errstate(invalid="ignore", over="ignore"):
-                ints = np.rint(v / scale)
-            if not np.isfinite(ints).all():
-                break  # NaN/inf present: ship raw
-            if not (ints * scale == v).all():
-                continue  # not exactly representable at this scale
-            mm = _valid_minmax(ints, validity)
-            vmin = 0 if mm is None else int(mm[0])
-            vmax = 0 if mm is None else int(mm[1])
-            rng = vmax - vmin
-            if rng >= (1 << 32):
-                continue
-            bits = bits_needed(rng)
-            if bits > 32:
-                continue
-            enc = (ints.astype(np.int64) - vmin).astype(np.uint32)
-            if validity is not None and not validity.all():
-                enc = np.where(validity, enc, 0)
-            return ("fbits", add_leaf(pack_bits_host(enc, bits, cap)),
-                    bits, out_dtype, add_i64(vmin), scale)
+        with np.errstate(invalid="ignore", over="ignore"):
+            for per_unit, limit in _FLOAT_UNITS:
+                # told from its first rows where it is not (a price is
+                # not whole dollars): a failed probe costs no pass
+                head = v[:_PROBE_ROWS]
+                if not (np.rint(head * per_unit) / per_unit == head).all():
+                    continue
+                ints = np.rint(v * per_unit)
+                mm = _valid_minmax(ints, validity)
+                vmin, vmax = (0.0, 0.0) if mm is None else mm
+                if not (np.isfinite(vmin) and np.isfinite(vmax)):
+                    break  # NaN/inf present: ship raw
+                if vmax - vmin >= (1 << 32) or max(-vmin, vmax) >= limit:
+                    continue
+                if not (ints / per_unit == v).all():
+                    continue  # not whole numbers of this unit
+                bits = bits_needed(int(vmax - vmin))
+                enc = (ints - vmin).astype(np.uint32)
+                if validity is not None and not validity.all():
+                    enc = np.where(validity, enc, 0)
+                return ("fbits", add_leaf(pack_bits_host(enc, bits, cap)),
+                        bits, out_dtype, add_i64(int(vmin)), per_unit)
         return raw()
     return raw()
 
@@ -312,10 +332,23 @@ def decode_dict(mat, dlens, idx):
     return data, lens
 
 
+def rebuild_double(xp, raw, base, per_unit: int):
+    """The float64 column of an ``fbits`` leaf: ``raw`` (uint32, the
+    unpacked bits) + ``base`` (int64) whole numbers of ``1 / per_unit``,
+    as the doubles the host held.  ``xp`` as in ``ops/cents.py``
+    (``jax.numpy`` in the unpack program; the tests run the same code
+    under the chip's pair arithmetic, tests/chip_f64.py)."""
+    n = raw.astype(xp.int64) + base
+    if per_unit == 1:
+        return n.astype(xp.float64)
+    from spark_rapids_tpu.ops.cents import from_cents
+    return from_cents(xp, n)
+
+
 def decode_data(desc, leaf, i64p, cap: int):
     """Traced decode of a data/lengths desc to its full-capacity array
     (padding/null slots NOT yet zeroed — the caller masks by validity).
-    Divisors/scales are static program constants; only the FOR base is
+    Divisors/units are static program constants; only the FOR base is
     dynamic (read from the i64 params vector)."""
     import jax.numpy as jnp
     kind = desc[0]
@@ -327,8 +360,7 @@ def decode_data(desc, leaf, i64p, cap: int):
     raw = _unpack_bits_device(leaf(li), cap, bits)
     dt = np.dtype(out_dtype)
     if kind == "fbits":
-        return ((raw.astype(jnp.float64) + i64p[pbase].astype(jnp.float64))
-                * factor).astype(dt.str)
+        return rebuild_double(jnp, raw, i64p[pbase], factor)
     if dt.kind == "b":
         return raw != 0
     val = (raw.astype(jnp.int64) + i64p[pbase]) * factor

@@ -40,6 +40,10 @@ warm launch, exec/compile_cache.py ``SharedJit.__call__``),
 inside the puts of a ``_PackBuilder.build``, columnar/batch.py) and
 ``unpack.leaves.static`` / ``unpack.leaves.gather`` (that build's
 leaves decoded with no gather, and the dictionary gathers left),
+``wire.double.scaled`` / ``wire.double.raw`` / ``wire.double.bytes``
+(that build's float64 columns shipped as scaled integers the device
+rebuilds exactly, those shipped as 8-byte doubles, and the
+host-to-device bytes of both: columnar/wirecodec.py),
 ``d2h_calls`` / ``d2h_bytes`` / ``sync_wait_s`` (host blocked inside a
 fetch), ``scan_backpressure_s`` (scan worker blocked on its full
 queue), and from the other end of that queue ``scan.wait_s`` (the

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chip_f64 import _Int64s, _Pair, _PairXP
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.host.batch import HostBatch
 from spark_rapids_tpu.ops import cents
@@ -82,7 +83,7 @@ def test_group_sums_do_not_hang_on_row_order(groups):
     assert (s1 == s2).all() and (m1 == m2).all()
     total = np.zeros(groups, np.int64)
     np.add.at(total, keys, np.rint(vals * 100).astype(np.int64))
-    assert (s1 == total[k1] * 0.01).all()           # exact, rounded once
+    assert (s1 == total[k1] / 100.0).all()          # exact, rounded once
     # the host oracle sums the same way
     host = hk.host_group_by(
         HostBatch.from_pydict({"k": keys.astype(np.int32), "v": vals},
@@ -97,7 +98,7 @@ def test_group_sums_do_not_hang_on_row_order(groups):
     merged = jax.jit(lambda b: sorted_group_by(b, [0], [AggSpec("sum", 1)]))(
         hb.to_device(capacity=1 << 11))
     ms = np.asarray(merged.columns[1].data)[:int(merged.num_rows)]
-    assert (ms == 2 * total[k1] * 0.01).all()
+    assert (ms == 2 * total[k1] / 100.0).all()
 
 
 def test_a_group_with_one_other_double_sums_as_doubles():
@@ -106,71 +107,11 @@ def test_a_group_with_one_other_double_sums_as_doubles():
     update = jax.jit(lambda b: group_by_update(b, [0], SPECS)[0])
     _, s, _, m = _group_by(keys, vals, update)
     assert s[0] == pytest.approx(0.1 + 0.2 + 1 / 3, rel=1e-15)
-    assert s[1] == 30 * 0.01 and m[1] == 0.15   # not (0.1 + 0.2) / 2
+    assert s[1] == 30 / 100.0 and m[1] == 0.15   # not (0.1 + 0.2) / 2
 
 
 # ------------------------------------------- the chip's f64, on the CPU
-
-class _Pair:
-    """A float64 array as the chip computes on it: an unevaluated sum of
-    two float32 (``hi`` the nearest float32, ``lo`` what is left, about
-    48 bits together; PERF.md Findings PR 23 and 31).  Products are an
-    exact two-product of the high parts plus the cross terms in float32,
-    renormalised; a comparison looks at ``hi``, then at ``lo``."""
-
-    def __init__(self, hi, lo):
-        self.hi = np.asarray(hi, np.float32)
-        self.lo = np.asarray(lo, np.float32)
-
-    @classmethod
-    def of(cls, x):
-        """What a float64 (a literal, a column from HBM) turns into."""
-        x = np.asarray(x, np.float64)
-        hi = x.astype(np.float32)
-        return cls(hi, (x - hi.astype(np.float64)).astype(np.float32))
-
-    def stored(self):
-        """The float64 it is stored as (HBM keeps real float64)."""
-        return self.hi.astype(np.float64) + self.lo.astype(np.float64)
-
-    def __mul__(self, other):
-        other = other if isinstance(other, _Pair) else _Pair.of(other)
-        exact = self.hi.astype(np.float64) * other.hi.astype(np.float64)
-        hi = exact.astype(np.float32)
-        lo = (exact - hi.astype(np.float64)).astype(np.float32)
-        lo = lo + (self.hi * other.lo + self.lo * other.hi)
-        s = hi + lo                                  # fast two-sum
-        return _Pair(s, lo - (s - hi))
-
-    def _cmp(self, other):
-        other = other if isinstance(other, _Pair) else _Pair.of(other)
-        return np.where(self.hi != other.hi, np.sign(self.hi - other.hi),
-                        np.sign(self.lo - other.lo))
-
-    def __gt__(self, other):
-        return self._cmp(other) > 0
-
-    def __ge__(self, other):
-        return self._cmp(other) >= 0
-
-
-class _Int64s:
-    """int64 values whose ``astype(float64)`` is a ``_Pair`` (exact below
-    2^48: the high float32 and the remainder)."""
-
-    def __init__(self, v):
-        self.v = np.asarray(v, np.int64)
-
-    def astype(self, dtype):
-        assert dtype is _PairXP.float64
-        hi = self.v.astype(np.float32)
-        return _Pair(hi, (self.v - hi.astype(np.int64)).astype(np.float32))
-
-
-class _PairXP:
-    """Stands in for ``xp`` where a function only converts and
-    multiplies, as ``cents.from_cents`` does."""
-    float64 = object()
+# (tests/chip_f64.py: the emulator test_wirecodec.py shares)
 
 
 def test_pair_arithmetic_is_the_chips():
@@ -178,21 +119,27 @@ def test_pair_arithmetic_is_the_chips():
     x = np.array([300.0, 0.05, 1 / 3, 499095.24])
     assert np.abs(_Pair.of(x).stored() - x).max() <= 2.0 ** -47 * x.max()
     assert (_Pair.of(np.array([300.0, 1e6])).lo == 0).all()
-    # and TPC-H Q6's loss (PERF.md Findings PR 23): five hundredths
-    # rebuilt as 5 * 0.01 is under the literal 0.05, six are not under
-    # 0.06 — so ``l_discount >= 0.05`` drops the rows at exactly 0.05
-    d = cents.from_cents(_PairXP, _Int64s([4, 5, 6, 7]))
-    assert (d >= 0.05).tolist() == [False, False, True, True]
+    # and TPC-H Q6's loss (PERF.md Findings PR 23), on record so that
+    # nobody restores it: five hundredths rebuilt as ``5 * 0.01`` are
+    # under the literal 0.05, six are not under 0.06 — ``l_discount >=
+    # 0.05`` dropped the rows at exactly 0.05 until PR 46
+    old = _Int64s([4, 5, 6, 7]).astype(_PairXP.float64) * 0.01
+    assert (old >= 0.05).tolist() == [False, False, True, True]
     assert (np.array([4, 5, 6, 7]) * 0.01 >= 0.05).tolist() \
         == [False, True, True, True]                # real float64 keeps them
+    # from_cents builds the pair the host's double turns into
+    d = cents.from_cents(_PairXP, _Int64s([4, 5, 6, 7]))
+    assert (d >= 0.05).tolist() == [False, True, True, True]
+    assert (d <= 0.07).tolist() == [True, True, True, True]
+    assert (d == np.array([4, 5, 6, 7]) / 100.0).all()
 
 
 @pytest.mark.parametrize("scale", [1, 100], ids=["300", "30000"])
 def test_a_sum_at_a_whole_literal_compares_exactly_in_f32_pairs(scale):
     """TPC-H Q18's ``having sum(l_quantity) > 300``: the sum is 30000
-    hundredths given back as ``30000 * 0.01``, and 160 orders of SF1 sum
-    to exactly 300.  In the chip's arithmetic that product is the pair
-    (300.0, 0.0): not greater than the literal, and not less."""
+    hundredths given back as the double ``30000 / 100``, and 160 orders
+    of SF1 sum to exactly 300.  In the chip's arithmetic that double is
+    the pair (300.0, 0.0): not greater than the literal, and not less."""
     c = np.array([29999, 30000, 30001]) * scale
     lit = 300.0 * scale
     x = cents.from_cents(_PairXP, _Int64s(c))
@@ -207,3 +154,22 @@ def test_a_sum_at_a_whole_literal_compares_exactly_in_f32_pairs(scale):
     assert (whole.hi == dollars).all() and (whole.lo == 0).all()
     # and real float64 agrees at the boundary
     assert (cents.from_cents(np, c) > lit).tolist() == [False, False, True]
+
+
+@pytest.mark.parametrize("limit", [200_000, 10**7, 1 << 31, 1 << 44],
+                         ids=["2e5", "1e7", "2^31", "2^44"])
+def test_from_cents_is_the_hosts_double_in_f32_pairs(limit):
+    """``from_cents(n)`` compares equal to ``device_put(n / 100.0)`` in
+    the chip's arithmetic: every hundredth to 200,000, then seeded
+    ``|n| < limit`` up to ``SUM_LIMIT``; and in real float64 (numpy,
+    XLA:CPU) it is that double bit for bit."""
+    rng = np.random.default_rng(limit % 1000)
+    n = np.arange(-limit, limit + 1, dtype=np.int64) if limit == 200_000 \
+        else rng.integers(-limit + 1, limit, 300_000)
+    n[:6] = [0, 5, -5, limit - 1, 1 - limit, 100 << 24][:6]
+    want = n / 100.0
+    assert cents.from_cents(_PairXP, _Int64s(n)).is_the_pair_of(want)
+    assert (cents.from_cents(np, n).view(np.int64)
+            == want.view(np.int64)).all()
+    got = jax.jit(lambda c: cents.from_cents(jnp, c))(jnp.asarray(n))
+    assert (np.asarray(got).view(np.int64) == want.view(np.int64)).all()

@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar import batch as B
 from spark_rapids_tpu.columnar.batch import ColumnBatch
+from spark_rapids_tpu.obs.registry import get_registry
 
 CAP = 1 << 14
 
@@ -92,7 +93,11 @@ def test_query_step_compiles_for_v5e(one_chip):
 
 def test_unpack_decode_compiles_for_v5e(one_chip, monkeypatch):
     """The packed H2D unpack + wire-codec decode program of a scanned
-    batch (nullable int32 keys, an s64, an f64, a dictionary string)."""
+    batch (nullable int32 keys, an s64, a dictionary string, a float64
+    of whole cents and one of whole numbers: both rebuilt into the
+    host's doubles from integers, ``wirecodec.rebuild_double``, by
+    shifts, float32 products and three widenings — no gather, no
+    64-bit division, nothing the chip's compiler refuses)."""
     import pyarrow as pa
     rng = np.random.default_rng(7)
     n = CAP - 100
@@ -104,6 +109,8 @@ def test_unpack_decode_compiles_for_v5e(one_chip, monkeypatch):
         "ss_ticket_number": pa.array(
             rng.integers(1, 1 << 40, n).astype(np.int64)),
         "ss_sales_price": pa.array(np.round(rng.uniform(0, 200, n), 2)),
+        "ss_quantity": pa.array(
+            rng.integers(1, 101, n).astype(np.float64)),
         "ca_state": pa.array(rng.choice(["CA", "TX", "NY", "WA"], n)),
     })
     seen = {}
@@ -117,8 +124,15 @@ def test_unpack_decode_compiles_for_v5e(one_chip, monkeypatch):
             return program(bufs)
         return call
     monkeypatch.setattr(B, "_packed_unpack_cached", spy)
+    before = get_registry().counters()
     ColumnBatch.from_arrow(rb, capacity=CAP, codec=True)
-    _compile(seen["program"].fn, _shapes(seen["bufs"], one_chip))
+    moved = {k: v for k, v in get_registry().counters_since(before).items()
+             if k.startswith("wire.double.")}
+    assert moved == {"wire.double.scaled": 2,
+                     "wire.double.bytes": CAP * (16 + 8) // 8}
+    hlo = _compile(seen["program"].fn,
+                   _shapes(seen["bufs"], one_chip)).as_text()
+    assert " gather(" not in hlo and " divide(" not in hlo
 
 
 def _keyed_batch(n: int, cap: int, seed: int) -> ColumnBatch:

@@ -275,3 +275,10 @@ def test_chip_smoke_cpu_rehearsal(smoke_data, capsys, chips):
     assert [c["compile_count"] for c in collects[1:]] == [0, 0]
     plan = next(f for f in facts if f.get("phase") == "plan")
     assert any("Mesh" in ln for ln in plan["exec"]) is (chips == 4)
+    # one chip: TPC-H Q6 and its kept rows by discount, bounds included
+    tpch = [f for f in facts if f.get("phase") == "tpch_q6"]
+    assert [f["query"] for f in tpch] \
+        == (["q6", "q6 by discount"] if chips == 1 else [])
+    if tpch:
+        assert [r[0] for r in tpch[1]["rows"]] == [0.05, 0.06, 0.07]
+        assert tpch[1]["rows"] == tpch[1]["oracle"]

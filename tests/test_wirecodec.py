@@ -9,6 +9,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from chip_f64 import _Pair, _PairXP, _on_chip
 from spark_rapids_tpu.columnar import wirecodec as wc
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 
@@ -81,9 +82,9 @@ def test_timestamp_micros_divisor():
 def test_money_doubles_cents():
     rng = np.random.default_rng(3)
     cents = rng.integers(0, 3_000_000, size=4096)
-    vals = (cents * 0.01).astype(np.float64)
-    # exactness precondition of the cents path
-    assert (np.rint(vals / 0.01) * 0.01 == vals).all()
+    vals = cents / 100.0
+    # precondition of the cents path: each the double nearest its cents
+    assert (np.rint(vals * 100) / 100 == vals).all()
     arr = pa.array(vals)
     roundtrip(pa.record_batch([arr], names=["price"]))
 
@@ -269,7 +270,7 @@ def test_packed_batch_round_trip(bits, cap, rows, nulls):
         ("fixed", ranged(-1000, 1, np.int32 if bits < 32 else np.int64),
          validity()),
         ("fixed", ranged(1_600_000_000, 1_000_000, np.int64), validity()),
-        ("fixed", ranged(-50, 1, np.int64) * 0.01, validity()),
+        ("fixed", ranged(-50, 1, np.int64) / 100.0, validity()),
         ("fixed", rng.random(n) < 0.5, validity()),
         ("fixed", ranged(10_000, 1, np.int32) if bits < 32
          else rng.integers(-2**31, 2**31, n).astype(np.int32), validity()),
@@ -390,3 +391,99 @@ def test_unpack_program_gathers_only_from_large_dictionaries(
             _padded(mat[idx], cap, v).tobytes()
         np.testing.assert_array_equal(np.asarray(col.lengths),
                                       _padded(lens[idx], cap, v))
+
+
+# ------------------------------ a float64 reaches the chip as itself
+# (tests/chip_f64.py: the chip's float64 is an f32 pair; XLA:CPU's is
+# real, so only the emulator can see what the rebuild gives there)
+
+def _rebuilt_as_the_chip_would(v):
+    """``(desc, pair)``: how ``encode_fixed`` ships the float64 array
+    ``v`` and what ``rebuild_double`` makes of the unpacked bits in the
+    chip's arithmetic (the bits unpacked by the device's own code)."""
+    import jax.numpy as jnp
+    cap = max(2048, 1 << int(np.ceil(np.log2(len(v)))))
+    leaves, params = [], []
+    desc = wc.encode_fixed(v, None, cap,
+                           lambda a: leaves.append(a) or len(leaves) - 1,
+                           lambda b: params.append(b) or len(params) - 1)
+    if desc[0] != "fbits":
+        return desc, None
+    _, li, bits, _, pbase, per_unit = desc
+    raw = np.asarray(wc._unpack_bits_device(
+        jnp.asarray(leaves[li]), cap, bits))[:len(v)]
+    return desc, wc.rebuild_double(_PairXP, _on_chip(raw),
+                                   np.int64(params[pbase]), per_unit)
+
+
+_REBUILDS = {
+    "every hundredth to 200,000":
+        lambda rng: np.arange(0, 200_001, dtype=np.int64),
+    "seeded to 1e7 hundredths":
+        lambda rng: rng.integers(0, 10**7, 100_000),
+    "a negative base": lambda rng: rng.integers(-10**7, -10**6, 100_000),
+    "a base across zero": lambda rng: rng.integers(-37, 4000, 100_000),
+    "a base near -2^43":
+        lambda rng: -(1 << 43) + 5 + rng.integers(0, 1 << 31, 100_000),
+    "a base near 2^44":
+        lambda rng: (1 << 44) - (1 << 32) + rng.integers(0, 1 << 32,
+                                                         100_000),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_REBUILDS))
+def test_rebuilt_hundredths_are_the_hosts_doubles_in_f32_pairs(case):
+    n = _REBUILDS[case](np.random.default_rng(len(case)))
+    v = n / 100.0
+    desc, got = _rebuilt_as_the_chip_would(v)
+    assert desc[0] == "fbits" and desc[5] == 100
+    assert got.is_the_pair_of(v)
+
+
+@pytest.mark.parametrize("per_unit", [1, 100], ids=["units", "hundredths"])
+@pytest.mark.parametrize("bits", wc._BIT_BUCKETS)
+def test_each_width_rebuilds_the_hosts_doubles_in_f32_pairs(bits, per_unit):
+    """Every width the float probe can return, whole numbers and
+    hundredths, a negative base: the pair the chip rebuilds is the pair
+    the host's double turns into."""
+    rng = np.random.default_rng(bits * 31 + per_unit)
+    top = (1 << bits) - 1
+    n = -12_345 + rng.integers(0, top + 1, 50_000, dtype=np.int64)
+    n[0], n[-1] = -12_345, -12_345 + top
+    v = n / float(per_unit)
+    desc, got = _rebuilt_as_the_chip_would(v)
+    assert desc[0] == "fbits" and desc[5] == per_unit and desc[2] == bits
+    assert got.is_the_pair_of(v)
+
+
+def test_the_formula_before_pr_46_fails_at_five_hundredths():
+    """On record, so that nobody restores it: ``(raw + base) * 0.01``
+    in the chip's arithmetic is under the literal 0.05 at five
+    hundredths (TPC-H Q6 lost 27.7 % of its revenue to it) and is not
+    the host's double for about half of all hundredths."""
+    n = np.arange(0, 200_001, dtype=np.int64)
+    f64 = _PairXP.float64
+    old = (_on_chip(n.astype(np.uint32)).astype(f64)
+           + _on_chip(np.zeros(1, np.int64)).astype(f64)) * 0.01
+    assert not (old >= 0.05)[5] and (n / 100.0 >= 0.05)[5]
+    same = np.asarray(old == n / 100.0)
+    assert not same[[5, 6, 7]].any()
+    assert 0.40 < same.mean() < 0.55
+    _, new = _rebuilt_as_the_chip_would(n / 100.0)
+    assert (new >= 0.05)[5] and (new <= 0.07)[7] and (new == n / 100.0).all()
+
+
+@pytest.mark.parametrize("values,why", [
+    ([0.05, 1 / 3], "not whole hundredths"),
+    ([0.05, np.nan], "NaN"), ([0.05, np.inf], "an infinity"),
+    ([0.05, -0.0], "a negative zero"),
+    ([0.0, float(1 << 44)], "hundredths past SUM_LIMIT, not whole"),
+    ([0.5, float(1 << 33) + 0.5], "a range past 32 bits"),
+    ([5 * 0.01 + 2 ** -57, 0.06], "an ulp off the nearest double"),
+], ids=lambda x: x if isinstance(x, str) else None)
+def test_doubles_that_are_not_rebuilt_exactly_travel_raw(values, why):
+    v = np.array(values * 2048)
+    if why.startswith("hundredths past"):
+        v = v + 0.25
+    desc, _ = _rebuilt_as_the_chip_would(v)
+    assert desc[0] == "raw", why
